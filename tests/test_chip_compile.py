@@ -1,0 +1,305 @@
+"""Compile every Pallas entry of the main path for a DESCRIBED TPU v5e.
+
+The TPU compiler is installed in the CPU sandbox and compiles for a chip
+that is described, not attached (``jax.experimental.topologies``).  These
+cases hand each kernel entry its real GPT-2 124M shapes with
+``interpret=False`` and require that it lowers and that the program holds
+a ``tpu_custom_call`` — what interpret-mode equality tests cannot see.  A
+compile that passes is not a run: ``chip_smoke.py`` is the run.
+
+Nothing executes, so arguments are ``ShapeDtypeStruct``s pinned to the
+described device.  The persistent compile cache is switched off around
+the file: an entry written for a described chip cannot be read back
+without one, and the next run would warn.
+"""
+
+from __future__ import annotations
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from trustworthy_dl_tpu.ops import fused_dequant_matmul as dq
+from trustworthy_dl_tpu.ops import fused_stats
+from trustworthy_dl_tpu.ops import paged_attention as pa
+# (``ops.flash_attention`` the attribute is the entry function, which
+# shadows its submodule.)
+from trustworthy_dl_tpu.ops.flash_attention import (
+    _blocks_for,
+    _flash_bwd,
+    _flash_fwd,
+)
+
+# GPT-2 124M widths and the serve CLI's pool defaults (cli.py: 8 slots,
+# block 16, KV in the model dtype = bf16).
+H, DH, D, V = 12, 64, 768, 50257
+SLOTS, BLOCK, MAX_SEQ, CHUNK = 8, 16, 1024, 64
+V_PAD = -(-V // pa.TRUST_TILE) * pa.TRUST_TILE
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """Sharding on one chip of a described v5e 2x2; skip where this
+    jaxlib cannot describe it."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology: {exc}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_compile_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(jitted, dev, *args, **static):
+    """Lower ``jitted`` on shapes pinned to the described chip and
+    compile; the program must contain a Mosaic kernel."""
+    def pin(a):
+        if isinstance(a, jax.ShapeDtypeStruct):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev)
+        return a
+
+    compiled = jitted.lower(*map(pin, args), **static).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def S(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _paged_args(program, kv_dtype, block=BLOCK, head_dim=DH, heads=H,
+                max_seq=MAX_SEQ, q_dtype=jnp.bfloat16):
+    """Argument shapes of ``_paged_attn_call`` (fused decode over every
+    slot) or ``_paged_prefill_call`` (one slot's chunk)."""
+    nbps = max_seq // block
+    pool = S((SLOTS * nbps + 1, heads, block, head_dim), kv_dtype)
+    scale = (S(pool.shape[:3], jnp.float32)
+             if jnp.dtype(kv_dtype) == jnp.int8 else None)
+    if program == "decode":
+        r, t, jmax = SLOTS, pa.QROWS, S((SLOTS,), jnp.int32)
+    else:
+        r, t = 1, CHUNK
+        jmax = S((1, CHUNK // pa.QROWS), jnp.int32)
+    return (S((r, heads, t, head_dim), q_dtype), pool, pool, scale, scale,
+            S((r, nbps), jnp.int32), S((r,), jnp.int32), jmax)
+
+
+_PAGED_CALL = {"decode": pa._paged_attn_call,
+               "prefill": pa._paged_prefill_call}
+
+
+@pytest.mark.parametrize("kv_dtype", [jnp.float32, jnp.bfloat16, jnp.int8],
+                         ids=lambda d: jnp.dtype(d).name)
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_paged_attention_lowers(v5e, program, kv_dtype):
+    """Paged decode and chunked prefill at the CLI's default pool
+    geometry, for every KV storage dtype the CLI offers."""
+    assert pa.supports_paged_attention(
+        head_dim=DH, block_size=BLOCK, kv_dtype=kv_dtype, interpret=False,
+        program=program, n_embd=D)
+    _compile(_PAGED_CALL[program], v5e, *_paged_args(program, kv_dtype),
+             interpret=False)
+
+
+def _flash_forward(dev, dtype):
+    q = S((H, 1024, DH), dtype)
+    bq, bk = _blocks_for(1024)
+    _compile(_flash_fwd, dev, q, q, q, causal=True, bq=bq, bk=bk,
+             interpret=False)
+
+
+def _flash_backward(dev, dtype):
+    q = S((H, 1024, DH), dtype)
+    bq, bk = _blocks_for(1024)
+    _compile(_flash_bwd, dev, q, q, q, q, S((H, 1024), jnp.float32), q,
+             causal=True, bq=bq, bk=bk, interpret=False)
+
+
+def _fused_moments(dev):
+    _compile(fused_stats._fused_tile_moments, dev,
+             S((4 * fused_stats.BLOCK_ROWS, fused_stats.LANES),
+               jnp.float32), interpret=False)
+
+
+def _dequant_matmul(dev):
+    # The decode MLP up-projection: [slots, D] @ int8 [D, 4D].
+    _compile(dq._dq_matmul_pallas, dev, S((SLOTS, D), jnp.float32),
+             S((D, 4 * D), jnp.int8), S((4 * D,), jnp.float32),
+             interpret=False)
+
+
+def _trust_epilogue(dev):
+    _compile(pa._trust_stats_call, dev, S((SLOTS, V_PAD), jnp.float32),
+             interpret=False)
+
+
+def _verify_tail(dev):
+    # spec_k=4 over every slot: 8 x 5 verify rows.
+    _compile(pa._verify_tail_call, dev, S((SLOTS * 5, D), jnp.bfloat16),
+             S((V_PAD, D), jnp.bfloat16), V, interpret=False,
+             round_to="bfloat16")
+
+
+def _adapter_delta(dev, rank, pool_dtype):
+    pages = 5
+    _compile(pa._adapter_delta_call, dev, S((SLOTS, pa.QROWS, D),
+                                            jnp.bfloat16),
+             S((pages, D, rank), pool_dtype),
+             S((pages, rank, D), pool_dtype), S((SLOTS,), jnp.int32),
+             S((SLOTS,), jnp.float32), S((SLOTS,), jnp.float32),
+             interpret=False)
+
+
+@pytest.mark.parametrize("entry", [
+    pytest.param(lambda d: _flash_forward(d, jnp.bfloat16),
+                 id="flash-fwd-bf16"),
+    pytest.param(lambda d: _flash_forward(d, jnp.float32),
+                 id="flash-fwd-f32"),
+    pytest.param(lambda d: _flash_backward(d, jnp.bfloat16),
+                 id="flash-bwd-bf16"),
+    pytest.param(lambda d: _flash_backward(d, jnp.float32),
+                 id="flash-bwd-f32"),
+    pytest.param(_fused_moments, id="fused-moments"),
+    pytest.param(_dequant_matmul, id="dequant-matmul"),
+    pytest.param(_trust_epilogue, id="trust-epilogue"),
+    pytest.param(_verify_tail, id="verify-tail"),
+    pytest.param(lambda d: _adapter_delta(d, 8, jnp.bfloat16),
+                 id="adapter-delta-r8"),
+    pytest.param(lambda d: _adapter_delta(d, 16, jnp.int8),
+                 id="adapter-delta-r16-int8"),
+])
+def test_kernel_entry_lowers(v5e, entry):
+    """The other kernels the trainer and the server reach, at GPT-2 124M
+    shapes: flash forward/backward at T=1024, the detector's moments
+    tile, the int8 dequant-matmul, the trust epilogue, the speculative
+    verify tail and the adapter gather."""
+    entry(v5e)
+
+
+# Geometries off the dtype's sublane, off the 128 lanes, and large: what
+# the predicate admits must lower — the old rule refused most of these.
+_ADMITTED = [
+    ("decode", jnp.bfloat16, 8, 64), ("prefill", jnp.int8, 16, 64),
+    ("decode", jnp.float32, 12, 80), ("prefill", jnp.bfloat16, 32, 128),
+    ("decode", jnp.int8, 4096, 128), ("prefill", jnp.float32, 1024, 256),
+]
+_REFUSED = [
+    ("decode", jnp.float32, 4096, 512), ("prefill", jnp.bfloat16, 8192, 512),
+]
+
+
+def _geometry_id(case):
+    program, dtype, block, head_dim = case
+    return f"{program}-{jnp.dtype(dtype).name}-b{block}-d{head_dim}"
+
+
+@pytest.mark.parametrize("case", _ADMITTED, ids=_geometry_id)
+def test_supported_geometry_lowers(v5e, case):
+    program, kv_dtype, block, head_dim = case
+    assert pa.supports_paged_attention(
+        head_dim=head_dim, block_size=block, kv_dtype=kv_dtype,
+        interpret=False, program=program, n_embd=H * head_dim)
+    _compile(_PAGED_CALL[program], v5e,
+             *_paged_args(program, kv_dtype, block, head_dim,
+                          max_seq=max(MAX_SEQ, block)), interpret=False)
+
+
+@pytest.mark.parametrize("case", _REFUSED, ids=_geometry_id)
+def test_refused_geometry_is_refused_by_both(v5e, case):
+    program, kv_dtype, block, head_dim = case
+    assert not pa.supports_paged_attention(
+        head_dim=head_dim, block_size=block, kv_dtype=kv_dtype,
+        interpret=False, program=program, n_embd=H * head_dim)
+    with pytest.raises(Exception, match="(?i)vmem"):
+        _compile(_PAGED_CALL[program], v5e,
+                 *_paged_args(program, kv_dtype, block, head_dim,
+                              max_seq=block), interpret=False)
+
+
+def test_verify_and_adapter_rules_match_the_compiler(v5e):
+    """The satellite programs' side of the same rule: a head tile past
+    the budget is refused by predicate and compiler alike; small ranks
+    and an ``n_embd`` off the lanes are admitted and lower."""
+    kw = dict(head_dim=DH, block_size=BLOCK, kv_dtype=jnp.bfloat16,
+              interpret=False)
+    assert pa.supports_paged_attention(program="verify", n_embd=100, **kw)
+    _compile(pa._verify_tail_call, v5e, S((8, 100), jnp.float32),
+             S((V_PAD, 100), jnp.float32), V, interpret=False)
+    assert pa.supports_paged_attention(program="adapter", n_embd=D,
+                                       adapter_rank=2, **kw)
+    _adapter_delta(v5e, 2, jnp.float32)
+    assert not pa.supports_paged_attention(program="verify", n_embd=4096,
+                                           **kw)
+    with pytest.raises(Exception, match="(?i)vmem"):
+        _compile(pa._verify_tail_call, v5e, S((8, 4096), jnp.float32),
+                 S((V_PAD, 4096), jnp.float32), V, interpret=False)
+
+
+# --------------------------------------------------------------------------
+# The whole trusted step, for one described chip and for four
+# --------------------------------------------------------------------------
+
+
+@pytest.mark.slow  # ~55 s a case on one core (a whole step compiled)
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_trusted_step_lowers_for_described_chips(monkeypatch, n_devices):
+    """The data-parallel trusted step on a described v5e: one device holds
+    the detector's Mosaic moments kernel; four devices are a GSPMD
+    program, which cannot hold one ("Mosaic kernels cannot be
+    automatically partitioned") — the step must still lower there, on the
+    XLA reductions, with its collectives.  The model is narrow but wide
+    enough for a gradient leaf to reach the kernel's tile."""
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from trustworthy_dl_tpu import DistributedTrainer, TrainingConfig
+    from trustworthy_dl_tpu.core.mesh import DATA_AXIS
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:
+        pytest.skip(f"cannot describe a v5e topology: {exc}")
+    config = TrainingConfig(model_name="gpt2", batch_size=4, num_nodes=4,
+                            parallelism="data", async_host_depth=0)
+    trainer = DistributedTrainer(
+        config, mesh=Mesh(np.array(jax.devices()[:n_devices]), (DATA_AXIS,)),
+        model_overrides=dict(n_layer=1, n_embd=128, n_head=4,
+                             vocab_size=512, n_positions=32, seq_len=32))
+    trainer.initialize()
+    batch = trainer._node_batch(jax.tree_util.tree_map(
+        np.asarray, trainer.model.example_batch(4, jax.random.PRNGKey(0))))
+    tpu_mesh = Mesh(np.array(topo.devices[:n_devices]), (DATA_AXIS,))
+
+    def described(a):
+        spec = (a.sharding.spec if isinstance(a.sharding, NamedSharding)
+                else PartitionSpec())
+        return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                    sharding=NamedSharding(tpu_mesh, spec))
+
+    args = jax.tree_util.tree_map(
+        described, (trainer.state, batch, trainer.attack_plan))
+    # The dispatch predicates ask the backend; the trace is for the chip.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = trainer._train_step.lower(*args).compile().as_text()
+    assert ("tpu_custom_call" in text) == (n_devices == 1)
+    assert ("all-reduce" in text) == (n_devices == 4)

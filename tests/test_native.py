@@ -190,3 +190,25 @@ def test_text_file_byte_tier(tmp_path, monkeypatch):
     wdl = get_dataloader("openwebtext", batch_size=4, seq_len=32,
                          num_examples=16, sampling="windows")
     assert next(iter(wdl))["input"].shape == (4, 32)
+
+
+def test_library_is_keyed_on_the_source_hash(lib_built, tmp_path,
+                                             monkeypatch):
+    """The built library is named after a hash of dataloader.cpp — not
+    trusted by mtime: a copied tree has arbitrary mtimes, and a library
+    built from another source must never be loaded for this one."""
+    import hashlib
+    import os
+
+    with open(native._source_path(), "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    assert os.path.basename(lib_built) == f"libtddl_native.{digest}.so"
+    # An older library lying beside the source is not what gets loaded,
+    # however new its mtime.
+    stale = os.path.join(os.path.dirname(lib_built), "libtddl_native.so")
+    assert lib_built != stale
+    # A changed source names a different library.
+    changed = tmp_path / "dataloader.cpp"
+    changed.write_bytes(open(native._source_path(), "rb").read() + b"\n//x\n")
+    monkeypatch.setattr(native, "_source_path", lambda: str(changed))
+    assert os.path.basename(native._lib_path()) != os.path.basename(lib_built)

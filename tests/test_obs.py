@@ -331,10 +331,13 @@ def test_mfu_from_throughput_names_its_peak_source():
     assert block["mfu"] == pytest.approx(
         6 * 124e6 * 50e3 / 275e12, rel=1e-6
     )
-    fallback = mfu_from_throughput(124_000_000, 50_000, device_kind="???")
-    assert fallback["mfu"] is not None
-    assert "estimate" in fallback["peak_flops_source"] \
-        or "env" in fallback["peak_flops_source"]
+    # Only the CPU test mesh gets a nominal peak; an accelerator that is
+    # not in the table is an error, never a CPU figure under its name.
+    nominal = mfu_from_throughput(124_000_000, 50_000, device_kind="cpu")
+    assert nominal["peak_flops_source"] == "cpu-nominal-estimate"
+    for unknown in ("???", "TPU v9"):
+        with pytest.raises(ValueError, match="no peak FLOP/s"):
+            mfu_from_throughput(124_000_000, 50_000, device_kind=unknown)
 
 
 def test_phase_names_cover_the_issue_contract():

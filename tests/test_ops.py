@@ -94,3 +94,38 @@ def test_fused_moments_under_value_and_grad():
     # The battery itself is constant under differentiation on all paths.
     gm = jax.grad(lambda w: fused_moments(x * w)[0])(1.5)
     assert float(gm) == 0.0
+
+
+def test_partitioned_program_takes_no_mosaic_kernel(monkeypatch):
+    """A compiled Mosaic kernel cannot sit in a program GSPMD partitions,
+    so the default of every kernel dispatch asks how the program being
+    traced will be compiled: ``for_mesh`` over more than one device turns
+    the kernels off for the trace (and only for it); one device keeps
+    them; the env override still wins (interpret-mode tests partition
+    fine)."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from trustworthy_dl_tpu import ops
+    from trustworthy_dl_tpu.models import gpt2
+
+    monkeypatch.delenv("TDDL_FUSED_STATS", raising=False)
+    assert not ops.mosaic_dispatchable()              # CPU backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.mosaic_dispatchable() and ops.pallas_enabled()
+
+    def probe():
+        return (ops.mosaic_dispatchable(), ops.pallas_enabled(),
+                gpt2.auto_picks_flash(gpt2.AUTO_FLASH_MIN_T, 64))
+
+    one = Mesh(np.array(jax.devices()[:1]), ("data",))
+    four = Mesh(np.array(jax.devices()[:4]), ("data",))
+    assert ops.for_mesh(probe, one)() == (True, True, True)
+    assert ops.for_mesh(probe, four)() == (False, False, False)
+    assert probe() == (True, True, True)              # the trace is over
+    # Under jit the wrapper's body runs while tracing — where it matters.
+    seen = []
+    jax.jit(ops.for_mesh(lambda x: seen.append(probe()) or x, four))(1.0)
+    assert seen == [(False, False, False)]
+    monkeypatch.setenv("TDDL_FUSED_STATS", "1")
+    assert ops.for_mesh(ops.pallas_enabled, four)()

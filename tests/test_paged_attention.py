@@ -230,9 +230,8 @@ def test_resolve_and_supports_gate(monkeypatch):
     follows TDDL_PAGED_ATTN (default off-TPU = jnp fallback, the CPU
     container tier's green path); opt-in resolves to interpret off-TPU;
     explicit "pallas" on a non-TPU backend RAISES (the interpreter is
-    not the kernel); compiled tiling rules (per-dtype sublane: f32 8,
-    bf16 16, int8 32) downgrade "auto" loudly and REJECT an explicit
-    ask."""
+    not the kernel); a geometry the predicate refuses downgrades "auto"
+    loudly and REJECTS an explicit ask."""
     monkeypatch.delenv("TDDL_PAGED_ATTN", raising=False)
     kw = dict(head_dim=64, block_size=16, kv_dtype=jnp.float32)
     assert pattn.resolve_attn_impl("jnp", **kw) == "jnp"
@@ -248,29 +247,27 @@ def test_resolve_and_supports_gate(monkeypatch):
     # backend that must fail loudly, not silently serve the interpreter.
     with pytest.raises(ValueError, match="TPU backend"):
         pattn.resolve_attn_impl("pallas", **kw)
-    # Compiled tiling rules: the sublane follows the POOL dtype
-    # (interpret mode has none — the int8 equality pins above run at
-    # block_size 8).
-    assert pattn.kv_sublane(jnp.float32) == 8
-    assert pattn.kv_sublane(jnp.bfloat16) == 16
-    assert pattn.kv_sublane(jnp.int8) == 32
-    assert pattn.supports_paged_attention(
-        head_dim=64, block_size=16, kv_dtype=jnp.float32, interpret=False)
+    # Compiled eligibility is the VMEM rule the TPU compiler enforces
+    # (tests/test_chip_compile.py holds it to the compiler): blocks off
+    # the dtype's sublane lower, blocks past the budget do not.
+    for dtype, block in ((jnp.float32, 12), (jnp.bfloat16, 8),
+                         (jnp.int8, 16)):
+        assert pattn.supports_paged_attention(
+            head_dim=64, block_size=block, kv_dtype=dtype, interpret=False)
     assert not pattn.supports_paged_attention(
-        head_dim=64, block_size=12, kv_dtype=jnp.float32, interpret=False)
-    # bf16 pools need the 16-sublane: block_size 8 must NOT pass.
+        head_dim=512, block_size=4096, kv_dtype=jnp.float32,
+        interpret=False)
+    assert pattn.supports_paged_attention(
+        head_dim=512, block_size=4096, kv_dtype=jnp.float32,
+        interpret=True)
+    # The int8 tier's scale blocks carry every head's plane.
+    assert pattn.supports_paged_attention(
+        head_dim=128, block_size=8192, kv_dtype=jnp.int8, interpret=False)
     assert not pattn.supports_paged_attention(
-        head_dim=64, block_size=8, kv_dtype=jnp.bfloat16, interpret=False)
-    assert pattn.supports_paged_attention(
-        head_dim=64, block_size=16, kv_dtype=jnp.bfloat16, interpret=False)
-    assert pattn.supports_paged_attention(
-        head_dim=64, block_size=32, kv_dtype=jnp.int8, interpret=False)
-    assert not pattn.supports_paged_attention(
-        head_dim=64, block_size=16, kv_dtype=jnp.int8, interpret=False)
-    assert pattn.supports_paged_attention(
-        head_dim=64, block_size=8, kv_dtype=jnp.int8, interpret=True)
+        head_dim=128, block_size=8192, kv_dtype=jnp.int8, interpret=False,
+        n_embd=128 * 128)
     with pytest.raises(ValueError, match="cannot dispatch"):
-        pattn.resolve_attn_impl("interpret", head_dim=1024, block_size=8,
+        pattn.resolve_attn_impl("interpret", head_dim=0, block_size=8,
                                 kv_dtype=jnp.float32)
 
 

@@ -217,21 +217,26 @@ def test_resolve_attn_impls_unconfigured_adapter_is_silent_jnp(caplog):
 
 
 def test_compiled_eligibility_per_program():
-    """The compiled-Mosaic geometry rules the resolver consults:
-    verify needs n_embd % 128, adapter additionally rank % 8 — and
-    interpret mode waives both (how the CPU test tier runs the small
-    geometries above)."""
+    """The compiled-Mosaic rule the resolver consults is VMEM (held to
+    the TPU compiler in tests/test_chip_compile.py): ``n_embd`` off the
+    lanes and ranks below 8 lower; a head tile or a chunk of rows past
+    the block budget does not — and interpret mode waives it (how the
+    CPU test tier runs the small geometries above)."""
     kw = dict(head_dim=64, block_size=16, kv_dtype=jnp.bfloat16)
-    assert pattn.supports_paged_attention(
-        program="verify", interpret=False, n_embd=768, **kw)
+    for n_embd in (768, 100):
+        assert pattn.supports_paged_attention(
+            program="verify", interpret=False, n_embd=n_embd, **kw)
     assert not pattn.supports_paged_attention(
-        program="verify", interpret=False, n_embd=100, **kw)
-    assert pattn.supports_paged_attention(
+        program="verify", interpret=False, n_embd=4096, **kw)
+    assert not pattn.supports_paged_attention(
+        program="verify", interpret=False, **kw)        # n_embd unknown
+    for rank in (8, 6, 1):
+        assert pattn.supports_paged_attention(
+            program="adapter", interpret=False, n_embd=768,
+            adapter_rank=rank, **kw)
+    assert not pattn.supports_paged_attention(
         program="adapter", interpret=False, n_embd=768, adapter_rank=8,
-        **kw)
-    assert not pattn.supports_paged_attention(
-        program="adapter", interpret=False, n_embd=768, adapter_rank=6,
-        **kw)
+        rows=1024, **kw)
     assert not pattn.supports_paged_attention(
         program="adapter", interpret=False, n_embd=768, adapter_rank=0,
         **kw)
@@ -244,14 +249,16 @@ def test_compiled_eligibility_per_program():
 
 
 def test_resolve_attn_impls_partial_downgrade_warns(caplog, monkeypatch):
-    """A geometry that decodes on compiled Mosaic but cannot tile the
-    verify/adapter matmuls downgrades ONLY those programs, loudly."""
+    """A geometry that decodes on compiled Mosaic but whose verify head
+    tile and adapter rows overflow VMEM downgrades ONLY those programs,
+    loudly."""
     monkeypatch.setattr(pattn, "pallas_interpret", lambda: False)
     with caplog.at_level(logging.WARNING,
                          logger="trustworthy_dl_tpu.ops.paged_attention"):
         impls = pattn.resolve_attn_impls(
             "pallas", head_dim=64, block_size=16,
-            kv_dtype=jnp.bfloat16, n_embd=100, adapter_rank=6)
+            kv_dtype=jnp.bfloat16, n_embd=4096, adapter_rank=6,
+            rows=512)
     assert impls["decode"] == "pallas"
     assert impls["prefill"] == "pallas"
     assert impls["verify"] == "jnp"

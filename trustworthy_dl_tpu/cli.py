@@ -53,11 +53,6 @@ def build_parser() -> argparse.ArgumentParser:
                              "step; 0 = fully synchronous (config "
                              "default: 2).  Deterministic chaos drills "
                              "asserting exact retry counts need 0")
-    parser.add_argument("--compile-cache", action="store_true",
-                        help="enable JAX's persistent compilation cache "
-                             "under the run dir (<obs-dir or "
-                             "checkpoint-dir>/jax_cache) so repeat runs "
-                             "skip recompiles of identical SPMD programs")
     # Self-healing supervisor (engine/supervisor.py) + chaos drills.
     parser.add_argument("--supervise", action="store_true",
                         help="wrap training in the self-healing supervisor: "
@@ -104,6 +99,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     from trustworthy_dl_tpu.core.config import TrainingConfig, load_config
     from trustworthy_dl_tpu.data import get_dataloader
     from trustworthy_dl_tpu.engine.trainer import DistributedTrainer
+    from trustworthy_dl_tpu.utils.compile_cache import configure_compile_cache
 
     args = build_parser().parse_args(argv)
     overrides = {
@@ -125,15 +121,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         config = load_config(args.config, **overrides)
     else:
         config = TrainingConfig(**overrides)
-    if args.compile_cache:
-        import dataclasses
-        import os
-
-        run_dir = args.obs_dir or config.checkpoint_dir
-        config = dataclasses.replace(
-            config,
-            compilation_cache_dir=os.path.join(run_dir, "jax_cache"),
-        )
+    configure_compile_cache()
 
     trainer = DistributedTrainer(config)
     trainer.initialize()
@@ -277,6 +265,7 @@ def generate_main(argv: Optional[List[str]] = None,
     from trustworthy_dl_tpu.engine.checkpoint import CheckpointManager
     from trustworthy_dl_tpu.engine.trainer import DistributedTrainer
     from trustworthy_dl_tpu.models.generate import generate
+    from trustworthy_dl_tpu.utils.compile_cache import configure_compile_cache
 
     args = build_generate_parser().parse_args(argv)
     if not args.model.startswith("gpt") or args.model.endswith("-moe"):
@@ -321,6 +310,7 @@ def generate_main(argv: Optional[List[str]] = None,
             print(f"--prompt must be comma-separated token ids, got "
                   f"{args.prompt!r}")
             return 2
+    configure_compile_cache()
     config = TrainingConfig(model_name=args.model, num_nodes=1, batch_size=1,
                             checkpoint_dir=args.checkpoint_dir)
     trainer = DistributedTrainer(config, model_overrides=model_overrides)
@@ -471,13 +461,6 @@ def build_serve_parser() -> argparse.ArgumentParser:
                              "per-page-scaled deltas dequantized in "
                              "register inside the gathered matmul "
                              "(~1/4 the pool bytes at f32 model dtype)")
-    parser.add_argument("--compile-cache", action="store_true",
-                        help="enable JAX's persistent compilation cache "
-                             "under the run dir (<obs-dir or "
-                             "checkpoint-dir>/jax_cache) so repeat "
-                             "serves skip recompiles of the prefill/"
-                             "decode programs (parity with "
-                             "trustworthy-dl-train)")
     parser.add_argument("--obs-dir", type=str, default=None,
                         help="write serving telemetry here: trace.jsonl "
                              "(request lifecycle events + spans "
@@ -602,6 +585,7 @@ def serve_main(argv: Optional[List[str]] = None,
     from trustworthy_dl_tpu.engine.checkpoint import CheckpointManager
     from trustworthy_dl_tpu.engine.trainer import DistributedTrainer
     from trustworthy_dl_tpu.serve import ServeRequest, ServingEngine
+    from trustworthy_dl_tpu.utils.compile_cache import configure_compile_cache
 
     args = build_serve_parser().parse_args(argv)
     if not args.model.startswith("gpt") or args.model.endswith("-moe"):
@@ -630,15 +614,7 @@ def serve_main(argv: Optional[List[str]] = None,
         adapter_pool_pages=args.adapter_pool_pages,
         adapter_dtype=args.adapter_dtype,
     )
-    if args.compile_cache:
-        import os
-
-        from trustworthy_dl_tpu.utils.compile_cache import (
-            enable_persistent_cache,
-        )
-
-        run_dir = args.obs_dir or args.checkpoint_dir
-        enable_persistent_cache(os.path.join(run_dir, "jax_cache"))
+    configure_compile_cache()
     probe = CheckpointManager(args.checkpoint_dir)
     # verified=False: this probe only reads the topology sidecar to
     # refuse pipeline checkpoints — no reason to checksum the whole
@@ -780,6 +756,9 @@ def serve_main(argv: Optional[List[str]] = None,
             value = summary[key]
             shown = f"{value:.3f}" if isinstance(value, float) else value
             print(f"  {key}: {shown}")
+    print("  attn_kernel_paths: " + " ".join(
+        f"{program}={path}"
+        for program, path in summary["attn_kernel_paths"].items()))
     if summary.get("quarantined_slots"):
         print(f"  quarantined slots: {summary['quarantined_slots']}")
     adapters = summary.get("adapters")

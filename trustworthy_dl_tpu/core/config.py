@@ -164,12 +164,6 @@ class TrainingConfig:
     # counts (FaultPlan.predict) must run at depth 0: the lagged guard
     # skips in-place retries.
     async_host_depth: int = 2
-    # Persistent XLA compilation cache (jax_compilation_cache_dir): repeat
-    # runs of identical SPMD programs skip recompiles.  None = off (the
-    # default); set a path (conventionally under the run dir) to enable —
-    # cli.py --compile-cache and bench.py TDDL_BENCH_COMPILE_CACHE=1 wire
-    # it for their run dirs.
-    compilation_cache_dir: Optional[str] = None
     # Epoch-cadence host intelligence — the reference defined these but never
     # called them (SURVEY §7.5: trust_manager.py:333; attack_detector.py:381).
     adaptive_thresholds: bool = True   # trust_manager.adaptive_threshold_adjustment

@@ -19,26 +19,6 @@ from jax.sharding import Mesh, NamedSharding
 
 logger = logging.getLogger(__name__)
 
-
-def shard_map_compat(f, mesh, in_specs, out_specs, check_vma=True):
-    """``jax.shard_map`` across jax versions: new jax exposes it at the top
-    level with a ``check_vma`` flag; older releases (<= 0.4.x, as baked into
-    this container) only have ``jax.experimental.shard_map`` where the same
-    knob is named ``check_rep``.  One shim so every call site is
-    version-agnostic.  The check defaults ON, matching jax's own default —
-    call sites that need it off (the pipeline/sequence rings) say so
-    explicitly."""
-    try:
-        from jax import shard_map as _sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_vma)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_vma=check_vma)
-
-
 # Canonical axis names (SURVEY §7.1).  The reference's "node" maps onto
 # whichever axis the chosen parallelism strategy uses.
 DATA_AXIS = "data"     # data parallel shards

@@ -294,9 +294,6 @@ def evict_and_reshard(trainer, drop: Sequence[int]) -> Dict[str, Any]:
     """Evict mesh coordinates, migrate state, re-jit; returns the measured
     migration record.  ``drop`` holds CURRENT coordinates (the trainer
     translates original ids before calling)."""
-    from trustworthy_dl_tpu.engine.step import build_node_eval_step, \
-        build_train_step
-
     config = trainer.config
     if config.parallelism not in ELASTIC_MODES:
         raise NotImplementedError(
@@ -367,11 +364,7 @@ def evict_and_reshard(trainer, drop: Sequence[int]) -> Dict[str, Any]:
     # per SURVEY §7.4(1)).
     trainer.mesh = new_mesh
     trainer.config = new_config
-    trainer._train_step = jax.jit(
-        build_train_step(trainer.model, new_config, trainer.optimizer),
-        donate_argnums=(0,),
-    )
-    trainer._eval_step = jax.jit(build_node_eval_step(trainer.model))
+    trainer._build_steps()
     trainer.state = new_state
     trainer.attack_plan = trainer._place_plan(
         trainer.attack_plan._replace(
@@ -476,9 +469,6 @@ def readmit_and_reshard(trainer, node_ids: Sequence[int]) -> Dict[str, Any]:
     coordinate re-enters RECOVERING with fresh detector baselines; if it is
     still hostile, the cross-sectional checks (which need no history) and
     the post-warmup batteries evict it again."""
-    from trustworthy_dl_tpu.engine.step import build_node_eval_step, \
-        build_train_step
-
     config = trainer.config
     if config.parallelism not in ELASTIC_MODES:
         raise NotImplementedError(
@@ -534,11 +524,7 @@ def readmit_and_reshard(trainer, node_ids: Sequence[int]) -> Dict[str, Any]:
 
     trainer.mesh = new_mesh
     trainer.config = new_config
-    trainer._train_step = jax.jit(
-        build_train_step(trainer.model, new_config, trainer.optimizer),
-        donate_argnums=(0,),
-    )
-    trainer._eval_step = jax.jit(build_node_eval_step(trainer.model))
+    trainer._build_steps()
     trainer.state = new_state
     trainer.node_map = list(trainer.node_map) + node_ids
     # Rebuild the injection mask from original identities: a readmitted

@@ -100,8 +100,6 @@ def restaff_pipeline(trainer, drop: Sequence[int]) -> Dict[str, Any]:
     the survivors.  ``drop`` holds CURRENT stage coordinates.  Returns the
     migration record (same contract as evict_and_reshard)."""
     from trustworthy_dl_tpu.parallel.pipeline import (
-        build_pipeline_eval_step,
-        build_pipeline_train_step,
         init_canary_state,
         make_canary,
     )
@@ -327,14 +325,7 @@ def restaff_pipeline(trainer, drop: Sequence[int]) -> Dict[str, Any]:
     # --- re-jit + host bookkeeping ---------------------------------------
     trainer.mesh = new_mesh
     trainer.config = new_config
-    trainer._train_step = jax.jit(
-        build_pipeline_train_step(trainer.model, new_config,
-                                  trainer.optimizer, new_mesh),
-        donate_argnums=(0,),
-    )
-    trainer._eval_step = jax.jit(
-        build_pipeline_eval_step(trainer.model, new_config, new_mesh)
-    )
+    trainer._build_steps()
     trainer.state = new_state
     evicted_ids = [trainer.node_map[i] for i in drop]
     idle_ids = sorted(new_pool)

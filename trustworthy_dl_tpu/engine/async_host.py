@@ -1,7 +1,7 @@
 """Bounded in-flight dispatch with lagged host telemetry.
 
 The fused train step keeps detection *inside* the device program (the
-paper's near-zero-overhead claim, BENCH_r02/r03), but the synchronous
+paper's near-zero-overhead claim), but the synchronous
 host loop threw that away: every step ended with a blocking
 ``float(metrics.loss)`` followed by ~10 separate device→host pulls in
 ``_record_batch``, so the accelerator idled through all per-step Python

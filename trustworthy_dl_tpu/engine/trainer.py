@@ -90,12 +90,6 @@ class DistributedTrainer:
         self.training_state = TrainingState.INITIALIZING
         if config.debug_nans:
             enable_nan_debugging()
-        if config.compilation_cache_dir:
-            from trustworthy_dl_tpu.utils.compile_cache import (
-                enable_persistent_cache,
-            )
-
-            enable_persistent_cache(config.compilation_cache_dir)
 
         # Epoch-cadence ML tier, gated once on sklearn availability:
         # without it the refit is a permanent no-op, so the per-step
@@ -137,39 +131,23 @@ class DistributedTrainer:
                 "'expert' mesh axis will carry no sharded computation",
                 self.config.model_name,
             )
-        if config.parallelism == "model":
+        if config.parallelism == "model" and config.num_microbatches == 0:
+            # Auto schedule depth.  Resolve into a COPY: the trainer owns
+            # (and mutates) its config, but the caller's object must stay
+            # pristine — a second trainer built from it (different mesh,
+            # different dp) needs the 0 sentinel intact to re-resolve.
             from trustworthy_dl_tpu.parallel.pipeline import (
-                build_pipeline_eval_step,
-                build_pipeline_train_step,
                 choose_num_microbatches,
             )
 
-            if config.num_microbatches == 0:  # auto schedule depth
-                # Resolve into a COPY: the trainer owns (and mutates) its
-                # config, but the caller's object must stay pristine — a
-                # second trainer built from it (different mesh, different
-                # dp) needs the 0 sentinel intact to re-resolve.
-                config = self.config = dataclasses.replace(
-                    config,
-                    num_microbatches=choose_num_microbatches(
-                        config.batch_size, config.num_nodes,
-                        self.mesh.shape.get(DATA_AXIS, 1),
-                    ),
-                )
-            self._train_step = jax.jit(
-                build_pipeline_train_step(self.model, config, self.optimizer,
-                                          self.mesh),
-                donate_argnums=(0,),
+            self.config = dataclasses.replace(
+                config,
+                num_microbatches=choose_num_microbatches(
+                    config.batch_size, config.num_nodes,
+                    self.mesh.shape.get(DATA_AXIS, 1),
+                ),
             )
-            self._eval_step = jax.jit(
-                build_pipeline_eval_step(self.model, config, self.mesh)
-            )
-        else:
-            self._train_step = jax.jit(
-                build_train_step(self.model, config, self.optimizer),
-                donate_argnums=(0,),
-            )
-            self._eval_step = jax.jit(build_node_eval_step(self.model))
+        self._build_steps()
         self.checkpointer = CheckpointManager(config.checkpoint_dir)
 
         self.state: Optional[TrainState] = None
@@ -182,6 +160,38 @@ class DistributedTrainer:
     # ------------------------------------------------------------------
     # Setup
     # ------------------------------------------------------------------
+
+    def _build_steps(self) -> None:
+        """(Re)jit the train and eval steps for the CURRENT model, config
+        and mesh — the constructor's spelling, shared by every site that
+        changes the topology (elastic eviction and readmission, pipeline
+        restaff, checkpoint topology adoption).  The steps are traced
+        ``for_mesh``: over more than one device GSPMD partitions them, and
+        a partitioned program cannot hold a compiled Mosaic kernel (ops/)."""
+        from trustworthy_dl_tpu.ops import for_mesh
+
+        if self.config.parallelism == "model":
+            from trustworthy_dl_tpu.parallel.pipeline import (
+                build_pipeline_eval_step,
+                build_pipeline_train_step,
+            )
+
+            train = build_pipeline_train_step(self.model, self.config,
+                                              self.optimizer, self.mesh)
+            evaluate = build_pipeline_eval_step(self.model, self.config,
+                                                self.mesh)
+        else:
+            train = build_train_step(self.model, self.config,
+                                     self.optimizer)
+            evaluate = build_node_eval_step(self.model)
+        if self.mesh.size > 1 and jax.default_backend() == "tpu":
+            logger.info(
+                "steps over %d devices are GSPMD-partitioned programs: "
+                "Pallas kernels off, XLA paths in their place",
+                self.mesh.size)
+        self._train_step = jax.jit(for_mesh(train, self.mesh),
+                                   donate_argnums=(0,))
+        self._eval_step = jax.jit(for_mesh(evaluate, self.mesh))
 
     def _init_host_state(self) -> None:
         """Per-run host world-view, shared verbatim by the constructor and
@@ -1538,26 +1548,7 @@ class DistributedTrainer:
                                self.config.mesh_shape, devices=devices,
                                dcn_mesh_shape=self.config.dcn_mesh_shape)
         bind_mode_mesh(self.mesh, self.config.parallelism)
-        if self.config.parallelism == "model":
-            from trustworthy_dl_tpu.parallel.pipeline import (
-                build_pipeline_eval_step,
-                build_pipeline_train_step,
-            )
-
-            self._train_step = jax.jit(
-                build_pipeline_train_step(self.model, self.config,
-                                          self.optimizer, self.mesh),
-                donate_argnums=(0,),
-            )
-            self._eval_step = jax.jit(
-                build_pipeline_eval_step(self.model, self.config, self.mesh)
-            )
-        else:
-            self._train_step = jax.jit(
-                build_train_step(self.model, self.config, self.optimizer),
-                donate_argnums=(0,),
-            )
-            self._eval_step = jax.jit(build_node_eval_step(self.model))
+        self._build_steps()
         self.node_map = [int(i) for i in meta["node_map"]]
         # Any attack plan was shaped for the constructor's node count;
         # injection targets are per-run anyway — reset, caller re-plans.

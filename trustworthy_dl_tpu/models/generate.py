@@ -323,11 +323,10 @@ def _apply_with_cache(params: Params, tokens: jax.Array, cache: KVCache,
                                                 cfg, lks, lvs)
         return x, (lk, lv, lks, lvs)
 
-    # Rolled layer scan: unrolling was measured SLOWER on v5e decode
-    # (1.39 vs 1.24 ms/token b=1) — the rolled body's weight streams
-    # pipeline fine, and the smaller program wins.  The int8 scale
-    # planes (None on the dense path — zero leaves, same program) ride
-    # the same scan.
+    # Rolled layer scan: one compiled block body regardless of depth
+    # (rolled against unrolled decode is not measured — PERF.md).  The
+    # int8 scale planes (None on the dense path — zero leaves, same
+    # program) ride the same scan.
     x, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(
         scan_fn, x,
         (params["blocks"], cache.k, cache.v, cache.k_scale, cache.v_scale),

@@ -49,10 +49,9 @@ class GPT2Config:
     n_head: int = 12
     dtype: Any = jnp.bfloat16
     # full | flash | ring | ulysses | auto.  "auto" (the default) picks
-    # the Pallas flash kernel for T >= AUTO_FLASH_MIN_T — where its
-    # advantage is measured (BASELINE.md: 1.29-2.92× fwd+bwd) — and the
-    # fused XLA path below it (measured faster under block-remat at
-    # short T); the branch resolves at trace time from the static shape,
+    # the Pallas flash kernel for T >= AUTO_FLASH_MIN_T and the fused XLA
+    # path below it (where the threshold belongs is not measured —
+    # PERF.md); the branch resolves at trace time from the static shape,
     # so short-T programs are bit-identical to attn_impl="full".
     attn_impl: str = "auto"
     remat: bool = False
@@ -68,8 +67,8 @@ class GPT2Config:
     # path, an int > 0 forces chunking with that width, and "auto" (the
     # default, mirroring attn_impl) resolves per shape at trace time:
     # chunked only where the materialised logits would pressure HBM
-    # (auto_picks_chunked_ce) — below that the materialised path is
-    # measured faster (BASELINE.md: chunked is −8 % at the default batch).
+    # (auto_picks_chunked_ce) — below that the materialised path, which
+    # does not recompute the head in the backward pass.
     lm_head_chunk: Any = "auto"
 
     @staticmethod
@@ -124,14 +123,16 @@ def auto_picks_flash(t: int, d: int) -> bool:
     """THE attn_impl='auto' dispatch predicate — shared by the attention
     registry AND the remat-policy classifier (apply_blocks), so 'does auto
     resolve to the flash kernel here?' has exactly one answer.  Flash is
-    picked for long sequences (where its advantage is measured,
-    BASELINE.md), only for kernel-eligible shapes, and only on the TPU
-    backend (off-TPU the kernel would run in interpret mode — orders of
-    magnitude slower, correctness-test territory)."""
+    picked for long sequences, only for kernel-eligible shapes, and only
+    where a compiled Mosaic kernel can be dispatched: the TPU backend
+    (off-TPU the kernel would run in interpret mode — orders of
+    magnitude slower, correctness-test territory) and a program GSPMD
+    does not partition (``ops.mosaic_dispatchable``)."""
+    from trustworthy_dl_tpu.ops import mosaic_dispatchable
     from trustworthy_dl_tpu.ops.flash_attention import supports_flash
 
     return (t >= AUTO_FLASH_MIN_T and supports_flash(t, d)
-            and jax.default_backend() == "tpu")
+            and mosaic_dispatchable())
 
 
 def _auto_attention(q, k, v, causal=True):
@@ -147,11 +148,10 @@ def _auto_attention(q, k, v, causal=True):
 
 # lm_head_chunk="auto": chunk width used when the predicate picks the
 # fused path (the bench-swept sweet spot), and the per-node materialised-
-# logits budget above which it engages.  The budget is calibrated on the
-# measured crossover (BASELINE.md): 4 nodes × b16 × T512 × V50257 bf16
-# logits are ~0.82 GiB/node and the materialised path wins by 8 %; at
-# b32/node (~1.65 GiB/node) the materialised program exceeds HBM and only
-# the chunked path runs.  1 GiB/node splits the two.
+# logits budget above which it engages.  By arithmetic, 4 nodes × b16 ×
+# T512 × V50257 bf16 logits are ~0.82 GiB/node and b32/node ~1.65
+# GiB/node; 1 GiB/node splits the two.  Where the crossover really lies
+# on the chip is not measured (PERF.md).
 AUTO_CE_CHUNK = 8192
 AUTO_CE_MAX_LOGITS_BYTES = 1 << 30
 

@@ -17,6 +17,7 @@ routine, so the two tiers can never drift.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -42,16 +43,20 @@ def _source_path() -> str:
 
 
 def _lib_path() -> str:
-    return os.path.join(os.path.dirname(__file__), "libtddl_native.so")
+    """The built library, named after a hash of its source: a copied
+    tree has arbitrary mtimes, and a library left over from another
+    ``dataloader.cpp`` must never be loaded for this one."""
+    with open(_source_path(), "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    return os.path.join(os.path.dirname(__file__),
+                        f"libtddl_native.{digest}.so")
 
 
 def build_library(force: bool = False) -> Optional[str]:
     """Compile dataloader.cpp with g++ (cached next to the source)."""
     out = _lib_path()
     src = _source_path()
-    if not force and os.path.exists(out) and (
-        os.path.getmtime(out) >= os.path.getmtime(src)
-    ):
+    if not force and os.path.exists(out):
         return out
     # Build into a temp file then rename, so a concurrent test runner never
     # dlopens a half-written library.

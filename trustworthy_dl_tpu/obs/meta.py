@@ -8,10 +8,8 @@ hardware that produced it (MLPerf-style run stamping — PAPERS.md).
 writer that does not reference it.
 
 Device discovery is cached per process (``jax.devices()`` initialises
-the backend — call this only where the backend is already expected to be
-live, e.g. bench's watchdogged inner body, never its probe-first parent)
-and degrades to ``platform: "unavailable"`` instead of raising: a
-metadata stamp must never be the reason an artifact is lost.
+the backend).  A backend that cannot start raises here as it would
+anywhere: a stamp never names a platform that was not there.
 """
 
 from __future__ import annotations
@@ -35,39 +33,23 @@ RUN_METADATA_KEYS = (
 @functools.lru_cache(maxsize=1)
 def _device_info() -> Dict[str, Any]:
     """Backend identity, resolved once per process."""
-    try:
-        import jax
+    import jax
 
-        devices = jax.devices()
-        return {
-            "platform": devices[0].platform,
-            "device_kind": getattr(devices[0], "device_kind", "unknown"),
-            "num_devices": len(devices),
-            "jax_version": jax.__version__,
-        }
-    except Exception as exc:  # dead backend must not kill the artifact
-        try:
-            import jax
-
-            jax_version = jax.__version__
-        except Exception:
-            jax_version = "unknown"
-        return {
-            "platform": "unavailable",
-            "device_kind": "unknown",
-            "num_devices": 0,
-            "jax_version": jax_version,
-            "backend_error": f"{type(exc).__name__}: {str(exc)[:120]}",
-        }
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "num_devices": len(devices),
+        "jax_version": jax.__version__,
+    }
 
 
 def run_metadata(host_only: bool = False) -> Dict[str, Any]:
     """The metadata block every published JSON artifact embeds.
 
     ``host_only=True`` skips device discovery entirely (platform
-    ``"unprobed"``) — for writers that must never touch the backend,
-    like bench's probe-first parent emitting a SKIP record while the
-    backend is the very thing that is wedged."""
+    ``"unprobed"``) — for the host-only modules (the verdict store, the
+    incident assembler), which must never import JAX."""
     from trustworthy_dl_tpu import __version__
 
     meta = {

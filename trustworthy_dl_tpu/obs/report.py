@@ -1,10 +1,10 @@
 """Step-time breakdown + MFU/roofline reporter (``obs_report.json``).
 
-VERDICT round 5: GPT-2-medium sits at ~29 % MFU with no artifact
-explaining where the other ~65 % goes.  This module is that artifact's
-producer: named-phase wall-clock accounting on the host step loop, and
-model-FLOPs utilization computed from the model config — attached by
-the trainer (``--obs-dir``), bench.py and the experiment runner.
+A utilization figure needs an artifact saying where the rest of the
+time goes.  This module is that artifact's producer: named-phase
+wall-clock accounting on the host step loop, and model-FLOPs utilization
+computed from the model config — attached by the trainer
+(``--obs-dir``), bench.py and the experiment runner.
 
 Phase semantics (the canonical names in :data:`PHASES`):
 
@@ -26,9 +26,9 @@ Phase semantics (the canonical names in :data:`PHASES`):
 MFU uses the standard ~6 FLOPs/param/token transformer-training
 estimate (fwd 2 + bwd 4; remat recompute not counted, so achieved
 hardware FLOPs are a lower bound) against a per-``device_kind`` peak
-table.  Unknown device kinds fall back to ``TDDL_PEAK_FLOPS_PER_CHIP``
-or a nominal CPU estimate — the figure is always computed, and
-``peak_flops_source`` says how much to trust it.
+table.  A device kind that is not in the table raises; only the CPU
+test mesh gets a nominal figure, named as such in
+``peak_flops_source``.
 """
 
 from __future__ import annotations
@@ -60,21 +60,23 @@ PEAK_FLOPS_BF16 = (
     ("v2", 45e12),
 )
 
-#: Nominal per-core CPU fallback (order-of-magnitude only) so a CPU-mesh
-#: dev run still produces a number instead of a null.
+#: Nominal per-core figure for the CPU test mesh (order of magnitude
+#: only), so a CPU-mesh dev run's report still has its MFU block.
 CPU_NOMINAL_FLOPS = 5e10
 
 
 def peak_flops_per_chip(device_kind: str) -> "tuple[float, str]":
-    """(peak FLOP/s, source) for one chip of ``device_kind``."""
+    """(peak FLOP/s, source) for one chip of ``device_kind``.  An
+    accelerator that is not in the table is an error, not a default."""
     kind = (device_kind or "").lower()
     for token, peak in PEAK_FLOPS_BF16:
         if token in kind:
             return peak, f"bf16-peak-table:{token}"
-    env = os.environ.get("TDDL_PEAK_FLOPS_PER_CHIP")
-    if env:
-        return float(env), "env:TDDL_PEAK_FLOPS_PER_CHIP"
-    return CPU_NOMINAL_FLOPS, "cpu-nominal-estimate"
+    if kind == "cpu":
+        return CPU_NOMINAL_FLOPS, "cpu-nominal-estimate"
+    raise ValueError(
+        f"no peak FLOP/s known for device kind {device_kind!r}; add it "
+        "to PEAK_FLOPS_BF16 with its source")
 
 
 def mfu_from_throughput(n_params: int, tokens_per_s_per_chip: float,

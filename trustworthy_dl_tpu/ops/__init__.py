@@ -26,15 +26,59 @@ All four dispatch through the ONE shared gate below: :func:`pallas_enabled`
 (off-TPU kernels run in Pallas interpret mode — tests only).  The gate
 lives HERE, above the kernel imports, so the kernels can import it from
 the package without a cycle.
+
+A compiled Mosaic kernel cannot be partitioned by GSPMD ("Mosaic kernels
+cannot be automatically partitioned" — the lowering refuses), and the
+trusted step over a multi-device mesh IS a GSPMD program: its per-node
+work is a ``vmap`` whose node axis the compiler shards.  So the default
+also asks whether the program being traced will be partitioned:
+:func:`for_mesh` is how the code that jits a program over a mesh says
+so, and :func:`mosaic_dispatchable` is the answer the dispatch
+predicates read.  A partitioned program takes the XLA spelling of every
+kernel here; one device takes the kernels.
 """
 
+import contextvars
+import functools
 import os
+
+#: True while a program that GSPMD will partition is being traced.
+_PARTITIONED = contextvars.ContextVar("tddl_gspmd_partitioned",
+                                      default=False)
+
+
+def for_mesh(fn, mesh):
+    """``fn``, about to be jitted over ``mesh``, traced knowing whether
+    the compiler will partition it (the mesh holds more than one
+    device).  The wrapper's body runs only while tracing."""
+    partitioned = mesh.size > 1
+
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        token = _PARTITIONED.set(partitioned)
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            _PARTITIONED.reset(token)
+
+    return traced
+
+
+def mosaic_dispatchable() -> bool:
+    """Can the program being traced hold a COMPILED Mosaic kernel: the
+    TPU backend, and not under GSPMD partitioning (see the module
+    docstring).  THE default of every kernel dispatch in this package
+    and of ``models.gpt2.auto_picks_flash``."""
+    import jax
+
+    return jax.default_backend() == "tpu" and not _PARTITIONED.get()
 
 
 def pallas_enabled(env: str = "TDDL_FUSED_STATS") -> bool:
     """THE dispatch gate every Pallas kernel in this package shares:
-    default ON on TPU, opt-out via ``<env>=0`` (and opt-in via ``=1``
-    off-TPU, where the kernel runs in interpret mode — tests only).
+    default ON where :func:`mosaic_dispatchable`, opt-out via
+    ``<env>=0`` (and opt-in via ``=1`` off-TPU, where the kernel runs in
+    interpret mode — tests only).
 
     Env-var map: ``TDDL_FUSED_STATS`` gates fused_stats AND
     dequant_matmul (the int8 decode tier shipped riding the stats gate
@@ -42,15 +86,11 @@ def pallas_enabled(env: str = "TDDL_FUSED_STATS") -> bool:
     ``TDDL_PAGED_ATTN`` gates paged_attention.  The policy is
     deliberately identical everywhere: the jnp/XLA path stays the
     always-available reference semantics, and the CPU container tier
-    never compiles Mosaic.  Measured dispatch notes live with the
-    kernels (e.g. fused_stats: ~20 % step-time win on VGG/ResNet conv
-    gradients, parity on transformer gradients)."""
+    never compiles Mosaic."""
     flag = os.environ.get(env)
     if flag is not None:
         return flag != "0"
-    import jax
-
-    return jax.default_backend() == "tpu"
+    return mosaic_dispatchable()
 
 
 def pallas_interpret() -> bool:
@@ -92,8 +132,10 @@ __all__ = [
     "dequant_matmul",
     "flash_attention",
     "fused_moments",
+    "for_mesh",
     "fused_verify_tail",
     "logit_trust_stats",
+    "mosaic_dispatchable",
     "paged_prefill_attention",
     "pallas_enabled",
     "pallas_interpret",

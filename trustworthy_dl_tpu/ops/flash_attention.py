@@ -61,11 +61,10 @@ def _block_for(t: int) -> int:
 
 
 def _blocks_for(t: int) -> Tuple[int, int]:
-    """(bq, bk) tile sizes, tuned on v5e (BASELINE.md sweep): large tiles
-    win — per-tile bookkeeping and online-softmax rescales amortise, and
-    the K loop (inner, streaming) benefits most, so bk runs up to 1024.
-    (512, 1024) measured 24.6 ms at T=16384 fwd+bwd vs 60.8 ms for the
-    round-2 (256, 256) choice and 77.8 ms for XLA full attention."""
+    """(bq, bk) tile sizes: large tiles — per-tile bookkeeping and
+    online-softmax rescales amortise, and the K loop (inner, streaming)
+    benefits most, so bk runs up to 1024.  The choice against smaller
+    tiles and against XLA full attention is not measured (PERF.md)."""
     bq = _block_for(t)
     if not bq:
         return 0, 0

@@ -26,12 +26,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 # The ONE shared Pallas gate (ops/__init__.py) — re-exported here because
 # this module introduced it and call sites (detect/stats.py, PARITY.md)
-# name it as ``fused_stats.pallas_enabled``.  Measured dispatch policy for
-# THIS kernel: on GPT-2-sized transformer gradients XLA's own fusion of
-# the eight reductions is at parity with the kernel (round 3), but on
-# VGG/ResNet conv gradients XLA emits multiple HBM passes and the
-# kernel's explicit single pass is a ~20 % step-time win with detection
-# on (round 4: VGG-16 48.3 → 57.8 steps/s).
+# name it as ``fused_stats.pallas_enabled``.  Why this kernel: XLA may
+# emit several HBM passes for the eight reductions (seen on conv
+# gradients); the kernel makes the single pass explicit.  Its effect on
+# step time is not measured (PERF.md).
 from trustworthy_dl_tpu.ops import pallas_enabled, pallas_interpret  # noqa: F401
 
 LANES = 128

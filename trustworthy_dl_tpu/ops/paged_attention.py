@@ -111,11 +111,24 @@ from trustworthy_dl_tpu.ops import pallas_enabled, pallas_interpret
 logger = logging.getLogger(__name__)
 
 NEG_INF = -1e30          # finite stand-in: exp(NEG_INF - m) flushes to 0
-MAX_HEAD_DIM = 512       # same Mosaic comfort bound as flash_attention
 #: f32 sublane: the query tile's second-to-minor dim (T) pads up to this.
 QROWS = 8
 #: Vocab tile of the trust epilogue (lanes; V pads up to a multiple).
 TRUST_TILE = 512
+#: Scoped VMEM one Mosaic kernel may use on the chips this targets (v5e:
+#: 16 MiB).  Compiling these kernels for a described v5e, VMEM is the
+#: ONLY thing the compiler refuses: block sizes off the dtype's sublane,
+#: head sizes off the 128 lanes, ``n_embd`` off 128 and adapter ranks
+#: below 8 all lower (Mosaic pads the tile), while a kernel whose
+#: double-buffered operand blocks reach this limit is refused with
+#: RESOURCE_EXHAUSTED — first at 4 x 4 MiB of K/V tiles for the
+#: attention programs and at 2 x 8 MiB of head tile for the verify tail
+#: (tests/test_chip_compile.py keeps both sides of that boundary).
+VMEM_LIMIT_BYTES = 16 << 20
+#: What the eligibility predicate lets the pipelined blocks take: half
+#: the limit, the rest being the kernel's own scratch and temporaries
+#: (the f32 upcast of a K/V tile, the score tile).
+VMEM_BLOCK_BUDGET = VMEM_LIMIT_BYTES // 2
 
 #: Engine-facing path names.  "auto" resolves through the shared gate;
 #: the resolved value is one of the other three.
@@ -127,69 +140,90 @@ ATTN_IMPLS = ("auto", "pallas", "interpret", "jnp")
 PAGED_PROGRAMS = ("decode", "prefill", "verify", "adapter")
 
 
-def kv_sublane(kv_dtype) -> int:
-    """Mosaic sublane width for a compiled KV tile of ``kv_dtype``: the
-    second-to-minor dim must be a multiple of 32/itemsize — 8 for f32,
-    16 for bf16, 32 for int8 (= quant.int8.INT8_SUBLANE)."""
-    import numpy as np
+def _tile_bytes(rows: int, cols: int, dtype) -> int:
+    """VMEM bytes of one [rows, cols] block of ``dtype`` as Mosaic lays
+    it out: rows pad to the dtype's sublane (32 bytes' worth: 8 f32,
+    16 bf16, 32 int8), cols to the 128 lanes."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sublane = max(QROWS, 32 // itemsize)
+    return (-(-rows // sublane) * sublane) * (-(-cols // 128) * 128) \
+        * itemsize
 
-    return max(QROWS, 32 // np.dtype(kv_dtype).itemsize)
+
+def _pipelined_block_bytes(program: str, *, head_dim: int,
+                           block_size: int, kv_dtype,
+                           n_embd: Optional[int],
+                           adapter_rank: Optional[int],
+                           rows: int) -> int:
+    """Double-buffered bytes of the operand and output blocks one grid
+    step of ``program`` keeps in VMEM — the quantity the compiler's
+    refusal is about.  Activations count as f32 (the widest the engine
+    feeds); ``rows`` is the query rows of one call."""
+    f32 = jnp.float32
+    if program in ("decode", "prefill"):
+        blocks = 2 * _tile_bytes(QROWS, head_dim, f32)           # q, out
+        blocks += 2 * _tile_bytes(block_size, head_dim, kv_dtype)
+        if jnp.dtype(kv_dtype) == jnp.int8:
+            # The scale blocks carry every head's plane (see
+            # _paged_attn_call); without n_embd the head count is
+            # unknown and only the K/V tiles are counted.
+            heads = n_embd // head_dim if n_embd else 0
+            blocks += 2 * _tile_bytes(heads, block_size, f32)
+    elif program == "verify":
+        blocks = (_tile_bytes(rows, n_embd, f32)
+                  + _tile_bytes(TRUST_TILE, n_embd, f32)
+                  + _tile_bytes(rows, TRUST_TILE, f32))
+    else:
+        blocks = (2 * _tile_bytes(rows, n_embd, f32)            # x, out
+                  + _tile_bytes(n_embd, adapter_rank, f32)
+                  + _tile_bytes(adapter_rank, n_embd, f32))
+    return 2 * blocks
 
 
 def supports_paged_attention(*, head_dim: int, block_size: int,
                              kv_dtype, interpret: bool,
                              program: str = "decode",
                              n_embd: Optional[int] = None,
-                             adapter_rank: Optional[int] = None) -> bool:
+                             adapter_rank: Optional[int] = None,
+                             rows: int = QROWS) -> bool:
     """THE kernel-eligibility predicate (the ``supports_flash`` pattern),
-    now PER PROGRAM: every dispatch site must consult it so the fallback
-    condition can never drift from a kernel's real constraints.
+    PER PROGRAM: every dispatch site must consult it so the fallback
+    condition can never drift from a kernel's real constraints.  True
+    means the program lowers for the chip; tests/test_chip_compile.py
+    holds it to that against the TPU compiler.
 
-    ``"decode"`` / ``"prefill"`` (the attention programs): compiled
-    Mosaic needs the KV tile's sublane (= pool ``block_size``) to be a
-    multiple of :func:`kv_sublane` for the POOL's storage dtype (8 f32,
-    16 bf16, 32 int8), and ``head_dim <= MAX_HEAD_DIM``.  The prefill
-    program's query tiles add no constraint beyond the decode program's
-    (its T dim pads to the same :data:`QROWS` sublane).
+    What the compiler refuses is VMEM (see :data:`VMEM_LIMIT_BYTES`), so
+    compiled eligibility is one rule for all four programs: the
+    double-buffered blocks of a grid step (:func:`_pipelined_block_bytes`)
+    fit :data:`VMEM_BLOCK_BUDGET`.  For the attention programs that
+    bounds ``block_size x head_dim`` in the POOL's storage dtype; for the
+    verify tail ``n_embd`` (the [TRUST_TILE, n_embd] head tile); for the
+    adapter gather ``rows x n_embd`` (``rows`` = the most query rows one
+    call carries, the prefill chunk).  ``verify`` and ``adapter`` need
+    ``n_embd``; ``adapter`` a positive ``adapter_rank``.
 
-    ``"verify"`` (the fused logits + trust tail): the head matmul's
-    contraction dim is ``n_embd`` — compiled Mosaic wants it a multiple
-    of the 128-lane width (true for every real GPT-2 geometry; tiny
-    test configs run interpret).
-
-    ``"adapter"`` (the in-grid low-rank gather): the delta contraction's
-    minor dim is the adapter rank — compiled eligibility conservatively
-    requires ``rank % QROWS == 0`` plus the verify rule on ``n_embd``
-    (small-rank Mosaic tiling is unvalidated until a healthy TPU round —
-    ROADMAP items 3/4); ranks below that downgrade loudly to the
-    gathered jnp path.
-
-    Interpret mode (CPU tests) has no tiling rules — only sanity bounds
-    — so the equality pins run at the small geometries the test pools
+    Interpret mode (CPU tests) has no such limit — only sanity bounds —
+    so the equality pins run at the small geometries the test pools
     use."""
     if program not in PAGED_PROGRAMS:
         raise ValueError(
             f"program must be one of {PAGED_PROGRAMS}, got {program!r}")
-    if head_dim < 1 or block_size < 1 or head_dim > MAX_HEAD_DIM:
+    if head_dim < 1 or block_size < 1:
         return False
-    if program == "verify":
-        if interpret:
-            return True
-        return n_embd is not None and n_embd % 128 == 0
-    if program == "adapter":
-        if adapter_rank is None or adapter_rank < 1:
-            return False
-        if interpret:
-            return True
-        return (adapter_rank % QROWS == 0
-                and n_embd is not None and n_embd % 128 == 0)
+    if program == "adapter" and (adapter_rank is None or adapter_rank < 1):
+        return False
     if interpret:
         return True
-    return block_size % kv_sublane(kv_dtype) == 0
+    if program in ("verify", "adapter") and not n_embd:
+        return False
+    return _pipelined_block_bytes(
+        program, head_dim=head_dim, block_size=block_size,
+        kv_dtype=kv_dtype, n_embd=n_embd, adapter_rank=adapter_rank,
+        rows=rows) <= VMEM_BLOCK_BUDGET
 
 
 def resolve_attn_impl(requested: str, *, head_dim: int, block_size: int,
-                      kv_dtype) -> str:
+                      kv_dtype, n_embd: Optional[int] = None) -> str:
     """Resolve the engine's ``attn_impl`` knob ONCE, at construction —
     never inside a traced program — to the path its compiled programs
     will bake in: ``"pallas"`` (compiled Mosaic, TPU), ``"interpret"``
@@ -199,7 +233,7 @@ def resolve_attn_impl(requested: str, *, head_dim: int, block_size: int,
 
     ``"auto"`` consults the shared ``pallas_enabled("TDDL_PAGED_ATTN")``
     gate and downgrades to "jnp" with a loud warning when the geometry
-    cannot tile (a silent fallback must at least log; the serve snapshot
+    overflows VMEM (a silent fallback must at least log; the serve snapshot
     gauge + the sentinel's decode-tick fraction page the rest).  An
     explicit ``"pallas"`` that cannot dispatch COMPILED Mosaic raises —
     the operator asked for the kernel by name, and that includes a
@@ -223,14 +257,13 @@ def resolve_attn_impl(requested: str, *, head_dim: int, block_size: int,
     mode = "interpret" if (requested == "interpret"
                            or pallas_interpret()) else "pallas"
     if supports_paged_attention(head_dim=head_dim, block_size=block_size,
-                                kv_dtype=kv_dtype,
+                                kv_dtype=kv_dtype, n_embd=n_embd,
                                 interpret=(mode == "interpret")):
         return mode
     detail = (
         f"head_dim={head_dim}, block_size={block_size}, "
-        f"kv_dtype={kv_dtype}: compiled Mosaic needs block_size % "
-        f"{kv_sublane(kv_dtype)} (the dtype's sublane) == 0 "
-        f"and head_dim <= {MAX_HEAD_DIM}"
+        f"kv_dtype={kv_dtype}: the double-buffered K/V blocks must fit "
+        f"{VMEM_BLOCK_BUDGET >> 20} MiB of VMEM"
     )
     if requested in ("pallas", "interpret"):
         raise ValueError(
@@ -247,7 +280,8 @@ def resolve_attn_impl(requested: str, *, head_dim: int, block_size: int,
 
 def resolve_attn_impls(requested: str, *, head_dim: int, block_size: int,
                        kv_dtype, n_embd: int,
-                       adapter_rank: Optional[int] = None) -> dict:
+                       adapter_rank: Optional[int] = None,
+                       rows: int = QROWS) -> dict:
     """Resolve the WHOLE serving-kernel tier at construction: one impl
     per program in :data:`PAGED_PROGRAMS`.
 
@@ -256,13 +290,16 @@ def resolve_attn_impls(requested: str, *, head_dim: int, block_size: int,
     — prefill, verify, adapter — inherit the decode resolution where
     their geometry is eligible and DOWNGRADE LOUDLY to ``"jnp"`` where
     it is not, even under an explicit ask: a pool that can decode but
-    whose ``n_embd`` cannot tile the verify matmul must still serve,
-    and the per-program gauge + the sentinel fractions page the
+    whose ``n_embd`` overflows the verify tail's head tile must still
+    serve, and the per-program gauge + the sentinel fractions page the
     downgrade rather than an exception unwinding the engine.  An
     unconfigured adapter tier (``adapter_rank`` falsy) resolves its
-    program to ``"jnp"`` silently — there is nothing to fuse."""
+    program to ``"jnp"`` silently — there is nothing to fuse.  ``rows``
+    is the most query rows one call of a program carries (the prefill
+    chunk, or the verify window over every slot)."""
     decode = resolve_attn_impl(requested, head_dim=head_dim,
-                               block_size=block_size, kv_dtype=kv_dtype)
+                               block_size=block_size, kv_dtype=kv_dtype,
+                               n_embd=n_embd)
     impls = {p: "jnp" for p in PAGED_PROGRAMS}
     impls["decode"] = decode
     if decode == "jnp":
@@ -274,14 +311,14 @@ def resolve_attn_impls(requested: str, *, head_dim: int, block_size: int,
         if supports_paged_attention(
                 head_dim=head_dim, block_size=block_size,
                 kv_dtype=kv_dtype, interpret=interp, program=program,
-                n_embd=n_embd, adapter_rank=adapter_rank):
+                n_embd=n_embd, adapter_rank=adapter_rank, rows=rows):
             impls[program] = decode
         else:
             logger.warning(
-                "paged %s program cannot dispatch compiled Mosaic for "
-                "this geometry (n_embd=%s, adapter_rank=%s); that "
+                "paged %s program's blocks overflow VMEM for this "
+                "geometry (n_embd=%s, adapter_rank=%s, rows=%s); that "
                 "program falls back to jnp — expect its sentinel "
-                "fraction to page", program, n_embd, adapter_rank,
+                "fraction to page", program, n_embd, adapter_rank, rows,
             )
     return impls
 
@@ -300,16 +337,22 @@ def _dot(a: jax.Array, b: jax.Array, trans_b: bool = False) -> jax.Array:
 
 
 def _paged_attn_kernel(table_ref, start_ref, jmax_ref, q_ref, k_ref, v_ref,
-                       ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                       scale: float, bsz: int, tq: int, quantized: bool):
+                       *rest, scale: float, bsz: int, tq: int,
+                       quantized: bool):
     """One (row, head, logical-block) grid step of the online softmax.
 
     Scalar-prefetch refs: ``table_ref`` i32[R, NBPS] (physical ids —
     also consumed by the index maps, which is what makes the gather part
     of the DMA pipeline), ``start_ref`` i32[R] (first query's absolute
     position) and ``jmax_ref`` i32[R] (the row's last useful logical
-    block — the ragged early-exit bound)."""
+    block — the ragged early-exit bound).  ``rest`` is ``(ks_ref,
+    vs_ref, o_ref, acc_ref, m_ref, l_ref)`` on the int8 tier and the
+    last four otherwise: the scale operands exist only when there are
+    scales."""
+    ks_ref, vs_ref = rest[:2] if quantized else (None, None)
+    o_ref, acc_ref, m_ref, l_ref = rest[-4:]
     r = pl.program_id(0)
+    hd = pl.program_id(1)
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -330,7 +373,7 @@ def _paged_attn_kernel(table_ref, start_ref, jmax_ref, q_ref, k_ref, v_ref,
             # Dh axis, so it multiplies the int8 score AFTER the dot —
             # the same algebra models/generate._block_with_cache applies
             # to the gathered view.
-            s = s * ks_ref[0, 0][None, :]
+            s = s * ks_ref[0, pl.ds(hd, 1), :]
         # Causal + ragged mask in absolute positions: query start+t sees
         # cache slots [0, start+t]; everything past the row's true length
         # (garbage in the final block, trash-block padding) is masked.
@@ -349,7 +392,7 @@ def _paged_attn_kernel(table_ref, start_ref, jmax_ref, q_ref, k_ref, v_ref,
         if quantized:
             # V scale folds into the probabilities before the PV
             # contraction — again the gathered-view algebra, in-register.
-            p = p * vs_ref[0, 0][None, :]
+            p = p * vs_ref[0, pl.ds(hd, 1), :]
         v = v_ref[0, 0].astype(jnp.float32)              # [bsz, Dh]
         acc_ref[:] = acc_ref[:] * corr + _dot(p, v)
         m_ref[:] = jnp.broadcast_to(m_cur, m_ref.shape)
@@ -389,7 +432,7 @@ def _paged_attn_call(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
         return (tbl[ri, jnp.minimum(ji, jm[ri])], hi, 0, 0)
 
     def scale_idx(ri, hi, ji, tbl, st, jm):
-        return (tbl[ri, jnp.minimum(ji, jm[ri])], hi, 0)
+        return (tbl[ri, jnp.minimum(ji, jm[ri])], 0, 0)
 
     def q_idx(ri, hi, ji, tbl, st, jm):
         return (ri, hi, 0, 0)
@@ -401,20 +444,15 @@ def _paged_attn_call(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     ]
     operands = [q, pool_k, pool_v]
     if quantized:
+        # Mosaic tiles the last two dims, so a (1, bsz) window over the
+        # [H, BLOCK] scale plane does not lower: the block carries every
+        # head's scales for the physical block and the kernel picks its
+        # head's sublane.
         in_specs += [
-            pl.BlockSpec((1, 1, bsz), scale_idx),
-            pl.BlockSpec((1, 1, bsz), scale_idx),
+            pl.BlockSpec((1, h, bsz), scale_idx),
+            pl.BlockSpec((1, h, bsz), scale_idx),
         ]
         operands += [k_scale, v_scale]
-    else:
-        # Arity filler for the unquantized trace: the kernel never reads
-        # ks_ref/vs_ref when ``quantized`` is static-False; feeding the
-        # (already-resident) table keeps one kernel body for both tiers.
-        in_specs += [
-            pl.BlockSpec((1, nbps), lambda ri, hi, ji, tbl, st, jm: (0, 0)),
-            pl.BlockSpec((1, nbps), lambda ri, hi, ji, tbl, st, jm: (0, 0)),
-        ]
-        operands += [table, table]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(r, h, nbps),
@@ -524,8 +562,7 @@ def paged_attention_reference(q: jax.Array, pool_k: jax.Array,
 
 
 def _paged_prefill_kernel(table_ref, start_ref, jmax_ref, q_ref, k_ref,
-                          v_ref, ks_ref, vs_ref, o_ref, acc_ref, m_ref,
-                          l_ref, *, scale: float, bsz: int, qt: int,
+                          v_ref, *rest, scale: float, bsz: int, qt: int,
                           quantized: bool):
     """One (row, head, query-tile, logical-block) grid step.
 
@@ -535,8 +572,11 @@ def _paged_prefill_kernel(table_ref, start_ref, jmax_ref, q_ref, k_ref,
     its own last query position, so an early tile of a long chunk
     streams a fraction of the blocks the whole chunk touches — the
     decode program's single per-row bound would stream (and mask) them
-    all, for every tile."""
+    all, for every tile.  ``rest`` as in :func:`_paged_attn_kernel`."""
+    ks_ref, vs_ref = rest[:2] if quantized else (None, None)
+    o_ref, acc_ref, m_ref, l_ref = rest[-4:]
     r = pl.program_id(0)
+    hd = pl.program_id(1)
     ti = pl.program_id(2)
     j = pl.program_id(3)
 
@@ -554,7 +594,7 @@ def _paged_prefill_kernel(table_ref, start_ref, jmax_ref, q_ref, k_ref,
         k = k_ref[0, 0].astype(jnp.float32)              # [bsz, Dh]
         s = _dot(q, k, trans_b=True) * scale             # [qt, bsz] f32
         if quantized:
-            s = s * ks_ref[0, 0][None, :]
+            s = s * ks_ref[0, pl.ds(hd, 1), :]
         # Causal + ragged mask in absolute positions: the tile's queries
         # sit at start + ti·qt + t.
         kpos = j * bsz + jax.lax.broadcasted_iota(jnp.int32, (qt, bsz), 1)
@@ -570,7 +610,7 @@ def _paged_prefill_kernel(table_ref, start_ref, jmax_ref, q_ref, k_ref,
             l_ref.shape,
         )
         if quantized:
-            p = p * vs_ref[0, 0][None, :]
+            p = p * vs_ref[0, pl.ds(hd, 1), :]
         v = v_ref[0, 0].astype(jnp.float32)              # [bsz, Dh]
         acc_ref[:] = acc_ref[:] * corr + _dot(p, v)
         m_ref[:] = jnp.broadcast_to(m_cur, m_ref.shape)
@@ -610,7 +650,7 @@ def _paged_prefill_call(q: jax.Array, pool_k: jax.Array,
         return (tbl[ri, jnp.minimum(ji, jm[ri, ti])], hi, 0, 0)
 
     def scale_idx(ri, hi, ti, ji, tbl, st, jm):
-        return (tbl[ri, jnp.minimum(ji, jm[ri, ti])], hi, 0)
+        return (tbl[ri, jnp.minimum(ji, jm[ri, ti])], 0, 0)
 
     def q_idx(ri, hi, ti, ji, tbl, st, jm):
         return (ri, hi, ti, 0)
@@ -623,18 +663,10 @@ def _paged_prefill_call(q: jax.Array, pool_k: jax.Array,
     operands = [q, pool_k, pool_v]
     if quantized:
         in_specs += [
-            pl.BlockSpec((1, 1, bsz), scale_idx),
-            pl.BlockSpec((1, 1, bsz), scale_idx),
+            pl.BlockSpec((1, h, bsz), scale_idx),
+            pl.BlockSpec((1, h, bsz), scale_idx),
         ]
         operands += [k_scale, v_scale]
-    else:
-        in_specs += [
-            pl.BlockSpec((1, nbps),
-                         lambda ri, hi, ti, ji, tbl, st, jm: (0, 0)),
-            pl.BlockSpec((1, nbps),
-                         lambda ri, hi, ti, ji, tbl, st, jm: (0, 0)),
-        ]
-        operands += [table, table]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(r, h, nt, nbps),
@@ -1054,7 +1086,6 @@ def adapter_delta(x: jax.Array, a_pool: jax.Array, b_pool: jax.Array,
 
 __all__ = [
     "ATTN_IMPLS",
-    "MAX_HEAD_DIM",
     "PAGED_PROGRAMS",
     "adapter_delta",
     "fused_verify_tail",

@@ -41,8 +41,7 @@ from trustworthy_dl_tpu.core import sharding as shreg
 from trustworthy_dl_tpu.attacks.adversarial import AttackPlan, \
     corrupt_stage_compute, poison_gradients
 from trustworthy_dl_tpu.core.config import TrainingConfig
-from trustworthy_dl_tpu.core.mesh import DATA_AXIS, STAGE_AXIS, \
-    shard_map_compat as shard_map
+from trustworthy_dl_tpu.core.mesh import DATA_AXIS, STAGE_AXIS
 from trustworthy_dl_tpu.detect import baseline as bl
 from trustworthy_dl_tpu.detect import stats as st
 from trustworthy_dl_tpu.detect.detector import AttackType, anomaly_verdicts
@@ -249,7 +248,7 @@ def build_pipeline_apply(
         outputs = jax.lax.psum(outputs, STAGE_AXIS)
         return outputs, stage_stats, act_mean, act_std
 
-    pipe = shard_map(
+    pipe = jax.shard_map(
         pipe_local,
         mesh=mesh,
         # mb (dim 1 of x_mb / outputs) shards over the DP replica rows; on

@@ -33,8 +33,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from trustworthy_dl_tpu.core import sharding as shreg
-from trustworthy_dl_tpu.core.mesh import SEQ_AXIS, \
-    shard_map_compat as shard_map
+from trustworthy_dl_tpu.core.mesh import SEQ_AXIS
 
 #: Registry rules for this mode: the Ulysses exchange is exactly the
 #: head<->seqlen logical rename the table encodes (both map onto the
@@ -249,7 +248,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if q.shape[2] % ring_size:
         return full_attention(q, k, v, causal)
     spec = _SP_RULES.partition_spec(None, None, shreg.SEQLEN, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda q_, k_, v_: _ring_attention_local(q_, k_, v_, causal, ring_size),
         mesh=mesh,
         in_specs=(spec, spec, spec),

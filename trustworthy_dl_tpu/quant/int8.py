@@ -55,15 +55,6 @@ WEIGHT_DTYPES = ("model", "int8")
 #: [-127, 127]; -128 is never emitted so the range stays symmetric).
 QMAX = 127.0
 
-#: Mosaic int8 sublane width: a compiled int8 VMEM tile's second-to-minor
-#: dim must be a multiple of 32 (= 32/itemsize; f32 needs 8, bf16 16 —
-#: ``ops.paged_attention.kv_sublane`` is the per-dtype rule).  The
-#: paged-attention eligibility gate reads that rule — an int8 KV pool
-#: streams its [BLOCK, Dh] tiles through the kernel only when
-#: ``block_size`` tiles, otherwise serving falls back (loudly) to the
-#: jnp gather path.
-INT8_SUBLANE = 32
-
 
 def validate_dtypes(kv_dtype: str, weight_dtype: str) -> None:
     """Loud construction-time validation — an unknown dtype string must

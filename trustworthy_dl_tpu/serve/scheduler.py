@@ -836,11 +836,11 @@ class PagedBatchingScheduler:
         # "pallas" (compiled Mosaic kernels, TPU), "interpret" (same
         # kernels through the Pallas interpreter — tests), or "jnp"
         # (the gather/materialise fallbacks, the default wherever the
-        # gate is off or the geometry cannot tile).  One dict covers the
+        # gate is off or the blocks overflow VMEM).  One dict covers the
         # whole tier — decode attention, chunked-prefill attention, the
         # fused verify tail, the in-grid adapter gather — each program
         # downgrading independently (ops/paged_attention.py documents
-        # the gate TDDL_PAGED_ATTN and the per-program tiling rules);
+        # the gate TDDL_PAGED_ATTN and the per-program VMEM rule);
         # ``self.attn_impl`` stays the decode path, the tier's anchor.
         from trustworthy_dl_tpu.ops import paged_attention as pattn
 
@@ -850,6 +850,7 @@ class PagedBatchingScheduler:
             kv_dtype=q8.resolve_kv_dtype(kv_dtype, cfg),
             n_embd=cfg.n_embd,
             adapter_rank=getattr(adapters, "rank", None),
+            rows=max(self.chunk, max_slots * (spec_k + 1)),
         )
         self.attn_impl = self.attn_impls["decode"]
         self.allocator = SlotAllocator(max_slots)  # decode rows

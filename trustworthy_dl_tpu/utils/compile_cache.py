@@ -1,59 +1,44 @@
-"""Persistent XLA compilation cache wiring.
+"""Where JAX's persistent compilation cache lives.
 
-Repeat runs of this framework compile the SAME SPMD programs (the fused
-trusted step, eval step, serve prefill/decode) from scratch every
-process start — minutes of wall time on big models, pure waste for
-sweeps, bench A/Bs and CI.  JAX ships a persistent on-disk cache
-(``jax_compilation_cache_dir``); this module is the one switch the
-config/CLI/bench layers flip, so the thresholds stay consistent
-everywhere (the test suite's conftest has used the same settings since
-round 5 — this generalises it to runs).
+Every process of this framework compiles the same programs (the fused
+trusted step, the eval step, the serve prefill and decode programs), and
+a chip run keeps nothing but its output directory, so a cold start is
+minutes of compilation.  JAX's on-disk cache removes the repeats — if its
+path is stable: the path is part of what a hit needs, so a temporary,
+per-run or per-process directory never hits.
 
-Off by default: ``TrainingConfig.compilation_cache_dir=None``.  Enable
-with a path under the run directory (``cli.py --compile-cache``,
-``bench.py`` ``TDDL_BENCH_COMPILE_CACHE=1``) — cache entries are keyed
-by program + compiler fingerprint, so a shared directory is safe but a
-run-local one keeps artifacts self-contained.
+:func:`configure_compile_cache` is the one place that decides, and every
+entry point (the CLI, ``bench.py``, ``chip_smoke.py``, the tests) calls
+it before its first compile:
+
+* ``JAX_COMPILATION_CACHE_DIR`` set — JAX's own handling of the variable
+  stands, and nothing here touches ``jax_compilation_cache_dir``;
+* unset — the cache goes to ``<checkout>/.jax_cache`` (git-ignored).
+
+JAX's own thresholds stay as they are: a compile of one second or more
+is written, whatever the size of the entry.
 """
 
 from __future__ import annotations
 
-import logging
 import os
-from typing import Optional
 
-logger = logging.getLogger(__name__)
+#: ``<checkout>/.jax_cache``: beside the package, so a copied tree finds
+#: it at the same relative place.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
-_ENABLED_DIR: Optional[str] = None
 
-
-def enable_persistent_cache(cache_dir: str) -> str:
-    """Point JAX's persistent compilation cache at ``cache_dir``
-    (created if missing).  Idempotent; re-pointing at a different
-    directory logs the switch.  Returns the active cache dir."""
-    global _ENABLED_DIR
+def configure_compile_cache() -> str:
+    """Place the persistent compilation cache; returns its directory."""
+    from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if from_env:
+        return from_env
     import jax
 
-    cache_dir = os.path.abspath(str(cache_dir))
-    if _ENABLED_DIR == cache_dir:
-        return cache_dir
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    # Cache everything that takes >= 1 s to compile, however small the
-    # serialized entry — the fused step dominates, but serve's bucketed
-    # prefill programs are many and individually cheap-ish.
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    if _ENABLED_DIR is not None:
-        logger.info("compilation cache re-pointed: %s -> %s",
-                    _ENABLED_DIR, cache_dir)
-    else:
-        logger.info("persistent compilation cache enabled at %s", cache_dir)
-    _ENABLED_DIR = cache_dir
-    return cache_dir
-
-
-def active_cache_dir() -> Optional[str]:
-    """The directory enabled via :func:`enable_persistent_cache`, or
-    None when the cache was never switched on by this module."""
-    return _ENABLED_DIR
+    if jax.config.jax_compilation_cache_dir != DEFAULT_CACHE_DIR:
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    return DEFAULT_CACHE_DIR
