@@ -25,6 +25,7 @@ from typing import Any, Dict, Iterator, Optional
 import numpy as np
 
 from trustworthy_dl_tpu import native
+from trustworthy_dl_tpu.utils.profiling import span
 
 
 class ArrayDataLoader:
@@ -125,11 +126,14 @@ class TokenStreamLoader:
 class PrefetchLoader:
     """Background-thread prefetch over any batch iterable: batch k+1
     assembles on the host (native gathers) while batch k trains on device —
-    double buffering for the input pipeline (depth configurable)."""
+    double buffering for the input pipeline (depth configurable).  The
+    consumer's blocking wait for a batch is the span ``train.data_wait``
+    (recorded into ``timer``, a ``StepTimeReporter``, when one is set)."""
 
-    def __init__(self, loader: Any, depth: int = 2):
+    def __init__(self, loader: Any, depth: int = 2, timer: Any = None):
         self.loader = loader
         self.depth = max(1, depth)
+        self.timer = timer
 
     def __len__(self) -> int:
         return len(self.loader)
@@ -172,7 +176,8 @@ class PrefetchLoader:
         worker.start()
         try:
             while True:
-                item = q.get()
+                with span("train.data_wait", self.timer):
+                    item = q.get()
                 if item is sentinel:
                     break
                 yield item

@@ -543,9 +543,10 @@ class AttackDetector:
         grad_h = self.gradient_history.get(node_id)
         return self._joined_stats(out_h[-1], grad_h[-1] if grad_h else None)
 
-    def update_detection_models(self, fit_clustering: bool = False) -> None:
+    def update_detection_models(self, fit_clustering: bool = False) -> int:
         """Refit the per-node unsupervised detectors at epoch cadence; a
         no-op on nodes without enough history or when sklearn is absent.
+        Returns the rows fitted, summed over the nodes.
 
         ``fit_clustering`` also refits the per-node DBSCAN models.  Off by
         default as a deliberate deviation: the reference fits DBSCAN on
@@ -558,8 +559,8 @@ class AttackDetector:
             from sklearn.ensemble import IsolationForest
         except ImportError:
             logger.debug("detect: no sklearn in env, ML tier stays off")
-            return
-        fitted = 0
+            return 0
+        fitted = rows = 0
         for node_id in list(self.output_history):
             features = self._node_feature_matrix(node_id)
             if features is None:
@@ -574,8 +575,10 @@ class AttackDetector:
                     **self.ML_DBSCAN_KW
                 ).fit(matrix)
             fitted += 1
+            rows += len(matrix)
         if fitted:
             logger.info("detect: refit ML detectors for %d node(s)", fitted)
+        return rows
 
     def detect_with_ml_models(self, stats: Dict[str, float], node_id: int) -> bool:
         """Score one stat vector against the node's fitted IsolationForest;

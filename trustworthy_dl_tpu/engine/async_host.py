@@ -57,6 +57,7 @@ from typing import Any, Deque, Optional, Set
 import numpy as np
 
 from trustworthy_dl_tpu.engine.step import HostMetricsPacker, StepMetrics
+from trustworthy_dl_tpu.utils.profiling import span
 
 logger = logging.getLogger(__name__)
 
@@ -94,11 +95,20 @@ class AsyncHostPipeline:
     deferred topology change at the frontier.  ``epoch_loss`` /
     ``num_batches`` accumulate exactly what the synchronous loop's local
     counters would have.
+
+    Spans (``utils.profiling.span``, into ``timer`` where one is given):
+    each resolved entry is ``<under>.wait`` (blocked on the device for the
+    packed metrics to land) and ``<under>.records`` (the host's own work
+    for that step: unpack, guard, records, incidents), ``under`` being the
+    caller's span round the drain — ``train.host_drain`` in the step
+    loop, ``train.epoch_end.drain`` for the epoch's mandatory full drain,
+    whose close is also the moment the host view has caught up.
     """
 
-    def __init__(self, trainer: Any, depth: int):
+    def __init__(self, trainer: Any, depth: int, timer: Any = None):
         self.trainer = trainer
         self.depth = int(depth)
+        self.timer = timer
         self.entries: Deque[_InFlight] = collections.deque()
         self.packer: Optional[HostMetricsPacker] = None
         self.pending_evicts: Set[int] = set()
@@ -137,13 +147,15 @@ class AsyncHostPipeline:
 
     # -- drain side --------------------------------------------------------
 
-    def drain(self, depth: Optional[int] = None) -> None:
+    def drain(self, depth: Optional[int] = None,
+              under: str = "train.host_drain") -> None:
         """Resolve oldest entries until at most ``depth`` (default: the
         configured window) remain, then apply deferred topology changes.
-        ``drain(0)`` is the mandatory full drain."""
+        ``drain(0)`` is the mandatory full drain.  ``under`` names the
+        caller's span, which the entries' spans extend."""
         target = self.depth if depth is None else int(depth)
-        self._drain_until(target)
-        self._maybe_apply_topology()
+        self._drain_until(target, under)
+        self._maybe_apply_topology(under)
 
     def consume_rejection(self) -> bool:
         """True when any entry was guard-rejected since the last check —
@@ -154,22 +166,28 @@ class AsyncHostPipeline:
         self._rejected_since_check = False
         return rejected
 
-    def _drain_until(self, target: int) -> None:
+    def _drain_until(self, target: int, under: str) -> None:
         while len(self.entries) > target:
             # Peek-then-pop: if the guard raises mid-drain (a preemption
             # signal), the entry stays queued, so the unwind drain still
             # records it — the host stream must never have a mid-run gap
             # the synchronous path could not produce.
             entry = self.entries[0]
-            self._drain_one(entry)
+            self._drain_one(entry, under)
             if self.entries and self.entries[0] is entry:
                 self.entries.popleft()
 
-    def _drain_one(self, entry: _InFlight) -> None:
+    def _drain_one(self, entry: _InFlight, under: str) -> None:
         """Run one lagged step through the host path with its own step
         number restored, exactly as the synchronous loop would have."""
+        with span(under + ".wait", self.timer):
+            packed = np.asarray(entry.packed)
+        with span(under + ".records", self.timer):
+            self._record_one(entry, packed)
+
+    def _record_one(self, entry: _InFlight, packed: np.ndarray) -> None:
         trainer = self.trainer
-        host, streak = entry.packer.unpack(np.asarray(entry.packed))
+        host, streak = entry.packer.unpack(packed)
         frontier = trainer.global_step
         trainer.global_step = entry.step
         try:
@@ -240,13 +258,14 @@ class AsyncHostPipeline:
         finally:
             trainer.global_step = frontier
 
-    def _maybe_apply_topology(self) -> None:
+    def _maybe_apply_topology(self, under: str) -> None:
         """Deferred elastic transitions: mandatory full drain first, then
         evict/readmit once at the dispatch frontier."""
         trainer = self.trainer
         if not self.pending_evicts and not trainer._readmit_due():
             return
-        self._drain_until(0)  # may itself add evicts or clear on rollback
+        # may itself add evicts or clear on rollback
+        self._drain_until(0, under)
         evicts = sorted(self.pending_evicts)
         self.pending_evicts.clear()
         n = trainer.config.num_nodes

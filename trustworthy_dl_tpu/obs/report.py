@@ -16,12 +16,23 @@ Phase semantics (the canonical names in :data:`PHASES`):
   time blocked on the lagged metrics landing + the host bookkeeping —
   the number the pipeline exists to collapse; compare it across
   ``async_host_depth`` 0 vs K in ``bench.py``'s ``TDDL_BENCH_ASYNC=1``
-  A/B), ``host_sync``, ``checkpoint`` — are accounted by
-  :class:`StepTimeReporter` per step.
-* Device-internal phases — ``forward``, ``backward``, ``optimizer`` —
-  live *inside* the one jitted program and are only separable in the
-  XLA trace timeline; ``utils.profiling.phase_annotation`` uses the
-  same names so a ``profile_dir`` trace and this report line up.
+  A/B), ``checkpoint`` — are accounted by :class:`StepTimeReporter` per
+  step, from the loop's ``lap`` calls.
+* What a step's laps do not cover arrives as SPANS
+  (``utils.profiling.span(name, timer)`` → :meth:`record_span`): the
+  pieces of a lap (``train.data_wait``, ``train.batch_place``,
+  ``train.host_drain`` with ``.wait`` and ``.records``) and the epoch's
+  end (``train.epoch_end`` with ``.drain``, ``.host_sync``,
+  ``.thresholds``, ``.ml_refit``, ``.collect``), which no lap accounts.
+  They are kept apart from the per-step ring, so the phase medians do
+  not shift; ``report()`` shows them as ``spans`` and ``epoch_end``.
+* ``host_sync``, ``forward``, ``backward``, ``optimizer`` and ``other``
+  are names no lap feeds.  The epoch-end host sync is the span
+  ``train.epoch_end.host_sync``; forward, backward and optimizer live
+  inside the one jitted program, where ``engine/step.py`` names them
+  with ``jax.named_scope`` (``train.fwd_bwd``, backward ops carrying
+  ``transpose(`` in their ``op_name``; ``train.optimizer``; the trust
+  plane as ``trust.*``): a ``profile_dir`` trace shows those names.
 
 MFU uses the standard ~6 FLOPs/param/token transformer-training
 estimate (fwd 2 + bwd 4; remat recompute not counted, so achieved
@@ -36,16 +47,19 @@ from __future__ import annotations
 import json
 import os
 import time
-from contextlib import contextmanager
-from typing import Any, Deque, Dict, Iterator, Optional
+from typing import Any, Deque, Dict, Optional, Tuple
 
 import collections
 
 import numpy as np
 
-#: Canonical phase names — host-measured and trace-timeline both.
+#: Canonical phase names of ``lap`` (see the module docstring for which
+#: of them the loop feeds).
 PHASES = ("data", "forward", "backward", "optimizer", "detection",
           "host", "host_sync", "compute", "checkpoint", "other")
+
+#: The span round everything that follows the step loop in ``train_epoch``.
+EPOCH_END_SPAN = "train.epoch_end"
 
 #: Peak dense bf16 FLOP/s per chip by jax ``device_kind`` (marketing
 #: peaks; MFU denominators, not guarantees).  Matched by substring so
@@ -128,6 +142,11 @@ class StepTimeReporter:
         )
         self._current: Dict[str, float] = {}
         self._laps: list = []          # (phase, start, end) this step
+        #: name -> the newest (seconds, attrs) of ``record_span``, ring-
+        #: bounded like the steps.
+        self._spans: Dict[str, Deque[Tuple[float, Dict[str, Any]]]] = \
+            collections.defaultdict(
+                lambda: collections.deque(maxlen=max_steps))
         self._mark: Optional[float] = None
         #: Optional obs.spans.SpanTracker: when attached (ObsSession
         #: enable_spans), finish_step synthesizes a ``train.step`` span
@@ -178,19 +197,15 @@ class StepTimeReporter:
             self._laps.append((phase, self._mark, now))
         self._mark = now
 
-    @contextmanager
-    def phase(self, name: str) -> Iterator[None]:
-        """Scoped alternative to ``lap`` for non-loop call sites."""
-        if name not in PHASES:
-            raise ValueError(f"unknown phase {name!r}; one of {PHASES}")
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            t1 = time.perf_counter()
-            self._current[name] = self._current.get(name, 0.0) + (t1 - t0)
-            self._laps.append((name, t0, t1))
-            self._mark = time.perf_counter()
+    def record_span(self, name: str, start: float, end: float,
+                    **attrs: Any) -> None:
+        """An interval that ``utils.profiling.span`` measured (the
+        ``perf_counter`` domain of the laps).  Kept by name, apart from
+        the per-step ring: ``lap`` and ``finish_step`` never see it."""
+        self._spans[name].append((end - start, attrs))
+        if self.spans is not None:
+            self.spans.add(name, start, end, kind=name.split(".", 1)[0],
+                           **attrs)
 
     def finish_step(self, step: Optional[int] = None) -> None:
         record = self._current
@@ -304,6 +319,12 @@ class StepTimeReporter:
                     "note": "MFU defined for LM (6 FLOPs/param/token) "
                             "only",
                 }
+        if self._spans:
+            out["spans"] = {
+                name: self._span_block(name) for name in sorted(self._spans)}
+        epoch_end = self._epoch_end_block()
+        if epoch_end:
+            out["epoch_end"] = epoch_end
         ledger = self.cost_ledger
         if ledger:
             out["cost_ledger"] = ledger.to_dict()
@@ -311,6 +332,32 @@ class StepTimeReporter:
             if analyzed is not None:
                 out["mfu_analyzed"] = analyzed
         return out
+
+    def _span_block(self, name: str) -> Dict[str, Any]:
+        seconds = np.asarray([s for s, _ in self._spans[name]])
+        return {"count": len(seconds), "total_s": float(seconds.sum()),
+                "p50_s": float(np.percentile(seconds, 50))}
+
+    def _epoch_end_block(self) -> Dict[str, Any]:
+        """The epoch's end by its parts: what follows the step loop in
+        ``train_epoch`` (``train.epoch_end`` and its children), which no
+        lap covers.  ``refit_rows`` are the rows of the matrices the ML
+        detectors were refitted on, newest epochs last: the refit is the
+        one part whose cost may grow with the run's history."""
+        root = EPOCH_END_SPAN
+        if root not in self._spans:
+            return {}
+        parts = {name[len(root) + 1:]: self._span_block(name)
+                 for name in sorted(self._spans)
+                 if name.startswith(root + ".")}
+        block = self._span_block(root)
+        block["epochs"] = block.pop("count")
+        block["parts"] = parts
+        rows = [attrs["rows"] for _, attrs in
+                self._spans.get(root + ".ml_refit", ()) if "rows" in attrs]
+        if rows:
+            block["refit_rows"] = rows[-8:]  # enough to see a trend
+        return block
 
     def _analyzed_mfu(self, out: Dict[str, Any]) -> Optional[Dict[str, Any]]:
         """MFU from XLA's OWN flop count of the train step program

@@ -1,51 +1,59 @@
-"""Tracing / profiling + debug subsystem (SURVEY §5.1, §5.2).
+"""Host spans, profiler sessions and the NaN debug mode.
 
-The reference has neither: only wall-clock epoch timers
-(experiment_runner.py:154,170-172) and tensorboard/wandb pinned in
-requirements but never imported (requirements.txt:44-45).  Race detection
-(§5.2) does not apply to the SPMD design — there is no shared mutable state
-inside the compiled step — so the debug story here is numerical: XLA-level
-NaN trapping plus the step-time histogram in obs/report.py.
+One vocabulary of host spans, on the clock the device trace uses:
 
-* ``trace(log_dir)`` — context manager around ``jax.profiler.trace``;
-  produces TensorBoard/Perfetto-loadable device+host traces of everything
-  dispatched inside.
-* ``step_annotation(step)`` — ``StepTraceAnnotation`` so per-step slices are
-  attributed in the trace timeline.
-* ``phase_annotation(name)`` — ``TraceAnnotation`` carrying one of the
-  canonical ``obs.report.PHASES`` names, so the XLA timeline and the
-  host-side ``obs_report.json`` breakdown use the same vocabulary.
-* ``enable_nan_debugging()`` — flips ``jax_debug_nans``: any NaN produced by
-  a jitted computation re-runs un-jitted and raises FloatingPointError at
-  the exact primitive.  Training-time detection of *adversarial* non-finite
-  gradients does NOT rely on this (the verifier's finite flag handles that
-  in-step); this is a developer mode for debugging the framework itself.
+* ``span(name, timer=None, **args)`` — the ONE way the program opens a
+  host span.  It always enters a ``jax.profiler.TraceAnnotation`` (which
+  lands on ``/host:CPU`` of the profiler's trace, beside the device's
+  ops; a no-op without a profiler session), and where a
+  ``obs.report.StepTimeReporter`` is given it records the same interval
+  into it from one pair of clock reads — so ``obs_report.json``, the
+  ``SpanTracker`` Chrome timeline (``cli obs --chrome``) and the xplane
+  agree by construction.  Spans are named ``<layer>.<what>[.<part>]``; a
+  child's name extends its parent's, so a reader that has only
+  ``(name, start, duration)`` can still read the nesting.
+* ``step_annotation(step)`` — the ``StepTraceAnnotation`` round one step's
+  dispatch (``train_step``); the dispatch gets no second span.
+* ``recorded_spans()`` — the set-up spans (``setup.*``) of this process.
+  The profiler is not running while a trainer is built, so these, and
+  only these, are also kept in a small bounded list on the wall clock.
+* ``trace(log_dir)`` — a profiler session round everything inside
+  (``TrainingConfig.profile_dir``): device ops, the spans above and the
+  ``jax.named_scope`` names of the trusted step (``engine/step.py``), for
+  xprof / Perfetto.
+* ``enable_nan_debugging()`` — ``jax_debug_nans``: a jitted NaN producer
+  re-runs op by op and raises at the exact primitive.  A developer mode
+  (``TrainingConfig.debug_nans``); the detection of *adversarial*
+  non-finite gradients does not rely on it (the verifier's finite flag
+  handles that in-step).
 
-All annotations are **no-op-safe**: constructing or entering one outside
-an active profiler session (or on a backend whose profiler plugin is
-broken) degrades to a null context instead of raising — the trainer's
-hot loop annotates every step, and an instrumentation shim must never be
-the thing that kills a run.
-
-Wired into DistributedTrainer via TrainingConfig.profile_dir /
-TrainingConfig.debug_nans.
+Every annotation is **no-op-safe**: constructing or entering one outside a
+profiler session, or on a backend whose profiler plugin is broken, degrades
+to a null context — the hot loop opens a few every step, and an
+instrumentation shim must never be what kills a run.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import logging
 import os
-from typing import Iterator, Optional
+import time
+from typing import Any, Deque, Dict, Iterator, List, Optional, Tuple
 
 import jax
 
-from trustworthy_dl_tpu.obs.report import PHASES  # canonical phase names
-
-__all__ = ["PHASES", "enable_nan_debugging", "phase_annotation",
+__all__ = ["enable_nan_debugging", "recorded_spans", "span",
            "step_annotation", "trace"]
 
 logger = logging.getLogger(__name__)
+
+#: Spans whose name starts with this are kept for ``recorded_spans()``.
+SETUP_PREFIX = "setup."
+#: (name, wall-clock start, seconds) of the newest set-up spans.  A trainer
+#: leaves about ten; the bound only guards a process that builds hundreds.
+_RECORDED: Deque[Tuple[str, float, float]] = collections.deque(maxlen=64)
 
 
 @contextlib.contextmanager
@@ -99,12 +107,45 @@ def step_annotation(step: int) -> _SafeAnnotation:
                            step_num=step)
 
 
-def phase_annotation(name: str) -> _SafeAnnotation:
-    """Label a host-side phase in the trace timeline with one of the
-    canonical ``obs.report.PHASES`` names (no-op-safe)."""
-    if name not in PHASES:
-        raise ValueError(f"unknown phase {name!r}; one of {PHASES}")
-    return _SafeAnnotation(jax.profiler.TraceAnnotation, name)
+class span(contextlib.ContextDecorator):
+    """``with span("train.epoch_end.drain", timer):`` — see the module
+    docstring; ``@span("setup.build_steps")`` wraps a whole function.
+    ``args`` ride the annotation as its metadata; ``with`` binds them as a
+    dict, and what the body adds to it before the span closes reaches the
+    timer's record too (a count known only afterwards)."""
+
+    def __init__(self, name: str, timer: Any = None, **args: Any):
+        self.name, self.timer, self.args = name, timer, args
+        self._kept = name.startswith(SETUP_PREFIX)
+
+    def _recreate_cm(self) -> "span":
+        # A decorated function gets a fresh span on every call (its own
+        # clock reads and annotation, whatever calls it meanwhile).
+        return span(self.name, self.timer, **self.args)
+
+    def __enter__(self) -> Dict[str, Any]:
+        if self._kept:
+            self._wall = time.time()
+        self._ann = _SafeAnnotation(jax.profiler.TraceAnnotation, self.name,
+                                    **self.args)
+        self._t0 = time.perf_counter()
+        self._ann.__enter__()
+        return self.args
+
+    def __exit__(self, *exc: Any) -> bool:
+        self._ann.__exit__(*exc)
+        t1 = time.perf_counter()
+        if self.timer is not None:
+            self.timer.record_span(self.name, self._t0, t1, **self.args)
+        if self._kept:
+            _RECORDED.append((self.name, self._wall, t1 - self._t0))
+        return False
+
+
+def recorded_spans() -> List[Tuple[str, float, float]]:
+    """(name, wall-clock start, seconds) of this process's newest set-up
+    spans, oldest first."""
+    return list(_RECORDED)
 
 
 def enable_nan_debugging(enabled: bool = True) -> None:
