@@ -119,18 +119,16 @@ def test_paged_attention_lowers(v5e, program, kv_dtype):
              interpret=False)
 
 
-def _flash_forward(dev, dtype):
-    q = S((H, 1024, DH), dtype)
-    bq, bk = _blocks_for(1024)
-    _compile(_flash_fwd, dev, q, q, q, causal=True, bq=bq, bk=bk,
-             interpret=False)
+def _flash_forward(dev, dtype, bh=H, t=1024, d=DH, causal=True):
+    q = S((bh, t, d), dtype)
+    _compile(_flash_fwd, dev, q, q, q, causal=causal,
+             blocks=_blocks_for(t, d), interpret=False)
 
 
-def _flash_backward(dev, dtype):
-    q = S((H, 1024, DH), dtype)
-    bq, bk = _blocks_for(1024)
-    _compile(_flash_bwd, dev, q, q, q, q, S((H, 1024), jnp.float32), q,
-             causal=True, bq=bq, bk=bk, interpret=False)
+def _flash_backward(dev, dtype, bh=H, t=1024, d=DH, causal=True):
+    q = S((bh, t, d), dtype)
+    _compile(_flash_bwd, dev, q, q, q, q, S((bh, t), jnp.float32), q,
+             causal=causal, blocks=_blocks_for(t, d), interpret=False)
 
 
 def _fused_moments(dev):
@@ -177,6 +175,28 @@ def _adapter_delta(dev, rank, pool_dtype):
                  id="flash-bwd-bf16"),
     pytest.param(lambda d: _flash_backward(d, jnp.float32),
                  id="flash-bwd-f32"),
+    # The benchmark cell's own call (8 rows x 12 heads a layer: one
+    # [1024, 1024] tile walked in sub-tiles), the long shape (eight tiles a
+    # side: carried accumulators, tile-level skip), no diagonal, and a
+    # head as wide as the lanes in f32 (the fullest VMEM the tile rule
+    # admits at this length).
+    pytest.param(lambda d: _flash_forward(d, jnp.bfloat16, bh=96),
+                 id="flash-fwd-cell-96x1024x64"),
+    pytest.param(lambda d: _flash_backward(d, jnp.bfloat16, bh=96),
+                 id="flash-bwd-cell-96x1024x64"),
+    pytest.param(lambda d: _flash_forward(d, jnp.bfloat16, bh=12, t=8192),
+                 id="flash-fwd-long-12x8192x64"),
+    pytest.param(lambda d: _flash_backward(d, jnp.bfloat16, bh=12, t=8192),
+                 id="flash-bwd-long-12x8192x64"),
+    pytest.param(lambda d: _flash_forward(d, jnp.bfloat16, t=2048,
+                                          causal=False),
+                 id="flash-fwd-full-2048"),
+    pytest.param(lambda d: _flash_backward(d, jnp.bfloat16, t=2048,
+                                           causal=False),
+                 id="flash-bwd-full-2048"),
+    pytest.param(lambda d: _flash_backward(d, jnp.float32, bh=4, t=2048,
+                                           d=128),
+                 id="flash-bwd-f32-2048x128"),
     pytest.param(_fused_moments, id="fused-moments"),
     pytest.param(_dequant_matmul, id="dequant-matmul"),
     pytest.param(_trust_epilogue, id="trust-epilogue"),

@@ -141,17 +141,13 @@ def _ring_attention_local(q: jax.Array, k: jax.Array, v: jax.Array,
     q_pos = stage * tl + jnp.arange(tl)
 
     if _use_flash_chunks(tl, d):
-        from trustworthy_dl_tpu.ops.flash_attention import (
-            _blocks_for,
-            flash_chunk,
-        )
+        from trustworthy_dl_tpu.ops.flash_attention import flash_chunk
 
-        bq, bk = _blocks_for(tl)
         merge = lambda a: a.reshape(b * h, tl, d)
 
         def chunk(k_cur, v_cur, chunk_causal: bool):
             o, lse = flash_chunk(merge(q), merge(k_cur), merge(v_cur),
-                                 chunk_causal, bq, bk)
+                                 chunk_causal)
             return (o.reshape(b, h, tl, d),
                     lse.reshape(b, h, tl))
 
