@@ -86,20 +86,20 @@ def S(shape, dtype):
 
 
 def _paged_args(program, kv_dtype, block=BLOCK, head_dim=DH, heads=H,
-                max_seq=MAX_SEQ, q_dtype=jnp.bfloat16):
+                max_seq=MAX_SEQ, q_dtype=jnp.bfloat16, slots=SLOTS):
     """Argument shapes of ``_paged_attn_call`` (fused decode over every
-    slot) or ``_paged_prefill_call`` (one slot's chunk)."""
+    slot) or ``_paged_prefill_call`` (one slot's chunk), ``jmax`` a query
+    tile of the program's own rule."""
     nbps = max_seq // block
-    pool = S((SLOTS * nbps + 1, heads, block, head_dim), kv_dtype)
+    pool = S((slots * nbps + 1, heads, block, head_dim), kv_dtype)
     scale = (S(pool.shape[:3], jnp.float32)
              if jnp.dtype(kv_dtype) == jnp.int8 else None)
-    if program == "decode":
-        r, t, jmax = SLOTS, pa.QROWS, S((SLOTS,), jnp.int32)
-    else:
-        r, t = 1, CHUNK
-        jmax = S((1, CHUNK // pa.QROWS), jnp.int32)
+    r, t = (slots, pa.QROWS) if program == "decode" else (1, CHUNK)
+    tiles = pa.grid_steps(program, r, heads, nbps, t, head_dim, block,
+                          kv_dtype)[2]
     return (S((r, heads, t, head_dim), q_dtype), pool, pool, scale, scale,
-            S((r, nbps), jnp.int32), S((r,), jnp.int32), jmax)
+            S((r, nbps), jnp.int32), S((r,), jnp.int32),
+            S((r, tiles), jnp.int32))
 
 
 _PAGED_CALL = {"decode": pa._paged_attn_call,
@@ -116,6 +116,21 @@ def test_paged_attention_lowers(v5e, program, kv_dtype):
         head_dim=DH, block_size=BLOCK, kv_dtype=kv_dtype, interpret=False,
         program=program, n_embd=D)
     _compile(_PAGED_CALL[program], v5e, *_paged_args(program, kv_dtype),
+             interpret=False)
+
+
+@pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8],
+                         ids=lambda d: jnp.dtype(d).name)
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_paged_attention_lowers_at_the_serving_cell(v5e, program, kv_dtype):
+    """The benchmark's serving cell (GPT-2 large: 24 slots, 20 heads of
+    64, blocks of 16, 1,024 positions, chunk 64): a step takes every head
+    of a block, the prefill program the whole chunk besides."""
+    rows, t = (24, 1) if program == "decode" else (1, CHUNK)
+    assert pa.grid_steps(program, rows, 20, MAX_SEQ // BLOCK, t, DH, BLOCK,
+                         kv_dtype) == (rows, 1, 1, MAX_SEQ // BLOCK)
+    _compile(_PAGED_CALL[program], v5e,
+             *_paged_args(program, kv_dtype, heads=20, slots=24),
              interpret=False)
 
 
@@ -216,10 +231,19 @@ def test_kernel_entry_lowers(v5e, entry):
 
 # Geometries off the dtype's sublane, off the 128 lanes, and large: what
 # the predicate admits must lower — the old rule refused most of these.
+# (program, pool dtype, block, head width[, heads]).  The large blocks
+# leave room for one head a step or for a proper divisor of the twelve;
+# the last three fill the budget from the other side, with q, out and the
+# scratch of many wide heads (groups of 32, 16 and 32 at 7.5, 7.5 and 7.0
+# of the 8 MiB).
 _ADMITTED = [
     ("decode", jnp.bfloat16, 8, 64), ("prefill", jnp.int8, 16, 64),
     ("decode", jnp.float32, 12, 80), ("prefill", jnp.bfloat16, 32, 128),
     ("decode", jnp.int8, 4096, 128), ("prefill", jnp.float32, 1024, 256),
+    ("decode", jnp.float32, 1024, 64), ("prefill", jnp.float32, 1024, 64),
+    ("prefill", jnp.bfloat16, 16, 128, 64),
+    ("prefill", jnp.float32, 16, 128, 96),
+    ("prefill", jnp.int8, 32, 128, 64),
 ]
 _REFUSED = [
     ("decode", jnp.float32, 4096, 512), ("prefill", jnp.bfloat16, 8192, 512),
@@ -227,18 +251,20 @@ _REFUSED = [
 
 
 def _geometry_id(case):
-    program, dtype, block, head_dim = case
-    return f"{program}-{jnp.dtype(dtype).name}-b{block}-d{head_dim}"
+    program, dtype, block, head_dim, *heads = case
+    return (f"{program}-{jnp.dtype(dtype).name}-b{block}-d{head_dim}"
+            + "".join(f"-h{h}" for h in heads))
 
 
 @pytest.mark.parametrize("case", _ADMITTED, ids=_geometry_id)
 def test_supported_geometry_lowers(v5e, case):
-    program, kv_dtype, block, head_dim = case
+    program, kv_dtype, block, head_dim, *heads = case
+    (heads,) = heads or (H,)
     assert pa.supports_paged_attention(
         head_dim=head_dim, block_size=block, kv_dtype=kv_dtype,
-        interpret=False, program=program, n_embd=H * head_dim)
+        interpret=False, program=program, n_embd=heads * head_dim)
     _compile(_PAGED_CALL[program], v5e,
-             *_paged_args(program, kv_dtype, block, head_dim,
+             *_paged_args(program, kv_dtype, block, head_dim, heads=heads,
                           max_seq=max(MAX_SEQ, block)), interpret=False)
 
 

@@ -109,6 +109,154 @@ def test_kernel_matches_reference_int8_scales():
 
 
 # --------------------------------------------------------------------------
+# What a grid step holds: the head group and the query tile, by shape
+# --------------------------------------------------------------------------
+
+# (heads, head_dim, block, pool dtype, the head group the rule must pick).
+# The group is forced by SHAPE alone: small blocks take every head in one
+# step; blocks of 1,024 to 4,096 positions leave VMEM for a proper divisor
+# of the heads; twice that for one head, which is the kernel of before.
+# The first two are a small copy of the serving cell's geometry.
+_GEOMETRIES = [
+    (20, 64, 16, "bfloat16", 20), (20, 64, 16, "int8", 20),
+    (4, 64, 8, "float32", 4), (3, 16, 8, "bfloat16", 3),
+    (4, 16, 8, "int8", 4),
+    (4, 64, 1024, "float32", 2), (4, 16, 2048, "bfloat16", 2),
+    (4, 64, 4096, "int8", 2),
+    (3, 16, 2048, "float32", 1), (2, 64, 4096, "bfloat16", 1),
+    (2, 16, 8192, "int8", 1),
+]
+
+
+def _geometry_id(case):
+    h, dh, bsz, dtype, group = case
+    return f"h{h}-d{dh}-b{bsz}-{dtype}-g{group}"
+
+
+@pytest.mark.parametrize("t", [1, 3, 64])
+@pytest.mark.parametrize("case", _GEOMETRIES, ids=_geometry_id)
+def test_grouped_step_matches_reference(case, t):
+    """Both programs on the grouped step against the gather-semantics
+    reference: every head in one step, a proper divisor of the heads and
+    one head a step (each forced by the pool's shape and dtype alone),
+    head widths 64 and 16, f32 / bf16 / int8 pools, decode (T = 1), the
+    verify window (T = k + 1) and the chunk (T = 64, one query tile), on
+    three rows: an empty history, a start that is block-aligned but (at
+    the small blocks) not chunk-aligned, and a ragged one mid-block."""
+    h, dh, bsz, dtype, group = case
+    program = "prefill" if t > pattn.QROWS else "decode"
+    aligned = 5 * bsz if bsz <= 16 else bsz
+    start = jnp.asarray([0, aligned, aligned + 5], jnp.int32)
+    r, nbps = 3, -(-(aligned + 5 + t) // bsz) + 1
+    nb = r * nbps + 1
+    assert pattn._step_shape(program, heads=h, head_dim=dh, block_size=bsz,
+                             kv_dtype=dtype, t=t) == (
+        group, -(-t // pattn.QROWS) * pattn.QROWS)
+    assert pattn.grid_steps(program, r, h, nbps, t, dh, bsz, dtype) == (
+        r, h // group, 1, nbps)
+    rng = np.random.default_rng(h * bsz + t)
+    if dtype == "int8":
+        pool_k, pool_v = (jnp.asarray(
+            rng.integers(-127, 128, size=(nb, h, bsz, dh)), jnp.int8)
+            for _ in range(2))
+        scales = dict(
+            k_scale=jnp.asarray(rng.uniform(0.01, 0.1, (nb, h, bsz)),
+                                jnp.float32),
+            v_scale=jnp.asarray(rng.uniform(0.01, 0.1, (nb, h, bsz)),
+                                jnp.float32))
+    else:
+        pool_k, pool_v = (jnp.asarray(rng.normal(size=(nb, h, bsz, dh)),
+                                      dtype) for _ in range(2))
+        scales = {}
+    table = jnp.asarray(1 + rng.permutation(r * nbps).reshape(r, nbps),
+                        jnp.int32)
+    q = jnp.asarray(rng.normal(size=(r, h, t, dh)), jnp.float32)
+    attend = (pattn.paged_prefill_attention if program == "prefill"
+              else pattn.paged_attention)
+    got = attend(q, pool_k, pool_v, table, start, interpret=True, **scales)
+    ref = pattn.paged_attention_reference(q, pool_k, pool_v, table, start,
+                                          **scales)
+    tol = 5e-5 if dtype == "int8" else 2e-5
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=tol, atol=tol)
+
+
+def test_chunk_in_query_tiles_matches_reference():
+    """A chunk too long for one query tile beside blocks this large goes
+    in tiles, each with its own causal bound: 72 rows walk as three tiles
+    of 32 (the last one a quarter real), one head a step."""
+    h, dh, bsz, t, nbps = 2, 128, 4032, 72, 2
+    assert pattn._step_shape("prefill", heads=h, head_dim=dh,
+                             block_size=bsz, kv_dtype="float32",
+                             t=t) == (1, 32)
+    assert pattn.grid_steps("prefill", 1, h, nbps, t, dh, bsz,
+                            "float32") == (1, h, 3, nbps)
+    rng = np.random.default_rng(72)
+    pool_k, pool_v = (jnp.asarray(rng.normal(size=(3, h, bsz, dh)),
+                                  jnp.float32) for _ in range(2))
+    table = jnp.asarray([[2, 1]], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(1, h, t, dh)), jnp.float32)
+    # The second tile's window crosses from block 0 into block 1.
+    start = jnp.asarray(bsz - 40, jnp.int32)
+    got = pattn.paged_prefill_attention(q, pool_k, pool_v, table, start,
+                                        interpret=True)
+    ref = pattn.paged_attention_reference(q, pool_k, pool_v, table, start)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+# Every attention geometry the other tests of the tier run or compile:
+# this file's and test_serving_kernel_tier.py's pools, the engines' (4
+# heads of 8, blocks of 8), test_chip_compile.py's and the serving cell's.
+_RULE_GEOMETRIES = [c[:4] for c in _GEOMETRIES] + [
+    (3, 16, 8, "float32"), (2, 8, 8, "int8"), (2, 16, 8, "float32"),
+    (2, 16, 8, "int8"), (4, 8, 8, "float32"), (4, 8, 8, "int8"),
+    (2, 128, 4032, "float32"),
+    (12, 64, 16, "float32"), (12, 64, 16, "bfloat16"), (12, 64, 16, "int8"),
+    (12, 64, 8, "bfloat16"), (12, 80, 12, "float32"),
+    (12, 128, 32, "bfloat16"), (12, 128, 4096, "int8"),
+    (12, 256, 1024, "float32"), (64, 128, 16, "bfloat16"),
+    (20, 64, 16, "float32"),
+]
+
+
+@pytest.mark.parametrize("t", [1, 5, 13, 64, 72])
+@pytest.mark.parametrize("case", _RULE_GEOMETRIES,
+                         ids=lambda c: "h%d-d%d-b%d-%s" % c)
+def test_rule_divides_heads_and_fits_budget(case, t):
+    """The one rule: its group divides the heads, its tile is whole
+    sublanes, what the step pins fits the budget the predicate asks
+    about, no wider step of the same kind would, and the padded call the
+    kernel sees picks the same step."""
+    h, dh, bsz, dtype = case
+    kw = dict(head_dim=dh, block_size=bsz, kv_dtype=dtype)
+    assert pattn.supports_paged_attention(interpret=False, n_embd=h * dh,
+                                          **kw)
+    # (The decode program never tiles: the engine hands it a sublane of
+    # query rows at most, models/generate._paged_block_kernel.)
+    for program in ("decode", "prefill")[t > pattn.QROWS:]:
+        group, tile = pattn._step_shape(program, heads=h, t=t, **kw)
+        t8 = -(-t // pattn.QROWS) * pattn.QROWS
+
+        def pinned(g, qt):
+            return pattn._pipelined_block_bytes(
+                program, n_embd=h * dh, group=g, q_tile=qt, **kw)
+
+        assert h % group == 0 and tile % pattn.QROWS == 0
+        assert tile == t8 or (program == "prefill" and tile < t8)
+        assert pinned(group, tile) <= pattn.VMEM_BLOCK_BUDGET
+        wider = [g for g in range(group + 1, h + 1) if h % g == 0]
+        assert all(pinned(g, tile) > pattn.VMEM_BLOCK_BUDGET for g in wider)
+        if tile < t8:
+            assert pinned(1, 2 * tile) > pattn.VMEM_BLOCK_BUDGET
+        tiles = -(-t8 // tile)
+        assert pattn.grid_steps(program, 7, h, 5, t, dh, bsz, dtype) == (
+            7, h // group, tiles, 5)
+        assert pattn._step_shape(program, heads=h, t=tiles * tile,
+                                 **kw) == (group, tile)
+
+
+# --------------------------------------------------------------------------
 # Kernel path vs jnp path through the REAL paged transformer stack
 # --------------------------------------------------------------------------
 

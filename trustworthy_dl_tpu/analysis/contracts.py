@@ -80,8 +80,8 @@ HOST_SYNC_SCOPES = {
     # any host pull of a traced value here would serialise every decode
     # tick (there is no intentional pull — these scopes allow zero).
     "trustworthy_dl_tpu/ops/paged_attention.py": (
-        "paged_attention", "paged_prefill_attention", "fused_verify_tail",
-        "adapter_delta", "logit_trust_stats",
+        "paged_attention", "paged_prefill_attention", "_attend",
+        "fused_verify_tail", "adapter_delta", "logit_trust_stats",
     ),
 }
 

@@ -577,10 +577,11 @@ def _paged_block_kernel(block: Params, x: jax.Array, pool_k_l: jax.Array,
     [0, start+T) straight from the pool with the causal window masked
     in absolute positions, which is precisely what the gathered view
     exposes to ``_block_with_cache``.  T selects the program (static —
-    each serve program compiles one shape): the one-query-tile decode
-    kernel up to ``QROWS`` rows (decode T=1, speculative verify T=k+1),
-    the query-tiled chunked-prefill flash kernel above it (per-tile
-    causal block bounds skip KV tiles whole query tiles cannot see)."""
+    each serve program compiles one shape): the decode program up to
+    ``QROWS`` rows (decode T=1, speculative verify T=k+1), the
+    chunked-prefill program above it (the same kernel; the chunk in one
+    query tile where VMEM allows, else in tiles whose causal block
+    bounds skip the KV blocks a whole tile cannot see)."""
     from trustworthy_dl_tpu.ops import paged_attention as pattn
     from trustworthy_dl_tpu.quant import int8 as q8
 

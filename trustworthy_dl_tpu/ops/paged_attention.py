@@ -1,5 +1,5 @@
 """Pallas TPU serving-kernel tier: ragged paged attention (decode +
-query-tiled chunked prefill), the fused speculative-verify tail, the
+chunked prefill), the fused speculative-verify tail, the
 in-grid adapter gather, and the fused trust epilogue.
 
 Decode attention over the paged KV pool (serve/kv_slots.PagedKV) has been
@@ -10,11 +10,21 @@ the row's true length, and dequantises the int8 tier by algebra over that
 view.  This kernel makes the stream explicit — the single biggest
 tokens/sec lever ROADMAP item 2 names:
 
-* **one program per block-table row** (grid ``(R, H, NBPS)``): the block
-  table and per-row lengths ride as scalar-prefetch operands, so the
-  KV BlockSpec index map resolves ``logical block j -> physical block
-  table[r, j]`` before the DMA is issued — the gather IS the pipeline,
-  no [R, H, S, Dh] view is ever materialised;
+* **one program per block-table row** (grid ``(R, H/g, NT, NBPS)``):
+  the block table and per-row lengths ride as scalar-prefetch operands,
+  so the KV BlockSpec index map resolves ``logical block j -> physical
+  block table[r, j]`` before the DMA is issued — the gather IS the
+  pipeline, no [R, H, S, Dh] view is ever materialised;
+* **a step holds a block's heads**: the pool ``[NB, H, BLOCK, Dh]``
+  keeps the heads of a physical block contiguous, so one step copies
+  the block for a GROUP of ``g`` heads and runs their products as one
+  batch.  A grid step costs a quarter to a third of a microsecond on a
+  v5e whatever it holds, so the kernels' time is their step count:
+  ``g`` and the query tile come from ONE rule over shapes and the pool's
+  dtype (:func:`_step_shape`: the widest tile, then the largest divisor
+  of ``H``, whose blocks fit :data:`VMEM_BLOCK_BUDGET` — all 20 heads
+  and the chunk's 64 queries at GPT-2 large, one head at blocks of
+  4,096 x 128), and :func:`grid_steps` counts what a call pays;
 * **int8 streaming**: int8 KV tiles DMA HBM→VMEM at half the bf16 bytes
   (a quarter of f32), upcast in-register, and the per-(head, position)
   scales PagedKV already pages multiply the scores/probabilities exactly
@@ -28,16 +38,15 @@ tokens/sec lever ROADMAP item 2 names:
   ``flash_attention``'s causal skip) and ``pl.when`` skips their compute.
 
 **Chunked-prefill program** (:func:`paged_prefill_attention`): the
-multi-query-row extension.  T chunk rows per slot tile into
-``q_tile``-row query tiles (grid ``(R, H, NT, NBPS)``) attending over
-the SAME scalar-prefetch block tables with the ragged causal mask in
-absolute positions.  The per-(row, tile) last-useful-block bound rides
-as a third scalar-prefetch operand, so an early query tile streams only
-the KV blocks its causal window can see — the flash-attention causal
-skip applied ACROSS query tiles of a paged table, which the one-block-
-bound decode program cannot express.  This replaces ``paged_chunk``'s
-gathered-view attention (the whole-prompt [R, H, S, Dh] view per chunk
-per layer).
+multi-query-row extension, on the same kernel.  The T chunk rows of a
+slot go in ONE query tile where that fits (the rule above) and else in
+tiles (the grid's ``NT``), attending over the SAME scalar-prefetch block
+tables with the ragged causal mask in absolute positions.  The per-(row,
+tile) last-useful-block bound rides as the third scalar-prefetch
+operand, so an early query tile streams only the KV blocks its causal
+window can see — the flash-attention causal skip applied ACROSS query
+tiles of a paged table.  This replaces ``paged_chunk``'s gathered-view
+attention (the whole-prompt [R, H, S, Dh] view per chunk per layer).
 
 **Fused speculative-verify tail** (:func:`fused_verify_tail`): the spec
 verify window needs logits at EVERY draft position plus the per-position
@@ -135,7 +144,7 @@ VMEM_BLOCK_BUDGET = VMEM_LIMIT_BYTES // 2
 ATTN_IMPLS = ("auto", "pallas", "interpret", "jnp")
 
 #: The serving-kernel tier's programs: ragged paged-decode attention,
-#: the query-tiled chunked-prefill program, the fused speculative-verify
+#: the chunked-prefill program, the fused speculative-verify
 #: tail, and the in-grid adapter low-rank gather.
 PAGED_PROGRAMS = ("decode", "prefill", "verify", "adapter")
 
@@ -152,24 +161,35 @@ def _tile_bytes(rows: int, cols: int, dtype) -> int:
 
 def _pipelined_block_bytes(program: str, *, head_dim: int,
                            block_size: int, kv_dtype,
-                           n_embd: Optional[int],
-                           adapter_rank: Optional[int],
-                           rows: int) -> int:
-    """Double-buffered bytes of the operand and output blocks one grid
-    step of ``program`` keeps in VMEM — the quantity the compiler's
-    refusal is about.  Activations count as f32 (the widest the engine
-    feeds); ``rows`` is the query rows of one call."""
+                           n_embd: Optional[int] = None,
+                           adapter_rank: Optional[int] = None,
+                           rows: int = QROWS, group: int = 1,
+                           q_tile: int = QROWS) -> int:
+    """VMEM bytes one grid step of ``program`` pins — the quantity the
+    compiler's refusal is about: its operand and output blocks, double
+    buffered.  Activations count as f32 (the widest the engine feeds);
+    ``rows`` is the query rows of one call of the verify tail or the
+    adapter gather.
+
+    A step of the attention programs holds ``group`` heads of ONE
+    physical block and ``q_tile`` query rows: q and out ``[group,
+    q_tile, head_dim]``, K and V ``[group, block_size, head_dim]`` in the
+    pool's dtype, on the int8 tier the two scale planes of every head
+    (see :func:`_paged_attn_kernel`), and the f32 scratch ``[group,
+    q_tile, .]`` of the online softmax, which grows with the same two
+    numbers and is therefore counted here (once: it is not pipelined)."""
     f32 = jnp.float32
     if program in ("decode", "prefill"):
-        blocks = 2 * _tile_bytes(QROWS, head_dim, f32)           # q, out
-        blocks += 2 * _tile_bytes(block_size, head_dim, kv_dtype)
+        blocks = 2 * group * _tile_bytes(q_tile, head_dim, f32)  # q, out
+        blocks += 2 * group * _tile_bytes(block_size, head_dim, kv_dtype)
         if jnp.dtype(kv_dtype) == jnp.int8:
-            # The scale blocks carry every head's plane (see
-            # _paged_attn_call); without n_embd the head count is
-            # unknown and only the K/V tiles are counted.
-            heads = n_embd // head_dim if n_embd else 0
+            # Without n_embd the head count is unknown: the group's.
+            heads = n_embd // head_dim if n_embd else group
             blocks += 2 * _tile_bytes(heads, block_size, f32)
-    elif program == "verify":
+        scratch = group * (_tile_bytes(q_tile, head_dim, f32)
+                           + 2 * _tile_bytes(q_tile, 128, f32))
+        return 2 * blocks + scratch
+    if program == "verify":
         blocks = (_tile_bytes(rows, n_embd, f32)
                   + _tile_bytes(TRUST_TILE, n_embd, f32)
                   + _tile_bytes(rows, TRUST_TILE, f32))
@@ -178,6 +198,54 @@ def _pipelined_block_bytes(program: str, *, head_dim: int,
                   + _tile_bytes(n_embd, adapter_rank, f32)
                   + _tile_bytes(adapter_rank, n_embd, f32))
     return 2 * blocks
+
+
+def _step_shape(program: str, *, heads: int, head_dim: int,
+                block_size: int, kv_dtype, t: int) -> Tuple[int, int]:
+    """THE rule for what one grid step of an attention program holds:
+    ``(head group, query tile)``, from shapes and the pool's dtype alone.
+
+    The query tile first: the ``t`` query rows of a call, padded to the
+    sublane, in ONE tile if a single head's blocks then fit
+    :data:`VMEM_BLOCK_BUDGET` (:func:`_pipelined_block_bytes`), else the
+    widest ``QROWS * 2**i`` under it that does (a wider tile streams the
+    row's K and V fewer times); the decode program never tiles.  Then the
+    largest divisor of ``heads`` whose group fits beside that tile: the
+    pool keeps a physical block's heads contiguous, so a group is one
+    copy.  A geometry nothing fits gets the narrowest step, which
+    :func:`supports_paged_attention` refuses."""
+    t8 = -(-t // QROWS) * QROWS
+    tiles = [t8]
+    if program == "prefill":
+        tiles += [QROWS << i for i in reversed(range(t8.bit_length()))
+                  if QROWS << i < t8]
+
+    def fits(group: int, q_tile: int) -> bool:
+        return _pipelined_block_bytes(
+            program, head_dim=head_dim, block_size=block_size,
+            kv_dtype=kv_dtype, n_embd=heads * head_dim, group=group,
+            q_tile=q_tile) <= VMEM_BLOCK_BUDGET
+
+    q_tile = next((qt for qt in tiles if fits(1, qt)), tiles[-1])
+    group = next(g for g in range(heads, 0, -1)
+                 if heads % g == 0 and (g == 1 or fits(g, q_tile)))
+    return group, q_tile
+
+
+def grid_steps(program: str, rows: int, heads: int, nbps: int, t: int,
+               head_dim: int, block_size: int,
+               kv_dtype) -> Tuple[int, int, int, int]:
+    """The grid of one attention call, ``(rows, head groups, query tiles,
+    logical blocks)``: ``program`` "decode" or "prefill" over ``rows``
+    block-table rows of ``nbps`` blocks, ``t`` query rows each.  Its
+    product is the grid steps the call pays (each has a fixed cost of a
+    quarter to a third of a microsecond on a v5e whatever it holds);
+    :func:`_attn_pallas_call` builds its ``grid=`` from this and nothing
+    else, so the count cannot drift from the kernel."""
+    group, q_tile = _step_shape(program, heads=heads, head_dim=head_dim,
+                                block_size=block_size, kv_dtype=kv_dtype,
+                                t=t)
+    return rows, heads // group, -(-t // q_tile), nbps
 
 
 def supports_paged_attention(*, head_dim: int, block_size: int,
@@ -193,11 +261,15 @@ def supports_paged_attention(*, head_dim: int, block_size: int,
     holds it to that against the TPU compiler.
 
     What the compiler refuses is VMEM (see :data:`VMEM_LIMIT_BYTES`), so
-    compiled eligibility is one rule for all four programs: the
-    double-buffered blocks of a grid step (:func:`_pipelined_block_bytes`)
-    fit :data:`VMEM_BLOCK_BUDGET`.  For the attention programs that
-    bounds ``block_size x head_dim`` in the POOL's storage dtype; for the
-    verify tail ``n_embd`` (the [TRUST_TILE, n_embd] head tile); for the
+    compiled eligibility is one rule for all four programs: what a grid
+    step pins (:func:`_pipelined_block_bytes`) fits
+    :data:`VMEM_BLOCK_BUDGET`.  For the attention programs the step
+    asked about is the narrowest :func:`_step_shape` can fall to, one
+    head and one sublane of queries (the function's defaults): that
+    bounds ``block_size x head_dim`` in the POOL's storage dtype (and, on
+    the int8 tier, the scale planes of ``n_embd // head_dim`` heads);
+    what fits beyond it only widens the step.  For the verify tail
+    it bounds ``n_embd`` (the [TRUST_TILE, n_embd] head tile); for the
     adapter gather ``rows x n_embd`` (``rows`` = the most query rows one
     call carries, the prefill chunk).  ``verify`` and ``adapter`` need
     ``n_embd``; ``adapter`` a positive ``adapter_rank``.
@@ -324,36 +396,58 @@ def resolve_attn_impls(requested: str, *, head_dim: int, block_size: int,
 
 
 def _dot(a: jax.Array, b: jax.Array, trans_b: bool = False) -> jax.Array:
-    """f32-accumulating matmul for the MXU."""
-    cb = 1 if trans_b else 0
+    """f32-accumulating matmul for the MXU: ``a @ b`` (``a @ b.T`` with
+    ``trans_b``) over the last two dims, any leading dim a batch."""
+    batch = tuple(range(a.ndim - 2))
+    cb = b.ndim - 1 if trans_b else b.ndim - 2
     return jax.lax.dot_general(
-        a, b, (((1,), (cb,)), ((), ())), preferred_element_type=jnp.float32
+        a, b, (((a.ndim - 1,), (cb,)), (batch, batch)),
+        preferred_element_type=jnp.float32,
     )
 
 
+def _times_head_scales(x: jax.Array, scale_ref, head0) -> jax.Array:
+    """``x`` [g, rows, bsz] times the int8 tier's per-(head, position)
+    scales of heads ``head0 ..``.  The scale block carries every head's
+    plane [1, H, bsz]; each head's sublane is read alone and the heads'
+    products restacked, because a dynamic window of several sublanes
+    lowers only where it starts on a multiple of 8."""
+    return jnp.stack([x[i] * scale_ref[0, pl.ds(head0 + i, 1), :]
+                      for i in range(x.shape[0])])
+
+
 # ---------------------------------------------------------------------------
-# Ragged paged-decode attention kernel
+# Ragged paged attention: one kernel, the decode and the chunked-prefill
+# programs
 # ---------------------------------------------------------------------------
 
 
 def _paged_attn_kernel(table_ref, start_ref, jmax_ref, q_ref, k_ref, v_ref,
-                       *rest, scale: float, bsz: int, tq: int,
+                       *rest, scale: float, bsz: int, qt: int, group: int,
                        quantized: bool):
-    """One (row, head, logical-block) grid step of the online softmax.
+    """One (row, head group, query tile, logical block) grid step of the
+    online softmax: ``group`` heads of ONE physical block against ``qt``
+    query rows, the heads a batch dimension of both products (per head
+    the algebra is what a step of one head was; on the chip the batched
+    spelling beat a static unroll of two-dimensional dots by 1.4 to 1.7
+    times, PERF.md section 6).
 
     Scalar-prefetch refs: ``table_ref`` i32[R, NBPS] (physical ids —
     also consumed by the index maps, which is what makes the gather part
     of the DMA pipeline), ``start_ref`` i32[R] (first query's absolute
-    position) and ``jmax_ref`` i32[R] (the row's last useful logical
-    block — the ragged early-exit bound).  ``rest`` is ``(ks_ref,
-    vs_ref, o_ref, acc_ref, m_ref, l_ref)`` on the int8 tier and the
-    last four otherwise: the scale operands exist only when there are
-    scales."""
+    position) and ``jmax_ref`` i32[R, NT] (the last useful logical block
+    of each (row, query tile) — the ragged early-exit bound: tile
+    ``ti``'s causal window ends at its own last query, so an early tile
+    of a long chunk streams a fraction of the blocks the chunk touches).
+    ``rest`` is ``(ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref)`` on the
+    int8 tier and the last four otherwise: the scale operands exist only
+    when there are scales."""
     ks_ref, vs_ref = rest[:2] if quantized else (None, None)
     o_ref, acc_ref, m_ref, l_ref = rest[-4:]
     r = pl.program_id(0)
-    hd = pl.program_id(1)
-    j = pl.program_id(2)
+    hg = pl.program_id(1)
+    ti = pl.program_id(2)
+    j = pl.program_id(3)
 
     @pl.when(j == 0)
     def _init():
@@ -361,50 +455,123 @@ def _paged_attn_kernel(table_ref, start_ref, jmax_ref, q_ref, k_ref, v_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    jmax = jmax_ref[r]
+    jmax = jmax_ref[r, ti]
 
     @pl.when(j <= jmax)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)              # [tq, Dh]
-        k = k_ref[0, 0].astype(jnp.float32)              # [bsz, Dh]
-        s = _dot(q, k, trans_b=True) * scale             # [tq, bsz] f32
+        # Causal + ragged mask in absolute positions: query start+ti·qt+t
+        # sees cache slots [0, its own position]; everything past the
+        # row's true length (garbage in the final block, trash-block
+        # padding) is masked.  One mask for the group.
+        kpos = j * bsz + jax.lax.broadcasted_iota(jnp.int32, (qt, bsz), 1)
+        qpos = start_ref[r] + ti * qt + jax.lax.broadcasted_iota(
+            jnp.int32, (qt, bsz), 0)
+        visible = (kpos <= qpos)[None]
+        q = q_ref[0].astype(jnp.float32)                 # [g, qt, Dh]
+        k = k_ref[0].astype(jnp.float32)                 # [g, bsz, Dh]
+        s = _dot(q, k, trans_b=True) * scale             # [g, qt, bsz] f32
         if quantized:
             # Per-(head, position) K scale: constant along the contracted
             # Dh axis, so it multiplies the int8 score AFTER the dot —
             # the same algebra models/generate._block_with_cache applies
             # to the gathered view.
-            s = s * ks_ref[0, pl.ds(hd, 1), :]
-        # Causal + ragged mask in absolute positions: query start+t sees
-        # cache slots [0, start+t]; everything past the row's true length
-        # (garbage in the final block, trash-block padding) is masked.
-        kpos = j * bsz + jax.lax.broadcasted_iota(jnp.int32, (tq, bsz), 1)
-        qpos = start_ref[r] + jax.lax.broadcasted_iota(
-            jnp.int32, (tq, bsz), 0)
-        s = jnp.where(kpos <= qpos, s, NEG_INF)
-        m_prev = m_ref[:, :1]                            # [tq, 1]
+            s = _times_head_scales(s, ks_ref, hg * group)
+        s = jnp.where(visible, s, NEG_INF)
+        m_prev = m_ref[:, :, :1]                         # [g, qt, 1]
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_cur)                           # masked -> 0
         corr = jnp.exp(m_prev - m_cur)
         l_ref[:] = jnp.broadcast_to(
-            l_ref[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True),
+            l_ref[:, :, :1] * corr + jnp.sum(p, axis=-1, keepdims=True),
             l_ref.shape,
         )
         if quantized:
             # V scale folds into the probabilities before the PV
             # contraction — again the gathered-view algebra, in-register.
-            p = p * vs_ref[0, pl.ds(hd, 1), :]
-        v = v_ref[0, 0].astype(jnp.float32)              # [bsz, Dh]
+            p = _times_head_scales(p, vs_ref, hg * group)
+        v = v_ref[0].astype(jnp.float32)                 # [g, bsz, Dh]
         acc_ref[:] = acc_ref[:] * corr + _dot(p, v)
         m_ref[:] = jnp.broadcast_to(m_cur, m_ref.shape)
 
     @pl.when(j == jmax)
     def _finalize():
-        # Finalised at the row's LAST USEFUL block, not the grid's last
+        # Finalised at the tile's LAST USEFUL block, not the grid's last
         # iteration — the remaining j > jmax steps touch neither the
         # accumulators nor the output block, and their DMAs are clamped
         # to repeats by the index maps (no copies issued).
-        l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0, 0] = (acc_ref[:] / l).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[:, :, :1], 1e-30)
+        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
+
+
+def _attn_pallas_call(program: str, q: jax.Array, pool_k: jax.Array,
+                      pool_v: jax.Array, k_scale: Optional[jax.Array],
+                      v_scale: Optional[jax.Array], table: jax.Array,
+                      start: jax.Array, jmax: jax.Array,
+                      interpret: bool) -> jax.Array:
+    """q [R, H, NT·QT, Dh] x pool [NB, H, BLOCK, Dh] -> out like q, on
+    the grid :func:`grid_steps` gives ``program``.  ``jmax`` i32[R, NT]
+    is the per-(row, query-tile) last useful logical block."""
+    r, h, t_pad, dh = q.shape
+    nbps = table.shape[1]
+    bsz = pool_k.shape[2]
+    grid = grid_steps(program, r, h, nbps, t_pad, dh, bsz, pool_k.dtype)
+    group, qt = h // grid[1], t_pad // grid[2]
+    if jmax.shape != (r, grid[2]) or qt * grid[2] != t_pad:
+        raise ValueError(
+            f"{program}: q rows {t_pad} and jmax {jmax.shape} are not the "
+            f"padding of grid {grid}")
+    quantized = k_scale is not None
+    kernel = functools.partial(
+        _paged_attn_kernel, scale=1.0 / math.sqrt(dh), bsz=bsz, qt=qt,
+        group=group, quantized=quantized,
+    )
+
+    # Ragged early exit at the DMA level: logical block j of (row r, tile
+    # ti) maps to physical block table[r, min(j, jmax[r, ti])] — beyond
+    # the tile's last useful block the index repeats and Pallas issues no
+    # further copy.
+    def kv_idx(ri, gi, ti, ji, tbl, st, jm):
+        return (tbl[ri, jnp.minimum(ji, jm[ri, ti])], gi, 0, 0)
+
+    def scale_idx(ri, gi, ti, ji, tbl, st, jm):
+        return (tbl[ri, jnp.minimum(ji, jm[ri, ti])], 0, 0)
+
+    def q_idx(ri, gi, ti, ji, tbl, st, jm):
+        return (ri, gi, ti, 0)
+
+    in_specs = [
+        pl.BlockSpec((1, group, qt, dh), q_idx),
+        pl.BlockSpec((1, group, bsz, dh), kv_idx),
+        pl.BlockSpec((1, group, bsz, dh), kv_idx),
+    ]
+    operands = [q, pool_k, pool_v]
+    if quantized:
+        # Mosaic tiles the last two dims, so a (group, bsz) window over
+        # the [H, BLOCK] scale plane lowers only where the group is whole
+        # sublanes: the block carries every head's scales for the
+        # physical block and the kernel picks each head's sublane.
+        in_specs += [
+            pl.BlockSpec((1, h, bsz), scale_idx),
+            pl.BlockSpec((1, h, bsz), scale_idx),
+        ]
+        operands += [k_scale, v_scale]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, group, qt, dh), q_idx),
+        scratch_shapes=[
+            pltpu.VMEM((group, qt, dh), jnp.float32),
+            pltpu.VMEM((group, qt, 128), jnp.float32),
+            pltpu.VMEM((group, qt, 128), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((r, h, t_pad, dh), q.dtype),
+        interpret=interpret,
+    )(table, start, jmax, *operands)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -413,63 +580,60 @@ def _paged_attn_call(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                      v_scale: Optional[jax.Array],
                      table: jax.Array, start: jax.Array, jmax: jax.Array,
                      interpret: bool = False) -> jax.Array:
-    """q [R, H, TQ, Dh] (TQ a multiple of QROWS) x pool [NB, H, BLOCK, Dh]
-    -> out [R, H, TQ, Dh]."""
-    r, h, tq, dh = q.shape
-    nbps = table.shape[1]
+    """The decode program's call (its name is what the device trace shows
+    the kernel as): every query row of a slot in one tile."""
+    return _attn_pallas_call("decode", q, pool_k, pool_v, k_scale, v_scale,
+                             table, start, jmax, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _paged_prefill_call(q: jax.Array, pool_k: jax.Array,
+                        pool_v: jax.Array,
+                        k_scale: Optional[jax.Array],
+                        v_scale: Optional[jax.Array],
+                        table: jax.Array, start: jax.Array,
+                        jmax: jax.Array,
+                        interpret: bool = False) -> jax.Array:
+    """The chunked-prefill program's call (likewise named on the trace):
+    the chunk's rows in as few query tiles as fit."""
+    return _attn_pallas_call("prefill", q, pool_k, pool_v, k_scale, v_scale,
+                             table, start, jmax, interpret)
+
+
+_ATTN_CALLS = {"decode": _paged_attn_call, "prefill": _paged_prefill_call}
+
+
+def _attend(program: str, q: jax.Array, pool_k: jax.Array,
+            pool_v: jax.Array, table: jax.Array, start: jax.Array,
+            k_scale: Optional[jax.Array], v_scale: Optional[jax.Array],
+            interpret: Optional[bool]) -> jax.Array:
+    """Pad ``q`` to ``program``'s query tiles, bound each tile's walk and
+    call the kernel."""
+    r, h, t, dh = q.shape
     bsz = pool_k.shape[2]
-    quantized = k_scale is not None
-    scale = 1.0 / math.sqrt(dh)
-    kernel = functools.partial(
-        _paged_attn_kernel, scale=scale, bsz=bsz, tq=tq,
-        quantized=quantized,
-    )
-
-    # Ragged early exit at the DMA level: logical block j of row r maps
-    # to physical block table[r, min(j, jmax[r])] — beyond the row's last
-    # useful block the index repeats and Pallas issues no further copy.
-    def kv_idx(ri, hi, ji, tbl, st, jm):
-        return (tbl[ri, jnp.minimum(ji, jm[ri])], hi, 0, 0)
-
-    def scale_idx(ri, hi, ji, tbl, st, jm):
-        return (tbl[ri, jnp.minimum(ji, jm[ri])], 0, 0)
-
-    def q_idx(ri, hi, ji, tbl, st, jm):
-        return (ri, hi, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, tq, dh), q_idx),
-        pl.BlockSpec((1, 1, bsz, dh), kv_idx),
-        pl.BlockSpec((1, 1, bsz, dh), kv_idx),
-    ]
-    operands = [q, pool_k, pool_v]
-    if quantized:
-        # Mosaic tiles the last two dims, so a (1, bsz) window over the
-        # [H, BLOCK] scale plane does not lower: the block carries every
-        # head's scales for the physical block and the kernel picks its
-        # head's sublane.
-        in_specs += [
-            pl.BlockSpec((1, h, bsz), scale_idx),
-            pl.BlockSpec((1, h, bsz), scale_idx),
-        ]
-        operands += [k_scale, v_scale]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(r, h, nbps),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, tq, dh), q_idx),
-        scratch_shapes=[
-            pltpu.VMEM((tq, dh), jnp.float32),
-            pltpu.VMEM((tq, 128), jnp.float32),
-            pltpu.VMEM((tq, 128), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((r, h, tq, dh), q.dtype),
-        interpret=interpret,
-    )(table, start, jmax, *operands)
+    nbps = table.shape[1]
+    if interpret is None:
+        interpret = pallas_interpret()
+    if jnp.ndim(start) == 0:
+        start = jnp.broadcast_to(start, (r,))
+    start = start.astype(jnp.int32)
+    _, qt = _step_shape(program, heads=h, head_dim=dh, block_size=bsz,
+                        kv_dtype=pool_k.dtype, t=t)
+    nt = -(-t // qt)
+    # Tile ti's last useful logical block: that of its last REAL query,
+    # at start + min((ti+1)·qt, t) − 1 (pad rows compute a finite, masked
+    # attention nobody reads), clipped into the table: a padded prefill
+    # chunk can extend past the slot's allocation — those query rows are
+    # discarded by the caller, and the mask keeps them finite.
+    last = jnp.minimum((jnp.arange(nt, dtype=jnp.int32) + 1) * qt, t) - 1
+    jmax = jnp.clip((start[:, None] + last[None, :]) // bsz,
+                    0, nbps - 1).astype(jnp.int32)
+    if nt * qt != t:
+        # Mosaic sublane: the query tile's row dim pads to 8.
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, nt * qt - t), (0, 0)))
+    out = _ATTN_CALLS[program](q, pool_k, pool_v, k_scale, v_scale,
+                               table, start, jmax, interpret=interpret)
+    return out[:, :, :t]
 
 
 def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
@@ -495,26 +659,8 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     mask ``kpos <= start+t`` in absolute positions, int8 scales applied
     post-dot (K) / pre-contraction (V), positions past a row's length
     never read — neither compute nor DMA."""
-    r, h, t, dh = q.shape
-    bsz = pool_k.shape[2]
-    nbps = table.shape[1]
-    if interpret is None:
-        interpret = pallas_interpret()
-    if jnp.ndim(start) == 0:
-        start = jnp.broadcast_to(start, (r,))
-    start = start.astype(jnp.int32)
-    # Last useful logical block per row (clipped into the table: a padded
-    # prefill chunk can extend past the slot's allocation — those query
-    # rows are discarded by the caller, and the mask keeps them finite).
-    jmax = jnp.clip((start + t - 1) // bsz, 0, nbps - 1).astype(jnp.int32)
-    t_pad = -(-t // QROWS) * QROWS
-    if t_pad != t:
-        # Mosaic sublane: the query tile's T dim pads to 8.  Pad rows
-        # compute a (finite, masked) attention nobody reads.
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, t_pad - t), (0, 0)))
-    out = _paged_attn_call(q, pool_k, pool_v, k_scale, v_scale,
-                           table, start, jmax, interpret=interpret)
-    return out[:, :, :t]
+    return _attend("decode", q, pool_k, pool_v, table, start, k_scale,
+                   v_scale, interpret)
 
 
 def paged_attention_reference(q: jax.Array, pool_k: jax.Array,
@@ -555,179 +701,26 @@ def paged_attention_reference(q: jax.Array, pool_k: jax.Array,
     return jnp.einsum("rhtk,rhkd->rhtd", p, view_v).astype(q.dtype)
 
 
-# ---------------------------------------------------------------------------
-# Chunked-prefill program: query-tiled multi-row attention with the
-# flash causal skip ACROSS query tiles of the paged table
-# ---------------------------------------------------------------------------
-
-
-def _paged_prefill_kernel(table_ref, start_ref, jmax_ref, q_ref, k_ref,
-                          v_ref, *rest, scale: float, bsz: int, qt: int,
-                          quantized: bool):
-    """One (row, head, query-tile, logical-block) grid step.
-
-    Identical online-softmax algebra to :func:`_paged_attn_kernel`; the
-    difference is the grid's query-tile dim and the PER-TILE ragged
-    bound ``jmax_ref`` i32[R, NT]: tile ``ti``'s causal window ends at
-    its own last query position, so an early tile of a long chunk
-    streams a fraction of the blocks the whole chunk touches — the
-    decode program's single per-row bound would stream (and mask) them
-    all, for every tile.  ``rest`` as in :func:`_paged_attn_kernel`."""
-    ks_ref, vs_ref = rest[:2] if quantized else (None, None)
-    o_ref, acc_ref, m_ref, l_ref = rest[-4:]
-    r = pl.program_id(0)
-    hd = pl.program_id(1)
-    ti = pl.program_id(2)
-    j = pl.program_id(3)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    jmax = jmax_ref[r, ti]
-
-    @pl.when(j <= jmax)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)              # [qt, Dh]
-        k = k_ref[0, 0].astype(jnp.float32)              # [bsz, Dh]
-        s = _dot(q, k, trans_b=True) * scale             # [qt, bsz] f32
-        if quantized:
-            s = s * ks_ref[0, pl.ds(hd, 1), :]
-        # Causal + ragged mask in absolute positions: the tile's queries
-        # sit at start + ti·qt + t.
-        kpos = j * bsz + jax.lax.broadcasted_iota(jnp.int32, (qt, bsz), 1)
-        qpos = start_ref[r] + ti * qt + jax.lax.broadcasted_iota(
-            jnp.int32, (qt, bsz), 0)
-        s = jnp.where(kpos <= qpos, s, NEG_INF)
-        m_prev = m_ref[:, :1]                            # [qt, 1]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_cur)
-        corr = jnp.exp(m_prev - m_cur)
-        l_ref[:] = jnp.broadcast_to(
-            l_ref[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True),
-            l_ref.shape,
-        )
-        if quantized:
-            p = p * vs_ref[0, pl.ds(hd, 1), :]
-        v = v_ref[0, 0].astype(jnp.float32)              # [bsz, Dh]
-        acc_ref[:] = acc_ref[:] * corr + _dot(p, v)
-        m_ref[:] = jnp.broadcast_to(m_cur, m_ref.shape)
-
-    @pl.when(j == jmax)
-    def _finalize():
-        l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0, 0] = (acc_ref[:] / l).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _paged_prefill_call(q: jax.Array, pool_k: jax.Array,
-                        pool_v: jax.Array,
-                        k_scale: Optional[jax.Array],
-                        v_scale: Optional[jax.Array],
-                        table: jax.Array, start: jax.Array,
-                        jmax: jax.Array,
-                        interpret: bool = False) -> jax.Array:
-    """q [R, H, NT·QT, Dh] x pool [NB, H, BLOCK, Dh] -> out like q.
-    ``jmax`` i32[R, NT] is the per-(row, query-tile) last useful logical
-    block."""
-    r, h, t_pad, dh = q.shape
-    nt = jmax.shape[1]
-    qt = t_pad // nt
-    nbps = table.shape[1]
-    bsz = pool_k.shape[2]
-    quantized = k_scale is not None
-    scale = 1.0 / math.sqrt(dh)
-    kernel = functools.partial(
-        _paged_prefill_kernel, scale=scale, bsz=bsz, qt=qt,
-        quantized=quantized,
-    )
-
-    # Per-tile ragged early exit at the DMA level: past tile ti's causal
-    # window the index repeats and no further copy is issued.
-    def kv_idx(ri, hi, ti, ji, tbl, st, jm):
-        return (tbl[ri, jnp.minimum(ji, jm[ri, ti])], hi, 0, 0)
-
-    def scale_idx(ri, hi, ti, ji, tbl, st, jm):
-        return (tbl[ri, jnp.minimum(ji, jm[ri, ti])], 0, 0)
-
-    def q_idx(ri, hi, ti, ji, tbl, st, jm):
-        return (ri, hi, ti, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, qt, dh), q_idx),
-        pl.BlockSpec((1, 1, bsz, dh), kv_idx),
-        pl.BlockSpec((1, 1, bsz, dh), kv_idx),
-    ]
-    operands = [q, pool_k, pool_v]
-    if quantized:
-        in_specs += [
-            pl.BlockSpec((1, h, bsz), scale_idx),
-            pl.BlockSpec((1, h, bsz), scale_idx),
-        ]
-        operands += [k_scale, v_scale]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(r, h, nt, nbps),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, qt, dh), q_idx),
-        scratch_shapes=[
-            pltpu.VMEM((qt, dh), jnp.float32),
-            pltpu.VMEM((qt, 128), jnp.float32),
-            pltpu.VMEM((qt, 128), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((r, h, t_pad, dh), q.dtype),
-        interpret=interpret,
-    )(table, start, jmax, *operands)
-
-
 def paged_prefill_attention(q: jax.Array, pool_k: jax.Array,
                             pool_v: jax.Array, table: jax.Array,
                             start: jax.Array, *,
                             k_scale: Optional[jax.Array] = None,
                             v_scale: Optional[jax.Array] = None,
-                            interpret: Optional[bool] = None,
-                            q_tile: int = QROWS) -> jax.Array:
-    """Query-tiled chunked-prefill attention over ONE layer's block pool.
+                            interpret: Optional[bool] = None) -> jax.Array:
+    """Chunked-prefill attention over ONE layer's block pool.
 
-    The multi-query-row twin of :func:`paged_attention` for T ≫ 1: the
-    chunk's T query rows split into ``q_tile``-row tiles, each with its
-    OWN ragged causal bound (the last logical block its final query can
-    see), so KV streaming is proportional to the causal area — the
-    flash-attention causal skip over a paged block table.  Same
-    semantics contract as :func:`paged_attention` (absolute-position
-    mask, int8 scales post-dot / pre-contraction, clamped DMAs past
-    each bound); the jnp pin is the same
+    The multi-query-row twin of :func:`paged_attention` for T ≫ 1, on
+    the same kernel: the chunk's T query rows go in ONE tile where that
+    fits VMEM (:func:`_step_shape`; 64 rows of 20 heads do) and else in
+    tiles, each with its OWN ragged causal bound (the last logical block
+    its final query can see), so KV streaming is proportional to the
+    causal area — the flash-attention causal skip over a paged block
+    table.  Same semantics contract as :func:`paged_attention`
+    (absolute-position mask, int8 scales post-dot / pre-contraction,
+    clamped DMAs past each bound); the jnp pin is the same
     :func:`paged_attention_reference`."""
-    r, h, t, dh = q.shape
-    bsz = pool_k.shape[2]
-    nbps = table.shape[1]
-    if interpret is None:
-        interpret = pallas_interpret()
-    if jnp.ndim(start) == 0:
-        start = jnp.broadcast_to(start, (r,))
-    start = start.astype(jnp.int32)
-    t_pad = -(-t // q_tile) * q_tile
-    nt = t_pad // q_tile
-    # Tile ti's last useful logical block: its final query sits at
-    # start + (ti+1)·q_tile − 1 (pad rows in the last tile only widen
-    # the bound — their output is sliced away and real rows' masks are
-    # position-exact).
-    tiles = jnp.arange(nt, dtype=jnp.int32)
-    jmax = jnp.clip(
-        (start[:, None] + (tiles[None, :] + 1) * q_tile - 1) // bsz,
-        0, nbps - 1,
-    ).astype(jnp.int32)
-    if t_pad != t:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, t_pad - t), (0, 0)))
-    out = _paged_prefill_call(q, pool_k, pool_v, k_scale, v_scale,
-                              table, start, jmax, interpret=interpret)
-    return out[:, :, :t]
+    return _attend("prefill", q, pool_k, pool_v, table, start, k_scale,
+                   v_scale, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -1089,6 +1082,7 @@ __all__ = [
     "PAGED_PROGRAMS",
     "adapter_delta",
     "fused_verify_tail",
+    "grid_steps",
     "logit_trust_stats",
     "logit_trust_stats_reference",
     "paged_attention",
