@@ -23,11 +23,9 @@ materialised-logits CE; >0 forces the fused vocab-chunked head),
 TDDL_BENCH_ATTN (model default), TDDL_BENCH_ACCUM (grad accumulation
 microbatches, 1).  Optional legs: TDDL_BENCH_LONGCTX=1 (flash vs XLA
 long-context A/B), TDDL_BENCH_GEN=1 (decode), TDDL_BENCH_SERVE=1
-(continuous-batching offered-load sweep + paged-vs-stripe KV A/B at
-equal HBM: concurrent-request capacity ratio, tokens-in-flight
-occupancy, prefix-cache hit rate — "serve_paged" record key,
-TDDL_BENCH_PAGED_* knobs; TDDL_BENCH_SPEC=1 rides it and adds the
-speculative-decode A/B — spec off vs spec_k ∈ {2,4} over identical
+(continuous-batching offered-load sweep; TDDL_BENCH_SPEC=1 rides it
+and adds the speculative-decode A/B — spec off vs spec_k ∈ {2,4} over
+identical
 seeded traffic, accepted_rate + draft/verify tick fractions +
 tokens/s per arm, "spec" record key whose accepted_rate feeds the
 sentinel fingerprint, TDDL_BENCH_SPEC_* knobs; TDDL_BENCH_PAGED_ATTN=1
@@ -605,150 +603,6 @@ def bench_serve() -> "list[dict]":
             f"TTFT p50 {row['ttft_p50_ms']:.1f} ms, shed {shed}")
         records.append(row)
     return records
-
-
-def bench_paged() -> "dict":
-    """Paged-vs-stripe KV A/B (runs with TDDL_BENCH_SERVE=1): concurrency
-    at an EQUAL HBM BUDGET.  The budget is what the stripe pool of
-    TDDL_BENCH_PAGED_SLOTS full MAX_SEQ stripes costs; the paged arm gets
-    ``paged_pool_blocks(budget)`` blocks and one decode row per block, so
-    its admission is bounded by TOKENS in flight, not request count.  Two
-    workloads:
-
-    * **short-request mix** (both arms): every request uses a small
-      fraction of a stripe — the stripe arm strands the rest, the paged
-      arm packs blocks.  ``capacity_ratio`` = peak concurrently-active
-      requests paged/stripe (the >= 1.5x acceptance bar lives in
-      tests/test_bench_contract.py).
-    * **shared-prefix** (paged only): every prompt shares a multi-block
-      prefix — the radix cache prefills it once and later admissions
-      reuse it copy-on-write (``prefix.hit_rate`` > 0, suffix-only
-      prefill).
-
-    Env: TDDL_BENCH_PAGED_MODEL (gpt2), TDDL_BENCH_PAGED_SLOTS (8),
-    TDDL_BENCH_PAGED_SEQ (256), TDDL_BENCH_PAGED_BLOCK (16),
-    TDDL_BENCH_PAGED_REQUESTS (32), TDDL_BENCH_PAGED_NEW (8)."""
-    import jax
-    import numpy as np
-
-    from trustworthy_dl_tpu.models import gpt2
-    from trustworthy_dl_tpu.serve import (
-        ServeRequest,
-        ServingEngine,
-        kv_bytes_per_token,
-        paged_pool_blocks,
-    )
-
-    cfg = gpt2.GPT2Config.from_name(
-        os.environ.get("TDDL_BENCH_PAGED_MODEL", "gpt2")
-    )
-    params = gpt2.init_params(jax.random.PRNGKey(0), cfg)
-    stripe_slots = int(os.environ.get("TDDL_BENCH_PAGED_SLOTS", "8"))
-    max_seq = int(os.environ.get("TDDL_BENCH_PAGED_SEQ", "256"))
-    block = int(os.environ.get("TDDL_BENCH_PAGED_BLOCK", "16"))
-    n_requests = int(os.environ.get("TDDL_BENCH_PAGED_REQUESTS", "32"))
-    max_new = int(os.environ.get("TDDL_BENCH_PAGED_NEW", "8"))
-
-    budget = stripe_slots * max_seq * kv_bytes_per_token(cfg)
-    num_blocks = paged_pool_blocks(cfg, budget, block)
-    # Short-request mix: prompt + new spans 1-2 blocks, a small fraction
-    # of a stripe — the workload shape where request-count capacity and
-    # token capacity diverge the most.
-    plen_lo, plen_hi = 8, max(9, min(2 * block - max_new, max_seq // 8))
-
-    def short_workload(rng):
-        return [ServeRequest(
-            prompt=rng.integers(0, cfg.vocab_size,
-                                int(rng.integers(plen_lo, plen_hi))
-                                ).tolist(),
-            max_new_tokens=int(rng.integers(min(4, max_new), max_new + 1)),
-            temperature=0.0,
-        ) for _ in range(n_requests)]
-
-    record = {
-        "budget_bytes": int(budget), "block_size": block,
-        "max_seq": max_seq, "arms": {},
-    }
-    arm_defs = (
-        ("stripe", dict(paged=False, max_slots=stripe_slots)),
-        # One decode row per block: row count can never bind before the
-        # block pool does — admission is genuinely token-bounded.
-        ("paged", dict(paged=True, max_slots=num_blocks,
-                       num_blocks=num_blocks, block_size=block)),
-    )
-    for label, kw in arm_defs:
-        engine = ServingEngine(params, cfg, max_seq=max_seq,
-                               queue_limit=n_requests,
-                               rng=jax.random.PRNGKey(1), **kw)
-        reqs = short_workload(np.random.default_rng(0))
-        t0 = time.perf_counter()
-        for req in reqs:
-            engine.submit(req)
-        engine.run_until_idle()
-        elapsed = time.perf_counter() - t0
-        summary = engine.metrics_summary()
-        row = {
-            "kv_bytes": int(engine.scheduler.kv.pool_bytes),
-            "peak_active_requests": summary["peak_active_requests"],
-            "peak_tokens_in_flight": summary["peak_tokens_in_flight"],
-            "tokens_per_s": round(summary["tokens_per_s"], 1),
-            "completed": summary["requests_completed"],
-            "wall_s": round(elapsed, 3),
-        }
-        if label == "paged":
-            row["num_blocks"] = num_blocks
-            row["blocks_in_use_final"] = summary["blocks_in_use"]
-        else:
-            row["slots"] = stripe_slots
-        record["arms"][label] = row
-        log(f"paged A/B [{label}]: peak {row['peak_active_requests']} "
-            f"active / {row['peak_tokens_in_flight']} tokens in flight, "
-            f"{row['tokens_per_s']:.1f} tok/s "
-            f"({row['completed']} completed)")
-    stripe, paged = record["arms"]["stripe"], record["arms"]["paged"]
-    record["capacity_ratio"] = round(
-        paged["peak_active_requests"]
-        / max(stripe["peak_active_requests"], 1), 3)
-    record["tokens_per_s_ratio"] = round(
-        paged["tokens_per_s"] / max(stripe["tokens_per_s"], 1e-9), 3)
-
-    # Shared-prefix leg (paged only — the stripe pool cannot share):
-    # every prompt = one multi-block common prefix + a short unique
-    # suffix; rows are scarce relative to requests so later admissions
-    # find the prefix already cached.
-    prefix_len = 2 * block
-    rows = max(2, n_requests // 4)
-    engine = ServingEngine(params, cfg, max_seq=max_seq,
-                           queue_limit=n_requests, max_slots=rows,
-                           block_size=block,
-                           rng=jax.random.PRNGKey(1))
-    rng = np.random.default_rng(7)
-    common = rng.integers(0, cfg.vocab_size, prefix_len).tolist()
-    for _ in range(n_requests):
-        suffix = rng.integers(0, cfg.vocab_size,
-                              int(rng.integers(2, 6))).tolist()
-        engine.submit(ServeRequest(
-            prompt=common + suffix,
-            max_new_tokens=int(rng.integers(min(4, max_new),
-                                            max_new + 1)),
-            temperature=0.0,
-        ))
-    engine.run_until_idle()
-    summary = engine.metrics_summary()
-    record["prefix"] = {
-        "prefix_len": prefix_len,
-        "lookups": summary["prefix_lookups"],
-        "hits": summary["prefix_hits"],
-        "hit_rate": round(summary["prefix_hit_rate"], 3),
-        "tokens_reused": summary["prefix_tokens_reused"],
-        "completed": summary["requests_completed"],
-        "tokens_per_s": round(summary["tokens_per_s"], 1),
-    }
-    log(f"paged A/B: capacity {record['capacity_ratio']}x at equal HBM "
-        f"({budget / 1e6:.1f} MB), prefix hit rate "
-        f"{record['prefix']['hit_rate']} "
-        f"({record['prefix']['tokens_reused']} tokens reused)")
-    return record
 
 
 def bench_spec() -> "dict":
@@ -1977,7 +1831,7 @@ def bench_quant() -> "dict | None":
     from trustworthy_dl_tpu.serve import (
         ServeRequest,
         ServingEngine,
-        kv_bytes_per_slot,
+        kv_bytes_per_token,
     )
 
     cfg = gpt2.GPT2Config.from_name(
@@ -1992,8 +1846,8 @@ def bench_quant() -> "dict | None":
 
     import jax.numpy as jnp
 
-    budget = base_slots * kv_bytes_per_slot(cfg, max_seq)
-    int8_slots = budget // kv_bytes_per_slot(cfg, max_seq, jnp.int8)
+    budget = base_slots * max_seq * kv_bytes_per_token(cfg)
+    int8_slots = budget // (max_seq * kv_bytes_per_token(cfg, jnp.int8))
     plen_hi = min(64, max_seq - max_new + 1)
     if plen_hi <= 8:
         raise ValueError(
@@ -2117,7 +1971,7 @@ def bench_adapters() -> "dict | None":
     def run_arm(label, num_blocks, **kw):
         engine = ServingEngine(params, cfg, max_slots=max_slots,
                                max_seq=max_seq, queue_limit=n_requests,
-                               paged=True, block_size=block_size,
+                               block_size=block_size,
                                num_blocks=num_blocks,
                                rng=jax.random.PRNGKey(1), **kw)
         t0 = time.perf_counter()
@@ -2382,12 +2236,10 @@ def measure(n_chips: int, platform: str) -> dict:
     if os.environ.get("TDDL_BENCH_GEN") == "1":
         bench_generate()
     serve_records = None
-    paged_record = None
     spec_record = None
     paged_attn_record = None
     if os.environ.get("TDDL_BENCH_SERVE") == "1":
         serve_records = bench_serve()
-        paged_record = bench_paged()
         if os.environ.get("TDDL_BENCH_SPEC") == "1":
             spec_record = bench_spec()
         if os.environ.get("TDDL_BENCH_PAGED_ATTN") == "1":
@@ -2458,8 +2310,6 @@ def measure(n_chips: int, platform: str) -> dict:
     _attach_perf_sections(record, compiles=compiles, hbm=hbm_monitor)
     if serve_records is not None:
         record["serve"] = serve_records
-    if paged_record is not None:
-        record["serve_paged"] = paged_record
     if fleet_record is not None:
         record["fleet"] = fleet_record
     if shard_record is not None:

@@ -69,18 +69,16 @@ def params():
 
 
 def test_validate_adapters_contract():
-    validate_adapters(0, None, "model", False, 0)   # disabled: no demands
-    validate_adapters(4, 4, "int8", True, 0)        # the int8 tier
+    validate_adapters(0, None, "fp4", 2)      # disabled: no demands
+    validate_adapters(4, 4, "int8", 0)        # the int8 tier
     with pytest.raises(ValueError):
-        validate_adapters(-1, None, "model", True, 0)
+        validate_adapters(-1, None, "model", 0)
     with pytest.raises(ValueError):
-        validate_adapters(4, 4, "model", False, 0)  # stripe pool
+        validate_adapters(4, 4, "model", 2)   # speculative decode
     with pytest.raises(ValueError):
-        validate_adapters(4, 4, "model", True, 2)   # speculative decode
+        validate_adapters(4, 4, "fp4", 0)     # unknown tier
     with pytest.raises(ValueError):
-        validate_adapters(4, 4, "fp4", True, 0)     # unknown tier
-    with pytest.raises(ValueError):
-        validate_adapters(4, 0, "model", True, 0)   # zero usable pages
+        validate_adapters(4, 0, "model", 0)   # zero usable pages
 
 
 def test_adapter_page_row_is_the_one_spelling():
@@ -178,7 +176,7 @@ def test_adapter_quota_throttles_and_refunds_tenant_spend(params):
             adapter_quota=TenantQuotaConfig(capacity_tokens=10.0),
         ),
         max_slots=2, max_seq=48, queue_limit=8,
-        paged=True, block_size=8, num_blocks=16,
+        block_size=8, num_blocks=16,
         adapter_rank=2, adapter_pool_pages=2,
         adapter_map={"t1": "ad-hot", "t2": "ad-hot"},
     )
@@ -232,8 +230,8 @@ def _mixed_requests(tenant=None):
 def test_adapter_off_and_zero_page_streams_bit_identical(params):
     """Adapter-off (rank 0: structural absence) AND adapter-capable-but
     -unused (rank > 0, every slot on the zero page) streams are
-    bit-identical to generate() — greedy and sampled, paged and stripe;
-    the int8-KV tier pins rank 0 vs zero-page against each other."""
+    bit-identical to generate() — greedy and sampled; the int8-KV tier
+    pins rank 0 vs zero-page against each other."""
     refs = []
     for r in _mixed_requests():
         ref = generate(params, CFG,
@@ -243,10 +241,9 @@ def test_adapter_off_and_zero_page_streams_bit_identical(params):
         refs.append(np.asarray(ref)[0, len(r.prompt):].tolist())
 
     arms = {
-        "paged-rank0": dict(paged=True, block_size=8, num_blocks=24),
-        "stripe-rank0": dict(paged=False),
-        "paged-zero-page": dict(paged=True, block_size=8, num_blocks=24,
-                                adapter_rank=2, adapter_pool_pages=2),
+        "rank0": dict(block_size=8, num_blocks=24),
+        "zero-page": dict(block_size=8, num_blocks=24,
+                          adapter_rank=2, adapter_pool_pages=2),
     }
     for label, kw in arms.items():
         engine = ServingEngine(params, CFG, max_slots=2, max_seq=48,
@@ -256,7 +253,7 @@ def test_adapter_off_and_zero_page_streams_bit_identical(params):
     i8 = []
     for kw in (dict(), dict(adapter_rank=2, adapter_pool_pages=2)):
         engine = ServingEngine(params, CFG, max_slots=2, max_seq=48,
-                               queue_limit=8, paged=True, block_size=8,
+                               queue_limit=8, block_size=8,
                                num_blocks=24, kv_dtype="int8", **kw)
         i8.append(_drain(engine, _mixed_requests()))
     assert i8[0] == i8[1]      # int8 KV: rank 0 == zero page, stream-exact
@@ -274,7 +271,7 @@ def test_adapter_streams_diverge_and_replicate_deterministically(params):
 
     def run_replica():
         engine = ServingEngine(params, CFG, max_slots=2, max_seq=48,
-                               queue_limit=4, paged=True, block_size=8,
+                               queue_limit=4, block_size=8,
                                num_blocks=24, adapter_rank=4,
                                adapter_pool_pages=2,
                                adapter_map={"tx": "ad-x"})
@@ -304,7 +301,7 @@ def test_two_wave_adapter_churn_never_recompiles(params):
 
     adapter_map = {f"t{i}": f"ad{i}" for i in range(6)}
     engine = ServingEngine(params, CFG, max_slots=2, max_seq=48,
-                           queue_limit=16, paged=True, block_size=8,
+                           queue_limit=16, block_size=8,
                            num_blocks=24, adapter_rank=2,
                            adapter_pool_pages=2, adapter_map=adapter_map)
 
@@ -365,7 +362,7 @@ def test_adapter_poison_drill_quarantines_adapter_not_replica(params):
         ),
         chaos=inj,
         max_slots=2, max_seq=48, queue_limit=32,
-        paged=True, block_size=8, num_blocks=32,
+        block_size=8, num_blocks=32,
         adapter_rank=4, adapter_pool_pages=4,
         adapter_map={"t-evil": "ad-ev", "t-good": "ad-ok"},
         monitor=PoisonSignatureMonitor(),

@@ -107,48 +107,6 @@ def test_bench_serve_sweep_records(monkeypatch):
     assert slo["ttft_s"]["count"] == row["completed"]
 
 
-def test_bench_paged_ab_records(monkeypatch):
-    """bench_paged's equal-HBM paged-vs-stripe A/B on a tiny model: the
-    paged arm's concurrent-request capacity beats the stripe arm >= 1.5x
-    inside the stripe pool's byte budget (THE acceptance bar), and the
-    shared-prefix leg records a positive radix-cache hit rate."""
-    import pytest
-    import jax.numpy as jnp
-
-    sys.path.insert(0, str(REPO))
-    import bench
-    from trustworthy_dl_tpu.models import gpt2
-
-    pytest.importorskip("jax")
-    tiny = gpt2.GPT2Config(vocab_size=97, n_positions=64, n_layer=2,
-                           n_embd=32, n_head=4, dtype=jnp.float32)
-    monkeypatch.setattr(gpt2.GPT2Config, "from_name",
-                        staticmethod(lambda name, **kw: tiny))
-    monkeypatch.setenv("TDDL_BENCH_PAGED_SLOTS", "2")
-    monkeypatch.setenv("TDDL_BENCH_PAGED_SEQ", "48")
-    monkeypatch.setenv("TDDL_BENCH_PAGED_BLOCK", "16")
-    monkeypatch.setenv("TDDL_BENCH_PAGED_REQUESTS", "6")
-    monkeypatch.setenv("TDDL_BENCH_PAGED_NEW", "4")
-    record = bench.bench_paged()
-    assert set(record["arms"]) == {"stripe", "paged"}
-    stripe, paged = record["arms"]["stripe"], record["arms"]["paged"]
-    # Short-request mix at equal HBM: tokens-bounded admission must beat
-    # request-bounded admission on concurrently active requests.
-    assert record["capacity_ratio"] >= 1.5          # the acceptance bar
-    assert paged["kv_bytes"] <= record["budget_bytes"]  # equal-HBM arm
-    assert paged["peak_tokens_in_flight"] >= stripe["peak_tokens_in_flight"]
-    assert stripe["completed"] == paged["completed"] == 6
-    for row in (stripe, paged):
-        for key in ("kv_bytes", "peak_active_requests",
-                    "peak_tokens_in_flight", "tokens_per_s", "wall_s"):
-            assert key in row, row
-    # Shared-prefix leg: the radix cache actually shared.
-    prefix = record["prefix"]
-    assert prefix["hit_rate"] > 0
-    assert prefix["tokens_reused"] > 0
-    assert prefix["completed"] == 6
-
-
 def test_bench_spec_ab_records(monkeypatch):
     """bench_spec's spec-off vs spec_k A/B on a tiny model: the off arm
     carries EXACTLY today's serve-sweep record shape (enabling the spec

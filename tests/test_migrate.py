@@ -10,9 +10,9 @@ What this file pins, in three rings:
   impound (blocks leave the request but never re-enter the suspect's
   free list), adapter-page re-acquire on the destination, speculative
   claims unwound before the snapshot travels.
-* **Capability gate** — :func:`can_migrate` is structural: stripe
-  pools, self-migration, geometry/dtype/quantization mismatches and
-  fakes all fall back to the pre-existing cancel-and-recompute path.
+* **Capability gate** — :func:`can_migrate` is structural:
+  self-migration, geometry/dtype/quantization mismatches and fakes
+  all fall back to the pre-existing cancel-and-recompute path.
 * **Fleet drills** — a REPLICA_PREEMPT mid-decode drill whose
   migration/preempt counters match ``predict_fleet()`` EXACTLY, with
   zero lost accepted requests, streams bit-identical to ``generate()``,
@@ -56,7 +56,7 @@ def _ref(params, prompt, new, temperature=0.0, rng=None):
 
 def _paged(params, **kw):
     return ServingEngine(params, CFG, max_slots=2, max_seq=48,
-                         queue_limit=4, paged=True, block_size=8,
+                         queue_limit=4, block_size=8,
                          num_blocks=24, **kw)
 
 
@@ -79,20 +79,15 @@ def _decode_until(engine, rid, n_tokens):
 def test_can_migrate_structural_gate(params):
     """The gate admits only paged↔paged pairs with identical pool
     geometry/dtype/quantization and the export/adopt surface on both
-    ends; everything else (self, stripe, fakes, mismatched tiers)
-    falls back to cancel-and-recompute instead of corrupting a copy."""
+    ends; everything else (self, fakes, mismatched tiers) falls
+    back to cancel-and-recompute instead of corrupting a copy."""
     a, b = _paged(params), _paged(params)
     assert can_migrate(a, b) and can_migrate(b, a)
     # Self-migration is a no-op by definition, not a copy.
     assert not can_migrate(a, a)
-    # Stripe pools have no block table to export on either end.
-    stripe = ServingEngine(params, CFG, max_slots=2, max_seq=48,
-                           queue_limit=4, paged=False)
-    assert not can_migrate(stripe, b)
-    assert not can_migrate(a, stripe)
     # Pool-geometry mismatch: a block copy would be silent corruption.
     small = ServingEngine(params, CFG, max_slots=2, max_seq=48,
-                          queue_limit=4, paged=True, block_size=8,
+                          queue_limit=4, block_size=8,
                           num_blocks=12)
     assert not can_migrate(a, small)
     # Quantization-tier mismatch: f32 → int8 would be a silent dequant.
@@ -146,10 +141,10 @@ def test_destination_refusal_leaves_source_untouched(params):
     request itself, stream-exact."""
     prompt, new = [5, 17, 3], 6
     src = ServingEngine(params, CFG, max_slots=1, max_seq=64,
-                        queue_limit=8, paged=True, block_size=8,
+                        queue_limit=8, block_size=8,
                         num_blocks=24)
     dst = ServingEngine(params, CFG, max_slots=1, max_seq=64,
-                        queue_limit=8, paged=True, block_size=8,
+                        queue_limit=8, block_size=8,
                         num_blocks=24)
     # A live blocker pins the destination's only slot.
     dst.submit(ServeRequest(prompt=list(range(1, 40)),

@@ -560,14 +560,9 @@ def test_attn_gauge_and_summary_surface(params):
         assert 0.0 < summary["decode_tick_fraction"] <= 1.0
         assert 0.0 < summary["prefill_chunk_fraction"] <= 1.0
         assert summary["spec_verify_fraction"] == 0.0  # spec_k == 0
-    # The stripe pool has no paged kernel: its paths are always jnp.
-    stripe = ServingEngine(params, CFG, max_slots=2, max_seq=32,
-                           paged=False, registry=MetricsRegistry())
-    assert stripe.attn_kernel_path == "jnp"
-    assert set(stripe.attn_kernel_paths.values()) == {"jnp"}
 
 
-def test_config_knob_validation_and_threading(params):
+def test_config_knob_validation_and_threading(params, monkeypatch):
     """ServeConfig.attn_impl fails loudly where the operator typed it
     and threads through from_config to the resolved scheduler path."""
     from trustworthy_dl_tpu.core.config import ServeConfig
@@ -583,14 +578,17 @@ def test_config_knob_validation_and_threading(params):
     # Default "auto" resolves to the jnp fallback on the CPU tier (gate
     # closed) — the container default stays green and kernel-free.
     assert off.attn_kernel_path == "jnp"
-    # A forced path on the stripe pool (no kernel exists there) fails
-    # loudly at the engine, and ServeConfig warns like any paged knob
-    # set alongside paged=False.
-    with pytest.raises(ValueError, match="paged"):
-        ServingEngine(params, CFG, max_slots=2, max_seq=32, paged=False,
+    # A forced kernel path that cannot dispatch fails loudly at the
+    # engine: compiled Mosaic on this CPU backend, and any kernel path on
+    # a geometry the eligibility predicate refuses.
+    with pytest.raises(ValueError, match="TPU backend"):
+        ServingEngine(params, CFG, max_slots=2, max_seq=32,
+                      attn_impl="pallas")
+    monkeypatch.setattr(pattn, "supports_paged_attention",
+                        lambda **kw: False)
+    with pytest.raises(ValueError, match="cannot dispatch"):
+        ServingEngine(params, CFG, max_slots=2, max_seq=32,
                       attn_impl="interpret")
-    with pytest.warns(UserWarning, match="attn_impl"):
-        ServeConfig(paged=False, attn_impl="jnp")
 
 
 def test_poison_drill_same_flag_decisions(params):

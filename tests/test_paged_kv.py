@@ -6,15 +6,17 @@ Fast tier, ``paged`` marker.  Host contracts: block alloc/free/COW
 refcount lifecycle, quarantine-of-a-slot releases only UNSHARED blocks,
 out-of-blocks backpressure (and prefix-cache eviction under admission
 pressure), radix insert/lookup/LRU-eviction, pool-sizing math, and the
-``ServeConfig(paged=False)`` warn-don't-drop contract.  The compile-once
+refusal of every way the removed stripe pool could be asked for.  The
+compile-once
 cell jits the tiny 2-layer GPT-2 (seconds, the test_quant pattern) and
 pins that block-table churn never recompiles the fused decode step.
 
 Slow tier: THE smoke — heterogeneous requests with a shared multi-block
-prefix through the paged ``ServingEngine``, streams bit-identical to the
-legacy stripe engine and to batch ``generate()``, prefix hits > 0."""
+prefix through the paged ``ServingEngine``, streams bit-identical to
+batch ``generate()``, prefix hits > 0."""
 
-import warnings
+import json
+import pathlib
 
 import numpy as np
 import pytest
@@ -33,7 +35,6 @@ from trustworthy_dl_tpu.serve import (
     ServeRequest,
     ServingEngine,
     init_paged_pool,
-    kv_bytes_per_slot,
     kv_bytes_per_token,
     paged_pool_blocks,
 )
@@ -349,14 +350,16 @@ def test_admission_evicts_prefix_cache_under_pressure(params):
 
 
 def test_pool_sizing_helpers():
-    """kv_bytes_per_token is the budgeting primitive both layouts share;
-    the deprecated per-slot wrapper and the paged block sizing agree with
-    the pools they describe (trash block included — honest HBM math)."""
+    """kv_bytes_per_token is the budgeting primitive; it and the block
+    sizing agree with the pools they describe (trash block included —
+    honest HBM math)."""
     dh = CFG.n_embd // CFG.n_head
     heads = CFG.n_layer * CFG.n_head
     assert kv_bytes_per_token(CFG) == 2 * heads * dh * 4        # f32
     assert kv_bytes_per_token(CFG, jnp.int8) == 2 * heads * (dh + 4)
-    assert kv_bytes_per_slot(CFG, 48) == 48 * kv_bytes_per_token(CFG)
+    # One full 48-position sequence: 3 blocks + the trash block.
+    assert (init_paged_pool(CFG, 3, 16).pool_bytes
+            == (48 + 16) * kv_bytes_per_token(CFG))
     # A budget of exactly N blocks' bytes buys N-1 usable (+1 trash).
     bpt = kv_bytes_per_token(CFG)
     assert paged_pool_blocks(CFG, 6 * 16 * bpt, 16) == 5
@@ -375,9 +378,9 @@ def test_pool_sizing_helpers():
 def test_int8_kv_defaults_to_full_prompt_prefill(params):
     """Under int8 KV the default prefill chunk is the WHOLE prompt: a
     chunked continuation would attend to the previous chunk's
-    already-quantized blocks, while the stripe int8 engine prefills the
-    whole prompt through a full-precision local cache — parity holds on
-    the one-chunk path.  An explicit chunk opts back into chunking."""
+    already-quantized blocks, while the whole-prompt program prefills
+    through a full-precision local cache — the first token's parity with
+    generate() holds on the one-chunk path.  An explicit chunk opts back into chunking."""
     sched = PagedBatchingScheduler(params, CFG, max_slots=2, max_seq=32,
                                    block_size=8, kv_dtype="int8")
     assert sched.chunk == 32
@@ -392,38 +395,83 @@ def test_int8_kv_defaults_to_full_prompt_prefill(params):
     assert sched.chunk == 32
 
 
-def test_serve_config_paged_false_warns_not_drops():
-    """Satellite contract: paged knobs on a paged=False config must WARN
-    loudly (the legacy stripe pool has no block pool) — silently dropping
-    them would mask an operator error.  Bad paged geometry fails at
-    construction, where the operator typed it."""
-    for kwargs in (dict(block_size=32), dict(num_blocks=12),
-                   dict(prefix_cache=False), dict(prefill_chunk=32)):
-        with pytest.warns(UserWarning, match="ignores paged-pool knob"):
-            ServeConfig(paged=False, **kwargs)
-    # Plain legacy opt-out (no knobs touched) stays silent.
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        ServeConfig(paged=False)
-        ServeConfig()                      # paged default is warning-free
+def _serve_config_of_the_benchmark():
+    path = (pathlib.Path(__file__).resolve().parents[1] / "benchmark"
+            / "configs" / "gpt2-large-774m.json")
+    return json.loads(path.read_text())["deployment"]["serve_config"]
+
+
+def _cli_legacy_stripe(_params):
+    from trustworthy_dl_tpu import cli
+
+    cli.build_serve_parser().parse_args(["--legacy-stripe"])
+
+
+# Every way the removed stripe pool could be asked for: the error, what
+# its message names (argparse exits 2 and has none), and the ask.
+_STRIPE_ASKS = {
+    "config-paged-false": (
+        ValueError, "stripe pool was removed",
+        lambda params: ServeConfig(paged=False)),
+    "engine-paged-kwarg": (
+        TypeError, "paged",
+        lambda params: ServingEngine(params, CFG, max_seq=32, paged=False)),
+    "engine-buckets-kwarg": (
+        TypeError, "buckets",
+        lambda params: ServingEngine(params, CFG, max_seq=32,
+                                     buckets=(16,))),
+    "scheduler-buckets-kwarg": (
+        TypeError, "buckets",
+        lambda params: PagedBatchingScheduler(
+            params, CFG, max_slots=2, max_seq=32, block_size=8,
+            buckets=(16,))),
+    "cli-legacy-stripe": (SystemExit, None, _cli_legacy_stripe),
+}
+
+
+@pytest.mark.parametrize("case", [*_STRIPE_ASKS, "paged-true-constructs"])
+def test_one_kv_pool_no_switch(params, case):
+    """The serving stack has ONE KV pool since PR 33: every way the
+    removed stripe pool could be asked for is refused loudly, where the
+    operator typed it, and the one surviving spelling — the checked
+    ``paged: true`` key the benchmark's configuration file still passes
+    — keeps constructing."""
+    if case in _STRIPE_ASKS:
+        error, match, ask = _STRIPE_ASKS[case]
+        with pytest.raises(error, match=match) as refusal:
+            ask(params)
+        if error is SystemExit:
+            assert refusal.value.code == 2
+        return
+    assert ServeConfig(paged=True).paged
+    config = ServeConfig(**_serve_config_of_the_benchmark())
+    assert config.paged and config.max_slots == 24
+    engine = ServingEngine.from_config(
+        params, CFG, ServeConfig(max_slots=2, max_seq=32, paged=True))
+    assert isinstance(engine.scheduler, PagedBatchingScheduler)
+    assert not hasattr(engine, "paged")
+
+
+def test_engine_validates_geometry_and_routes_config(params):
+    """Engines built without a config hit the same loud geometry check
+    as ``ServeConfig`` (bad geometry fails at construction, where the
+    operator typed it), and from_config threads every paged knob
+    through."""
+    with pytest.raises(ValueError, match="multiple of block_size"):
+        ServingEngine(params, CFG, max_seq=40, block_size=16)
     with pytest.raises(ValueError, match="multiple of block_size"):
         ServeConfig(max_seq=40, block_size=16)
     with pytest.raises(ValueError, match="num_blocks"):
         ServeConfig(max_seq=64, block_size=16, num_blocks=2)
     with pytest.raises(ValueError, match="prefill_chunk"):
         ServeConfig(max_seq=64, block_size=16, prefill_chunk=24)
-
-
-def test_engine_validates_geometry_and_routes_config(params):
-    """Engines built without a config hit the same loud geometry check,
-    and from_config threads every paged knob through."""
-    with pytest.raises(ValueError, match="multiple of block_size"):
-        ServingEngine(params, CFG, max_seq=40, block_size=16)
-    # The paged pool enforces the model's position-table depth just like
-    # init_slots does for the stripe pool — a too-deep max_seq would
-    # otherwise silently gather clamped position embeddings.
+    # The paged scheduler enforces the model's position-table depth — a
+    # too-deep max_seq would otherwise silently gather clamped position
+    # embeddings.
     with pytest.raises(ValueError, match="position table"):
         ServingEngine(params, CFG, max_seq=128, block_size=16)
+    with pytest.raises(ValueError, match="position table"):
+        PagedBatchingScheduler(params, CFG, max_slots=2, max_seq=128)
     cfg = ServeConfig(max_slots=2, max_seq=32, block_size=8,
                       num_blocks=10, prefix_cache=False, prefill_chunk=16)
     engine = ServingEngine.from_config(params, CFG, cfg)
@@ -431,8 +479,7 @@ def test_engine_validates_geometry_and_routes_config(params):
     assert isinstance(sched, PagedBatchingScheduler)
     assert sched.block_size == 8 and sched.num_blocks == 10
     assert sched.prefix is None and sched.chunk == 16
-    # Default pool sizing: max_slots full stripes — paged-by-default is
-    # a strict superset of the stripe pool before any knob is touched.
+    # Default pool sizing: every slot can hold a full max_seq sequence.
     default = ServingEngine(params, CFG, max_slots=2, max_seq=32,
                             block_size=8)
     assert default.scheduler.num_blocks == 2 * (32 // 8)
@@ -534,14 +581,14 @@ def test_mid_prefill_deadline_expiry_releases_blocks(params):
 
 
 @pytest.mark.slow
-def test_paged_smoke_bit_identical_to_stripe_and_generate(params):
+def test_paged_smoke_bit_identical_to_generate(params):
     """THE acceptance smoke: heterogeneous requests — several sharing a
     multi-block prompt prefix, prompts longer than the prefill chunk, a
     temperature-sampled stream — through the paged engine (3 decode rows,
-    chunked prefill interleaved with decode) and the legacy stripe engine.
-    Every request's tokens must be BIT-IDENTICAL across the two engines
-    and to batch generate(); the paged run must actually share (prefix
-    hits > 0) and compile its decode step exactly once."""
+    chunked prefill interleaved with decode).  Every request's tokens
+    must be BIT-IDENTICAL to batch generate(); the paged run must
+    actually share (prefix hits > 0) and compile its decode step exactly
+    once."""
     rng = np.random.default_rng(11)
     common = rng.integers(0, CFG.vocab_size, 20).tolist()  # 2 full blocks
     sample_key = jax.random.PRNGKey(42)
@@ -562,29 +609,19 @@ def test_paged_smoke_bit_identical_to_stripe_and_generate(params):
                                  temperature=0.8, rng=sample_key))
         return reqs
 
-    outputs = {}
-    engines = {}
-    for label, kwargs in (
-        ("paged", dict(block_size=8, prefill_chunk=16)),
-        ("stripe", dict(paged=False)),
-    ):
-        engine = ServingEngine(params, CFG, max_slots=3, max_seq=48,
-                               queue_limit=32, rng=jax.random.PRNGKey(5),
-                               **kwargs)
-        before = engine.scheduler.decode_cache_size()
-        for req in build_requests():
-            engine.submit(req)
-        results = engine.run_until_idle()
-        assert len(results) == 8
-        assert all(r.status == "completed" for r in results.values())
-        assert engine.scheduler.decode_cache_size() - before == 1
-        outputs[label] = {rid: r.tokens for rid, r in results.items()}
-        engines[label] = engine
+    engine = ServingEngine(params, CFG, max_slots=3, max_seq=48,
+                           queue_limit=32, rng=jax.random.PRNGKey(5),
+                           block_size=8, prefill_chunk=16)
+    before = engine.scheduler.decode_cache_size()
+    for req in build_requests():
+        engine.submit(req)
+    results = engine.run_until_idle()
+    assert len(results) == 8
+    assert all(r.status == "completed" for r in results.values())
+    assert engine.scheduler.decode_cache_size() - before == 1
+    outputs = {rid: r.tokens for rid, r in results.items()}
 
-    # Bit-identical across the two memory disciplines, request by request.
-    assert outputs["paged"] == outputs["stripe"]
-
-    # And to batch generate() under the same keys.
+    # Bit-identical to batch generate() under the same keys.
     for rid, req in enumerate(build_requests()):
         ref = generate(params, CFG,
                        jnp.asarray([list(req.prompt)], jnp.int32),
@@ -593,16 +630,16 @@ def test_paged_smoke_bit_identical_to_stripe_and_generate(params):
                             else jax.random.fold_in(jax.random.PRNGKey(5),
                                                     rid)))
         ref_tokens = np.asarray(ref)[0, len(req.prompt):].tolist()
-        assert outputs["paged"][rid] == ref_tokens, f"request {rid}"
+        assert outputs[rid] == ref_tokens, f"request {rid}"
 
     # The sharing was real: later common-prefix admissions reused cached
     # blocks and prefilled only their suffix.
-    summary = engines["paged"].metrics_summary()
+    summary = engine.metrics_summary()
     assert summary["prefix_hits"] >= 2
     assert summary["prefix_tokens_reused"] >= 2 * 2 * 8
     assert summary["prefix_hit_rate"] > 0
     # After the drain only the radix cache still references blocks.
-    sched = engines["paged"].scheduler
+    sched = engine.scheduler
     assert sched.blocks.in_use == len(sched.prefix)
     assert summary["peak_tokens_in_flight"] > 0
 

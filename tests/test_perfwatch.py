@@ -262,12 +262,12 @@ def test_hbm_admit_denies_over_headroom_and_emits_pressure():
 @perfwatch
 def test_engine_consults_headroom_gate_and_shrinks_pool():
     """Low headroom at construction shrinks the paged pool to what the
-    budget buys (floor: one full stripe) instead of allocating past it."""
+    budget buys (floor: one full sequence) instead of allocating past it."""
     reg = MetricsRegistry()
     monitor = HbmMonitor(registry=reg, budget_bytes=1)   # no headroom
     engine, cfg = _tiny_engine(reg, hbm=monitor)
     sched = engine.scheduler
-    assert sched.num_blocks == 48 // sched.block_size    # one-stripe floor
+    assert sched.num_blocks == 48 // sched.block_size    # one-sequence floor
     assert monitor.pressure_denials == 1
     # With a generous budget the requested pool passes untouched.
     rich, _ = _tiny_engine(MetricsRegistry(),

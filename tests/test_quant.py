@@ -25,11 +25,11 @@ from trustworthy_dl_tpu.ops.fused_dequant_matmul import (
 )
 from trustworthy_dl_tpu.quant import int8 as q8
 from trustworthy_dl_tpu.serve import (
-    ContinuousBatchingScheduler,
+    PagedBatchingScheduler,
     ServeRequest,
     ServingEngine,
-    init_slots,
-    kv_bytes_per_slot,
+    init_paged_pool,
+    kv_bytes_per_token,
 )
 
 pytestmark = pytest.mark.quant
@@ -182,10 +182,17 @@ def test_slot_reuse_after_quantized_prefill_overwrites_stale_scales(params):
     position the new request can ever attend to."""
     engine = ServingEngine(params, CFG, max_slots=1, max_seq=48,
                            kv_dtype="int8")
+    tables = {}
+
+    def note_table(rid, _token):
+        tables.setdefault(rid, list(engine.scheduler.tables[0]))
+
     first = engine.submit(ServeRequest(prompt=[9, 8, 7, 6, 5, 4, 3, 2],
-                                       max_new_tokens=8))
+                                       max_new_tokens=8,
+                                       on_token=note_table))
     second = engine.submit(ServeRequest(prompt=[1, 2, 3],
-                                        max_new_tokens=4))
+                                        max_new_tokens=4,
+                                        on_token=note_table))
     results = engine.run_until_idle()
     assert results[first].tokens and results[second].tokens
 
@@ -193,39 +200,42 @@ def test_slot_reuse_after_quantized_prefill_overwrites_stale_scales(params):
                           kv_dtype="int8")
     rid = fresh.submit(ServeRequest(prompt=[1, 2, 3], max_new_tokens=4))
     assert fresh.run_until_idle()[rid].tokens == results[second].tokens
-    # Direct scale hygiene: the reused slot's prefill bucket (16 wide,
-    # covering prompt+new = 7 positions) re-wrote scales from position 0.
-    ks = np.asarray(engine.scheduler.kv.k_scale)[:, 0]   # [L, H, S]
-    assert np.all(ks[:, :, :3] > 0.0)   # prompt rows re-quantized
+    # Direct scale hygiene: the second request reused the first one's
+    # freed block (prompt+new = 7 positions fit one block of 16), and
+    # its prefill re-wrote the scales from position 0.
+    assert tables[second][0] == tables[first][0]
+    ks = np.asarray(engine.scheduler.kv.k_scale)[:, tables[second][0]]
+    assert np.all(ks[:, :, :3] > 0.0)   # [L, H, B]: prompt rows re-quantized
 
 
 def test_int8_halves_kv_value_bytes_and_slot_capacity(params):
     """int8 KV value arrays are exactly half the bf16 pool's bytes (a
-    quarter of f32); at GPT-2 head dims the per-slot total (values +
-    scales) admits >= 1.5x slots at equal HBM."""
-    bf16 = init_slots(CFG, 4, 48, kv_dtype=jnp.bfloat16)
-    q = init_slots(CFG, 4, 48, kv_dtype=jnp.int8)
+    quarter of f32); at GPT-2 head dims the per-token total (values +
+    scales) admits >= 1.5x tokens at equal HBM."""
+    # 4 sequences of 48 positions in blocks of 16: 12 blocks + trash.
+    bf16 = init_paged_pool(CFG, 12, 16, kv_dtype=jnp.bfloat16)
+    q = init_paged_pool(CFG, 12, 16, kv_dtype=jnp.int8)
     assert q.k.nbytes * 2 == bf16.k.nbytes
     assert q.v.nbytes * 2 == bf16.v.nbytes
-    assert q.k_scale.shape == (CFG.n_layer, 4, CFG.n_head, 48)
-    assert q.bytes_per_slot == kv_bytes_per_slot(CFG, 48, jnp.int8)
+    assert q.k_scale.shape == (CFG.n_layer, 13, CFG.n_head, 16)
+    assert q.bytes_per_block == 16 * kv_bytes_per_token(CFG, jnp.int8)
     # Capacity math at real serving dims (no allocation): gpt2 Dh=64.
     full = gpt2.GPT2Config.from_name("gpt2")
-    ratio = (kv_bytes_per_slot(full, 256, jnp.bfloat16)
-             / kv_bytes_per_slot(full, 256, jnp.int8))
+    ratio = (kv_bytes_per_token(full, jnp.bfloat16)
+             / kv_bytes_per_token(full, jnp.int8))
     assert ratio >= 1.5, ratio
 
 
 def test_parity_failure_falls_back_to_model_dtype(params, monkeypatch):
     """The safety latch: a failed parity probe silently (but loudly
     logged) swaps the pool back to the model dtype — serving proceeds,
-    nothing quantized, reason recorded — AND the slot pool shrinks to
+    nothing quantized, reason recorded — AND the block pool shrinks to
     what the int8 byte budget buys at model-dtype cost, so an engine
-    sized to fill HBM at int8 bytes/slot cannot over-allocate on
+    sized to fill HBM at int8 bytes/token cannot over-allocate on
     fallback."""
     monkeypatch.setattr("trustworthy_dl_tpu.quant.int8.kv_parity_probe",
                         lambda *a, **k: False)
-    # Paged (default) pool: the BLOCK count shrinks to what the int8
+    # The BLOCK count shrinks to what the int8
     # byte budget buys at model-dtype cost (6 int8 blocks * 192 B/token
     # // 512 B/token = 2, clamped to the one-full-sequence floor of 3).
     engine = ServingEngine(params, CFG, max_slots=2, max_seq=48,
@@ -234,15 +244,6 @@ def test_parity_failure_falls_back_to_model_dtype(params, monkeypatch):
     assert engine.kv_dtype == "model"
     assert not engine.scheduler.kv.quantized
     assert engine.scheduler.kv.num_blocks == 3
-    rid = engine.submit(ServeRequest(prompt=[1, 2, 3], max_new_tokens=2))
-    assert engine.run_until_idle()[rid].status == "completed"
-    # Legacy stripe pool: the SLOT count shrinks (2 int8 slots -> floor
-    # clamps to the 1-slot minimum here; a pool sized above the floor
-    # stays inside the budget exactly).
-    engine = ServingEngine(params, CFG, max_slots=2, max_seq=48,
-                           kv_dtype="int8", paged=False)
-    assert engine.kv_fallback_reason == "kv_parity_probe_failed"
-    assert engine.scheduler.kv.max_slots == 1
     rid = engine.submit(ServeRequest(prompt=[1, 2, 3], max_new_tokens=2))
     assert engine.run_until_idle()[rid].status == "completed"
 
@@ -262,8 +263,7 @@ def test_unknown_dtypes_fail_loudly_at_construction(params):
     with pytest.raises(ValueError, match="kv_dtype"):
         ServingEngine(params, CFG, kv_dtype="e4m3")
     with pytest.raises(ValueError, match="weight_dtype"):
-        ContinuousBatchingScheduler(params, CFG, 2, 32,
-                                    weight_dtype="nf4")
+        PagedBatchingScheduler(params, CFG, 2, 32, weight_dtype="nf4")
     # The valid surface stays constructible.
     ServeConfig(kv_dtype="int8", weight_dtype="int8")
     ServeConfig()  # defaults

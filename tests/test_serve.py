@@ -1,7 +1,7 @@
 """Serving engine (trustworthy_dl_tpu/serve): continuous batching over the
-slotted KV cache, pinned against models/generate.py numerics.
+paged KV pool, pinned against models/generate.py numerics.
 
-Fast tier: host-side contracts (slot allocator, buckets, backpressure,
+Fast tier: host-side contracts (slot allocator, backpressure,
 output-monitor math, sampling-key layout) — nothing jits a model.
 Slow tier (@pytest.mark.slow): jitted smoke tests, including THE acceptance
 scenario — >= 8 concurrent heterogeneous requests through fewer slots with
@@ -21,8 +21,6 @@ from trustworthy_dl_tpu.serve import (
     ServeRequest,
     ServingEngine,
     SlotAllocator,
-    choose_bucket,
-    default_buckets,
 )
 from trustworthy_dl_tpu.serve.scheduler import request_key_stream
 
@@ -59,16 +57,6 @@ def test_slot_allocator_lifecycle():
     assert alloc.capacity == 3 and alloc.free_count == 1
 
 
-def test_prefill_buckets():
-    assert default_buckets(48) == (16, 32, 48)
-    assert default_buckets(16) == (16,)
-    assert choose_bucket((16, 32, 48), 1) == 16
-    assert choose_bucket((16, 32, 48), 17) == 32
-    assert choose_bucket((16, 32, 48), 48) == 48
-    with pytest.raises(ValueError):
-        choose_bucket((16, 32), 33)
-
-
 def test_backpressure_and_validation(params):
     engine = ServingEngine(params, CFG, max_slots=2, max_seq=32,
                            queue_limit=2)
@@ -81,13 +69,13 @@ def test_backpressure_and_validation(params):
         engine.submit(ServeRequest(prompt=[], max_new_tokens=1))
     with pytest.raises(ValueError):      # can never fit the slot depth
         engine.submit(ServeRequest(prompt=[1] * 30, max_new_tokens=10))
-    # Custom (sub-max_seq) buckets: an unprefillable prompt is rejected at
-    # submit, not crashed on (and slot-leaked) at admission.
-    tight = ServingEngine(params, CFG, max_slots=2, max_seq=48,
-                          buckets=(16,))
-    with pytest.raises(ValueError, match="bucket"):
-        tight.submit(ServeRequest(prompt=[1] * 20, max_new_tokens=2))
+    # A prompt past max_seq is rejected at submit, not crashed on (and
+    # slot-leaked) at admission.
+    tight = ServingEngine(params, CFG, max_slots=2, max_seq=48)
+    with pytest.raises(ValueError, match="max_seq"):
+        tight.submit(ServeRequest(prompt=[1] * 50, max_new_tokens=2))
     assert tight.scheduler.allocator.free_count == 2  # nothing leaked
+    assert tight.scheduler.blocks_in_use == 0
 
 
 def test_request_key_stream_matches_generate_layout():

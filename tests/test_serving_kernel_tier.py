@@ -273,7 +273,7 @@ def test_resolve_attn_impls_partial_downgrade_warns(caplog, monkeypatch):
 
 
 def _engine(params, impl, **kw):
-    kwargs = dict(max_slots=2, max_seq=48, queue_limit=16, paged=True,
+    kwargs = dict(max_slots=2, max_seq=48, queue_limit=16,
                   block_size=8, num_blocks=24, attn_impl=impl)
     kwargs.update(kw)
     return ServingEngine(params, CFG, **kwargs)

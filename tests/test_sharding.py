@@ -345,7 +345,7 @@ def test_fleet_tp_scale_up_arrives_with_wider_shards():
     def factory(index, **kwargs):
         fakes[index] = FakeEngine(index, **kwargs)
         fakes[index].scheduler = type(  # compute-bound, empty queue
-            "S", (), {"occupancy": 1.0, "max_seq": 64, "buckets": (64,),
+            "S", (), {"occupancy": 1.0, "max_seq": 64,
                       "tokens_in_flight": 0})()
         return fakes[index]
 

@@ -13,9 +13,8 @@ spec counters/span surface.
 
 Slow tier: THE acceptance drill — heterogeneous requests (shared
 prefix, mid-prompt chunked prefill, deadline expiry mid-draft) at
-spec_k=4 across two waves, streams bit-identical to spec-off, the
-legacy stripe engine and ``generate()``, with the compile watcher
-attached and zero storms."""
+spec_k=4 across two waves, streams bit-identical to spec-off and
+``generate()``, with the compile watcher attached and zero storms."""
 
 import numpy as np
 import pytest
@@ -58,22 +57,21 @@ def params():
 
 def test_spec_config_validation(params):
     """spec_k fails loudly where the operator typed it: range bound,
-    paged pool required (COW rollback), model-dtype verify required
-    (the int8 tier is the DRAFT) — at ServeConfig AND at a raw engine
-    construction."""
+    model-dtype verify required (the int8 tier is the DRAFT) — at
+    ServeConfig AND at a raw engine construction."""
     with pytest.raises(ValueError, match="spec_k"):
         ServeConfig(spec_k=-1)
     with pytest.raises(ValueError, match="spec_k"):
         ServeConfig(spec_k=SPEC_K_MAX + 1)
-    with pytest.raises(ValueError, match="paged"):
-        ServeConfig(spec_k=2, paged=False)
     with pytest.raises(ValueError, match="weight_dtype"):
         ServeConfig(spec_k=2, weight_dtype="int8")
-    ServeConfig(spec_k=4)                       # valid: paged + model
-    validate_spec(0, False, "int8")             # disabled: anything goes
+    ServeConfig(spec_k=4)                       # valid: model-dtype verify
+    validate_spec(0, "int8")                    # disabled: anything goes
+    with pytest.raises(ValueError, match="spec_k"):
+        validate_spec(SPEC_K_MAX + 1, "model")
     # Engines built without a config hit the same loud checks.
-    with pytest.raises(ValueError, match="paged"):
-        ServingEngine(params, CFG, max_seq=32, paged=False, spec_k=2)
+    with pytest.raises(ValueError, match="spec_k"):
+        ServingEngine(params, CFG, max_seq=32, spec_k=SPEC_K_MAX + 1)
     with pytest.raises(ValueError, match="weight_dtype"):
         ServingEngine(params, CFG, max_seq=32, weight_dtype="int8",
                       spec_k=2)
@@ -261,8 +259,8 @@ def test_spec_drill_heterogeneous_bit_identical_zero_storms(params):
     """Acceptance drill: two waves of heterogeneous requests — a shared
     multi-block prefix, prompts crossing the chunked-prefill boundary,
     a seeded sampled stream — at spec_k=4, with the compile watcher
-    attached: streams BIT-IDENTICAL to spec-off, to the legacy stripe
-    engine and to generate(); a deadline expiring mid-draft retires
+    attached: streams BIT-IDENTICAL to spec-off and to generate(); a
+    deadline expiring mid-draft retires
     with a prefix of the reference stream; zero compile storms."""
     from trustworthy_dl_tpu.obs.compilewatch import (
         CompileRegistry,
@@ -290,7 +288,6 @@ def test_spec_drill_heterogeneous_bit_identical_zero_storms(params):
     arms = (
         ("spec", dict(block_size=8, prefill_chunk=8, spec_k=4)),
         ("off", dict(block_size=8, prefill_chunk=8)),
-        ("stripe", dict(paged=False)),
     )
     registry = CompileRegistry().install()
     watcher = CompileWatcher(registry)
@@ -312,7 +309,7 @@ def test_spec_drill_heterogeneous_bit_identical_zero_storms(params):
     finally:
         registry.uninstall()
 
-    assert outputs["spec"] == outputs["off"] == outputs["stripe"]
+    assert outputs["spec"] == outputs["off"]
     # Zero storms across accept/reject churn, block churn, prefix hits
     # and both waves: the three spec programs each compiled exactly
     # once, at their declared warmup.

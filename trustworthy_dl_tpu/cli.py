@@ -382,18 +382,13 @@ def build_serve_parser() -> argparse.ArgumentParser:
                         help="per-request wall-clock deadline")
     parser.add_argument("--no-monitor", action="store_true",
                         help="disable the trust-aware output monitor")
-    parser.add_argument("--legacy-stripe", action="store_true",
-                        help="use the legacy per-request stripe KV pool "
-                             "instead of the paged block pool (escape "
-                             "hatch; paged is the default — occupancy "
-                             "bounded by tokens in flight, not request "
-                             "count; README §Serving)")
     parser.add_argument("--block-size", type=int, default=16,
                         help="paged-pool token positions per KV block "
                              "(--max-seq must be a multiple)")
     parser.add_argument("--num-blocks", type=int, default=None,
                         help="usable paged-pool blocks; default sizes "
-                             "the pool to --max-slots full stripes")
+                             "the pool to --max-slots full --max-seq "
+                             "sequences")
     parser.add_argument("--no-prefix-cache", action="store_true",
                         help="disable the radix prefix cache (requests "
                              "sharing a prompt prefix otherwise reuse "
@@ -416,8 +411,8 @@ def build_serve_parser() -> argparse.ArgumentParser:
                              "through, counted in spec_near_tie_flips; "
                              "rejected draft KV rolls back by COW "
                              "refcount decrement. "
-                             "0 disables (default).  Requires the paged "
-                             "pool and weight-dtype 'model'; README "
+                             "0 disables (default).  Requires "
+                             "weight-dtype 'model'; README "
                              "§Serving/'Speculative decoding'")
     parser.add_argument("--no-spec-decode", action="store_true",
                         help="force speculative decoding OFF even when "
@@ -445,9 +440,9 @@ def build_serve_parser() -> argparse.ArgumentParser:
                              "per-slot page table.  0 disables "
                              "(default) — the serve programs keep their "
                              "adapter-free signatures, streams "
-                             "bit-identical to today's.  >0 requires "
-                             "the paged pool and is incompatible with "
-                             "--spec-k; README §Serving/Adapters")
+                             "bit-identical to today's.  >0 is "
+                             "incompatible with --spec-k; README "
+                             "§Serving/Adapters")
     parser.add_argument("--adapter-pool-pages", type=int, default=None,
                         help="usable pages in the adapter HBM pool "
                              "(page 0 is the pinned all-zero page; "
@@ -605,7 +600,7 @@ def serve_main(argv: Optional[List[str]] = None,
         max_slots=args.max_slots, max_seq=args.max_seq,
         queue_limit=args.queue_limit,
         kv_dtype=args.kv_dtype, weight_dtype=args.weight_dtype,
-        paged=not args.legacy_stripe, block_size=args.block_size,
+        block_size=args.block_size,
         num_blocks=args.num_blocks,
         prefix_cache=not args.no_prefix_cache,
         prefill_chunk=args.prefill_chunk,

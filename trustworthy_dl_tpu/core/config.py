@@ -11,7 +11,6 @@ and the YAML loader honours the README schema for real.
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -33,16 +32,13 @@ PARALLELISM_MODES = ("data", "model", "tensor", "sequence", "expert", "hybrid")
 SPEC_K_MAX = 8
 
 
-def validate_spec(spec_k: int, paged: bool, weight_dtype: str) -> None:
+def validate_spec(spec_k: int, weight_dtype: str) -> None:
     """Loud construction-time validation of the speculative-decoding
     knob — shared by ``ServeConfig`` and the serving engine so a bad
     combination fails where the operator typed it.
 
     * ``spec_k`` must sit in [0, SPEC_K_MAX] (0 = disabled — the
       serve path is bit-for-bit today's).
-    * spec decoding runs over the PAGED pool only: rejected draft KV
-      rolls back by COW refcount decrement, which the legacy stripe
-      pool has no machinery for.
     * the draft model IS the weight-only int8 tier, built automatically
       at engine construction; the verify pass is the MODEL-dtype tier —
       ``weight_dtype="int8"`` would collapse draft and verify onto the
@@ -53,12 +49,6 @@ def validate_spec(spec_k: int, paged: bool, weight_dtype: str) -> None:
     if not 0 <= int(spec_k) <= SPEC_K_MAX:
         raise ValueError(
             f"spec_k must be in [0, {SPEC_K_MAX}], got {spec_k}"
-        )
-    if spec_k > 0 and not paged:
-        raise ValueError(
-            "spec_k > 0 requires the paged KV pool (paged=True): "
-            "rejected draft tokens roll back by releasing COW block "
-            "claims, which the legacy stripe pool cannot express"
         )
     if spec_k > 0 and weight_dtype != "model":
         raise ValueError(
@@ -335,17 +325,13 @@ class ExperimentConfig:
 
 def validate_adapters(adapter_rank: int,
                       adapter_pool_pages: Optional[int],
-                      adapter_dtype: str, paged: bool,
-                      spec_k: int) -> None:
+                      adapter_dtype: str, spec_k: int) -> None:
     """Loud construction-time validation of the adapter-tier knobs —
     shared by ``ServeConfig`` and ``serve.adapters`` so a bad
     combination fails where the operator typed it.
 
     * ``adapter_rank`` must be >= 0 (0 = disabled: the serve programs
       keep their adapter-free signatures, bit-for-bit today's output).
-    * adapters ride the PAGED pool only: the per-slot adapter-page
-      table is the same traced-table discipline as the KV block table,
-      which the legacy stripe pool has no machinery for.
     * ``spec_k`` > 0 is rejected: the int8 draft model carries no
       adapter deltas, so draft and verify would diverge on every
       adapter-carrying request and speculation would never accept.
@@ -358,13 +344,6 @@ def validate_adapters(adapter_rank: int,
         )
     if adapter_rank == 0:
         return
-    if not paged:
-        raise ValueError(
-            "adapter_rank > 0 requires the paged KV pool (paged=True): "
-            "adapter pages are claimed per slot through the same traced "
-            "page-table discipline as KV blocks, which the legacy "
-            "stripe pool cannot express"
-        )
     if spec_k > 0:
         raise ValueError(
             "adapter_rank > 0 is incompatible with spec_k > 0: the int8 "
@@ -398,16 +377,14 @@ class ServeConfig:
     * ``weight_dtype``: "model" or "int8" (weight-only int8 for the
       decode matmuls; embedding/lm-head stay high precision).
 
-    The paged-pool knobs select the KV memory discipline (the default
-    since the paged-KV PR; README §Serving):
+    The pool knobs size the one KV layout, block-pooled KV with
+    per-slot block tables — occupancy bounded by tokens in flight, not
+    request count (README §Serving):
 
-    * ``paged``: block-pooled KV with per-slot block tables — occupancy
-      bounded by tokens in flight, not request count.  ``False`` is the
-      legacy per-request stripe pool escape hatch.
     * ``block_size``: token positions per block (``max_seq`` must be a
       multiple).
-    * ``num_blocks``: usable pool blocks; ``None`` sizes the pool to
-      ``max_slots`` full stripes (a strict superset of the stripe pool).
+    * ``num_blocks``: usable pool blocks; ``None`` sizes the pool so
+      every one of ``max_slots`` can hold a full ``max_seq`` sequence.
     * ``prefix_cache``: radix prefix cache — requests sharing a prompt
       prefix reuse already-filled blocks copy-on-write.
     * ``prefill_chunk``: positions fed per chunked-prefill tick (a
@@ -423,14 +400,15 @@ class ServeConfig:
     one counted departure from spec-off bit-parity), and rolls back
     rejected draft KV by COW refcount decrement.  0 (default) disables
     — the serve path is bit-for-bit today's; ``spec_k`` > 0 requires
-    ``paged=True`` and ``weight_dtype="model"``
-    (:func:`validate_spec`).
+    ``weight_dtype="model"`` (:func:`validate_spec`).
 
     Unknown dtype strings and bad paged geometry fail HERE, at
     construction — never at trace time inside a jitted serving program.
-    Paged knobs set on a ``paged=False`` config WARN loudly (the legacy
-    path has no block pool — silent dropping would mask an operator
-    error), but construction proceeds.
+
+    ``paged`` is a checked input, not a switch: the benchmark's
+    configuration file still passes ``"paged": true``, so the key is
+    accepted, ``False`` is refused (the per-request stripe pool was
+    removed in PR 33), and nothing reads the field after construction.
     """
 
     max_slots: int = 8
@@ -444,7 +422,7 @@ class ServeConfig:
     prefix_cache: bool = True
     prefill_chunk: Optional[int] = None
     spec_k: int = 0
-    # Decode-attention path (paged pool only): "auto" resolves through
+    # Decode-attention path: "auto" resolves through
     # the shared Pallas gate (TDDL_PAGED_ATTN; kernel on TPU, jnp gather
     # fallback elsewhere), "pallas"/"interpret"/"jnp" force a path —
     # README §Serving/"Decode attention kernel".
@@ -490,35 +468,21 @@ class ServeConfig:
                 f"attn_impl must be one of ('auto', 'pallas', "
                 f"'interpret', 'jnp'), got {self.attn_impl!r}"
             )
-        validate_spec(self.spec_k, self.paged, self.weight_dtype)
+        if not self.paged:
+            raise ValueError(
+                "ServeConfig.paged must be True: the stripe pool was "
+                "removed in PR 33; the paged block pool is the only KV "
+                "layout"
+            )
+        validate_spec(self.spec_k, self.weight_dtype)
         validate_adapters(self.adapter_rank, self.adapter_pool_pages,
-                          self.adapter_dtype, self.paged, self.spec_k)
+                          self.adapter_dtype, self.spec_k)
         if self.max_slots < 1:
             raise ValueError(f"max_slots must be >= 1, got {self.max_slots}")
         if self.max_seq < 1:
             raise ValueError(f"max_seq must be >= 1, got {self.max_seq}")
-        if self.paged:
-            validate_paged_geometry(self.max_seq, self.block_size,
-                                    self.num_blocks, self.prefill_chunk)
-        else:
-            paged_knobs = ("block_size", "num_blocks", "prefix_cache",
-                           "prefill_chunk", "attn_impl")
-            # Compare against the dataclass field defaults themselves —
-            # a hand-written (name, default) table here would be a third
-            # copy of the defaults that could silently drift.
-            ignored = [
-                f.name for f in dataclasses.fields(self)
-                if f.name in paged_knobs
-                and getattr(self, f.name) != f.default
-            ]
-            if ignored:
-                warnings.warn(
-                    f"ServeConfig(paged=False) ignores paged-pool knob(s) "
-                    f"{', '.join(ignored)}: the legacy stripe pool has no "
-                    f"block pool, no prefix cache and no chunked prefill. "
-                    f"Drop paged=False or drop the knob(s).",
-                    UserWarning, stacklevel=2,
-                )
+        validate_paged_geometry(self.max_seq, self.block_size,
+                                self.num_blocks, self.prefill_chunk)
 
 
 @dataclass

@@ -404,10 +404,10 @@ def fused_verify_logits(params: Params, x: jax.Array,
 # per layer; a slot's logical cache is reassembled by gathering its block
 # table (i32 per-slot physical ids — traced VALUES, so block churn never
 # recompiles).  The attention core is the untouched _block_with_cache:
-# the gathered view is numerically the same [R, H, S, Dh] cache the
-# stripe engine holds resident (valid positions carry identical values;
+# the gathered view is numerically the same [R, H, S, Dh] cache a
+# contiguous KVCache holds (valid positions carry identical values;
 # garbage positions are masked to exactly-zero probabilities), so paged
-# decode is bit-identical to stripe decode by construction.  After the
+# decode is bit-identical to generate()'s by construction.  After the
 # core runs, the rows it wrote into the view are extracted and scattered
 # back into the pool at (physical block, offset); positions outside the
 # slot's table land in the reserved trash block 0.
@@ -467,8 +467,8 @@ def _paged_block(block: Params, x: jax.Array, pool_k_l: jax.Array,
 
     * ``"jnp"`` (default, the reference semantics): gather each row's
       view through ``table``, run the dense ``_block_with_cache`` core on
-      it (one numerics source for generate, stripe serve and paged
-      serve), then scatter the newly written rows back into the pool.
+      it (one numerics source for generate and paged serve), then
+      scatter the newly written rows back into the pool.
     * ``"pallas"`` / ``"interpret"``: scatter the fresh K/V into the pool
       FIRST (same quantize-at-write values, same ``_pool_write_coords``
       scatter), then run the ragged ``ops.paged_attention`` kernel

@@ -6,16 +6,14 @@ concurrent requests with heterogeneous prompt/output lengths, admitted and
 retired mid-flight without recompiles.  This package is the Orca/vLLM-style
 answer, shaped for XLA's static-shape world:
 
-* ``kv_slots``  — KV memory pools: the PAGED block pool (default —
-  fixed-size token blocks [L, NB+1, H, BLOCK, Dh] + host-side block
-  tables/refcounts + radix prefix cache, vLLM/RadixAttention-style, so
-  occupancy is bounded by tokens in flight, not requests) and the legacy
-  slotted stripe cache [L, MAX_SLOTS, H, S, Dh]; no dynamic shapes
-  anywhere — block tables are traced gather indices.
+* ``kv_slots``  — the KV memory pool: fixed-size token blocks
+  [L, NB+1, H, BLOCK, Dh] + host-side block tables/refcounts + radix
+  prefix cache, vLLM/RadixAttention-style, so occupancy is bounded by
+  tokens in flight, not requests; no dynamic shapes anywhere — block
+  tables are traced gather indices.
 * ``scheduler`` — continuous (iteration-level) batching: chunked prefill
-  interleaved with ONE fused decode step for all active slots (paged),
-  or bucketed synchronous prefill (stripe), mid-flight retirement and
-  slot/block reuse.
+  interleaved with ONE fused decode step for all active slots,
+  mid-flight retirement and slot/block reuse.
 * ``engine``    — request lifecycle (queue → prefill → decode → stream),
   deadlines, backpressure, serving metrics (TTFT / ITL / tokens/s / slot
   occupancy), and trust-aware output monitoring: per-request logit
@@ -76,24 +74,15 @@ from trustworthy_dl_tpu.serve.kv_slots import (
     PagedKV,
     PrefixCache,
     SlotAllocator,
-    SlotKV,
     init_paged_pool,
-    init_slots,
-    kv_bytes_per_slot,
     kv_bytes_per_token,
     paged_pool_blocks,
 )
-from trustworthy_dl_tpu.serve.scheduler import (
-    ContinuousBatchingScheduler,
-    PagedBatchingScheduler,
-    choose_bucket,
-    default_buckets,
-)
+from trustworthy_dl_tpu.serve.scheduler import PagedBatchingScheduler
 
 __all__ = [
     "AutoscalerConfig",
     "BlockAllocator",
-    "ContinuousBatchingScheduler",
     "DEFAULT_SLO_CLASSES",
     "FleetConfig",
     "FleetResult",
@@ -110,19 +99,14 @@ __all__ = [
     "ServingEngine",
     "ServingFleet",
     "SlotAllocator",
-    "SlotKV",
     "Tenant",
     "TenantQuotaConfig",
     "WorkloadConfig",
     "WorkloadItem",
     "backoff_ticks",
-    "choose_bucket",
-    "default_buckets",
     "drive_closed_loop",
     "generate_workload",
     "init_paged_pool",
-    "init_slots",
-    "kv_bytes_per_slot",
     "kv_bytes_per_token",
     "paged_pool_blocks",
     "replay_workload",

@@ -7,7 +7,7 @@ admits queued requests into free slots, runs the scheduler's single fused
 decode step, streams new tokens to per-request callbacks, and retires
 finished/expired sequences.  Everything host-side is O(MAX_SLOTS) python;
 the device work per iteration is exactly one decode program plus one
-bucketed prefill per admission.
+prefill chunk per slot still prefilling.
 
 Trust-aware admission control (the inference mirror of the training trust
 state machine): every emitted token's logit entropy and top-1 margin are
@@ -51,7 +51,6 @@ from trustworthy_dl_tpu.serve.kv_slots import (
     validate_paged_geometry,
 )
 from trustworthy_dl_tpu.serve.scheduler import (
-    ContinuousBatchingScheduler,
     PagedBatchingScheduler,
     SlotTask,
     request_key_stream,
@@ -210,7 +209,6 @@ class ServingEngine:
     def __init__(self, params: Any, cfg: gpt2.GPT2Config,
                  max_slots: int = 8, max_seq: int = 256,
                  queue_limit: int = 64,
-                 buckets: Optional[Sequence[int]] = None,
                  rng: Optional[jax.Array] = None,
                  monitor: Optional[OutputMonitor] = None,
                  enable_monitor: bool = True,
@@ -219,7 +217,7 @@ class ServingEngine:
                  registry: Any = None,
                  kv_dtype: str = "model", weight_dtype: str = "model",
                  kv_parity_check: bool = True,
-                 paged: bool = True, block_size: int = 16,
+                 block_size: int = 16,
                  num_blocks: Optional[int] = None,
                  prefix_cache: bool = True,
                  prefill_chunk: Optional[int] = None,
@@ -260,20 +258,16 @@ class ServingEngine:
         # Paged pool geometry fails loudly HERE, before any model work
         # (kv_slots.validate_paged_geometry — the same check ServeConfig
         # runs, so engines built without a config stay just as safe).
-        self.paged = paged
-        if paged:
-            validate_paged_geometry(max_seq, block_size, num_blocks,
-                                    prefill_chunk)
-            if num_blocks is None:
-                # Default pool matches the stripe engine's token capacity
-                # exactly (max_slots full stripes), so paged-by-default
-                # is a strict superset before any knob is touched.
-                num_blocks = max_slots * (max_seq // block_size)
+        validate_paged_geometry(max_seq, block_size, num_blocks,
+                                prefill_chunk)
+        if num_blocks is None:
+            # Default pool: every slot can hold a full max_seq sequence.
+            num_blocks = max_slots * (max_seq // block_size)
         # HBM headroom gate (obs/hbm.py): the KV pool is the one
         # construction-time allocation an operator sizes to fill HBM —
         # consult the monitor BEFORE allocating and shrink to what the
-        # live budget actually has room for (floor: one full stripe /
-        # one slot), instead of discovering the OOM at device_put.  The
+        # live budget actually has room for (floor: one full sequence),
+        # instead of discovering the OOM at device_put.  The
         # denial itself is attributable: ``hbm_pressure`` event +
         # ``tddl_hbm_pressure_total``.
         self.hbm = hbm
@@ -286,33 +280,21 @@ class ServingEngine:
             # is what lets a scale-UP (bigger TP group) fit more blocks
             # into the same per-chip budget.
             bpt = max(bpt // max(self.tp_size, 1), 1)
-            if paged:
-                requested = num_blocks * block_size * bpt
-                if not hbm.admit(requested, what="serve_paged_pool"):
-                    # Size the shrunk pool from the SAME sweep that made
-                    # the deny decision (admit() stored it) — a second
-                    # sweep could report headroom the gate never saw.
-                    headroom = max(hbm.last_headroom or 0, 0)
-                    floor = max_seq // block_size
-                    allowed = max(int(headroom // (block_size * bpt)),
-                                  floor)
-                    logger.warning(
-                        "HBM headroom gate: paged pool shrunk %d -> %d "
-                        "blocks (requested %d bytes, headroom %d)",
-                        num_blocks, allowed, requested, headroom,
-                    )
-                    num_blocks = allowed
-            else:
-                requested = max_slots * max_seq * bpt
-                if not hbm.admit(requested, what="serve_stripe_pool"):
-                    headroom = max(hbm.last_headroom or 0, 0)
-                    allowed = max(int(headroom // (max_seq * bpt)), 1)
-                    logger.warning(
-                        "HBM headroom gate: stripe pool shrunk %d -> %d "
-                        "slots (requested %d bytes, headroom %d)",
-                        max_slots, allowed, requested, headroom,
-                    )
-                    max_slots = allowed
+            requested = num_blocks * block_size * bpt
+            if not hbm.admit(requested, what="serve_paged_pool"):
+                # Size the shrunk pool from the SAME sweep that made
+                # the deny decision (admit() stored it) — a second
+                # sweep could report headroom the gate never saw.
+                headroom = max(hbm.last_headroom or 0, 0)
+                floor = max_seq // block_size
+                allowed = max(int(headroom // (block_size * bpt)),
+                              floor)
+                logger.warning(
+                    "HBM headroom gate: paged pool shrunk %d -> %d "
+                    "blocks (requested %d bytes, headroom %d)",
+                    num_blocks, allowed, requested, headroom,
+                )
+                num_blocks = allowed
         # Quantization tier (quant/int8.py).  Unknown dtype strings fail
         # HERE; the int8 KV swap is additionally parity-gated: a short
         # eager greedy-token probe against the full-precision path, with
@@ -323,14 +305,14 @@ class ServingEngine:
         q8.validate_dtypes(kv_dtype, weight_dtype)
         # Speculative decoding (README §Serving/"Speculative decoding"):
         # the same loud knob validation ServeConfig runs, so engines
-        # built without a config fail identically (paged pool required,
-        # weight_dtype must stay "model" — the int8 tier is the DRAFT).
+        # built without a config fail identically (weight_dtype must
+        # stay "model" — the int8 tier is the DRAFT).
         from trustworthy_dl_tpu.core.config import (validate_adapters,
                                                     validate_spec)
 
-        validate_spec(spec_k, paged, weight_dtype)
+        validate_spec(spec_k, weight_dtype)
         validate_adapters(adapter_rank, adapter_pool_pages, adapter_dtype,
-                          paged, spec_k)
+                          spec_k)
         self.spec_k = int(spec_k)
         self.kv_fallback_reason: Optional[str] = None
         # The decode view is built at most ONCE here and shared with the
@@ -365,37 +347,23 @@ class ServingEngine:
                 # have the fallback allocate 2-4x that in the model dtype
                 # — on a budgeted deployment that is an OOM at
                 # construction, the opposite of "always safe".  Shrink
-                # the pool (blocks when paged, slots on the stripe path)
-                # to what the int8 byte budget buys at model-dtype cost.
+                # the pool's blocks to what the int8 byte budget buys at
+                # model-dtype cost (floor: one full sequence).
                 int8_bpt = kv_bytes_per_token(cfg, jnp.int8)
                 model_bpt = kv_bytes_per_token(cfg)
-                if paged:
-                    fallback_blocks = max(
-                        max_seq // block_size,
-                        (num_blocks * int8_bpt) // model_bpt,
-                    )
-                    logger.warning(
-                        "int8 KV parity probe failed: falling back to "
-                        "the model-dtype paged pool, shrinking %d -> %d "
-                        "blocks to stay inside the int8 pool's HBM "
-                        "budget (safety gate; see README "
-                        "§Serving/Quantization)",
-                        num_blocks, fallback_blocks,
-                    )
-                    num_blocks = fallback_blocks
-                else:
-                    fallback_slots = max(
-                        1, (max_slots * int8_bpt) // model_bpt
-                    )
-                    logger.warning(
-                        "int8 KV parity probe failed: falling back to "
-                        "the model-dtype KV pool, shrinking %d -> %d "
-                        "slots to stay inside the int8 pool's HBM "
-                        "budget (safety gate; see README "
-                        "§Serving/Quantization)",
-                        max_slots, fallback_slots,
-                    )
-                    max_slots = fallback_slots
+                fallback_blocks = max(
+                    max_seq // block_size,
+                    (num_blocks * int8_bpt) // model_bpt,
+                )
+                logger.warning(
+                    "int8 KV parity probe failed: falling back to "
+                    "the model-dtype paged pool, shrinking %d -> %d "
+                    "blocks to stay inside the int8 pool's HBM "
+                    "budget (safety gate; see README "
+                    "§Serving/Quantization)",
+                    num_blocks, fallback_blocks,
+                )
+                num_blocks = fallback_blocks
         self.kv_dtype = kv_dtype
         self.weight_dtype = weight_dtype
         # Multi-tenant adapter tier (serve/adapters.py): the SECOND
@@ -438,37 +406,21 @@ class ServingEngine:
                 cfg, adapter_rank, pages, adapter_dtype=adapter_dtype,
                 trace=trace,
             )
-        if paged:
-            # ``attn_impl`` selects the decode-attention read (README
-            # §Serving/"Decode attention kernel"): "auto" resolves
-            # through the shared Pallas gate to the ragged paged-
-            # attention kernel (+ fused trust epilogue) on TPU and the
-            # jnp gather fallback elsewhere; "pallas"/"jnp" force a
-            # path.  Resolution happens once, in the scheduler, and is
-            # baked into every compiled program as a static.
-            self.scheduler: Any = PagedBatchingScheduler(
-                params, cfg, max_slots, max_seq, buckets,
-                kv_dtype=kv_dtype, weight_dtype=weight_dtype, view=view,
-                block_size=block_size, num_blocks=num_blocks,
-                prefix_cache=prefix_cache, prefill_chunk=prefill_chunk,
-                spec_k=self.spec_k, draft_view=draft_view,
-                attn_impl=attn_impl, adapters=self.adapter_pool,
-            )
-        else:
-            if attn_impl not in ("auto", "jnp"):
-                # The stripe pool has no paged-attention kernel: an
-                # explicit kernel ask must fail where the operator typed
-                # it, not silently serve the gather path (ServeConfig
-                # additionally warns for any paged knob on paged=False).
-                raise ValueError(
-                    f"attn_impl={attn_impl!r} requires the paged pool "
-                    "(paged=True); the stripe engine always runs the "
-                    "jnp attention path"
-                )
-            self.scheduler = ContinuousBatchingScheduler(
-                params, cfg, max_slots, max_seq, buckets,
-                kv_dtype=kv_dtype, weight_dtype=weight_dtype, view=view,
-            )
+        # ``attn_impl`` selects the decode-attention read (README
+        # §Serving/"Decode attention kernel"): "auto" resolves through
+        # the shared Pallas gate to the ragged paged-attention kernel
+        # (+ fused trust epilogue) on TPU and the jnp gather fallback
+        # elsewhere; "pallas"/"jnp" force a path.  Resolution happens
+        # once, in the scheduler, and is baked into every compiled
+        # program as a static.
+        self.scheduler = PagedBatchingScheduler(
+            params, cfg, max_slots, max_seq,
+            kv_dtype=kv_dtype, weight_dtype=weight_dtype, view=view,
+            block_size=block_size, num_blocks=num_blocks,
+            prefix_cache=prefix_cache, prefill_chunk=prefill_chunk,
+            spec_k=self.spec_k, draft_view=draft_view,
+            attn_impl=attn_impl, adapters=self.adapter_pool,
+        )
         self.queue_limit = queue_limit
         self.monitor = monitor if monitor is not None else (
             OutputMonitor() if enable_monitor else None
@@ -556,14 +508,11 @@ class ServingEngine:
                                                   qview=view):
                 self._quant_err_hist.observe(err, **self._rlabels)
         # Paged-pool occupancy surface: blocks referenced (requests +
-        # prefix cache), tokens in flight, and prefix-cache reuse.  The
-        # gauges/counter are registered on BOTH pool layouts so every
-        # serve snapshot carries them (stripe reports 0 blocks — it has
-        # no block pool to occupy).
+        # prefix cache), tokens in flight, and prefix-cache reuse.
         self._blocks_gauge = _metric(
             registry.gauge, "tddl_serve_blocks_in_use",
             "Paged-KV blocks currently referenced (requests + prefix "
-            "cache); 0 on the legacy stripe pool",
+            "cache)",
             labels=self._rlabel_names,
         )
         self._tif_gauge = _metric(
@@ -733,7 +682,6 @@ class ServingEngine:
             queue_limit=serve_config.queue_limit,
             kv_dtype=serve_config.kv_dtype,
             weight_dtype=serve_config.weight_dtype,
-            paged=serve_config.paged,
             block_size=serve_config.block_size,
             num_blocks=serve_config.num_blocks,
             prefix_cache=serve_config.prefix_cache,
@@ -763,15 +711,6 @@ class ServingEngine:
             raise ValueError(
                 f"prompt+new = {total} exceeds max_seq="
                 f"{self.scheduler.max_seq}"
-            )
-        largest_bucket = max(self.scheduler.buckets)
-        if prompt.size > largest_bucket:
-            # Reject at submission, not at admission — an engine built
-            # with custom (sub-max_seq) buckets must fail the request up
-            # front rather than crash the serving loop mid-flight.
-            raise ValueError(
-                f"prompt of {prompt.size} tokens exceeds the largest "
-                f"prefill bucket {largest_bucket}"
             )
         # Tenant → adapter resolution: an explicit request.adapter wins,
         # else the engine's adapter_map by tenant.  Loud when the tier
@@ -878,7 +817,7 @@ class ServingEngine:
             return
         self.ledger.append({
             "request_id": rid, "status": status, "admitted": False,
-            "slot": -1, "layout": "paged" if self.paged else "stripe",
+            "slot": -1, "layout": "paged",
             "block_ids": [], "prefix_block_ids": [],
             "prefix_publishers": {},
             "kv_dtype": self.kv_dtype, "weight_dtype": self.weight_dtype,
@@ -945,13 +884,11 @@ class ServingEngine:
         self._expire_queued(now)
         self._shed_for_slo()
 
-        # Admit as many queued requests as there are free slots.  On the
-        # stripe path each admission prefetches the first token
-        # (synchronous bucketed prefill), so TTFT is the admission
-        # latency itself; the paged path only books host-side state here
-        # (block claim + prefix-cache lookup) and the chunked prefill
-        # runs inside subsequent decode_ticks — the first token lands
-        # when the final chunk completes.
+        # Admit as many queued requests as there are free slots.
+        # Admission only books host-side state (block claim +
+        # prefix-cache lookup); the chunked prefill runs inside
+        # subsequent decode_ticks — the first token lands when the final
+        # chunk completes.
         emitted = 0
         while self._queue and self.scheduler.has_free_slot:
             task, request = self._queue.popleft()
@@ -1040,25 +977,24 @@ class ServingEngine:
         self._tif_gauge.set(float(tif), **self._rlabels)
         if self.slo is not None:
             self.slo.observe("occupancy", self.scheduler.occupancy)
-        if self.paged:
-            self._blocks_gauge.set(float(self.scheduler.blocks_in_use),
-                                    **self._rlabels)
-            hits = self.scheduler.prefix_hits
-            if hits > self._prefix_hits_seen:
-                self._prefix_counter.inc(hits - self._prefix_hits_seen,
-                                         **self._rlabels)
-                self._prefix_hits_seen = hits
-            if self.spec_k:
-                proposed = self.scheduler.spec_proposed
-                accepted = self.scheduler.spec_accepted
-                seen_p, seen_a = self._spec_seen
-                if proposed > seen_p:
-                    self._spec_proposed_counter.inc(proposed - seen_p,
-                                                    **self._rlabels)
-                if accepted > seen_a:
-                    self._spec_accepted_counter.inc(accepted - seen_a,
-                                                    **self._rlabels)
-                self._spec_seen = (proposed, accepted)
+        self._blocks_gauge.set(float(self.scheduler.blocks_in_use),
+                                **self._rlabels)
+        hits = self.scheduler.prefix_hits
+        if hits > self._prefix_hits_seen:
+            self._prefix_counter.inc(hits - self._prefix_hits_seen,
+                                     **self._rlabels)
+            self._prefix_hits_seen = hits
+        if self.spec_k:
+            proposed = self.scheduler.spec_proposed
+            accepted = self.scheduler.spec_accepted
+            seen_p, seen_a = self._spec_seen
+            if proposed > seen_p:
+                self._spec_proposed_counter.inc(proposed - seen_p,
+                                                **self._rlabels)
+            if accepted > seen_a:
+                self._spec_accepted_counter.inc(accepted - seen_a,
+                                                **self._rlabels)
+            self._spec_seen = (proposed, accepted)
         self.metrics.collect_batch_metrics({
             "step": self._iteration,
             "active_slots": self.scheduler.active_count,
@@ -1083,8 +1019,8 @@ class ServingEngine:
             it += 1
             # Starvation check: with nothing in flight before the step,
             # a step that admitted nothing and shed nothing proves the
-            # queue can never drain — every row quarantined (stripe), or
-            # quarantined BLOCKS starving the paged pool even after
+            # queue can never drain — every row quarantined, or
+            # quarantined BLOCKS starving the pool even after
             # prefix-cache eviction; no retirement can ever free more
             # capacity.  Shed the queue instead of spinning to the
             # iteration bound.
@@ -1232,11 +1168,8 @@ class ServingEngine:
         pair = self._inflight.get(request_id)
         if pair is None:
             return None
-        exporter = getattr(self.scheduler, "export_migration", None)
-        if exporter is None:          # stripe pool: no block table
-            return None
         task, request = pair
-        snap = exporter(task)
+        snap = self.scheduler.export_migration(task)
         if snap is None:
             return None
         snap["request"] = request
@@ -1410,7 +1343,7 @@ class ServingEngine:
         """In-flight ids past prefill with tokens emitted — the set a
         disaggregated fleet moves off a prefill-specialist replica (a
         migration snapshot exists exactly for these)."""
-        prefilling = getattr(self.scheduler, "_prefill", {})
+        prefilling = self.scheduler._prefill
         return [rid for rid, (task, _) in self._inflight.items()
                 if task.emitted and not task.done
                 and task.slot not in prefilling]
@@ -1441,8 +1374,7 @@ class ServingEngine:
     @property
     def attn_kernel_path(self) -> str:
         """The resolved decode-attention path this engine's compiled
-        programs bake in: "pallas" | "interpret" | "jnp" (the stripe
-        scheduler is always "jnp" — it has no paged kernel).  The
+        programs bake in: "pallas" | "interpret" | "jnp".  The
         monitor's entropy/margin come from the kernel's fused trust
         epilogue exactly when this is not "jnp"."""
         return self.scheduler.attn_impl
@@ -1451,14 +1383,8 @@ class ServingEngine:
     def attn_kernel_paths(self) -> Dict[str, str]:
         """Per-program resolved paths for the whole serving-kernel tier
         (ops.paged_attention.PAGED_PROGRAMS: decode / prefill / verify /
-        adapter), each "pallas" | "interpret" | "jnp".  The stripe
-        scheduler has no paged programs — every entry is "jnp"."""
-        from trustworthy_dl_tpu.ops import paged_attention as pattn
-
-        impls = getattr(self.scheduler, "attn_impls", None)
-        if impls is None:
-            return {p: "jnp" for p in pattn.PAGED_PROGRAMS}
-        return dict(impls)
+        adapter), each "pallas" | "interpret" | "jnp"."""
+        return dict(self.scheduler.attn_impls)
 
     @property
     def quarantined_slots(self):
@@ -1521,33 +1447,32 @@ class ServingEngine:
             "attn_kernel_path": self.attn_kernel_path,
             "attn_kernel_paths": self.attn_kernel_paths,
         }
-        if self.paged:
-            sched = self.scheduler
-            out["blocks_in_use"] = sched.blocks_in_use
-            # Phase-share companions to decode_tick_fraction for the
-            # two new kernel arms: wall share spent advancing prefill
-            # chunks / inside the batched spec verify (both direction
-            # LOWER in the sentinel fingerprint — a kernel arm that
-            # does not shrink them is a regression signal).
-            out["prefill_chunk_fraction"] = (
-                sched.prefill_chunk_s / elapsed if elapsed > 0 else 0.0)
-            out["spec_verify_fraction"] = (
-                sched.spec_verify_s / elapsed if elapsed > 0 else 0.0)
-            out["prefix_lookups"] = sched.prefix_lookups
-            out["prefix_hits"] = sched.prefix_hits
-            out["prefix_tokens_reused"] = sched.prefix_tokens_reused
-            out["prefix_hit_rate"] = (
-                sched.prefix_hits / sched.prefix_lookups
-                if sched.prefix_lookups else 0.0
-            )
-            if self.spec_k:
-                out["spec_k"] = self.spec_k
-                out["spec_proposed"] = sched.spec_proposed
-                out["spec_accepted"] = sched.spec_accepted
-                out["accepted_rate"] = round(sched.accepted_rate, 4)
-                out["spec_near_tie_flips"] = sched.spec_near_tie_flips
-                out["spec_ticks"] = sched.spec_ticks
-                out["spec_fallback_ticks"] = sched.spec_fallback_ticks
+        sched = self.scheduler
+        out["blocks_in_use"] = sched.blocks_in_use
+        # Phase-share companions to decode_tick_fraction for the
+        # prefill and verify kernel arms: wall share spent advancing
+        # prefill chunks / inside the batched spec verify (both
+        # direction LOWER in the sentinel fingerprint — a kernel arm
+        # that does not shrink them is a regression signal).
+        out["prefill_chunk_fraction"] = (
+            sched.prefill_chunk_s / elapsed if elapsed > 0 else 0.0)
+        out["spec_verify_fraction"] = (
+            sched.spec_verify_s / elapsed if elapsed > 0 else 0.0)
+        out["prefix_lookups"] = sched.prefix_lookups
+        out["prefix_hits"] = sched.prefix_hits
+        out["prefix_tokens_reused"] = sched.prefix_tokens_reused
+        out["prefix_hit_rate"] = (
+            sched.prefix_hits / sched.prefix_lookups
+            if sched.prefix_lookups else 0.0
+        )
+        if self.spec_k:
+            out["spec_k"] = self.spec_k
+            out["spec_proposed"] = sched.spec_proposed
+            out["spec_accepted"] = sched.spec_accepted
+            out["accepted_rate"] = round(sched.accepted_rate, 4)
+            out["spec_near_tie_flips"] = sched.spec_near_tie_flips
+            out["spec_ticks"] = sched.spec_ticks
+            out["spec_fallback_ticks"] = sched.spec_fallback_ticks
         if self.adapter_pool is not None:
             out["adapters"] = {
                 "rank": self.adapter_rank,
@@ -1581,11 +1506,8 @@ class ServingEngine:
     def verify_attribution(self) -> "tuple[bool, list]":
         """Reconcile the attached ledger's records against the paged
         pool's block-lifecycle journal (obs.attribution) — the audit the
-        serve-trust acceptance runs.  Stripe engines verify trivially
-        (records carry no block ids)."""
+        serve-trust acceptance runs."""
         if self.ledger is None:
             raise ValueError("engine has no attribution ledger attached")
-        allocator = getattr(self.scheduler, "blocks", None) \
-            if self.paged else self.scheduler.allocator
         return attribution.verify_attribution(self.ledger.records(),
-                                              allocator)
+                                              self.scheduler.blocks)

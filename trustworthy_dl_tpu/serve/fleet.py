@@ -616,7 +616,6 @@ class ServingFleet:
         self._next_fid = 0
         self.rejected = 0
         self._max_seq: Optional[int] = None
-        self._max_bucket: Optional[int] = None
         self.requests: Dict[int, _FleetRequest] = {}
         # Fid whose terminal is mid-processing: an adapter conviction
         # fired from inside its own retirement must not usurp it.
@@ -746,7 +745,6 @@ class ServingFleet:
             queue_limit=serve_config.queue_limit,
             kv_dtype=serve_config.kv_dtype,
             weight_dtype=serve_config.weight_dtype,
-            paged=serve_config.paged,
             block_size=serve_config.block_size,
             num_blocks=serve_config.num_blocks,
             prefix_cache=serve_config.prefix_cache,
@@ -851,14 +849,11 @@ class ServingFleet:
         sched = getattr(engine, "scheduler", None)
         if sched is not None and self._max_seq is None:
             self._max_seq = sched.max_seq
-            self._max_bucket = max(sched.buckets)
         return rep
 
     @staticmethod
     def _engine_journal(engine: Any) -> Any:
-        sched = getattr(engine, "scheduler", None)
-        return getattr(sched, "blocks", None) or \
-            getattr(sched, "allocator", None)
+        return getattr(getattr(engine, "scheduler", None), "blocks", None)
 
     # -- submission --------------------------------------------------------
 
@@ -887,10 +882,6 @@ class ServingFleet:
                 raise ValueError(
                     f"prompt+new = {total} exceeds max_seq="
                     f"{self._max_seq}")
-            if prompt_len > self._max_bucket:
-                raise ValueError(
-                    f"prompt of {prompt_len} tokens exceeds the largest "
-                    f"prefill bucket {self._max_bucket}")
         cost = prompt_len + int(request.max_new_tokens)
         tenant = request.tenant
         # Resolve the adapter at the FLEET boundary (explicit wins, else

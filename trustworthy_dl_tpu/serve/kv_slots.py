@@ -1,18 +1,8 @@
-"""KV cache memory pools for the serving engine — slotted (legacy) and
-paged (default).
+"""KV cache memory pool for the serving engine: fixed-size token blocks.
 
-The original layout (PR 1) is the slotted stripe pool: one cache SLOT per
-in-flight sequence,
-
-    k, v: [L, MAX_SLOTS, H, MAX_SEQ, Dh]
-
-with per-slot valid lengths.  A short request strands almost its whole
-MAX_SEQ stripe, so concurrency is capped by *request count* rather than
-by tokens in flight.
-
-The paged pool (this PR) is the vLLM answer (PagedAttention, Kwon et al.,
-SOSP '23) shaped for XLA's static-shape world: fixed-size token BLOCKS in
-a global pool,
+The pool is the vLLM answer (PagedAttention, Kwon et al., SOSP '23)
+shaped for XLA's static-shape world: fixed-size token BLOCKS in a global
+pool,
 
     k, v: [L, NUM_BLOCKS + 1, H, BLOCK, Dh]      (physical block 0 = trash)
 
@@ -39,15 +29,11 @@ many requests are live.  Admission/retirement only change the host-side
 refcount bookkeeping are pure host work (SlotAllocator / BlockAllocator
 below).
 
-Slot hygiene (stripe pool): a freed slot's cache rows are NOT scrubbed —
-the decode step keeps writing garbage K/V at the freed slot's stale
-position (static shapes mean inactive rows still compute).  That is safe
-by construction: a slot is only re-used after prefill overwrites
-positions [0, prompt_len), the decode mask admits k_pos <= current
-position only, and every position a new request ever attends to is
-(re)written before it first becomes visible.  The paged pool gets the
-same property from the trash block instead (stale tables are never handed
-to the device; inactive rows are pointed at block 0).
+Block hygiene: a freed block is NOT scrubbed.  That is safe by
+construction: a block is only re-used after prefill or decode writes
+every position a new request attends to before it first becomes visible
+(the mask admits k_pos <= current position only), stale tables are never
+handed to the device, and inactive rows are pointed at block 0.
 """
 
 from __future__ import annotations
@@ -61,50 +47,11 @@ import jax.numpy as jnp
 from trustworthy_dl_tpu.models import gpt2
 
 
-class SlotKV(NamedTuple):
-    """Slot-pooled KV arrays; lengths live host-side (scheduler).
-
-    int8 tier (quant/int8.py): ``k``/``v`` store int8 and the
-    per-(head, position) f32 scales ride in ``k_scale``/``v_scale``
-    ``[L, MAX_SLOTS, H, MAX_SEQ]``.  None scales = full-precision pool
-    (the pre-quantization layout, byte-for-byte)."""
-
-    k: jax.Array  # [L, MAX_SLOTS, H, MAX_SEQ, Dh]
-    v: jax.Array  # [L, MAX_SLOTS, H, MAX_SEQ, Dh]
-    k_scale: Optional[jax.Array] = None  # [L, MAX_SLOTS, H, MAX_SEQ]
-    v_scale: Optional[jax.Array] = None
-
-    @property
-    def max_slots(self) -> int:
-        return self.k.shape[1]
-
-    @property
-    def max_seq(self) -> int:
-        return self.k.shape[3]
-
-    @property
-    def quantized(self) -> bool:
-        return self.k_scale is not None
-
-    @property
-    def pool_bytes(self) -> int:
-        """Total HBM the pool holds (values + scales) — the number the
-        ``tddl_serve_kv_bytes`` gauge reports."""
-        total = self.k.nbytes + self.v.nbytes
-        if self.k_scale is not None:
-            total += self.k_scale.nbytes + self.v_scale.nbytes
-        return total
-
-    @property
-    def bytes_per_slot(self) -> int:
-        return self.pool_bytes // self.max_slots
-
-
 def kv_bytes_per_token(cfg: gpt2.GPT2Config,
                        kv_dtype: Optional[Any] = None) -> int:
     """Bytes ONE cached token position costs under ``kv_dtype`` WITHOUT
-    allocating — the HBM-budget primitive both pool layouts share (a
-    stripe slot costs ``max_seq`` of these, a paged block ``block_size``).
+    allocating — the HBM-budget primitive (a block costs ``block_size``
+    of these, a full sequence ``max_seq``).
     int8 counts 1 byte/element plus the 4-byte per-(head, position)
     scale, K and V each."""
     kv_dtype = cfg.dtype if kv_dtype is None else kv_dtype
@@ -114,16 +61,6 @@ def kv_bytes_per_token(cfg: gpt2.GPT2Config,
         return 2 * heads * (dh + 4)
     itemsize = jnp.zeros((), kv_dtype).dtype.itemsize
     return 2 * heads * dh * itemsize
-
-
-def kv_bytes_per_slot(cfg: gpt2.GPT2Config, max_seq: int,
-                      kv_dtype: Optional[Any] = None) -> int:
-    """Deprecated thin wrapper: ``max_seq * kv_bytes_per_token(...)``.
-
-    Kept for the stripe pool's callers; new HBM budgeting should compute
-    from :func:`kv_bytes_per_token` (and :func:`paged_pool_blocks` for
-    block-count sizing) so the math works for both layouts."""
-    return max_seq * kv_bytes_per_token(cfg, kv_dtype)
 
 
 def paged_pool_blocks(cfg: gpt2.GPT2Config, hbm_bytes: int, block_size: int,
@@ -173,28 +110,6 @@ def resolve_prefill_chunk(max_seq: int, block_size: int,
     if prefill_chunk is not None:
         return prefill_chunk
     return max(block_size, (min(64, max_seq) // block_size) * block_size)
-
-
-def init_slots(cfg: gpt2.GPT2Config, max_slots: int, max_seq: int,
-               kv_dtype: Optional[Any] = None) -> SlotKV:
-    """``kv_dtype=None`` keeps the model compute dtype; ``jnp.int8``
-    allocates the quantized pool (int8 values + f32 scales, zeros — an
-    untouched row dequantises to exact zeros, same as the dense pool)."""
-    if max_seq > cfg.n_positions:
-        raise ValueError(
-            f"max_seq={max_seq} exceeds the model's position table "
-            f"(n_positions={cfg.n_positions})"
-        )
-    kv_dtype = cfg.dtype if kv_dtype is None else kv_dtype
-    shape = (cfg.n_layer, max_slots, cfg.n_head, max_seq,
-             cfg.n_embd // cfg.n_head)
-    if kv_dtype == jnp.int8:
-        scales = jnp.zeros(shape[:-1], jnp.float32)
-        return SlotKV(k=jnp.zeros(shape, jnp.int8),
-                      v=jnp.zeros(shape, jnp.int8),
-                      k_scale=scales, v_scale=scales)
-    return SlotKV(k=jnp.zeros(shape, kv_dtype),
-                  v=jnp.zeros(shape, kv_dtype))
 
 
 class SlotAllocator:
@@ -307,10 +222,9 @@ class PagedKV(NamedTuple):
 def init_paged_pool(cfg: gpt2.GPT2Config, num_blocks: int, block_size: int,
                     kv_dtype: Optional[Any] = None) -> PagedKV:
     """Allocate ``num_blocks`` usable blocks (+1 trash).  ``kv_dtype``
-    semantics match :func:`init_slots`: None follows the model compute
-    dtype, ``jnp.int8`` allocates the quantized pool (int8 values + f32
-    per-(head, position) scales, zeros — an untouched block dequantises
-    to exact zeros)."""
+    None follows the model compute dtype, ``jnp.int8`` allocates the
+    quantized pool (int8 values + f32 per-(head, position) scales,
+    zeros — an untouched block dequantises to exact zeros)."""
     if num_blocks < 1:
         raise ValueError(f"num_blocks must be >= 1, got {num_blocks}")
     if block_size > cfg.n_positions:

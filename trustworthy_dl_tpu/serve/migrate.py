@@ -39,8 +39,8 @@ the same compile-once programs.
 
 The capability gate (:func:`can_migrate`) is deliberately structural —
 paged scheduler on both ends, identical pool geometry/dtype, the
-export/adopt surface present — so heterogeneous or stripe-pool fleets
-(and the unit-test fake engines) fall back to the pre-existing
+export/adopt surface present — so heterogeneous fleets (and the
+unit-test fake engines) fall back to the pre-existing
 cancel-and-recompute path instead of failing.
 """
 
@@ -84,8 +84,8 @@ def can_migrate(src_engine: Any, dst_engine: Any) -> bool:
     both schedulers are paged, and the pools share geometry and dtype
     (a copy between mismatched pools would be a silent corruption, and
     between int8 and f32 tiers a silent dequant).  Anything that fails
-    the gate — stripe pools, fakes, heterogeneous fleets — keeps the
-    old cancel-and-recompute behaviour.
+    the gate — fakes, heterogeneous fleets — keeps the old
+    cancel-and-recompute behaviour.
     """
     if src_engine is dst_engine:
         return False

@@ -1,24 +1,34 @@
-"""Continuous (iteration-level) batching over the slotted KV cache.
+"""Continuous (iteration-level) batching over the paged KV block pool.
 
 Orca's insight (Yu et al., OSDI '22): schedule at token granularity, not
-request granularity — every iteration admits queued requests into free
-slots, runs ONE fused decode step for all live sequences, and retires
-finished ones immediately so their slots free up mid-flight.  Here that
-schedule drives exactly two kinds of XLA programs:
+request granularity — every tick admits queued requests, advances the
+prompts still being prefilled, runs ONE fused decode step for all live
+sequences, and retires finished ones immediately so their rows and blocks
+free up mid-flight.  ``PagedBatchingScheduler`` is the one scheduler, over
+the one pool layout (``kv_slots.PagedKV``):
 
-* **prefill** — per newly admitted slot, over its prompt padded to a
-  BUCKET length (``default_buckets``: powers of two), so the number of
-  distinct prefill programs is bounded by the bucket count, not by the
-  number of distinct prompt lengths ever seen;
-* **decode** — one program for the engine's lifetime: [MAX_SLOTS] tokens
-  in, [MAX_SLOTS] next tokens out, attending to the slot cache at per-slot
-  offsets via the SAME ``models/generate._block_with_cache`` numerics the
-  batch sampler uses (vector ``start``).  Admission/retirement never
-  change its shapes, so it compiles exactly once.
+* **admission** — pure host work: a request claims a decode row
+  (``SlotAllocator``) and ``ceil((prompt + max_new) / BLOCK)`` physical
+  blocks (``BlockAllocator``), reusing the blocks of the longest prompt
+  prefix the radix ``PrefixCache`` holds (refcounted).  No row, or no
+  blocks even after evicting cached prefixes, is backpressure: the task
+  stays queued, untouched.
+* **chunked prefill** — the unshared suffix of a prompt is fed
+  ``prefill_chunk`` positions a tick (``paged_chunk``: one compiled
+  program for every chunk of every prompt; a fresh prompt that fits one
+  chunk takes ``paged_prefill``), beside the decode step, so a long
+  prompt never head-of-line-blocks the live streams.
+* **decode** — one program for the scheduler's lifetime
+  (``paged_decode``): [MAX_SLOTS] tokens in, [MAX_SLOTS] next tokens out,
+  attending through per-slot BLOCK TABLES.  Tables and lengths are traced
+  values, so admission, retirement, block churn and prefix sharing never
+  change its shapes: it compiles exactly once.  With ``spec_k > 0`` a
+  tick drafts and verifies a window instead (``spec_draft``,
+  ``spec_verify``).
 
-Inactive slots still compute inside the decode step (static shapes); their
-outputs are ignored and their garbage cache writes are masked out by
-construction (see kv_slots module docstring).
+Inactive rows still compute inside the decode step (static shapes); their
+outputs are ignored and their garbage cache writes land in the trash block
+(see kv_slots module docstring).
 
 Sampling is per-slot: greedy is a *traced* bool (mixing greedy and
 temperature-sampled requests in one batch cannot recompile), temperature is
@@ -32,7 +42,7 @@ from __future__ import annotations
 
 import dataclasses
 import logging
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -48,39 +58,14 @@ from trustworthy_dl_tpu.serve.kv_slots import (
     PagedKV,
     PrefixCache,
     SlotAllocator,
-    SlotKV,
     TRASH_BLOCK,
     blocks_for_span,
     init_paged_pool,
-    init_slots,
     resolve_prefill_chunk,
     validate_paged_geometry,
 )
 
 logger = logging.getLogger(__name__)
-
-
-def default_buckets(max_seq: int, smallest: int = 16) -> Tuple[int, ...]:
-    """Power-of-two prefill buckets up to ``max_seq`` (inclusive) — bounds
-    the number of distinct prefill programs at O(log max_seq)."""
-    out: List[int] = []
-    b = smallest
-    while b < max_seq:
-        out.append(b)
-        b *= 2
-    out.append(max_seq)
-    return tuple(out)
-
-
-def choose_bucket(buckets: Sequence[int], prompt_len: int) -> int:
-    """Smallest bucket holding ``prompt_len`` tokens."""
-    for b in sorted(buckets):
-        if b >= prompt_len:
-            return b
-    raise ValueError(
-        f"prompt of {prompt_len} tokens exceeds the largest prefill "
-        f"bucket {max(buckets)}"
-    )
 
 
 # --------------------------------------------------------------------------
@@ -139,16 +124,17 @@ def _pack_step_outputs(next_tok: jax.Array, ent: jax.Array,
 
 def _local_prefill(cfg: gpt2.GPT2Config, view: Any, tokens: jax.Array,
                    real_len: jax.Array, quantized: bool):
-    """The parity-critical prologue BOTH pool layouts' prefill programs
-    share (one spelling, so a numerics fix cannot diverge them): run the
-    stacked blocks over the padded prompt through a FULL-PRECISION local
-    cache — prompt self-attention sees exact K/V, so the first sampled
-    token is bit-identical to the dense engine's — and sample logits at
-    ``real_len - 1`` (the prompt's last REAL position; padding beyond it
-    is causally invisible and overwritten before any decode step can
-    attend to it).  ``quantized``: quantize once HERE, at the pool
-    write — every scale in the written span is fresh, so a reused
-    slot/block cannot leak a stale scale (pinned by tests/test_quant.py).
+    """The parity-critical prologue of the whole-prompt prefill program:
+    run the stacked blocks over the padded prompt through a FULL-PRECISION
+    local cache — the contiguous ``KVCache`` path ``generate()`` itself
+    prefills through — so prompt self-attention sees exact K/V and the
+    first sampled token is bit-identical to ``generate()``'s whatever the
+    pool's tier, and sample logits at ``real_len - 1`` (the prompt's last
+    REAL position; padding beyond it is causally invisible and
+    overwritten before any decode step can attend to it).
+    ``quantized``: quantize once HERE, at the pool write — every scale
+    in the written span is fresh, so a reused block cannot leak a stale
+    scale (pinned by tests/test_quant.py).
     Returns (logits, k_rows, v_rows, k_scales, v_scales) with scales None
     on the full-precision path."""
     local = gen.init_cache(cfg, 1, tokens.shape[0])
@@ -171,77 +157,21 @@ def _sample_pack(logits: jax.Array, key: jax.Array, temp: jax.Array,
     return _pack_step_outputs(token, ent, margin)
 
 
-def _prefill_impl(cfg: gpt2.GPT2Config, slot_k: jax.Array, slot_v: jax.Array,
-                  slot_k_scale: Any, slot_v_scale: Any,
-                  view: Any, tokens: jax.Array, real_len: jax.Array,
-                  slot: jax.Array, key: jax.Array, temp: jax.Array,
-                  greedy: jax.Array):
-    """Prefill one STRIPE slot: the shared ``_local_prefill`` prologue
-    over the bucketed prompt [P], then write the K/V into the slot row."""
-    logits, k_rows, v_rows, k_s, v_s = _local_prefill(
-        cfg, view, tokens, real_len, slot_k_scale is not None
-    )
-    if k_s is not None:
-        new_k = jax.lax.dynamic_update_slice(
-            slot_k, k_rows, (0, slot, 0, 0, 0)
-        )
-        new_v = jax.lax.dynamic_update_slice(
-            slot_v, v_rows, (0, slot, 0, 0, 0)
-        )
-        new_ks = jax.lax.dynamic_update_slice(
-            slot_k_scale, k_s, (0, slot, 0, 0)
-        )
-        new_vs = jax.lax.dynamic_update_slice(
-            slot_v_scale, v_s, (0, slot, 0, 0)
-        )
-    else:
-        new_k = jax.lax.dynamic_update_slice(
-            slot_k, k_rows.astype(slot_k.dtype), (0, slot, 0, 0, 0)
-        )
-        new_v = jax.lax.dynamic_update_slice(
-            slot_v, v_rows.astype(slot_v.dtype), (0, slot, 0, 0, 0)
-        )
-        new_ks, new_vs = slot_k_scale, slot_v_scale
-    return new_k, new_v, new_ks, new_vs, _sample_pack(logits, key, temp,
-                                                      greedy)
-
-
-def _decode_impl(cfg: gpt2.GPT2Config, slot_k: jax.Array, slot_v: jax.Array,
-                 slot_k_scale: Any, slot_v_scale: Any,
-                 view: Any, tokens: jax.Array, lengths: jax.Array,
-                 keys: jax.Array, temps: jax.Array, greedy: jax.Array):
-    """THE fused decode step: one token for every slot, live or not.
-    ``lengths`` i32[MAX_SLOTS] are the per-slot write offsets — the vector
-    ``start`` path of models/generate._block_with_cache, so serving decode
-    and batch generate share one numerics source.  Host-facing outputs
-    ride one packed f32[3, MAX_SLOTS] — a single pull per decode tick.
-    int8 KV scales (None on the full-precision pool — the pytree branch
-    is structural, each engine still compiles this exactly once) thread
-    through the same cache."""
-    cache = gen.KVCache(k=slot_k, v=slot_v, length=lengths,
-                        k_scale=slot_k_scale, v_scale=slot_v_scale)
-    logits, cache = gen._apply_with_cache(view, tokens[:, None], cache, cfg)
-    next_tok = _sample_tokens(logits, keys, temps, greedy)
-    ent, margin = _logit_signals(logits)
-    return (_pack_step_outputs(next_tok, ent, margin), cache.k, cache.v,
-            cache.k_scale, cache.v_scale)
-
-
 def _paged_prefill_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
                         pool_v: jax.Array, pool_ks: Any, pool_vs: Any,
                         view: Any, tokens: jax.Array, real_len: jax.Array,
                         block_ids: jax.Array, key: jax.Array,
                         temp: jax.Array, greedy: jax.Array,
                         attn_impl: str = "jnp"):
-    """Fresh whole-prompt prefill into PAGED blocks: the SAME
-    ``_local_prefill`` prologue as the stripe path — so prompt
-    self-attention and the first sampled token match the stripe engine
-    bit-for-bit, int8 tier included (quantization happens once at the
-    block write) — then the local cache is re-laid-out block-wise and
-    scattered into the pool at ``block_ids`` (i32[C/BLOCK]; entries past
-    the slot's allocation point at the trash block).  Dispatched when
-    the whole prompt fits one chunk and no prefix blocks were reused;
-    longer or prefix-sharing prompts go through ``_paged_chunk_impl``."""
+    """Fresh whole-prompt prefill into PAGED blocks: the
+    ``_local_prefill`` prologue — so prompt self-attention and the first
+    sampled token match ``generate()`` bit-for-bit (under the int8 tier
+    quantization happens once, at the block write) — then the local
+    cache is re-laid-out block-wise and scattered into the pool at
+    ``block_ids`` (i32[C/BLOCK]; entries past the slot's allocation
+    point at the trash block).  Dispatched when the whole prompt fits
+    one chunk and no prefix blocks were reused; longer or prefix-sharing
+    prompts go through ``_paged_chunk_impl``."""
     c = tokens.shape[0]
     bsz = pool_k.shape[3]
     logits, k_rows, v_rows, k_s, v_s = _local_prefill(
@@ -323,8 +253,8 @@ def _paged_decode_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
     ``lengths`` the per-slot write offsets; both are traced VALUES, so
     admission, retirement, block churn and prefix sharing never change
     the program.  The attention core is the same
-    ``models/generate._block_with_cache`` the stripe engine and batch
-    generate run, over the gathered view — bit-identical streams.
+    ``models/generate._block_with_cache`` batch generate runs, over the
+    gathered view — bit-identical streams.
 
     The trailing adapter args are the paged adapter pool's device sides
     plus the per-slot page table ``apages`` i32[MAX_SLOTS]
@@ -426,12 +356,6 @@ def _programs() -> Dict[str, Any]:
         # donating a None (full-precision pool has no scales) donates
         # zero buffers, so one entry serves both tiers.
         donate = (1, 2, 3, 4) if jax.default_backend() == "tpu" else ()
-        _PROGRAMS["prefill"] = jax.jit(
-            _prefill_impl, static_argnums=(0,), donate_argnums=donate
-        )
-        _PROGRAMS["decode"] = jax.jit(
-            _decode_impl, static_argnums=(0,), donate_argnums=donate
-        )
         # The paged programs also take ``attn_impl`` (and, where the
         # program touches adapters or the verify tail, ``adapter_impl``/
         # ``verify_impl``) as STATIC keywords — the scheduler's
@@ -532,223 +456,8 @@ class SlotTask:
             self.done = True
 
 
-class ContinuousBatchingScheduler:
-    """Slot admission + fused decode over the slotted KV cache.
-
-    Host state: per-slot lengths (numpy — alloc/free never touch the
-    device) and the live ``SlotTask`` table.  Device state: the SlotKV
-    arrays, threaded functionally through the prefill/decode programs.
-    """
-
-    def __init__(self, params: Any, cfg: gpt2.GPT2Config, max_slots: int,
-                 max_seq: int,
-                 buckets: Optional[Sequence[int]] = None,
-                 kv_dtype: str = "model", weight_dtype: str = "model",
-                 view: Any = None):
-        q8.validate_dtypes(kv_dtype, weight_dtype)
-        self.cfg = cfg
-        self.kv_dtype = kv_dtype
-        self.weight_dtype = weight_dtype
-        if view is not None:
-            # Pre-built decode view (the engine builds it once and shares
-            # it with the parity probe — don't re-cast/re-quantize here).
-            self.view = view
-        elif weight_dtype == "int8":
-            # Weight-only int8 (quant/int8.py): converted ONCE here; the
-            # decode programs stream int8 weight bytes per token.
-            self.view = q8.quantize_decode_view(params, cfg)
-        else:
-            # One numerics source with batch generate: the same pre-cast
-            # decode view of the weights (bit-identical by construction
-            # — see models/generate._decode_view).
-            self.view = gen._decode_view(params, cfg)
-        self.kv = init_slots(cfg, max_slots, max_seq,
-                             kv_dtype=q8.resolve_kv_dtype(kv_dtype, cfg))
-        self.allocator = SlotAllocator(max_slots)
-        self.buckets = tuple(sorted(buckets or default_buckets(max_seq)))
-        if max(self.buckets) > max_seq:
-            raise ValueError("prefill bucket exceeds max_seq")
-        self.lengths = np.zeros(max_slots, np.int32)
-        self.tasks: Dict[int, SlotTask] = {}   # slot -> task
-        self.max_seq = max_seq
-        # The stripe pool has no paged-attention kernel: the engine's
-        # attention-path surface (gauge, summary) reads this uniformly.
-        self.attn_impl = "jnp"
-        self.spans: Any = None  # optional obs.spans.SpanTracker (engine)
-        # Optional obs.compilewatch.CompileWatcher (engine): the fused
-        # decode dispatch runs under its "serve_decode" guard, so a
-        # post-warmup recompile storms at runtime, not just in pytest.
-        self.compilewatch: Any = None
-        # The stripe pool has no adapter tier (validate_adapters pins
-        # adapter_rank > 0 to paged=True); the engine reads this
-        # uniformly across both scheduler classes.
-        self.adapters: Any = None
-
-    def attribution_info(self, task: SlotTask) -> Dict[str, Any]:
-        """What the attribution ledger records about THIS task's
-        physical placement.  The stripe pool has no block table — the
-        slot id is the whole story."""
-        return {"layout": "stripe", "slot": int(task.slot),
-                "block_ids": [], "prefix_block_ids": [],
-                "prefix_publishers": {},
-                "adapter": task.adapter,
-                "adapter_page": int(task.adapter_page)}
-
-    # -- admission ---------------------------------------------------------
-
-    @property
-    def has_free_slot(self) -> bool:
-        return self.allocator.free_count > 0
-
-    @property
-    def active_count(self) -> int:
-        return len(self.tasks)
-
-    @property
-    def occupancy(self) -> float:
-        return len(self.tasks) / max(self.allocator.max_slots, 1)
-
-    def admit(self, task: SlotTask) -> bool:
-        """Claim a slot, prefill the prompt, emit the first token.
-        Returns False (task untouched) when no slot is free."""
-        total = len(task.prompt) + task.max_new_tokens
-        if total > self.max_seq:
-            raise ValueError(
-                f"request {task.request_id}: prompt+new = {total} exceeds "
-                f"max_seq={self.max_seq}"
-            )
-        p = len(task.prompt)
-        # Resolve the bucket BEFORE claiming a slot: with custom (smaller
-        # than max_seq) buckets this can raise, and a slot claimed first
-        # would leak — the allocator has no owner to free it.
-        bucket = choose_bucket(self.buckets, p)
-        slot = self.allocator.alloc()
-        if slot is None:
-            return False
-        padded = np.zeros(bucket, np.int32)
-        padded[:p] = task.prompt
-        new_k, new_v, new_ks, new_vs, packed = _programs()["prefill"](
-            self.cfg, self.kv.k, self.kv.v,
-            self.kv.k_scale, self.kv.v_scale, self.view,
-            jnp.asarray(padded), jnp.asarray(p, jnp.int32),
-            jnp.asarray(slot, jnp.int32),
-            jnp.asarray(task.keys[0], jnp.uint32),
-            jnp.asarray(max(task.temperature, 1e-6), jnp.float32),
-            jnp.asarray(task.greedy),
-        )
-        self.kv = SlotKV(k=new_k, v=new_v, k_scale=new_ks, v_scale=new_vs)
-        task.slot = slot
-        # ONE host sync per admission: token/entropy/margin land together.
-        # tddl-lint: disable=host-sync — the intentional per-prefill pull
-        token, ent, margin = np.asarray(packed)[:, 0]
-        task._record(int(token), float(ent), float(margin))
-        self.lengths[slot] = p
-        self.tasks[slot] = task
-        return True
-
-    # -- decode ------------------------------------------------------------
-
-    def decode_tick(self) -> List[SlotTask]:
-        """One fused decode step for every active slot; returns the tasks
-        that received a token this tick (some may now be ``done``)."""
-        if not self.tasks:
-            return []
-        ms = self.allocator.max_slots
-        tokens = np.zeros(ms, np.int32)
-        keys = np.zeros((ms, 2), np.uint32)
-        temps = np.ones(ms, np.float32)
-        greedy = np.ones(ms, bool)
-        for slot, task in self.tasks.items():
-            tokens[slot] = task.next_token
-            # Next emission index is len(emitted) (< max_new while live).
-            keys[slot] = task.keys[len(task.emitted)]
-            temps[slot] = max(task.temperature, 1e-6)
-            greedy[slot] = task.greedy
-        with guarded(self.compilewatch, "serve_decode"):
-            packed, new_k, new_v, new_ks, new_vs = _programs()["decode"](
-                self.cfg, self.kv.k, self.kv.v,
-                self.kv.k_scale, self.kv.v_scale, self.view,
-                jnp.asarray(tokens), jnp.asarray(self.lengths),
-                jnp.asarray(keys), jnp.asarray(temps), jnp.asarray(greedy),
-            )
-        self.kv = SlotKV(k=new_k, v=new_v, k_scale=new_ks, v_scale=new_vs)
-        # ONE host pull for the whole tick (the cache stays on device);
-        # the per-slot feed below reads the already-landed numpy rows.
-        # tddl-lint: disable=host-sync — the tick's single intentional pull
-        host = np.asarray(packed)
-        next_tok, ent, margin = host[0], host[1], host[2]
-        live = list(self.tasks.items())
-        # The decode step wrote each live slot's token K/V at
-        # lengths[slot]; batch the offset bump before the record feed.
-        for slot, _ in live:
-            self.lengths[slot] += 1
-        ticked: List[SlotTask] = []
-        for slot, task in live:
-            task._record(int(next_tok[slot]), float(ent[slot]),
-                         float(margin[slot]))
-            ticked.append(task)
-        return ticked
-
-    # -- retirement --------------------------------------------------------
-
-    def retire(self, task: SlotTask, quarantine: bool = False) -> None:
-        """Release the task's slot (or quarantine it — flagged-anomalous
-        output; the slot leaves the pool until an operator releases it)."""
-        slot = task.slot
-        if slot < 0 or self.tasks.get(slot) is not task:
-            return
-        del self.tasks[slot]
-        if quarantine:
-            self.allocator.quarantine(slot)
-            logger.warning(
-                "slot %d quarantined after request %d was flagged "
-                "anomalous (%d slots remain in service)",
-                slot, task.request_id, self.allocator.capacity,
-            )
-        else:
-            self.allocator.free(slot)
-
-    def release_quarantine(self, slot: int) -> None:
-        """Operator action: return a quarantined slot to service."""
-        self.allocator.release(slot)
-
-    @property
-    def tokens_in_flight(self) -> int:
-        """Cached tokens currently backing live sequences."""
-        return int(sum(int(self.lengths[s]) for s in self.tasks))
-
-    def decode_cache_size(self) -> int:
-        """Number of compiled decode programs (the static-shape invariant
-        says this is 1 for the scheduler's lifetime)."""
-        prog = _PROGRAMS.get("decode")
-        return prog._cache_size() if prog is not None else 0
-
-    def analyze_costs(self, ledger: Any,
-                      memory: Optional[bool] = None) -> None:
-        """Stamp this engine's serve programs into an obs.hbm.CostLedger
-        (lowering-only by default — no extra backend compile)."""
-        kv = self.kv
-        ms = self.allocator.max_slots
-        bucket = max(self.buckets)
-        prog = _programs()
-        pool = (kv.k, kv.v, kv.k_scale, kv.v_scale)
-        ledger.analyze(
-            "serve.prefill", prog["prefill"], self.cfg, *pool, self.view,
-            jnp.zeros(bucket, jnp.int32), jnp.asarray(1, jnp.int32),
-            jnp.asarray(0, jnp.int32), jnp.zeros(2, jnp.uint32),
-            jnp.asarray(1.0, jnp.float32), jnp.asarray(True),
-            memory=memory,
-        )
-        ledger.analyze(
-            "serve.decode", prog["decode"], self.cfg, *pool, self.view,
-            jnp.zeros(ms, jnp.int32), jnp.asarray(self.lengths),
-            jnp.zeros((ms, 2), jnp.uint32), jnp.ones(ms, jnp.float32),
-            jnp.ones(ms, bool), memory=memory,
-        )
-
-
 # ---------------------------------------------------------------------------
-# Paged scheduler (the default data path since the paged-KV PR)
+# The scheduler
 # ---------------------------------------------------------------------------
 
 
@@ -768,9 +477,8 @@ class _PrefillProgress:
 class PagedBatchingScheduler:
     """Continuous batching over the paged block pool (kv_slots.PagedKV).
 
-    Same engine-facing surface as ``ContinuousBatchingScheduler`` (admit
-    / decode_tick / retire / allocator / lengths / kv), different memory
-    discipline: a request claims ``ceil((prompt + max_new) / BLOCK)``
+    Engine-facing surface: admit / decode_tick / retire / allocator /
+    lengths / kv.  A request claims ``ceil((prompt + max_new) / BLOCK)``
     blocks at admission — occupancy is bounded by tokens in flight, not
     by request count — reusing cached prefix blocks where its prompt
     matches the radix cache (refcounted; prefill then covers only the
@@ -781,7 +489,6 @@ class PagedBatchingScheduler:
 
     def __init__(self, params: Any, cfg: gpt2.GPT2Config, max_slots: int,
                  max_seq: int,
-                 buckets: Optional[Sequence[int]] = None,
                  kv_dtype: str = "model", weight_dtype: str = "model",
                  view: Any = None,
                  block_size: int = 16, num_blocks: Optional[int] = None,
@@ -794,10 +501,9 @@ class PagedBatchingScheduler:
         validate_paged_geometry(max_seq, block_size, num_blocks,
                                 prefill_chunk)
         if max_seq > cfg.n_positions:
-            # The stripe pool gets this from init_slots; the paged pool
-            # allocates per-block, so check the LOGICAL depth here — a
-            # sequence past the position table would silently gather
-            # clamped position embeddings, not raise.
+            # The pool allocates per-block, so check the LOGICAL depth
+            # here — a sequence past the position table would silently
+            # gather clamped position embeddings, not raise.
             raise ValueError(
                 f"max_seq={max_seq} exceeds the model's position table "
                 f"(n_positions={cfg.n_positions})"
@@ -818,12 +524,13 @@ class PagedBatchingScheduler:
         if prefill_chunk is None and kv_dtype == "int8":
             # Full-prompt prefill by default under int8 KV: a chunked
             # continuation attends to the previous chunk's
-            # already-QUANTIZED blocks, while the stripe int8 engine
-            # runs the whole prompt through a full-precision local
-            # cache — bit-parity with it holds only on the one-chunk
-            # path.  An explicit prefill_chunk opts back into chunking
-            # (near-tie caveat in README §Serving; prefix-cache hits
-            # read quantized prefix blocks the same way).
+            # already-QUANTIZED blocks, while ``_local_prefill`` runs
+            # the whole prompt through a full-precision local cache —
+            # the first token's parity with ``generate()`` holds only on
+            # the one-chunk path.  An explicit prefill_chunk opts back
+            # into chunking (near-tie caveat in README §Serving;
+            # prefix-cache hits read quantized prefix blocks the same
+            # way).
             self.chunk = max_seq
         else:
             self.chunk = resolve_prefill_chunk(max_seq, block_size,
@@ -857,13 +564,6 @@ class PagedBatchingScheduler:
         self.blocks = BlockAllocator(self.num_blocks)
         self.prefix = (PrefixCache(block_size, self.blocks)
                        if prefix_cache else None)
-        # ``buckets`` is the stripe engine's prefill-program bound; the
-        # paged engine has ONE chunk program, but the engine's submit
-        # contract (reject unprefillable prompts up front) reads
-        # max(buckets) — honour a caller-provided cap, default max_seq.
-        self.buckets = tuple(sorted(buckets or (max_seq,)))
-        if max(self.buckets) > max_seq:
-            raise ValueError("prefill bucket exceeds max_seq")
         self.lengths = np.zeros(max_slots, np.int32)
         self.tables: List[List[int]] = [[] for _ in range(max_slots)]
         self.tasks: Dict[int, SlotTask] = {}       # slot -> task
@@ -1069,11 +769,11 @@ class PagedBatchingScheduler:
         kv = self.kv
         if st.pos == 0 and st.plen <= c and task.adapter_page == ZERO_PAGE:
             # Whole prompt in one chunk, nothing shared: full-precision
-            # local prefill (stripe-engine numerics, bit-for-bit — the
-            # int8 tier quantizes once at the block write).  An
+            # local prefill (``generate()``'s numerics, bit-for-bit —
+            # the int8 tier quantizes once at the block write).  An
             # adapter-carrying request takes the chunk path below
             # instead: its prompt must run through the adapter-delta'd
-            # layers, and there is no stripe twin to hold parity with.
+            # layers.
             ids = np.full(c // self.block_size, TRASH_BLOCK, np.int32)
             n_ids = min(len(self.tables[slot]), len(ids))
             ids[:n_ids] = self.tables[slot][:n_ids]
