@@ -256,14 +256,23 @@ def kernel_parity(size: Size, seed: int, on_chip: bool) -> None:
     pool_shape = (slots * nbps + 1, heads, block, head_dim)
     k_f32 = jax.random.normal(keys[0], pool_shape, jnp.float32)
     v_f32 = jax.random.normal(keys[1], pool_shape, jnp.float32)
+
+    def stacked(a):
+        """[NB, H, BLOCK(, Dh)] -> the pool's [2, NB, BLOCK, H(·Dh)],
+        layer 1 the content and layer 0 its reverse: a kernel that read
+        the wrong layer would not agree."""
+        a = jnp.moveaxis(a, 1, 2).reshape(a.shape[0], block, -1)
+        return jnp.stack([a[::-1], a])
+
     worst = 0.0
     for kv_dtype in ("float32", "bfloat16", "int8"):
         if kv_dtype == "int8":
             # quantize_kv scales per (head, position) over [.., T, Dh].
-            k, ks = q8.quantize_kv(k_f32)
-            v, vs = q8.quantize_kv(v_f32)
+            k, ks = map(stacked, q8.quantize_kv(k_f32))
+            v, vs = map(stacked, q8.quantize_kv(v_f32))
         else:
-            k, v = k_f32.astype(kv_dtype), v_f32.astype(kv_dtype)
+            k = stacked(k_f32.astype(kv_dtype))
+            v = stacked(v_f32.astype(kv_dtype))
             ks = vs = None
         chunk = min(64, size.serve_max_seq // 2)
         for program, rows, r in (("decode", 1, slots),
@@ -278,11 +287,12 @@ def kernel_parity(size: Size, seed: int, on_chip: bool) -> None:
                 rng.integers(block, size.serve_max_seq - rows, r), jnp.int32)
             attend = (pattn.paged_attention if program == "decode"
                       else pattn.paged_prefill_attention)
-            got = attend(q, k, v, table[:r], start, k_scale=ks, v_scale=vs,
-                         interpret=not on_chip)
+            got = attend(q, k, v, table[:r], start, layer=1, k_scale=ks,
+                         v_scale=vs, interpret=not on_chip)
             with jax.default_matmul_precision("highest"):
                 want = pattn.paged_attention_reference(
-                    q, k, v, table[:r], start, k_scale=ks, v_scale=vs)
+                    q, k, v, table[:r], start, layer=1, k_scale=ks,
+                    v_scale=vs)
             err = float(jnp.max(jnp.abs(got.astype(jnp.float32)
                                         - want.astype(jnp.float32))))
             check(err <= KERNEL_ATOL,

@@ -85,21 +85,27 @@ def S(shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
+LAYERS = 2
+
+
 def _paged_args(program, kv_dtype, block=BLOCK, head_dim=DH, heads=H,
-                max_seq=MAX_SEQ, q_dtype=jnp.bfloat16, slots=SLOTS):
+                max_seq=MAX_SEQ, q_dtype=jnp.bfloat16, slots=SLOTS,
+                layers=LAYERS):
     """Argument shapes of ``_paged_attn_call`` (fused decode over every
-    slot) or ``_paged_prefill_call`` (one slot's chunk), ``jmax`` a query
-    tile of the program's own rule."""
+    slot) or ``_paged_prefill_call`` (one slot's chunk) over the stacked
+    pool, ``jmax`` a query tile of the program's own rule; the int8
+    tier's scales are the layer's planes."""
     nbps = max_seq // block
-    pool = S((slots * nbps + 1, heads, block, head_dim), kv_dtype)
-    scale = (S(pool.shape[:3], jnp.float32)
+    nb = slots * nbps + 1
+    pool = S((layers, nb, block, heads * head_dim), kv_dtype)
+    scale = (S((nb, heads, block), jnp.float32)
              if jnp.dtype(kv_dtype) == jnp.int8 else None)
     r, t = (slots, pa.QROWS) if program == "decode" else (1, CHUNK)
     tiles = pa.grid_steps(program, r, heads, nbps, t, head_dim, block,
                           kv_dtype)[2]
     return (S((r, heads, t, head_dim), q_dtype), pool, pool, scale, scale,
             S((r, nbps), jnp.int32), S((r,), jnp.int32),
-            S((r, tiles), jnp.int32))
+            S((r, tiles), jnp.int32), S((1,), jnp.int32))
 
 
 _PAGED_CALL = {"decode": pa._paged_attn_call,
@@ -132,6 +138,94 @@ def test_paged_attention_lowers_at_the_serving_cell(v5e, program, kv_dtype):
     _compile(_PAGED_CALL[program], v5e,
              *_paged_args(program, kv_dtype, heads=20, slots=24),
              interpret=False)
+
+
+def _serving_program(dev, program, kv_dtype):
+    """``_paged_chunk_impl`` or ``_paged_decode_impl`` at the serving
+    cell's geometry (GPT-2 large: 36 layers, 24 slots, 20 heads of 64,
+    blocks of 16, chunk 64), the pool donated as on the chip, compiled
+    for the described v5e."""
+    from trustworthy_dl_tpu.models import generate as gen
+    from trustworthy_dl_tpu.models import gpt2
+    from trustworthy_dl_tpu.serve import scheduler as sch
+    from trustworthy_dl_tpu.serve.kv_slots import init_paged_pool
+
+    cfg = gpt2.GPT2Config(n_layer=36, n_embd=1280, n_head=20)
+    slots, nbps = 24, MAX_SEQ // BLOCK
+
+    def pin(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev),
+            tree)
+
+    view = pin(jax.eval_shape(lambda: gen._decode_view(
+        gpt2.init_params(jax.random.PRNGKey(0), cfg), cfg)))
+    kv = pin(jax.eval_shape(
+        lambda: init_paged_pool(cfg, slots * nbps, BLOCK, kv_dtype)))
+    i32, f32 = jnp.int32, jnp.float32
+    if program == "chunk":
+        fn = sch._paged_chunk_impl
+        rest = (S((CHUNK,), i32), S((1, nbps), i32), S((), i32), S((), i32),
+                S((2,), jnp.uint32), S((), f32), S((), jnp.bool_))
+    else:
+        fn = sch._paged_decode_impl
+        rest = (S((slots,), i32), S((slots, nbps), i32), S((slots,), i32),
+                S((slots, 2), jnp.uint32), S((slots,), f32),
+                S((slots,), jnp.bool_))
+    jitted = jax.jit(fn, static_argnums=(0,),
+                     static_argnames=("attn_impl", "adapter_impl"),
+                     donate_argnums=(1, 2, 3, 4))
+    return kv, _compile(jitted, dev, cfg, kv.k, kv.v, kv.k_scale,
+                        kv.v_scale, view, *rest, attn_impl="pallas")
+
+
+# (pool dtype, the most the program's temporaries may take).  The int8
+# tier's scale planes [L, NB, BLOCK, H] are a sixty-fourth of the pool's
+# elements and the one array here the compiler still relays out: the chip
+# rests them block-index-minor with BLOCK on the sublanes, the row scatter
+# wants the heads there, so each plane is copied whole before and after the
+# layer loop (77 MB each: 93 MB of temporaries in the chunk program, 185
+# in the decode program) — PERF.md section 7 has what the alternatives
+# cost on the chip.
+_POOL_TIERS = [pytest.param(jnp.bfloat16, 64 << 20, id="bfloat16"),
+               pytest.param(jnp.int8, 256 << 20, id="int8")]
+
+
+@pytest.mark.parametrize("kv_dtype,temp_limit", _POOL_TIERS)
+@pytest.mark.parametrize("program", ["chunk", "decode"])
+def test_serving_program_keeps_the_pool_in_place(v5e, program, kv_dtype,
+                                                 temp_limit):
+    """THE pin of the pool's shape ``[L, NB, BLOCK, H·Dh]``: a serving
+    program carries the pool through its layer loop in the donated buffer
+    and in one layout.  It compiles; its temporaries stay under 64 MB
+    with the bf16 pool (6.0 GB when the layer scan re-stacked the pool;
+    3.1 GB then with the int8 pool); both pools are updated in the
+    buffers they came in; and the optimized HLO holds no copy, fresh
+    buffer or slice the size of the pool or of one layer of it — the row
+    write, the kernels' operand and the resting layout agree, so nothing
+    relays the pool out."""
+    import math
+    import re
+
+    kv, compiled = _serving_program(v5e, program, kv_dtype)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < temp_limit
+    pool_bytes = math.prod(kv.k.shape) * jnp.dtype(kv_dtype).itemsize
+    assert memory.alias_size_in_bytes >= 2 * pool_bytes
+    sizes = {math.prod(kv.k.shape), math.prod(kv.k.shape[1:])}
+    moved = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(", line)
+        if not m or not m.group(2):
+            continue
+        name, dims, opcode = m.groups()
+        if math.prod(map(int, dims.split(","))) not in sizes:
+            continue
+        if (opcode.startswith("copy") or "AllocateBuffer" in line
+                or "slice" in opcode or "slice" in name):
+            moved.append(line.strip()[:160])
+    assert not moved, moved
 
 
 def _flash_forward(dev, dtype, bh=H, t=1024, d=DH, causal=True):

@@ -50,6 +50,22 @@ def params():
 # --------------------------------------------------------------------------
 
 
+def _pools(rng, layers, nb, h, bsz, dh, dtype):
+    """Random stacked pools ``[L, NB, BLOCK, H·Dh]`` (K and V, every
+    layer different) and, for int8, the scale planes ``[L, NB, BLOCK,
+    H]`` as the ``k_scale``/``v_scale`` keywords."""
+    shape = (layers, nb, bsz, h * dh)
+    if jnp.dtype(dtype) == jnp.int8:
+        k, v = (jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8)
+                for _ in range(2))
+        ks, vs = (jnp.asarray(rng.uniform(0.01, 0.1, shape[:3] + (h,)),
+                              jnp.float32) for _ in range(2))
+        return k, v, dict(k_scale=ks, v_scale=vs)
+    k, v = (jnp.asarray(rng.normal(size=shape), dtype) for _ in range(2))
+    return k, v, {}
+
+
+
 def test_kernel_matches_reference_fp32_ragged():
     """Interpret-mode kernel equality against the gather-semantics
     reference: ragged per-row lengths, causal windows crossing block
@@ -58,8 +74,7 @@ def test_kernel_matches_reference_fp32_ragged():
     rng = np.random.default_rng(0)
     nb, h, bsz, dh = 9, 3, 8, 16
     r, nbps = 4, 4
-    pool_k = jnp.asarray(rng.normal(size=(nb, h, bsz, dh)), jnp.float32)
-    pool_v = jnp.asarray(rng.normal(size=(nb, h, bsz, dh)), jnp.float32)
+    pool_k, pool_v, _ = _pools(rng, 2, nb, h, bsz, dh, jnp.float32)
     table = jnp.asarray(rng.integers(0, nb, size=(r, nbps)), jnp.int32)
     # Ragged: row 0 empty history, row 3 nearly full; starts 5 and 13
     # put the causal window mid-block and across a block boundary.
@@ -67,9 +82,9 @@ def test_kernel_matches_reference_fp32_ragged():
     for t in (1, 3, 8):
         q = jnp.asarray(rng.normal(size=(r, h, t, dh)), jnp.float32)
         got = pattn.paged_attention(q, pool_k, pool_v, table, start,
-                                    interpret=True)
+                                    layer=1, interpret=True)
         ref = pattn.paged_attention_reference(q, pool_k, pool_v, table,
-                                              start)
+                                              start, layer=1)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
     # Scalar start (the chunked-prefill spelling, R=1).
@@ -89,21 +104,15 @@ def test_kernel_matches_reference_int8_scales():
     rng = np.random.default_rng(1)
     nb, h, bsz, dh = 7, 2, 8, 8
     r, nbps = 3, 3
-    pool_k = jnp.asarray(rng.integers(-127, 128, size=(nb, h, bsz, dh)),
-                         jnp.int8)
-    pool_v = jnp.asarray(rng.integers(-127, 128, size=(nb, h, bsz, dh)),
-                         jnp.int8)
-    ks = jnp.asarray(rng.uniform(0.01, 0.1, size=(nb, h, bsz)), jnp.float32)
-    vs = jnp.asarray(rng.uniform(0.01, 0.1, size=(nb, h, bsz)), jnp.float32)
+    pool_k, pool_v, scales = _pools(rng, 2, nb, h, bsz, dh, jnp.int8)
     table = jnp.asarray(rng.integers(0, nb, size=(r, nbps)), jnp.int32)
     start = jnp.asarray([0, 7, 17], jnp.int32)
     for t in (1, 4):
         q = jnp.asarray(rng.normal(size=(r, h, t, dh)), jnp.float32)
         got = pattn.paged_attention(q, pool_k, pool_v, table, start,
-                                    k_scale=ks, v_scale=vs, interpret=True)
+                                    layer=1, interpret=True, **scales)
         ref = pattn.paged_attention_reference(q, pool_k, pool_v, table,
-                                              start, k_scale=ks,
-                                              v_scale=vs)
+                                              start, layer=1, **scales)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
 
@@ -115,16 +124,18 @@ def test_kernel_matches_reference_int8_scales():
 # (heads, head_dim, block, pool dtype, the head group the rule must pick).
 # The group is forced by SHAPE alone: small blocks take every head in one
 # step; blocks of 1,024 to 4,096 positions leave VMEM for a proper divisor
-# of the heads; twice that for one head, which is the kernel of before.
-# The first two are a small copy of the serving cell's geometry.
+# of the heads, which is a window of the pool's rows and so whole 128-lane
+# columns (two heads of 64); larger ones still for one head, where a head
+# is 128 lanes itself.  The first two are a small copy of the serving
+# cell's geometry.
 _GEOMETRIES = [
     (20, 64, 16, "bfloat16", 20), (20, 64, 16, "int8", 20),
     (4, 64, 8, "float32", 4), (3, 16, 8, "bfloat16", 3),
     (4, 16, 8, "int8", 4),
-    (4, 64, 1024, "float32", 2), (4, 16, 2048, "bfloat16", 2),
+    (4, 64, 1024, "float32", 2), (4, 64, 2048, "bfloat16", 2),
     (4, 64, 4096, "int8", 2),
-    (3, 16, 2048, "float32", 1), (2, 64, 4096, "bfloat16", 1),
-    (2, 16, 8192, "int8", 1),
+    (3, 128, 2048, "float32", 1), (2, 128, 4096, "bfloat16", 1),
+    (2, 128, 8192, "int8", 1),
 ]
 
 
@@ -155,30 +166,82 @@ def test_grouped_step_matches_reference(case, t):
     assert pattn.grid_steps(program, r, h, nbps, t, dh, bsz, dtype) == (
         r, h // group, 1, nbps)
     rng = np.random.default_rng(h * bsz + t)
-    if dtype == "int8":
-        pool_k, pool_v = (jnp.asarray(
-            rng.integers(-127, 128, size=(nb, h, bsz, dh)), jnp.int8)
-            for _ in range(2))
-        scales = dict(
-            k_scale=jnp.asarray(rng.uniform(0.01, 0.1, (nb, h, bsz)),
-                                jnp.float32),
-            v_scale=jnp.asarray(rng.uniform(0.01, 0.1, (nb, h, bsz)),
-                                jnp.float32))
-    else:
-        pool_k, pool_v = (jnp.asarray(rng.normal(size=(nb, h, bsz, dh)),
-                                      dtype) for _ in range(2))
-        scales = {}
+    pool_k, pool_v, scales = _pools(rng, 2, nb, h, bsz, dh, dtype)
     table = jnp.asarray(1 + rng.permutation(r * nbps).reshape(r, nbps),
                         jnp.int32)
     q = jnp.asarray(rng.normal(size=(r, h, t, dh)), jnp.float32)
     attend = (pattn.paged_prefill_attention if program == "prefill"
               else pattn.paged_attention)
-    got = attend(q, pool_k, pool_v, table, start, interpret=True, **scales)
+    got = attend(q, pool_k, pool_v, table, start, layer=1, interpret=True,
+                 **scales)
     ref = pattn.paged_attention_reference(q, pool_k, pool_v, table, start,
-                                          **scales)
+                                          layer=1, **scales)
     tol = 5e-5 if dtype == "int8" else 2e-5
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=tol, atol=tol)
+
+
+# The stacked pool's layer operand: (heads, head_dim, block, pool dtype,
+# group) — every head a step, and a window of two heads.
+_LAYER_GEOMETRIES = [
+    (4, 64, 16, "bfloat16", 4), (4, 64, 16, "int8", 4),
+    (4, 64, 2048, "bfloat16", 2), (4, 64, 4096, "int8", 2),
+]
+
+
+@pytest.mark.parametrize("t", [1, 64], ids=["decode", "prefill"])
+@pytest.mark.parametrize("case", _LAYER_GEOMETRIES, ids=_geometry_id)
+def test_every_layer_of_a_stacked_pool(case, t):
+    """Both programs read layer ``l`` of the stacked pool and nothing
+    else: at EVERY layer index the kernel agrees with the reference at
+    that layer, and disagrees with the reference at the next one (a layer
+    operand that were dropped, or mapped to another axis, fails here)."""
+    h, dh, bsz, dtype, group = case
+    program = "prefill" if t > pattn.QROWS else "decode"
+    layers, r, nbps = 3, 2, 2
+    nb = r * nbps + 1
+    assert pattn._step_shape(program, heads=h, head_dim=dh, block_size=bsz,
+                             kv_dtype=dtype, t=t)[0] == group
+    rng = np.random.default_rng(bsz + t)
+    pool_k, pool_v, scales = _pools(rng, layers, nb, h, bsz, dh, dtype)
+    table = jnp.asarray(1 + rng.permutation(r * nbps).reshape(r, nbps),
+                        jnp.int32)
+    start = jnp.asarray([0, bsz + 3], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(r, h, t, dh)), jnp.float32)
+    attend = (pattn.paged_prefill_attention if program == "prefill"
+              else pattn.paged_attention)
+    tol = 5e-5 if dtype == "int8" else 2e-5
+    refs = [np.asarray(pattn.paged_attention_reference(
+        q, pool_k, pool_v, table, start, layer=l, **scales))
+        for l in range(layers)]
+    for l in range(layers):
+        # A traced layer index, as the layer loop hands it over.
+        got = np.asarray(jax.jit(
+            lambda layer: attend(q, pool_k, pool_v, table, start,
+                                 layer=layer, interpret=True, **scales)
+        )(jnp.asarray(l, jnp.int32)))
+        np.testing.assert_allclose(got, refs[l], rtol=tol, atol=tol)
+        assert np.abs(got - refs[(l + 1) % layers]).max() > 1e-2
+
+
+def test_a_head_group_is_a_window_of_the_lanes():
+    """What the stacked pool's rows add to the rule: a group is whole
+    128-lane columns or every head — at 64-wide heads never 1 or 5 — and
+    a geometry whose every-head step overflows VMEM while no narrower
+    window exists is refused, not served one head at a time."""
+    assert pattn._head_groups(20, 64) == [20, 10, 4, 2]
+    assert pattn._head_groups(12, 80) == [12]
+    assert pattn._head_groups(12, 128) == [12, 6, 4, 3, 2, 1]
+    assert pattn._head_groups(None, 64) == [2]
+    for h, dh, bsz, dtype in ((2, 64, 4096, "bfloat16"),
+                              (3, 16, 2048, "float32"),
+                              (4, 16, 2048, "bfloat16")):
+        assert not pattn.supports_paged_attention(
+            head_dim=dh, block_size=bsz, kv_dtype=dtype, interpret=False,
+            n_embd=h * dh)
+    assert pattn.supports_paged_attention(
+        head_dim=64, block_size=2048, kv_dtype="bfloat16", interpret=False,
+        n_embd=4 * 64)
 
 
 def test_chunk_in_query_tiles_matches_reference():
@@ -192,15 +255,15 @@ def test_chunk_in_query_tiles_matches_reference():
     assert pattn.grid_steps("prefill", 1, h, nbps, t, dh, bsz,
                             "float32") == (1, h, 3, nbps)
     rng = np.random.default_rng(72)
-    pool_k, pool_v = (jnp.asarray(rng.normal(size=(3, h, bsz, dh)),
-                                  jnp.float32) for _ in range(2))
+    pool_k, pool_v, _ = _pools(rng, 2, 3, h, bsz, dh, jnp.float32)
     table = jnp.asarray([[2, 1]], jnp.int32)
     q = jnp.asarray(rng.normal(size=(1, h, t, dh)), jnp.float32)
     # The second tile's window crosses from block 0 into block 1.
     start = jnp.asarray(bsz - 40, jnp.int32)
     got = pattn.paged_prefill_attention(q, pool_k, pool_v, table, start,
-                                        interpret=True)
-    ref = pattn.paged_attention_reference(q, pool_k, pool_v, table, start)
+                                        layer=1, interpret=True)
+    ref = pattn.paged_attention_reference(q, pool_k, pool_v, table, start,
+                                          layer=1)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
@@ -224,8 +287,8 @@ _RULE_GEOMETRIES = [c[:4] for c in _GEOMETRIES] + [
 @pytest.mark.parametrize("case", _RULE_GEOMETRIES,
                          ids=lambda c: "h%d-d%d-b%d-%s" % c)
 def test_rule_divides_heads_and_fits_budget(case, t):
-    """The one rule: its group divides the heads, its tile is whole
-    sublanes, what the step pins fits the budget the predicate asks
+    """The one rule: its group divides the heads and is a window of the
+    pool's lanes, its tile is whole sublanes, what the step pins fits the budget the predicate asks
     about, no wider step of the same kind would, and the padded call the
     kernel sees picks the same step."""
     h, dh, bsz, dtype = case
@@ -245,10 +308,12 @@ def test_rule_divides_heads_and_fits_budget(case, t):
         assert h % group == 0 and tile % pattn.QROWS == 0
         assert tile == t8 or (program == "prefill" and tile < t8)
         assert pinned(group, tile) <= pattn.VMEM_BLOCK_BUDGET
-        wider = [g for g in range(group + 1, h + 1) if h % g == 0]
+        groups = pattn._head_groups(h, dh)
+        assert group in groups
+        wider = [g for g in groups if g > group]
         assert all(pinned(g, tile) > pattn.VMEM_BLOCK_BUDGET for g in wider)
         if tile < t8:
-            assert pinned(1, 2 * tile) > pattn.VMEM_BLOCK_BUDGET
+            assert pinned(groups[-1], 2 * tile) > pattn.VMEM_BLOCK_BUDGET
         tiles = -(-t8 // tile)
         assert pattn.grid_steps(program, 7, h, 5, t, dh, bsz, dtype) == (
             7, h // group, tiles, 5)

@@ -372,7 +372,75 @@ def test_pool_sizing_helpers():
     q = init_paged_pool(CFG, 5, 16, kv_dtype=jnp.int8)
     assert q.quantized
     assert q.pool_bytes == 6 * 16 * kv_bytes_per_token(CFG, jnp.int8)
-    assert q.k_scale.shape == (CFG.n_layer, 6, CFG.n_head, 16)
+    assert q.k.shape == (CFG.n_layer, 6, 16, CFG.n_embd)
+    assert q.k_scale.shape == (CFG.n_layer, 6, 16, CFG.n_head)
+
+
+@pytest.mark.parametrize("attn_impl", ["jnp", "interpret"])
+@pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+@pytest.mark.parametrize("program", ["chunk", "decode"])
+def test_a_serving_program_writes_its_rows_and_nothing_else(
+        params, program, kv_dtype, attn_impl):
+    """The pool is carried through the layer loop and written in place:
+    after ONE call of a serving program every row of every pool array
+    outside the R·T positions the call wrote — other layers' rows do not
+    exist, each layer writes its own; other blocks; other offsets of the
+    written blocks; the trash block aside — is bit-equal to before, and
+    every written row of every layer is new."""
+    from trustworthy_dl_tpu.models import generate as gen
+    from trustworthy_dl_tpu.serve import scheduler as sch
+    from trustworthy_dl_tpu.serve.kv_slots import init_paged_pool
+
+    rng = np.random.default_rng(11)
+    bsz, nb, nbps = 8, 12, 4
+    kv = init_paged_pool(CFG, nb, bsz, kv_dtype=jnp.dtype(kv_dtype))
+
+    def fill(a):
+        if a is None:
+            return None
+        if a.dtype == jnp.int8:
+            return jnp.asarray(rng.integers(-127, 128, a.shape), jnp.int8)
+        return jnp.asarray(rng.uniform(0.01, 0.5, a.shape), a.dtype)
+
+    before = tuple(map(fill, (kv.k, kv.v, kv.k_scale, kv.v_scale)))
+    view = gen._decode_view(params, CFG)
+    key = jax.random.PRNGKey(3)
+    if program == "chunk":
+        # 16 positions from the block-aligned 8: blocks 6 and 7 whole.
+        table = jnp.asarray([[5, 6, 7, 8]], jnp.int32)
+        tokens = jnp.asarray(rng.integers(0, CFG.vocab_size, 16), jnp.int32)
+        out = sch._paged_chunk_impl(
+            CFG, *before, view, tokens, table, jnp.asarray(8, jnp.int32),
+            jnp.asarray(15, jnp.int32), key, jnp.asarray(1.0),
+            jnp.asarray(True), attn_impl=attn_impl)
+        after = out[:4]
+        written = [(6, o) for o in range(bsz)] + [(7, o) for o in range(bsz)]
+    else:
+        # Three live rows at ragged lengths and an idle row (all trash).
+        table = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8], [9, 10, 11, 12],
+                             [0, 0, 0, 0]], jnp.int32)
+        lengths = jnp.asarray([1, 11, 26, 0], jnp.int32)
+        tokens = jnp.asarray(rng.integers(0, CFG.vocab_size, 4), jnp.int32)
+        out = sch._paged_decode_impl(
+            CFG, *before, view, tokens, table, lengths,
+            jnp.stack([key] * 4), jnp.ones(4), jnp.ones(4, bool),
+            attn_impl=attn_impl)
+        after = out[1:]
+        written = [(1, 1), (6, 3), (12, 2)]
+    for was, now in zip(before, after):
+        if was is None:
+            assert now is None
+            continue
+        assert now.shape == was.shape and now.dtype == was.dtype
+        was, now = np.asarray(was), np.asarray(now)
+        untouched = np.ones(was.shape[:3], bool)
+        untouched[:, TRASH_BLOCK] = False
+        for block, offset in written:
+            untouched[:, block, offset] = False
+            # Every layer wrote its own row there.
+            assert (was[:, block, offset] != now[:, block, offset]) \
+                .any(axis=-1).all()
+        assert np.array_equal(was[untouched], now[untouched])
 
 
 def test_int8_kv_defaults_to_full_prompt_prefill(params):

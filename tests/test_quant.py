@@ -217,7 +217,7 @@ def test_int8_halves_kv_value_bytes_and_slot_capacity(params):
     q = init_paged_pool(CFG, 12, 16, kv_dtype=jnp.int8)
     assert q.k.nbytes * 2 == bf16.k.nbytes
     assert q.v.nbytes * 2 == bf16.v.nbytes
-    assert q.k_scale.shape == (CFG.n_layer, 13, CFG.n_head, 16)
+    assert q.k_scale.shape == (CFG.n_layer, 13, 16, CFG.n_head)
     assert q.bytes_per_block == 16 * kv_bytes_per_token(CFG, jnp.int8)
     # Capacity math at real serving dims (no allocation): gpt2 Dh=64.
     full = gpt2.GPT2Config.from_name("gpt2")

@@ -45,14 +45,19 @@ def params():
 
 
 def _pools(rng, nb, h, bsz, dh, quantized):
+    """Stacked pools [L, NB, BLOCK, H·Dh] of two layers (the tests read
+    layer 1) and, quantized, the scale planes [L, NB, BLOCK, H]."""
+    shape = (2, nb, bsz, h * dh)
     if quantized:
-        k = jnp.asarray(rng.integers(-127, 128, (nb, h, bsz, dh)), jnp.int8)
-        v = jnp.asarray(rng.integers(-127, 128, (nb, h, bsz, dh)), jnp.int8)
-        ks = jnp.asarray(rng.uniform(0.01, 0.2, (nb, h, bsz)), jnp.float32)
-        vs = jnp.asarray(rng.uniform(0.01, 0.2, (nb, h, bsz)), jnp.float32)
+        k = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+        v = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+        ks = jnp.asarray(rng.uniform(0.01, 0.2, shape[:3] + (h,)),
+                         jnp.float32)
+        vs = jnp.asarray(rng.uniform(0.01, 0.2, shape[:3] + (h,)),
+                         jnp.float32)
         return k, v, ks, vs
-    k = jnp.asarray(rng.normal(size=(nb, h, bsz, dh)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(nb, h, bsz, dh)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    v = jnp.asarray(rng.normal(size=shape), jnp.float32)
     return k, v, None, None
 
 
@@ -71,10 +76,10 @@ def test_prefill_kernel_matches_reference_ragged(quantized):
     table = jnp.asarray(rng.permutation(nb)[:r * nbps].reshape(r, nbps),
                         jnp.int32)
     start = jnp.asarray([0, 5, 17], jnp.int32)   # ragged, non-aligned
-    out = pattn.paged_prefill_attention(q, k, v, table, start,
+    out = pattn.paged_prefill_attention(q, k, v, table, start, layer=1,
                                         k_scale=ks, v_scale=vs,
                                         interpret=True)
-    ref = pattn.paged_attention_reference(q, k, v, table, start,
+    ref = pattn.paged_attention_reference(q, k, v, table, start, layer=1,
                                           k_scale=ks, v_scale=vs)
     tol = 5e-5 if quantized else 5e-6
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=tol)
@@ -92,9 +97,9 @@ def test_prefill_kernel_scalar_start_and_tile_multiple():
     table = jnp.asarray(rng.permutation(nb)[:nbps].reshape(r, nbps),
                         jnp.int32)
     start = jnp.asarray(11, jnp.int32)
-    out = pattn.paged_prefill_attention(q, k, v, table, start,
+    out = pattn.paged_prefill_attention(q, k, v, table, start, layer=1,
                                         interpret=True)
-    ref = pattn.paged_attention_reference(q, k, v, table, start)
+    ref = pattn.paged_attention_reference(q, k, v, table, start, layer=1)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=5e-6)
 
 

@@ -60,6 +60,10 @@ HOT_LOOP_MODULES = (
     "trustworthy_dl_tpu/serve/engine.py",
     "trustworthy_dl_tpu/engine/step.py",
     "trustworthy_dl_tpu/engine/trainer.py",
+    # Holds the serving programs' layer loop, which CARRIES the paged KV
+    # pool [L, NB, BLOCK, H·Dh] — the one shape on which the in-place row
+    # write, the kernels' block and the resting layout agree
+    # (tests/test_chip_compile.py pins that no program copies it).
     "trustworthy_dl_tpu/models/generate.py",
     # The paged-attention kernel module runs INSIDE every paged decode
     # program (its wrapper traces per layer per tick) — a per-call

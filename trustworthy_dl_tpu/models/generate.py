@@ -400,29 +400,35 @@ def fused_verify_logits(params: Params, x: jax.Array,
 # ---------------------------------------------------------------------------
 # Paged-KV read/write path (serve/kv_slots.PagedKV pools).
 #
-# The paged pool stores K/V in fixed-size token blocks [NB, H, BLOCK, Dh]
-# per layer; a slot's logical cache is reassembled by gathering its block
+# The paged pool stores K/V in fixed-size token blocks, every layer in ONE
+# array [L, NB, BLOCK, H·Dh]: a position's K (or V) of every head is one
+# contiguous row.  A slot's logical cache is reassembled through its block
 # table (i32 per-slot physical ids — traced VALUES, so block churn never
-# recompiles).  The attention core is the untouched _block_with_cache:
-# the gathered view is numerically the same [R, H, S, Dh] cache a
-# contiguous KVCache holds (valid positions carry identical values;
-# garbage positions are masked to exactly-zero probabilities), so paged
-# decode is bit-identical to generate()'s by construction.  After the
-# core runs, the rows it wrote into the view are extracted and scattered
-# back into the pool at (physical block, offset); positions outside the
-# slot's table land in the reserved trash block 0.
+# recompiles).  Inside a serving program the pool never leaves its buffer
+# or its layout: the layer loop CARRIES the stacked pool, a layer writes
+# its R·T new rows in place at (layer, physical block, offset) and reads
+# the pool through (layer, table) — the kernels through their index map,
+# the jnp path through one gather.  Row write, kernel block and resting
+# layout agree on this shape (tests/test_chip_compile.py holds the
+# compiler to it: no copy of the pool or of a layer in either program).
+#
+# The jnp attention core is the untouched _block_with_cache: the gathered
+# view is numerically the same [R, H, S, Dh] cache a contiguous KVCache
+# holds (valid positions carry identical values; garbage positions are
+# masked to exactly-zero probabilities), so paged decode is bit-identical
+# to generate()'s by construction.  Positions outside the slot's table
+# land in the reserved trash block 0.
 # ---------------------------------------------------------------------------
 
 
-def _paged_gather(layer_pool: jax.Array, table: jax.Array) -> jax.Array:
-    """[NB, H, BLOCK, Dh] (or scale [NB, H, BLOCK]) pool slice + block
-    table [R, NBPS] -> contiguous per-row view [R, H, NBPS*BLOCK(, Dh)]."""
-    g = layer_pool[table]                       # [R, NBPS, H, BLOCK(, Dh)]
-    if g.ndim == 5:
-        g = g.transpose(0, 2, 1, 3, 4)          # [R, H, NBPS, BLOCK, Dh]
-        return g.reshape(g.shape[0], g.shape[1], -1, g.shape[-1])
-    g = g.transpose(0, 2, 1, 3)                 # [R, H, NBPS, BLOCK]
-    return g.reshape(g.shape[0], g.shape[1], -1)
+def _paged_gather(pool: jax.Array, table: jax.Array, layer: jax.Array,
+                  n_head: int) -> jax.Array:
+    """Layer ``layer`` of a stacked pool array [L, NB, BLOCK, H·X] + block
+    table [R, NBPS] -> contiguous per-row view [R, H, NBPS*BLOCK, X]:
+    X = Dh for K and V, 1 for the int8 tier's scale planes."""
+    g = pool[layer, table]                      # [R, NBPS, BLOCK, H·X]
+    return g.reshape(g.shape[0], -1, n_head, g.shape[-1] // n_head) \
+        .transpose(0, 2, 1, 3)
 
 
 def _pool_write_coords(table_read: jax.Array, start: jax.Array, r: int,
@@ -432,7 +438,7 @@ def _pool_write_coords(table_read: jax.Array, start: jax.Array, r: int,
     for the T positions each row writes this call — positions past the
     slot's real table land in the reserved trash block 0.  ONE spelling
     shared by the gather path (which extracts the written rows from its
-    view at ``pos``) and the kernel path (which scatters the fresh K/V
+    view at ``pos``) and the kernel path (which writes the fresh K/V
     directly), so for the SAME block input the two paths write identical
     values to identical pool coordinates (across a multi-layer scan,
     deeper layers inherit the attention paths' f32-rounding epsilon
@@ -450,29 +456,42 @@ def _pool_write_coords(table_read: jax.Array, start: jax.Array, r: int,
     return pos, phys, offs
 
 
-def _paged_block(block: Params, x: jax.Array, pool_k_l: jax.Array,
-                 pool_v_l: jax.Array, table: jax.Array, start: jax.Array,
-                 cfg: gpt2.GPT2Config,
-                 pool_ks_l: Optional[jax.Array] = None,
-                 pool_vs_l: Optional[jax.Array] = None,
+def _pool_write_rows(pool: jax.Array, rows: jax.Array, layer: jax.Array,
+                     phys: jax.Array, offs: jax.Array) -> jax.Array:
+    """Write the call's R·T new positions into a stacked pool array in
+    place, one contiguous row a position at (layer, phys, offs): ``rows``
+    [R, H, T, Dh] (K or V) into [L, NB, BLOCK, H·Dh], or the int8 tier's
+    scales [R, H, T] into [L, NB, BLOCK, H]."""
+    r, _, t = rows.shape[:3]
+    rows = jnp.moveaxis(rows, 1, 2).reshape(r * t, -1)
+    return pool.at[layer, phys, offs].set(rows.astype(pool.dtype))
+
+
+def _paged_block(block: Params, x: jax.Array, pool_k: jax.Array,
+                 pool_v: jax.Array, table: jax.Array, start: jax.Array,
+                 cfg: gpt2.GPT2Config, layer: jax.Array,
+                 pool_ks: Optional[jax.Array] = None,
+                 pool_vs: Optional[jax.Array] = None,
                  attn_impl: str = "jnp",
                  adapter_l: Optional[tuple] = None,
                  adapter_impl: str = "jnp",
                  ) -> Tuple[jax.Array, jax.Array, jax.Array,
                             Optional[jax.Array], Optional[jax.Array]]:
-    """One transformer block over [R, T, D] new positions against a PAGED
-    layer pool.  ``attn_impl`` (trace-time static — the scheduler bakes
-    its resolved path into each compiled program) selects the attention
-    read:
+    """Transformer block ``layer`` over [R, T, D] new positions against
+    the STACKED paged pool [L, NB, BLOCK, H·Dh] (scale planes [L, NB,
+    BLOCK, H]); returns the activations and the pool arrays with this
+    layer's R·T new rows written, nothing else touched.  ``attn_impl``
+    (trace-time static — the scheduler bakes its resolved path into each
+    compiled program) selects the attention read:
 
     * ``"jnp"`` (default, the reference semantics): gather each row's
-      view through ``table``, run the dense ``_block_with_cache`` core on
-      it (one numerics source for generate and paged serve), then
-      scatter the newly written rows back into the pool.
-    * ``"pallas"`` / ``"interpret"``: scatter the fresh K/V into the pool
-      FIRST (same quantize-at-write values, same ``_pool_write_coords``
-      scatter), then run the ragged ``ops.paged_attention`` kernel
-      straight over the pool: no [R, H, S, Dh] view is ever
+      view through ``(layer, table)``, run the dense ``_block_with_cache``
+      core on it (one numerics source for generate and paged serve), then
+      write the newly written rows back into the pool.
+    * ``"pallas"`` / ``"interpret"``: write the fresh K/V into the pool
+      FIRST (same quantize-at-write values, same ``_pool_write_coords``),
+      then run the ragged ``ops.paged_attention`` kernel straight over
+      the stacked pool at ``layer``: no [R, H, S, Dh] view is ever
       materialised, int8 tiles dequantise in-register, rows stop
       streaming at their true length.  Write-then-attend equals the jnp
       path's write-into-view because writes only ever land in blocks the
@@ -503,8 +522,8 @@ def _paged_block(block: Params, x: jax.Array, pool_k_l: jax.Array,
             adapter_s = (a_l[apages], b_l[apages],
                          None if as_l is None else as_l[apages],
                          None if bs_l is None else bs_l[apages])
-        return _paged_block_kernel(block, x, pool_k_l, pool_v_l, table,
-                                   start, cfg, pool_ks_l, pool_vs_l,
+        return _paged_block_kernel(block, x, pool_k, pool_v, table,
+                                   start, cfg, layer, pool_ks, pool_vs,
                                    interpret=(attn_impl == "interpret"),
                                    adapter=adapter_s,
                                    adapter_pool=adapter_pool,
@@ -516,7 +535,7 @@ def _paged_block(block: Params, x: jax.Array, pool_k_l: jax.Array,
                      None if bs_l is None else bs_l[apages])
     r, t, _ = x.shape
     nbps = table.shape[1]
-    bsz = pool_k_l.shape[2]
+    bsz = pool_k.shape[2]
     if t > 1:
         # A prefill chunk may extend past the logical view (its start is
         # only block-aligned, not chunk-aligned, after a prefix hit) —
@@ -527,12 +546,12 @@ def _paged_block(block: Params, x: jax.Array, pool_k_l: jax.Array,
         table_read = jnp.concatenate([table, pad], axis=1)
     else:
         table_read = table
-    view_k = _paged_gather(pool_k_l, table_read)
-    view_v = _paged_gather(pool_v_l, table_read)
-    view_ks = (_paged_gather(pool_ks_l, table_read)
-               if pool_ks_l is not None else None)
-    view_vs = (_paged_gather(pool_vs_l, table_read)
-               if pool_vs_l is not None else None)
+    view_k = _paged_gather(pool_k, table_read, layer, cfg.n_head)
+    view_v = _paged_gather(pool_v, table_read, layer, cfg.n_head)
+    view_ks = (_paged_gather(pool_ks, table_read, layer, cfg.n_head)[..., 0]
+               if pool_ks is not None else None)
+    view_vs = (_paged_gather(pool_vs, table_read, layer, cfg.n_head)[..., 0]
+               if pool_vs is not None else None)
     x, view_k, view_v, view_ks, view_vs = _block_with_cache(
         block, x, view_k, view_v, start, cfg, view_ks, view_vs,
         adapter=adapter_s
@@ -542,27 +561,27 @@ def _paged_block(block: Params, x: jax.Array, pool_k_l: jax.Array,
                                          nbps)
     idx = pos[:, None, :, None]                            # [R, 1, T, 1]
 
-    def rows_of(view):                                     # [R, H, S(,Dh)]
+    def rows_of(view):                     # [R, H, S(, Dh)] -> [R, H, T(, Dh)]
         if view.ndim == 4:
-            got = jnp.take_along_axis(view, idx, axis=2)   # [R, H, T, Dh]
-            return got.transpose(0, 2, 1, 3).reshape(
-                r * t, got.shape[1], got.shape[-1])
-        got = jnp.take_along_axis(view, idx[..., 0], axis=2)  # [R, H, T]
-        return got.transpose(0, 2, 1).reshape(r * t, got.shape[1])
+            return jnp.take_along_axis(view, idx, axis=2)
+        return jnp.take_along_axis(view, idx[..., 0], axis=2)
 
-    pool_k_l = pool_k_l.at[phys, :, offs].set(rows_of(view_k))
-    pool_v_l = pool_v_l.at[phys, :, offs].set(rows_of(view_v))
-    if pool_ks_l is not None:
-        pool_ks_l = pool_ks_l.at[phys, :, offs].set(rows_of(view_ks))
-        pool_vs_l = pool_vs_l.at[phys, :, offs].set(rows_of(view_vs))
-    return x, pool_k_l, pool_v_l, pool_ks_l, pool_vs_l
+    pool_k = _pool_write_rows(pool_k, rows_of(view_k), layer, phys, offs)
+    pool_v = _pool_write_rows(pool_v, rows_of(view_v), layer, phys, offs)
+    if pool_ks is not None:
+        pool_ks = _pool_write_rows(pool_ks, rows_of(view_ks), layer,
+                                   phys, offs)
+        pool_vs = _pool_write_rows(pool_vs, rows_of(view_vs), layer,
+                                   phys, offs)
+    return x, pool_k, pool_v, pool_ks, pool_vs
 
 
-def _paged_block_kernel(block: Params, x: jax.Array, pool_k_l: jax.Array,
-                        pool_v_l: jax.Array, table: jax.Array,
+def _paged_block_kernel(block: Params, x: jax.Array, pool_k: jax.Array,
+                        pool_v: jax.Array, table: jax.Array,
                         start: jax.Array, cfg: gpt2.GPT2Config,
-                        pool_ks_l: Optional[jax.Array],
-                        pool_vs_l: Optional[jax.Array],
+                        layer: jax.Array,
+                        pool_ks: Optional[jax.Array],
+                        pool_vs: Optional[jax.Array],
                         interpret: bool,
                         adapter: Optional[tuple] = None,
                         adapter_pool: Optional[tuple] = None,
@@ -572,56 +591,45 @@ def _paged_block_kernel(block: Params, x: jax.Array, pool_k_l: jax.Array,
                                    Optional[jax.Array]]:
     """The kernel-path twin of the gather branch in :func:`_paged_block`:
     write-then-attend.  The fresh K/V (quantized at the write on the int8
-    tier — the exact values the gather path writes) scatter into the pool
-    first; a ``ops.paged_attention`` program then reads positions
-    [0, start+T) straight from the pool with the causal window masked
-    in absolute positions, which is precisely what the gathered view
-    exposes to ``_block_with_cache``.  T selects the program (static —
-    each serve program compiles one shape): the decode program up to
-    ``QROWS`` rows (decode T=1, speculative verify T=k+1), the
-    chunked-prefill program above it (the same kernel; the chunk in one
-    query tile where VMEM allows, else in tiles whose causal block
+    tier — the exact values the gather path writes) go into the stacked
+    pool first, in place; a ``ops.paged_attention`` program then reads
+    positions [0, start+T) of ``layer`` straight from the pool with the
+    causal window masked in absolute positions, which is precisely what
+    the gathered view exposes to ``_block_with_cache``.  T selects the
+    program (static — each serve program compiles one shape): the decode
+    program up to ``QROWS`` rows (decode T=1, speculative verify T=k+1),
+    the chunked-prefill program above it (the same kernel; the chunk in
+    one query tile where VMEM allows, else in tiles whose causal block
     bounds skip the KV blocks a whole tile cannot see)."""
     from trustworthy_dl_tpu.ops import paged_attention as pattn
     from trustworthy_dl_tpu.quant import int8 as q8
 
     r, t, _ = x.shape
-    h = cfg.n_head
     nbps = table.shape[1]
-    bsz = pool_k_l.shape[2]
-    quantized = pool_ks_l is not None
+    bsz = pool_k.shape[2]
 
     # Shared pre/post-attention scaffolding (_attn_qkv/_attn_mlp_tail):
     # only the attention READ differs from _block_with_cache.
     q, k, v = _attn_qkv(block, x, cfg)                     # [R, H, T, Dh]
 
     _, phys, offs = _pool_write_coords(table, start, r, t, bsz, nbps)
-
-    def rows_of(a):                       # [R, H, T(, Dh)] -> [R·T, H(, Dh)]
-        if a.ndim == 4:
-            return a.transpose(0, 2, 1, 3).reshape(r * t, h, a.shape[-1])
-        return a.transpose(0, 2, 1).reshape(r * t, h)
-
-    if quantized:
-        k_w, k_s = q8.quantize_kv(k)                       # int8, f32 [R,H,T]
-        v_w, v_s = q8.quantize_kv(v)
-        pool_ks_l = pool_ks_l.at[phys, :, offs].set(rows_of(k_s))
-        pool_vs_l = pool_vs_l.at[phys, :, offs].set(rows_of(v_s))
-    else:
-        k_w = k.astype(pool_k_l.dtype)
-        v_w = v.astype(pool_v_l.dtype)
-    pool_k_l = pool_k_l.at[phys, :, offs].set(rows_of(k_w))
-    pool_v_l = pool_v_l.at[phys, :, offs].set(rows_of(v_w))
+    if pool_ks is not None:
+        k, k_s = q8.quantize_kv(k)                         # int8, f32 [R,H,T]
+        v, v_s = q8.quantize_kv(v)
+        pool_ks = _pool_write_rows(pool_ks, k_s, layer, phys, offs)
+        pool_vs = _pool_write_rows(pool_vs, v_s, layer, phys, offs)
+    pool_k = _pool_write_rows(pool_k, k, layer, phys, offs)
+    pool_v = _pool_write_rows(pool_v, v, layer, phys, offs)
 
     attend = (pattn.paged_prefill_attention if t > pattn.QROWS
               else pattn.paged_attention)
     out = attend(
-        q, pool_k_l, pool_v_l, table, start,
-        k_scale=pool_ks_l, v_scale=pool_vs_l, interpret=interpret,
+        q, pool_k, pool_v, table, start, layer=layer,
+        k_scale=pool_ks, v_scale=pool_vs, interpret=interpret,
     ).astype(cfg.dtype)                                    # [R, H, T, Dh]
     x = _attn_mlp_tail(block, x, out, cfg, adapter=adapter,
                        adapter_pool=adapter_pool, adapter_impl=adapter_impl)
-    return x, pool_k_l, pool_v_l, pool_ks_l, pool_vs_l
+    return x, pool_k, pool_v, pool_ks, pool_vs
 
 
 def _apply_with_cache_paged(params: Params, tokens: jax.Array,
@@ -640,9 +648,12 @@ def _apply_with_cache_paged(params: Params, tokens: jax.Array,
                                        Optional[jax.Array],
                                        Optional[jax.Array]]:
     """Paged twin of :func:`_apply_with_cache`: run all blocks over
-    ``tokens`` [R, T] against the block pool, gathering each layer's view
-    inside the layer scan (only ONE layer's view is ever live) and
-    scattering its writes back.  Returns (logits [R, V], updated pool
+    ``tokens`` [R, T] against the stacked block pool [L, NB, BLOCK, H·Dh].
+    The pool arrays (on the int8 tier the scale planes too) are the layer
+    scan's CARRY beside the activations: each layer writes its R·T new
+    rows in place and reads through ``(layer, table)``, so the pool is
+    never sliced, relaid out or re-stacked — under donation the program
+    updates the caller's buffers.  Returns (logits [R, V], updated pool
     arrays) — pool updates are functional, the scheduler threads them.
     ``all_logits`` (trace-time bool) returns [R, T, V] logits at every
     fed position instead — the speculative-verify program's tail, where
@@ -661,12 +672,12 @@ def _apply_with_cache_paged(params: Params, tokens: jax.Array,
 
     ``adapter`` is the paged adapter-pool pytree ``(a [L, P+1, 2, D,
     r], b, a_scale, b_scale, apages [R])`` (serve/adapters.py): the
-    pool sides join the layer scan's xs (leading L axis, like the KV
-    pools) and the per-slot page table is closed over — both traced
-    values, so adapter churn and tenant-mix changes never recompile.
-    ``None`` (adapter_rank == 0) contributes zero pytree leaves: the
-    compiled program is structurally identical to the pre-adapter
-    one."""
+    pool sides join the layer scan's xs (leading L axis, beside the
+    blocks' weights and the layer's index) and the per-slot page table
+    is closed over — both traced values, so adapter churn and tenant-mix
+    changes never recompile.  ``None`` (adapter_rank == 0) contributes
+    zero pytree leaves: the compiled program is structurally identical
+    to the pre-adapter one."""
     t = tokens.shape[-1]
     if jnp.ndim(start) == 0:
         pos = start + jnp.arange(t)                        # [T]
@@ -679,21 +690,20 @@ def _apply_with_cache_paged(params: Params, tokens: jax.Array,
     else:
         ad_a = ad_b = ad_as = ad_bs = apages = None
 
-    def scan_fn(carry, layer):
-        x = carry
-        block, pk, pv, pks, pvs, a_l, b_l, as_l, bs_l = layer
+    def scan_fn(carry, layer_xs):
+        x, pk, pv, pks, pvs = carry
+        block, layer, a_l, b_l, as_l, bs_l = layer_xs
         adapter_l = (None if a_l is None
                      else (a_l, b_l, as_l, bs_l, apages))
-        x, pk, pv, pks, pvs = _paged_block(block, x, pk, pv, table, start,
-                                           cfg, pks, pvs,
-                                           attn_impl=attn_impl,
-                                           adapter_l=adapter_l,
-                                           adapter_impl=adapter_impl)
-        return x, (pk, pv, pks, pvs)
+        return _paged_block(block, x, pk, pv, table, start, cfg, layer,
+                            pks, pvs, attn_impl=attn_impl,
+                            adapter_l=adapter_l,
+                            adapter_impl=adapter_impl), None
 
-    x, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(
-        scan_fn, x, (params["blocks"], pool_k, pool_v, pool_ks, pool_vs,
-                     ad_a, ad_b, ad_as, ad_bs),
+    layers = jnp.arange(pool_k.shape[0], dtype=jnp.int32)
+    (x, new_k, new_v, new_ks, new_vs), _ = jax.lax.scan(
+        scan_fn, (x, pool_k, pool_v, pool_ks, pool_vs),
+        (params["blocks"], layers, ad_a, ad_b, ad_as, ad_bs),
     )
     if hidden:
         return x, new_k, new_v, new_ks, new_vs

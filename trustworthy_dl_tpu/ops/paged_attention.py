@@ -15,16 +15,27 @@ tokens/sec lever ROADMAP item 2 names:
   so the KV BlockSpec index map resolves ``logical block j -> physical
   block table[r, j]`` before the DMA is issued — the gather IS the
   pipeline, no [R, H, S, Dh] view is ever materialised;
-* **a step holds a block's heads**: the pool ``[NB, H, BLOCK, Dh]``
-  keeps the heads of a physical block contiguous, so one step copies
-  the block for a GROUP of ``g`` heads and runs their products as one
-  batch.  A grid step costs a quarter to a third of a microsecond on a
-  v5e whatever it holds, so the kernels' time is their step count:
-  ``g`` and the query tile come from ONE rule over shapes and the pool's
-  dtype (:func:`_step_shape`: the widest tile, then the largest divisor
-  of ``H``, whose blocks fit :data:`VMEM_BLOCK_BUDGET` — all 20 heads
-  and the chunk's 64 queries at GPT-2 large, one head at blocks of
-  4,096 x 128), and :func:`grid_steps` counts what a call pays;
+* **the pool is read where it lies**: the kernels take the STACKED pool
+  ``[L, NB, BLOCK, H·Dh]`` (every layer; a position's K, or V, of every
+  head one contiguous row) and the layer as a fourth scalar-prefetch
+  operand: the K/V index map returns ``(layer, table[r, j], 0, head
+  group)``.  On this shape the in-place row write ``pool.at[layer,
+  block, offset]``, the kernel's block and the array's resting layout
+  agree, so a serving program carries the pool through its layer loop in
+  one buffer and one layout and nothing copies it
+  (tests/test_chip_compile.py holds the compiler to that);
+* **a step holds a block's heads**: a physical block's rows keep the
+  heads side by side in the lanes, so one step copies the block for a
+  GROUP of ``g`` heads (a window of the lanes: whole 128-lane columns,
+  or every head — :func:`_head_groups`), cuts the heads out of the lanes
+  (:func:`_heads_from_lanes`) and runs their products as one batch.  A
+  grid step costs a quarter to a third of a microsecond on a v5e
+  whatever it holds, so the kernels' time is their step count: ``g`` and
+  the query tile come from ONE rule over shapes and the pool's dtype
+  (:func:`_step_shape`: the widest tile, then the widest head group,
+  whose blocks fit :data:`VMEM_BLOCK_BUDGET` — all 20 heads and the
+  chunk's 64 queries at GPT-2 large, one head at blocks of 4,096 x
+  128), and :func:`grid_steps` counts what a call pays;
 * **int8 streaming**: int8 KV tiles DMA HBM→VMEM at half the bf16 bytes
   (a quarter of f32), upcast in-register, and the per-(head, position)
   scales PagedKV already pages multiply the scores/probabilities exactly
@@ -200,25 +211,40 @@ def _pipelined_block_bytes(program: str, *, head_dim: int,
     return 2 * blocks
 
 
+def _head_groups(heads: Optional[int], head_dim: int) -> list:
+    """The head groups one grid step may hold, widest first.  The pool
+    keeps a position's heads side by side in ONE row ``[H·Dh]``, so a
+    group is a window of the lanes and Mosaic wants it whole 128-lane
+    columns, or the whole row: a divisor ``g`` of ``heads`` with ``g·Dh``
+    a multiple of 128, or every head (at 64-wide heads never 1 or 5).
+    ``heads`` unknown (None): the narrowest window any head count
+    allows."""
+    if not heads:
+        return [128 // math.gcd(128, head_dim)]
+    return [g for g in range(heads, 0, -1)
+            if heads % g == 0 and (g == heads or (g * head_dim) % 128 == 0)]
+
+
 def _step_shape(program: str, *, heads: int, head_dim: int,
                 block_size: int, kv_dtype, t: int) -> Tuple[int, int]:
     """THE rule for what one grid step of an attention program holds:
     ``(head group, query tile)``, from shapes and the pool's dtype alone.
 
     The query tile first: the ``t`` query rows of a call, padded to the
-    sublane, in ONE tile if a single head's blocks then fit
+    sublane, in ONE tile if the narrowest head group's blocks then fit
     :data:`VMEM_BLOCK_BUDGET` (:func:`_pipelined_block_bytes`), else the
     widest ``QROWS * 2**i`` under it that does (a wider tile streams the
     row's K and V fewer times); the decode program never tiles.  Then the
-    largest divisor of ``heads`` whose group fits beside that tile: the
-    pool keeps a physical block's heads contiguous, so a group is one
-    copy.  A geometry nothing fits gets the narrowest step, which
-    :func:`supports_paged_attention` refuses."""
+    widest group of :func:`_head_groups` that fits beside that tile: the
+    pool keeps a physical block's heads side by side in its rows, so a
+    group is one copy.  A geometry nothing fits gets the narrowest step,
+    which :func:`supports_paged_attention` refuses."""
     t8 = -(-t // QROWS) * QROWS
     tiles = [t8]
     if program == "prefill":
         tiles += [QROWS << i for i in reversed(range(t8.bit_length()))
                   if QROWS << i < t8]
+    groups = _head_groups(heads, head_dim)
 
     def fits(group: int, q_tile: int) -> bool:
         return _pipelined_block_bytes(
@@ -226,9 +252,8 @@ def _step_shape(program: str, *, heads: int, head_dim: int,
             kv_dtype=kv_dtype, n_embd=heads * head_dim, group=group,
             q_tile=q_tile) <= VMEM_BLOCK_BUDGET
 
-    q_tile = next((qt for qt in tiles if fits(1, qt)), tiles[-1])
-    group = next(g for g in range(heads, 0, -1)
-                 if heads % g == 0 and (g == 1 or fits(g, q_tile)))
+    q_tile = next((qt for qt in tiles if fits(groups[-1], qt)), tiles[-1])
+    group = next((g for g in groups if fits(g, q_tile)), groups[-1])
     return group, q_tile
 
 
@@ -264,11 +289,12 @@ def supports_paged_attention(*, head_dim: int, block_size: int,
     compiled eligibility is one rule for all four programs: what a grid
     step pins (:func:`_pipelined_block_bytes`) fits
     :data:`VMEM_BLOCK_BUDGET`.  For the attention programs the step
-    asked about is the narrowest :func:`_step_shape` can fall to, one
-    head and one sublane of queries (the function's defaults): that
-    bounds ``block_size x head_dim`` in the POOL's storage dtype (and, on
-    the int8 tier, the scale planes of ``n_embd // head_dim`` heads);
-    what fits beyond it only widens the step.  For the verify tail
+    asked about is the narrowest :func:`_step_shape` can fall to, the
+    narrowest head group the pool's rows allow (:func:`_head_groups`: one
+    head only where a head is whole 128-lane columns) and one sublane of
+    queries: that bounds ``block_size x head_dim`` in the POOL's storage
+    dtype (and, on the int8 tier, the scale planes of ``n_embd //
+    head_dim`` heads); what fits beyond it only widens the step.  For the verify tail
     it bounds ``n_embd`` (the [TRUST_TILE, n_embd] head tile); for the
     adapter gather ``rows x n_embd`` (``rows`` = the most query rows one
     call carries, the prefill chunk).  ``verify`` and ``adapter`` need
@@ -288,10 +314,14 @@ def supports_paged_attention(*, head_dim: int, block_size: int,
         return True
     if program in ("verify", "adapter") and not n_embd:
         return False
+    group = 1
+    if program in ("decode", "prefill"):
+        group = _head_groups(n_embd // head_dim if n_embd else None,
+                             head_dim)[-1]
     return _pipelined_block_bytes(
         program, head_dim=head_dim, block_size=block_size,
         kv_dtype=kv_dtype, n_embd=n_embd, adapter_rank=adapter_rank,
-        rows=rows) <= VMEM_BLOCK_BUDGET
+        rows=rows, group=group) <= VMEM_BLOCK_BUDGET
 
 
 def resolve_attn_impl(requested: str, *, head_dim: int, block_size: int,
@@ -422,9 +452,24 @@ def _times_head_scales(x: jax.Array, scale_ref, head0) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def _paged_attn_kernel(table_ref, start_ref, jmax_ref, q_ref, k_ref, v_ref,
-                       *rest, scale: float, bsz: int, qt: int, group: int,
-                       quantized: bool):
+def _heads_from_lanes(block_ref, group: int) -> jax.Array:
+    """The rows of a step's K (or V) block ``[1, 1, bsz, group * Dh]`` (a
+    position's heads side by side in the lanes, as the pool keeps them)
+    -> f32 ``[group, bsz, Dh]``, the heads a batch dimension for the two
+    products.  Static lane windows, restacked: Mosaic refuses the reshape
+    that splits the lane dimension ("unsupported shape cast", 16 x 1280
+    -> 16 x 20 x 64) and this jax has no ``einshape`` (PERF.md section 6
+    has what the windows cost on the chip)."""
+    x = block_ref[0, 0].astype(jnp.float32)
+    dh = x.shape[-1] // group
+    if group == 1:
+        return x[None]
+    return jnp.stack([x[:, i * dh:(i + 1) * dh] for i in range(group)])
+
+
+def _paged_attn_kernel(table_ref, start_ref, jmax_ref, layer_ref, q_ref,
+                       k_ref, v_ref, *rest, scale: float, bsz: int, qt: int,
+                       group: int, quantized: bool):
     """One (row, head group, query tile, logical block) grid step of the
     online softmax: ``group`` heads of ONE physical block against ``qt``
     query rows, the heads a batch dimension of both products (per head
@@ -468,7 +513,7 @@ def _paged_attn_kernel(table_ref, start_ref, jmax_ref, q_ref, k_ref, v_ref,
             jnp.int32, (qt, bsz), 0)
         visible = (kpos <= qpos)[None]
         q = q_ref[0].astype(jnp.float32)                 # [g, qt, Dh]
-        k = k_ref[0].astype(jnp.float32)                 # [g, bsz, Dh]
+        k = _heads_from_lanes(k_ref, group)               # [g, bsz, Dh]
         s = _dot(q, k, trans_b=True) * scale             # [g, qt, bsz] f32
         if quantized:
             # Per-(head, position) K scale: constant along the contracted
@@ -489,7 +534,7 @@ def _paged_attn_kernel(table_ref, start_ref, jmax_ref, q_ref, k_ref, v_ref,
             # V scale folds into the probabilities before the PV
             # contraction — again the gathered-view algebra, in-register.
             p = _times_head_scales(p, vs_ref, hg * group)
-        v = v_ref[0].astype(jnp.float32)                 # [g, bsz, Dh]
+        v = _heads_from_lanes(v_ref, group)               # [g, bsz, Dh]
         acc_ref[:] = acc_ref[:] * corr + _dot(p, v)
         m_ref[:] = jnp.broadcast_to(m_cur, m_ref.shape)
 
@@ -506,11 +551,17 @@ def _paged_attn_kernel(table_ref, start_ref, jmax_ref, q_ref, k_ref, v_ref,
 def _attn_pallas_call(program: str, q: jax.Array, pool_k: jax.Array,
                       pool_v: jax.Array, k_scale: Optional[jax.Array],
                       v_scale: Optional[jax.Array], table: jax.Array,
-                      start: jax.Array, jmax: jax.Array,
+                      start: jax.Array, jmax: jax.Array, layer: jax.Array,
                       interpret: bool) -> jax.Array:
-    """q [R, H, NT·QT, Dh] x pool [NB, H, BLOCK, Dh] -> out like q, on
-    the grid :func:`grid_steps` gives ``program``.  ``jmax`` i32[R, NT]
-    is the per-(row, query-tile) last useful logical block."""
+    """q [R, H, NT·QT, Dh] x the STACKED pool [L, NB, BLOCK, H·Dh] at
+    ``layer`` i32[1] -> out like q, on the grid :func:`grid_steps` gives
+    ``program``.  ``jmax`` i32[R, NT] is the per-(row, query-tile) last
+    useful logical block.  The pool is handed over whole, in the buffer
+    and the layout it rests in: the layer is one more scalar-prefetch
+    operand of the K/V index map, so no layer is sliced out of the pool
+    and none relaid out for the call.  The int8 tier's scales come as
+    the LAYER's planes, heads before positions: [NB, H, BLOCK] (see
+    :func:`_attend`)."""
     r, h, t_pad, dh = q.shape
     nbps = table.shape[1]
     bsz = pool_k.shape[2]
@@ -520,6 +571,10 @@ def _attn_pallas_call(program: str, q: jax.Array, pool_k: jax.Array,
         raise ValueError(
             f"{program}: q rows {t_pad} and jmax {jmax.shape} are not the "
             f"padding of grid {grid}")
+    if pool_k.ndim != 4 or pool_k.shape[3] != h * dh:
+        raise ValueError(
+            f"{program}: pool {pool_k.shape} is not [L, NB, BLOCK, "
+            f"{h} x {dh}]")
     quantized = k_scale is not None
     kernel = functools.partial(
         _paged_attn_kernel, scale=1.0 / math.sqrt(dh), bsz=bsz, qt=qt,
@@ -527,28 +582,29 @@ def _attn_pallas_call(program: str, q: jax.Array, pool_k: jax.Array,
     )
 
     # Ragged early exit at the DMA level: logical block j of (row r, tile
-    # ti) maps to physical block table[r, min(j, jmax[r, ti])] — beyond
-    # the tile's last useful block the index repeats and Pallas issues no
-    # further copy.
-    def kv_idx(ri, gi, ti, ji, tbl, st, jm):
-        return (tbl[ri, jnp.minimum(ji, jm[ri, ti])], gi, 0, 0)
+    # ti) maps to physical block table[r, min(j, jmax[r, ti])] of the
+    # call's layer — beyond the tile's last useful block the index
+    # repeats and Pallas issues no further copy.  A head group is a
+    # window of the lanes.
+    def kv_idx(ri, gi, ti, ji, tbl, st, jm, ly):
+        return (ly[0], tbl[ri, jnp.minimum(ji, jm[ri, ti])], 0, gi)
 
-    def scale_idx(ri, gi, ti, ji, tbl, st, jm):
+    def scale_idx(ri, gi, ti, ji, tbl, st, jm, ly):
         return (tbl[ri, jnp.minimum(ji, jm[ri, ti])], 0, 0)
 
-    def q_idx(ri, gi, ti, ji, tbl, st, jm):
+    def q_idx(ri, gi, ti, ji, tbl, st, jm, ly):
         return (ri, gi, ti, 0)
 
     in_specs = [
         pl.BlockSpec((1, group, qt, dh), q_idx),
-        pl.BlockSpec((1, group, bsz, dh), kv_idx),
-        pl.BlockSpec((1, group, bsz, dh), kv_idx),
+        pl.BlockSpec((1, 1, bsz, group * dh), kv_idx),
+        pl.BlockSpec((1, 1, bsz, group * dh), kv_idx),
     ]
     operands = [q, pool_k, pool_v]
     if quantized:
         # Mosaic tiles the last two dims, so a (group, bsz) window over
-        # the [H, BLOCK] scale plane lowers only where the group is whole
-        # sublanes: the block carries every head's scales for the
+        # a block's [H, BLOCK] scales lowers only where the group is
+        # whole sublanes: the block carries every head's scales for the
         # physical block and the kernel picks each head's sublane.
         in_specs += [
             pl.BlockSpec((1, h, bsz), scale_idx),
@@ -556,7 +612,7 @@ def _attn_pallas_call(program: str, q: jax.Array, pool_k: jax.Array,
         ]
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, group, qt, dh), q_idx),
@@ -571,7 +627,7 @@ def _attn_pallas_call(program: str, q: jax.Array, pool_k: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, h, t_pad, dh), q.dtype),
         interpret=interpret,
-    )(table, start, jmax, *operands)
+    )(table, start, jmax, layer, *operands)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -579,11 +635,12 @@ def _paged_attn_call(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                      k_scale: Optional[jax.Array],
                      v_scale: Optional[jax.Array],
                      table: jax.Array, start: jax.Array, jmax: jax.Array,
+                     layer: jax.Array,
                      interpret: bool = False) -> jax.Array:
     """The decode program's call (its name is what the device trace shows
     the kernel as): every query row of a slot in one tile."""
     return _attn_pallas_call("decode", q, pool_k, pool_v, k_scale, v_scale,
-                             table, start, jmax, interpret)
+                             table, start, jmax, layer, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -592,12 +649,12 @@ def _paged_prefill_call(q: jax.Array, pool_k: jax.Array,
                         k_scale: Optional[jax.Array],
                         v_scale: Optional[jax.Array],
                         table: jax.Array, start: jax.Array,
-                        jmax: jax.Array,
+                        jmax: jax.Array, layer: jax.Array,
                         interpret: bool = False) -> jax.Array:
     """The chunked-prefill program's call (likewise named on the trace):
     the chunk's rows in as few query tiles as fit."""
     return _attn_pallas_call("prefill", q, pool_k, pool_v, k_scale, v_scale,
-                             table, start, jmax, interpret)
+                             table, start, jmax, layer, interpret)
 
 
 _ATTN_CALLS = {"decode": _paged_attn_call, "prefill": _paged_prefill_call}
@@ -605,7 +662,8 @@ _ATTN_CALLS = {"decode": _paged_attn_call, "prefill": _paged_prefill_call}
 
 def _attend(program: str, q: jax.Array, pool_k: jax.Array,
             pool_v: jax.Array, table: jax.Array, start: jax.Array,
-            k_scale: Optional[jax.Array], v_scale: Optional[jax.Array],
+            layer: jax.Array, k_scale: Optional[jax.Array],
+            v_scale: Optional[jax.Array],
             interpret: Optional[bool]) -> jax.Array:
     """Pad ``q`` to ``program``'s query tiles, bound each tile's walk and
     call the kernel."""
@@ -617,6 +675,7 @@ def _attend(program: str, q: jax.Array, pool_k: jax.Array,
     if jnp.ndim(start) == 0:
         start = jnp.broadcast_to(start, (r,))
     start = start.astype(jnp.int32)
+    layer = jnp.reshape(layer, (1,)).astype(jnp.int32)
     _, qt = _step_shape(program, heads=h, head_dim=dh, block_size=bsz,
                         kv_dtype=pool_k.dtype, t=t)
     nt = -(-t // qt)
@@ -631,41 +690,56 @@ def _attend(program: str, q: jax.Array, pool_k: jax.Array,
     if nt * qt != t:
         # Mosaic sublane: the query tile's row dim pads to 8.
         q = jnp.pad(q, ((0, 0), (0, 0), (0, nt * qt - t), (0, 0)))
+    if k_scale is not None:
+        # The scale planes [L, NB, BLOCK, H] are a sixty-fourth of the
+        # pool's elements and rest block-index-minor (their two minor
+        # dimensions are far under a tile), so the kernel cannot window
+        # them where they lie: it takes the layer's planes, heads on the
+        # sublanes and positions on the lanes as the scores have them,
+        # sliced out and relaid out here (2 MB a plane a layer at the
+        # serving cell; PERF.md section 7 has what else was tried).
+        k_scale = k_scale[layer[0]].transpose(0, 2, 1)
+        v_scale = v_scale[layer[0]].transpose(0, 2, 1)
     out = _ATTN_CALLS[program](q, pool_k, pool_v, k_scale, v_scale,
-                               table, start, jmax, interpret=interpret)
+                               table, start, jmax, layer,
+                               interpret=interpret)
     return out[:, :, :t]
 
 
 def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                     table: jax.Array, start: jax.Array, *,
+                    layer: jax.Array = 0,
                     k_scale: Optional[jax.Array] = None,
                     v_scale: Optional[jax.Array] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
-    """Ragged paged-decode attention over ONE layer's block pool.
+    """Ragged paged-decode attention over layer ``layer`` of the STACKED
+    block pool.
 
     ``q`` [R, H, T, Dh] queries at absolute positions ``start[r] + t``
-    (``start`` i32[R] or scalar); ``pool_k``/``pool_v`` [NB, H, BLOCK,
-    Dh] with optional int8 tier scales [NB, H, BLOCK]; ``table`` i32
-    [R, NBPS] physical block ids (traced values — block churn never
-    recompiles).  The row's K/V for positions [0, start+T) — INCLUDING
-    the freshly written window — must already be in the pool: the
-    kernel-path block (models/generate._paged_block) scatters the new
-    rows first, then attends, where the jnp path writes into its
-    gathered view.  Returns [R, H, T, Dh] in q's dtype with f32
-    accumulation throughout.
+    (``start`` i32[R] or scalar); ``pool_k``/``pool_v`` [L, NB, BLOCK,
+    H·Dh] (a position's heads side by side in one row) with optional
+    int8 tier scales [L, NB, BLOCK, H]; ``layer`` an i32 scalar (a traced
+    value: the layer loop's index); ``table`` i32 [R, NBPS] physical
+    block ids (traced values — block churn never recompiles).  The row's
+    K/V for positions [0, start+T) — INCLUDING the freshly written
+    window — must already be in the pool: the kernel-path block
+    (models/generate._paged_block) writes the new rows first, then
+    attends, where the jnp path writes into its gathered view.  Returns
+    [R, H, T, Dh] in q's dtype with f32 accumulation throughout.
 
     Semantics contract (pinned by tests/test_paged_attention.py against
     :func:`paged_attention_reference` and the jnp serve path): causal
     mask ``kpos <= start+t`` in absolute positions, int8 scales applied
     post-dot (K) / pre-contraction (V), positions past a row's length
-    never read — neither compute nor DMA."""
-    return _attend("decode", q, pool_k, pool_v, table, start, k_scale,
-                   v_scale, interpret)
+    never read — neither compute nor DMA — and no layer but ``layer``
+    read at all."""
+    return _attend("decode", q, pool_k, pool_v, table, start, layer,
+                   k_scale, v_scale, interpret)
 
 
 def paged_attention_reference(q: jax.Array, pool_k: jax.Array,
                               pool_v: jax.Array, table: jax.Array,
-                              start: jax.Array, *,
+                              start: jax.Array, *, layer: jax.Array = 0,
                               k_scale: Optional[jax.Array] = None,
                               v_scale: Optional[jax.Array] = None
                               ) -> jax.Array:
@@ -674,40 +748,36 @@ def paged_attention_reference(q: jax.Array, pool_k: jax.Array,
     ``_block_with_cache``, spelled standalone (f32 softmax, full-width
     mask) so the kernel test does not depend on the transformer block."""
     r, h, t, dh = q.shape
-    bsz = pool_k.shape[2]
     if jnp.ndim(start) == 0:
         start = jnp.broadcast_to(start, (r,))
 
-    def gather(pool):                       # [R, H, NBPS*BLOCK(, Dh)]
-        g = pool[table]
-        if g.ndim == 5:
-            g = g.transpose(0, 2, 1, 3, 4)
-            return g.reshape(r, h, -1, dh)
-        g = g.transpose(0, 2, 1, 3)
-        return g.reshape(r, h, -1)
+    def gather(pool):                       # -> [R, H, NBPS*BLOCK, X]
+        g = pool[layer][table]              # [R, NBPS, BLOCK, H*X]
+        return g.reshape(r, -1, h, g.shape[-1] // h).transpose(0, 2, 1, 3)
 
     view_k = gather(pool_k).astype(jnp.float32)
     view_v = gather(pool_v).astype(jnp.float32)
     s = jnp.einsum("rhtd,rhkd->rhtk", q.astype(jnp.float32), view_k)
     s = s / math.sqrt(dh)
     if k_scale is not None:
-        s = s * gather(k_scale)[:, :, None, :]
+        s = s * gather(k_scale)[:, :, None, :, 0]
     kpos = jnp.arange(view_k.shape[2])[None, None, None, :]
     qpos = (start[:, None] + jnp.arange(t)[None, :])[:, None, :, None]
     s = jnp.where(kpos <= qpos, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     if v_scale is not None:
-        p = p * gather(v_scale)[:, :, None, :]
+        p = p * gather(v_scale)[:, :, None, :, 0]
     return jnp.einsum("rhtk,rhkd->rhtd", p, view_v).astype(q.dtype)
 
 
 def paged_prefill_attention(q: jax.Array, pool_k: jax.Array,
                             pool_v: jax.Array, table: jax.Array,
-                            start: jax.Array, *,
+                            start: jax.Array, *, layer: jax.Array = 0,
                             k_scale: Optional[jax.Array] = None,
                             v_scale: Optional[jax.Array] = None,
                             interpret: Optional[bool] = None) -> jax.Array:
-    """Chunked-prefill attention over ONE layer's block pool.
+    """Chunked-prefill attention over layer ``layer`` of the stacked
+    block pool.
 
     The multi-query-row twin of :func:`paged_attention` for T ≫ 1, on
     the same kernel: the chunk's T query rows go in ONE tile where that
@@ -719,8 +789,8 @@ def paged_prefill_attention(q: jax.Array, pool_k: jax.Array,
     (absolute-position mask, int8 scales post-dot / pre-contraction,
     clamped DMAs past each bound); the jnp pin is the same
     :func:`paged_attention_reference`."""
-    return _attend("prefill", q, pool_k, pool_v, table, start, k_scale,
-                   v_scale, interpret)
+    return _attend("prefill", q, pool_k, pool_v, table, start, layer,
+                   k_scale, v_scale, interpret)
 
 
 # ---------------------------------------------------------------------------

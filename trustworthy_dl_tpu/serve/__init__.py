@@ -7,7 +7,9 @@ retired mid-flight without recompiles.  This package is the Orca/vLLM-style
 answer, shaped for XLA's static-shape world:
 
 * ``kv_slots``  — the KV memory pool: fixed-size token blocks
-  [L, NB+1, H, BLOCK, Dh] + host-side block tables/refcounts + radix
+  [L, NB+1, BLOCK, H·Dh] (a position's heads in one row: the shape the
+  row write, the kernels and the resting layout agree on, so a serving
+  program never copies the pool) + host-side block tables/refcounts + radix
   prefix cache, vLLM/RadixAttention-style, so occupancy is bounded by
   tokens in flight, not requests; no dynamic shapes anywhere — block
   tables are traced gather indices.

@@ -4,10 +4,16 @@ The pool is the vLLM answer (PagedAttention, Kwon et al., SOSP '23)
 shaped for XLA's static-shape world: fixed-size token BLOCKS in a global
 pool,
 
-    k, v: [L, NUM_BLOCKS + 1, H, BLOCK, Dh]      (physical block 0 = trash)
+    k, v: [L, NUM_BLOCKS + 1, BLOCK, H·Dh]       (physical block 0 = trash)
 
-plus per-slot block tables (host-side lists of physical block ids).  The
-decode step gathers each slot's logical view through its block table —
+(a position's K, or V, of every head is ONE contiguous row: the shape on
+which the row write ``pool.at[layer, block, offset]``, the attention
+kernels' block ``(layer, table[...], :, head group)`` and the array's
+resting layout agree, so a serving program carries the pool through its
+layer loop in one buffer and one layout, written in place and never
+copied — tests/test_chip_compile.py holds the compiler to that) plus
+per-slot block tables (host-side lists of physical block ids).  The
+decode step reads each slot's logical view through its block table —
 the tables are plain i32 *values*, structurally stable, so block churn
 never recompiles the fused decode program — and occupancy is bounded by
 tokens (rounded up to blocks), not by requests.  Physical block 0 is a
@@ -180,16 +186,19 @@ TRASH_BLOCK = 0
 class PagedKV(NamedTuple):
     """Block-pooled KV arrays; block tables and refcounts live host-side.
 
-    Layout ``[L, NUM_BLOCKS + 1, H, BLOCK, Dh]`` — the +1 is the reserved
-    trash block (index 0).  int8 tier: ``k``/``v`` store int8 and the
-    per-(head, position) f32 scales ride in ``k_scale``/``v_scale``
-    ``[L, NUM_BLOCKS + 1, H, BLOCK]`` — the pool pages values and scales
-    identically, so the equal-HBM ~1.9x capacity win of the int8 tier
-    compounds with paging."""
+    Layout ``[L, NUM_BLOCKS + 1, BLOCK, H·Dh]`` — the +1 is the reserved
+    trash block (index 0); a position's heads lie side by side in one
+    row, so the row write, the kernels' block and the resting layout are
+    the same row-major array (see the module docstring).  int8 tier:
+    ``k``/``v`` store int8 and the per-(head, position) f32 scales ride
+    in ``k_scale``/``v_scale`` ``[L, NUM_BLOCKS + 1, BLOCK, H]`` (the
+    values' shape with one number a head) — the pool pages values and
+    scales identically, so the equal-HBM ~1.9x
+    capacity win of the int8 tier compounds with paging."""
 
-    k: jax.Array  # [L, NUM_BLOCKS + 1, H, BLOCK, Dh]
+    k: jax.Array  # [L, NUM_BLOCKS + 1, BLOCK, H·Dh]
     v: jax.Array
-    k_scale: Optional[jax.Array] = None  # [L, NUM_BLOCKS + 1, H, BLOCK]
+    k_scale: Optional[jax.Array] = None  # [L, NUM_BLOCKS + 1, BLOCK, H]
     v_scale: Optional[jax.Array] = None
 
     @property
@@ -199,7 +208,7 @@ class PagedKV(NamedTuple):
 
     @property
     def block_size(self) -> int:
-        return self.k.shape[3]
+        return self.k.shape[2]
 
     @property
     def quantized(self) -> bool:
@@ -233,13 +242,15 @@ def init_paged_pool(cfg: gpt2.GPT2Config, num_blocks: int, block_size: int,
             f"(n_positions={cfg.n_positions})"
         )
     kv_dtype = cfg.dtype if kv_dtype is None else kv_dtype
-    shape = (cfg.n_layer, num_blocks + 1, cfg.n_head, block_size,
-             cfg.n_embd // cfg.n_head)
+    shape = (cfg.n_layer, num_blocks + 1, block_size, cfg.n_embd)
     if kv_dtype == jnp.int8:
-        scales = jnp.zeros(shape[:-1], jnp.float32)
+        # Two buffers, not one array twice: the serving programs donate
+        # all four pool arrays, and one buffer cannot be donated twice.
+        scale_shape = shape[:3] + (cfg.n_head,)
         return PagedKV(k=jnp.zeros(shape, jnp.int8),
                        v=jnp.zeros(shape, jnp.int8),
-                       k_scale=scales, v_scale=scales)
+                       k_scale=jnp.zeros(scale_shape, jnp.float32),
+                       v_scale=jnp.zeros(scale_shape, jnp.float32))
     return PagedKV(k=jnp.zeros(shape, kv_dtype),
                    v=jnp.zeros(shape, kv_dtype))
 

@@ -66,8 +66,10 @@ def _programs() -> Dict[str, Any]:
         def _copy_blocks(dst_pool: jax.Array, src_pool: jax.Array,
                          dst_ids: jax.Array, src_ids: jax.Array
                          ) -> jax.Array:
-            # Gather the source rows along the block axis and scatter
-            # them into the destination pool.  Pad entries map trash →
+            # Gather the source rows along the block axis (axis 1 of
+            # every pool leaf: values [L, NB, BLOCK, H·Dh] and scales
+            # [L, NB, BLOCK, H] alike — the copy is blind to what follows
+            # it) and scatter them into the destination pool.  Pad entries map trash →
             # trash; duplicate trash writes are harmless (the reserved
             # block's content is garbage by contract).  No donation:
             # the source pool stays live under the source scheduler.

@@ -173,7 +173,7 @@ def _paged_prefill_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
     one chunk and no prefix blocks were reused; longer or prefix-sharing
     prompts go through ``_paged_chunk_impl``."""
     c = tokens.shape[0]
-    bsz = pool_k.shape[3]
+    bsz = pool_k.shape[2]
     logits, k_rows, v_rows, k_s, v_s = _local_prefill(
         cfg, view, tokens, real_len, pool_ks is not None
     )
@@ -181,22 +181,15 @@ def _paged_prefill_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
         k_rows = k_rows.astype(pool_k.dtype)
         v_rows = v_rows.astype(pool_v.dtype)
 
-    def to_blocks(a):                       # [L, 1, H, C, Dh] -> pool rows
-        l, _, h, _, dh = a.shape
-        a = a[:, 0].transpose(0, 2, 1, 3)                # [L, C, H, Dh]
-        a = a.reshape(l, c // bsz, bsz, h, dh)
-        return a.transpose(0, 1, 3, 2, 4)                # [L, nCB, H, B, Dh]
-
-    def to_blocks_s(s):                     # [L, 1, H, C] -> scale rows
-        l, _, h, _ = s.shape
-        s = s[:, 0].transpose(0, 2, 1).reshape(l, c // bsz, bsz, h)
-        return s.transpose(0, 1, 3, 2)                   # [L, nCB, H, B]
+    def to_blocks(a):       # [L, 1, H, C(, Dh)] -> pool rows [L, nCB, B, H(·Dh)]
+        a = jnp.moveaxis(a[:, 0], 1, 2)                  # [L, C, H(, Dh)]
+        return a.reshape(a.shape[0], c // bsz, bsz, -1)
 
     new_k = pool_k.at[:, block_ids].set(to_blocks(k_rows))
     new_v = pool_v.at[:, block_ids].set(to_blocks(v_rows))
     if pool_ks is not None:
-        new_ks = pool_ks.at[:, block_ids].set(to_blocks_s(k_s))
-        new_vs = pool_vs.at[:, block_ids].set(to_blocks_s(v_s))
+        new_ks = pool_ks.at[:, block_ids].set(to_blocks(k_s))
+        new_vs = pool_vs.at[:, block_ids].set(to_blocks(v_s))
     else:
         new_ks, new_vs = pool_ks, pool_vs
     return new_k, new_v, new_ks, new_vs, _sample_pack(logits, key, temp,
