@@ -1,6 +1,7 @@
 """What decides ``correct`` in a serving cell.
 
-For a sample of the requests the window finished, the plain reference runs
+For a sample of the requests the window finished, the plain reference of
+the configuration's family (``families/<model_type>.py``) runs
 ONE full forward over each prompt with the reply the timed path served, and
 reads at each reply position how far the served token's logit lies below the
 reference's largest, in units of the standard deviation of that position's
@@ -23,6 +24,7 @@ from typing import Any, Dict, List, Sequence, Tuple
 
 import numpy as np
 
+from benchmark.harness import families
 from benchmark.harness.traffic import rng_for
 
 Served = Tuple[np.ndarray, Sequence[int]]       # prompt ids, reply tokens
@@ -70,33 +72,26 @@ def numbers(gaps: Sequence[np.ndarray], missed: Sequence[np.ndarray]
             "argmax_miss_share": float(np.mean(miss))}
 
 
-def readings(seed: int, model: Dict[str, int], served: Sequence[Served],
+def readings(seed: int, config: Dict[str, Any], served: Sequence[Served],
              max_reply: int, precision: str = "f32", fault: str = "",
              chunk: int = 64) -> Dict[str, float]:
     """The three numbers of ``served`` against the reference made from the
-    seed, one request at a time.  With a lower ``precision`` or a ``fault``
-    the served replies are first replaced by what that control would have
-    served at each position of the same prompts and tokens (the reference
-    put in the program's place)."""
-    from benchmark.harness import reference_gpt2_serve as ref
-    from benchmark.harness import weights
-
-    params = weights.make(seed, model)
-    broken = params
-    if fault == "layer_cache_unwritten":
-        broken = ref.unwrite_layer_cache(params, model["n_layer"] // 2)
-    elif fault and fault not in ref.FAULTS:
-        raise ValueError(f"unknown fault {fault!r}")
-    sizes = (model["n_head"], model["n_positions"], max_reply)
+    seed, one request at a time, for the configuration's file ``config``.
+    With a lower ``precision`` or a ``fault`` the served replies are first
+    replaced by what that control would have served at each position of the
+    same prompts and tokens (the reference put in the program's place)."""
+    ref = families.of(config)
+    params = ref.make_weights(seed, config)
+    broken = ref.planted(params, fault, config)
     gaps, missed = [], []
     for i, (prompt, reply) in enumerate(served):
         prompt = np.asarray(prompt, np.int32)
-        logits = ref.reply_logits(params, prompt, reply, *sizes)
+        logits = ref.reply_logits(params, prompt, reply, config, max_reply)
         if precision != "f32" or fault:
             neighbour = np.asarray(served[(i + 1) % len(served)][0])
             context = ref.faulty_context(fault, prompt, neighbour, chunk)
             reply = ref.chosen_tokens(ref.reply_logits(
-                broken, context, reply, *sizes, precision))
+                broken, context, reply, config, max_reply, precision))
         gap, miss = shortfalls(logits, reply)
         gaps.append(gap)
         missed.append(miss)

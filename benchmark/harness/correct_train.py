@@ -1,8 +1,9 @@
 """What decides ``correct`` in a training cell.
 
 The program's first ``proof_steps`` steps, taken through the window's own
-call and feed, against the plain reference following the same steps from
-the same weights and batches:
+call and feed, against the plain reference of the configuration's family
+(``families/<model_type>.py``) following the same steps from the same
+weights and batches:
 
 * ``loss<i>``: |program - reference| / |reference| of each step's loss;
 * ``grad_norm_gap``: the first gradient as the optimizer got it (Adam's
@@ -20,6 +21,8 @@ from __future__ import annotations
 from typing import Any, Dict, List, Sequence
 
 import numpy as np
+
+from benchmark.harness import families
 
 #: Leaves under this share of the median leaf's gradient norm are left out
 #: of ``param_change_gap`` (a rule on the reference's gradient, not a name).
@@ -54,25 +57,24 @@ def numbers(program: Dict[str, Any], reference: Dict[str, Any]
     return out
 
 
-def reference_readings(seed: int, model: Dict[str, int],
+def reference_readings(seed: int, config: Dict[str, Any],
                        batches: List[Dict[str, Any]], opt: Dict[str, float],
                        precision: str = "f32", rows: int = 2,
                        fault: str = "") -> Dict[str, Any]:
     """The reference's side of ``numbers`` (or, at a lower ``precision`` or
-    with a ``fault``, a control's)."""
+    with a ``fault``, a control's), for the configuration's file
+    ``config``."""
     import jax
 
-    from benchmark.harness import reference_gpt2 as ref
-    from benchmark.harness import weights
-
-    p0 = weights.make(seed, model)
-    out = ref.train_steps(p0, batches, model["n_head"], opt, precision,
-                          rows, fault=fault)
+    ref = families.of(config)
+    p0 = ref.make_weights(seed, config)
+    out = ref.train_steps(p0, batches, config, opt, precision, rows,
+                          fault=fault)
     change = jax.tree_util.tree_map(lambda a, b: a - b, out["params"], p0)
     return {
         "losses": out["losses"],
-        "grad_norms": np.asarray(weights.leaf_norms(out["first_grads"])),
-        "change_norms": np.asarray(weights.leaf_norms(change)),
+        "grad_norms": np.asarray(ref.leaf_norms(out["first_grads"])),
+        "change_norms": np.asarray(ref.leaf_norms(change)),
     }
 
 

@@ -31,25 +31,25 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from benchmark.harness import (common, correct_serve, serve_trace,
-                               serve_traffic, weights)
+                               serve_traffic)
 from benchmark.harness.window import Window
 
 
 def build_engine(run: Any) -> Any:
     """The engine as ``cli.serve_main`` builds it from the configuration's
-    ``ServeConfig``, monitor on, with the benchmark's weights."""
+    ``ServeConfig``, monitor on, with the weights and the model description
+    of the configuration's family."""
     import jax
 
     from trustworthy_dl_tpu.core.config import ServeConfig
-    from trustworthy_dl_tpu.models.gpt2 import GPT2Config
     from trustworthy_dl_tpu.serve import ServingEngine
 
-    model = weights.sizes(run.config)
+    family = run.family
     deployment = run.config["deployment"]
     serve_config = ServeConfig(**deployment["serve_config"])
-    params = weights.make(run.seed, model)
+    params = family.make_weights(run.seed, run.config)
     return ServingEngine.from_config(
-        params, GPT2Config(**model), serve_config,
+        params, family.model(run.config), serve_config,
         enable_monitor=bool(deployment["enable_monitor"]),
         rng=jax.random.PRNGKey(int(run.seed) % (1 << 31)))
 
@@ -100,7 +100,7 @@ class ClosedLoop:
         self.clients = int(mix["clients"])
         self.ramp_ticks = int(mix["ramp_ticks"])
         self.table = serve_traffic.shapes(mix)
-        self.vocab = int(run.config["vocab_size"])
+        self.vocab = run.family.vocab(run.config)
         self.chunk = int(run.config["deployment"]["prefill_chunk_positions"])
         self.flights: Dict[int, Flight] = {}        # request id -> flight
         self.finished: List[Flight] = []
@@ -291,6 +291,6 @@ def run(run: Any, manifest: Any) -> None:
     run.counters["served_sample"] = [(f.prompt, f.result_tokens)
                                      for f in served]
     readings = correct_serve.readings(
-        run.seed, weights.sizes(run.config), run.counters["served_sample"],
+        run.seed, run.config, run.counters["served_sample"],
         int(mix["reply"]["max"]))
     correct_serve.judge(run, readings, manifest.limits(run.cell["name"]))

@@ -20,7 +20,7 @@ from typing import Any, Dict, Iterator, List
 
 import numpy as np
 
-from benchmark.harness import common, correct_train, traffic, weights
+from benchmark.harness import common, correct_train, traffic
 from benchmark.harness.window import Window
 
 
@@ -50,14 +50,15 @@ class _PhaseLaps:
 
 def build_trainer(run: Any) -> Any:
     """The trainer as ``cli.main`` builds it, for this cell's deployment,
-    with the benchmark's weights in place of the model's own init."""
+    with the weights of the configuration's family in place of the model's
+    own init."""
     from jax.sharding import Mesh
 
     from trustworthy_dl_tpu import DistributedTrainer, TrainingConfig
     from trustworthy_dl_tpu.core.mesh import DATA_AXIS
 
     mix, deployment = run.mix, run.config["deployment"]
-    model = weights.sizes(run.config)
+    family = run.family
     nodes = int(mix["nodes"])
     config = TrainingConfig(
         model_name=deployment["model_name"],
@@ -67,9 +68,11 @@ def build_trainer(run: Any) -> Any:
         **deployment["training_config"])
     trainer = DistributedTrainer(
         config, mesh=Mesh(np.array(run.devices), (DATA_AXIS,)),
-        model_overrides=dict(model, seq_len=int(mix["seq_len"])))
+        model_overrides=dict(family.sizes(run.config),
+                             seq_len=int(mix["seq_len"])))
     trainer.model = dataclasses.replace(
-        trainer.model, init=lambda key: weights.make(run.seed, model))
+        trainer.model,
+        init=lambda key: family.make_weights(run.seed, run.config))
     trainer.initialize()
     if not (trainer.config.attack_detection_enabled
             and trainer.config.gradient_verification_enabled):
@@ -81,7 +84,7 @@ def batches(run: Any, start: int, count: int) -> List[Dict[str, np.ndarray]]:
     rows = traffic.offered(run.mix)["rows"]
     return [traffic.train_batch(
         run.seed, step, rows, int(run.mix["seq_len"]),
-        int(run.config["vocab_size"]))
+        run.family.vocab(run.config))
         for step in range(start, start + count)]
 
 
@@ -103,15 +106,15 @@ def proof_steps(run: Any, trainer: Any) -> Dict[str, Any]:
 
     steps = int(run.mix["proof_steps"])
     b1 = float(run.config["assumed"]["optimizer"]["b1"])
-    model = weights.sizes(run.config)
+    family = run.family
     trainer.train_epoch(batches(run, 0, 1), 0)
     mu = _first_moment(trainer.state.opt_state)
-    grad_norms = np.asarray(weights.leaf_norms(mu)) / (1.0 - b1)
+    grad_norms = np.asarray(family.leaf_norms(mu)) / (1.0 - b1)
     trainer.train_epoch(batches(run, 1, steps - 1), 1)
     change = jax.tree_util.tree_map(
         lambda a, b: a - b, trainer.state.params,
-        weights.make(run.seed, model))
-    change_norms = np.asarray(weights.leaf_norms(change))
+        family.make_weights(run.seed, run.config))
+    change_norms = np.asarray(family.leaf_norms(change))
     del change, mu
     records = trainer.metrics_collector.batch_metrics[:steps]
     return {"losses": [float(r["loss"]) for r in records],
@@ -225,10 +228,9 @@ def run(run: Any, manifest: Any) -> None:
     trainer.state = None
     del trainer
     gc.collect()
-    model = weights.sizes(run.config)
     opt = dict(run.config["assumed"]["optimizer"], nodes=int(mix["nodes"]))
     reference = correct_train.reference_readings(
-        run.seed, model, batches(run, 0, int(mix["proof_steps"])), opt,
+        run.seed, run.config, batches(run, 0, int(mix["proof_steps"])), opt,
         rows=int(mix["reference_rows"]))
     correct_train.judge(run, program, reference,
                         manifest.limits(run.cell["name"]))

@@ -8,7 +8,7 @@ FLOP/s and bytes over peak bytes/s) over the kernel's device time.
 
 from __future__ import annotations
 
-from typing import Dict, NamedTuple, Tuple
+from typing import NamedTuple, Tuple
 
 from benchmark.harness.peaks import Peak
 
@@ -53,15 +53,6 @@ def roofline_pct(work: Work, device_seconds: float, peak: Peak
     t_bytes = work.bytes / peak.hbm_bytes_per_s
     bound = "compute" if t_flops >= t_bytes else "memory"
     return 100.0 * max(t_flops, t_bytes) / device_seconds, bound
-
-
-def gpt2_params(cfg: Dict[str, int]) -> int:
-    """Parameter count of a GPT-2 with a tied head, from its sizes."""
-    d, layers = cfg["n_embd"], cfg["n_layer"]
-    block = (2 * d) + (d * 3 * d + 3 * d) + (d * d + d) + (2 * d) \
-        + (d * 4 * d + 4 * d) + (4 * d * d + d)
-    return cfg["vocab_size"] * d + cfg["n_positions"] * d \
-        + layers * block + 2 * d
 
 
 def train_flops_per_token(n_params: int) -> float:

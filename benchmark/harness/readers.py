@@ -66,13 +66,17 @@ def _flash(run: Any, pattern: str, work_fn) -> Optional[float]:
     seconds, calls = xplane.time_of(_chip0(run), pattern)
     if not calls:
         return None
-    mix, cfg = run.mix, run.config
+    mix = run.mix
     rows = int(mix["nodes"]) * int(mix["per_node_batch"]) // run.trace.chips
-    one = work_fn(rows, int(cfg["n_head"]), int(mix["seq_len"]),
-                  int(cfg["n_embd"]) // int(cfg["n_head"]))
-    layers = int(cfg["n_layer"]) * int(run.counters["trace_steps"])
-    work = kw.Work(one.flops * layers, one.bytes * layers)
-    return kw.roofline_pct(work, seconds, run.peak)[0]
+    flops = nbytes = 0.0
+    for layers, heads, _, d in run.family.attention_layers(run.config):
+        one = work_fn(rows, heads, int(mix["seq_len"]), d)
+        traced = layers * int(run.counters["trace_steps"])
+        flops += one.flops * traced
+        nbytes += one.bytes * traced
+    if not flops:
+        return None                 # the family has no attention layer
+    return kw.roofline_pct(kw.Work(flops, nbytes), seconds, run.peak)[0]
 
 
 def flash_fwd_roofline(run: Any) -> Optional[float]:

@@ -7,11 +7,14 @@ import sys
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+from benchmark.harness import families
+
 
 class Run:
     """One run's facts, as the per-layer readers see them.
 
     ``counters``: numbers the program or the harness counted.
+    ``family``: the module that knows the configuration's architecture.
     ``trace``: the reduced device trace (``xplane.Summary``) of a
     ``--trace 1`` run, else None.  ``end_to_end``: what the window measured.
     """
@@ -35,6 +38,10 @@ class Run:
         """A piece of set-up ends: seconds since the process started."""
         self.setup_marks.append(
             (name, time.time() - self.counters["process_start"]))
+
+    @property
+    def family(self) -> Any:
+        return families.of(self.config)
 
     @property
     def correct(self) -> bool:

@@ -16,7 +16,6 @@ from typing import Any, Dict, List, Optional
 
 from benchmark.harness import kernel_work as kw
 from benchmark.harness import serve_work, xplane
-from benchmark.harness.weights import sizes
 
 #: Program calls on the device's ``XLA Modules`` line.
 DECODE_PROGRAM = r"paged_decode"
@@ -43,7 +42,7 @@ def serve_mfu_pct(run: Any) -> Optional[float]:
     fed = sum(rows for t in ticks for _, rows in t["prefill"]) \
         + sum(len(t["decode"]) for t in ticks)
     sampled = sum(t["tokens"] for t in ticks)
-    flops = serve_work.model_flops(sizes(run.config), fed, sampled)
+    flops = run.family.model_flops(run.config, fed, sampled)
     return 100.0 * flops / run.counters["window_s"] / run.peak.flops_bf16
 
 
@@ -106,27 +105,30 @@ def _kernel_roofline(run: Any, pattern: str, work_of) -> Optional[float]:
     seconds, calls = xplane.time_of(events, pattern)
     if not calls:
         return None
-    cfg = sizes(run.config)
-    heads, d = cfg["n_head"], cfg["n_embd"] // cfg["n_head"]
     block = int(run.config["deployment"]["serve_config"]["block_size"])
     flops = nbytes = 0.0
-    for tick in ticks:
-        one = work_of(tick, heads, d, block)
-        flops += one.flops * cfg["n_layer"]
-        nbytes += one.bytes * cfg["n_layer"]
+    for layers, heads, kv_heads, d in run.family.attention_layers(run.config):
+        for tick in ticks:
+            one = work_of(tick, heads, d, block, kv_heads)
+            flops += one.flops * layers
+            nbytes += one.bytes * layers
+    if not flops:
+        return None                 # the family has no paged attention layer
     return kw.roofline_pct(kw.Work(flops, nbytes), seconds, run.peak)[0]
 
 
 def paged_decode_roofline(run: Any) -> Optional[float]:
     return _kernel_roofline(
-        run, DECODE_KERNEL, lambda tick, heads, d, block:
-        serve_work.paged_decode(tick["decode"], heads, d, block))
+        run, DECODE_KERNEL, lambda tick, heads, d, block, kv_heads:
+        serve_work.paged_decode(tick["decode"], heads, d, block,
+                                kv_heads=kv_heads))
 
 
 def paged_prefill_roofline(run: Any) -> Optional[float]:
     return _kernel_roofline(
-        run, PREFILL_KERNEL, lambda tick, heads, d, block:
-        serve_work.paged_prefill(tick["prefill"], heads, d, block))
+        run, PREFILL_KERNEL, lambda tick, heads, d, block, kv_heads:
+        serve_work.paged_prefill(tick["prefill"], heads, d, block,
+                                 kv_heads=kv_heads))
 
 
 # -- device ------------------------------------------------------------------

@@ -1,5 +1,6 @@
 """Operations and bytes that the serving kernels' ALGORITHM needs, from a
-tick's live lengths alone, and the model's own products a token.
+tick's live lengths alone.  (The model's own products a token are its
+family's: ``families/<model_type>.py`` ``model_flops``.)
 
 Nothing an implementation pads, gathers twice or recomputes is counted: a
 decode row reads the K and V blocks that hold its live positions once and
@@ -11,7 +12,7 @@ implements it.
 from __future__ import annotations
 
 import math
-from typing import Dict, Sequence, Tuple
+from typing import Optional, Sequence, Tuple
 
 from benchmark.harness.kernel_work import Work, causal_pairs
 
@@ -23,35 +24,30 @@ def _kv_bytes(positions: int, heads: int, d: int, block: int,
 
 
 def paged_decode(lengths: Sequence[int], heads: int, d: int, block: int,
-                 itemsize: int = 2) -> Work:
+                 itemsize: int = 2, kv_heads: Optional[int] = None) -> Work:
     """One layer's paged decode attention for rows whose contexts hold
     ``lengths`` positions (the new one included): two products a
-    query-key pair; reads the live K and V blocks and Q, writes O."""
+    query-key pair; reads the live K and V blocks and Q, writes O.
+    ``heads`` query heads share ``kv_heads`` K and V heads (as many, where
+    not given): products by query heads, K and V bytes by K and V heads."""
+    kv_heads = heads if kv_heads is None else kv_heads
     flops = sum(2 * (2.0 * n * d) * heads for n in lengths)
-    nbytes = sum(_kv_bytes(n, heads, d, block, itemsize)
+    nbytes = sum(_kv_bytes(n, kv_heads, d, block, itemsize)
                  + 2.0 * heads * d * itemsize for n in lengths)
     return Work(flops, nbytes)
 
 
 def paged_prefill(chunks: Sequence[Tuple[int, int]], heads: int, d: int,
-                  block: int, itemsize: int = 2) -> Work:
+                  block: int, itemsize: int = 2,
+                  kv_heads: Optional[int] = None) -> Work:
     """One layer's chunked-prefill attention for chunks of ``rows`` queries
     that start at position ``pos``: the causal pairs of the rows against
-    the ``pos + rows`` positions cached by then."""
+    the ``pos + rows`` positions cached by then.  ``kv_heads`` as in
+    ``paged_decode``."""
+    kv_heads = heads if kv_heads is None else kv_heads
     flops = nbytes = 0.0
     for pos, rows in chunks:
         flops += 2 * (2.0 * causal_pairs(rows, pos + rows) * d) * heads
-        nbytes += _kv_bytes(pos + rows, heads, d, block, itemsize) \
+        nbytes += _kv_bytes(pos + rows, kv_heads, d, block, itemsize) \
             + 2.0 * rows * heads * d * itemsize
     return Work(flops, nbytes)
-
-
-def model_flops(cfg: Dict[str, int], fed_tokens: int, sampled: int) -> float:
-    """The matrix products of a GPT-2 forward over ``fed_tokens`` tokens
-    (prompt tokens prefilled and tokens decoded) of which ``sampled``
-    positions also go through the tied head: 2 a multiply-add.  Attention's
-    own products are left out, so a share of the peak this gives is a lower
-    bound."""
-    d, layers = cfg["n_embd"], cfg["n_layer"]
-    body = layers * (d * 3 * d + d * d + d * 4 * d + 4 * d * d)
-    return 2.0 * body * fed_tokens + 2.0 * cfg["vocab_size"] * d * sampled

@@ -10,6 +10,7 @@ from bench_paths import ROOT, manifest_data
 
 from benchmark.harness import manifest as mf
 from benchmark.harness import result
+from benchmark.harness.families import gpt2
 from trustworthy_dl_tpu.ops import paged_attention as pa
 
 MANIFEST = mf.Manifest(ROOT)
@@ -32,16 +33,15 @@ def read(run):
 
 def test_manifest_entry():
     (entry,) = [m for m in manifest_data()["per_layer"] if m["name"] == NAME]
-    # No ``workloads`` list: the entry is read wherever its end-to-end
-    # metric is reported, which is the serving cells.  (A list of this one
-    # cell would enrol it in ``test_benchmark_serve_readers.py``'s sweep,
-    # which wants a chip reading of every such metric in
-    # ``recorded_serve_ticks.json``: files this PR may not edit.)
+    # Listed in the serving cell since PR 35 (the sweep of
+    # ``test_benchmark_serve_readers.py`` selects by membership); a later
+    # serving cell appends its name to the list.
+    cells = entry.pop("workloads")
     assert entry == {
         "name": NAME, "unit": "x", "better": "lower",
         "source": "program_counter", "layer": "kernels",
         "moves": "serve_tokens_per_s"}
-    assert manifest_data()["per_layer"][-1] == entry
+    assert CELL in cells
     assert NAME in [m["name"] for m in MANIFEST.per_layer(CELL)]
     for cell in ("train-124m-trust-1chip", "train-124m-trust-dp4"):
         assert NAME not in [m["name"] for m in MANIFEST.per_layer(cell)]
@@ -95,8 +95,14 @@ def test_silent_where_nothing_is_served(cell):
     assert read(make_run(cell)) is None
 
 
-def test_silent_without_a_paged_pool_or_a_chunk():
-    assert read(make_run(paged=False)) is None
+def test_silent_without_a_chunk_or_a_paged_attention_layer(monkeypatch):
     run = make_run()
     del run.config["deployment"]["prefill_chunk_positions"]
     assert read(run) is None
+    # a family none of whose layers is a paged attention layer
+    monkeypatch.setattr(gpt2, "attention_layers", lambda config: [])
+    assert read(make_run()) is None
+    # and one where only some are: the others take no step
+    monkeypatch.setattr(gpt2, "attention_layers",
+                        lambda config: [(9, 20, 20, 64)])
+    assert read(make_run()) == 1.0

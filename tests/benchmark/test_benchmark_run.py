@@ -210,11 +210,10 @@ def test_training_control_in_lower_precision_is_not_correct(
     from benchmark.harness.drivers import train_steps
 
     def control(run, trainer):
-        model = train_steps.weights.sizes(run.config)
         opt = dict(run.config["assumed"]["optimizer"],
                    nodes=int(run.mix["nodes"]))
         return correct_train.reference_readings(
-            run.seed, model,
+            run.seed, run.config,
             train_steps.batches(run, 0, int(run.mix["proof_steps"])), opt,
             precision=precision)
 

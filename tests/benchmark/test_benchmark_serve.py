@@ -22,6 +22,22 @@ from benchmark.harness import serve_traffic, weights
 MIXES = {os.path.basename(p)[:-5]: json.load(open(p))
          for p in sorted(glob.glob(os.path.join(BENCH, "traffic", "*.json")))}
 SERVE = [name for name, mix in MIXES.items() if mix["kind"] == "serve-closed"]
+#: For each mix, the ``max_seq`` of the configurations whose cells use it;
+#: for a mix that no cell uses yet, the largest any serving configuration
+#: has, so that the cap never goes silently.
+MANIFEST = mf.Manifest(ROOT)
+
+
+def _max_seq(config):
+    return MANIFEST.config(config).get("deployment", {}).get(
+        "serve_config", {}).get("max_seq")
+
+
+SERVED = [n for n in (_max_seq(c["name"]) for c in MANIFEST.data["configs"])
+          if n is not None]
+MAX_SEQ = {name: [
+    _max_seq(cell["config"]) for cell in MANIFEST.data["workloads"]
+    if cell["traffic"] == name] or [max(SERVED)] for name in SERVE}
 CELL = "serve-large-docbatch"
 DRIVER = os.path.join(BENCH, "harness", "drivers", "serve_closed.py")
 TINY = dict(vocab_size=16384, n_positions=64, n_ctx=64, n_embd=64, n_layer=2,
@@ -51,7 +67,9 @@ def test_the_lengths_are_the_file_s_and_no_seed_s(name):
     assert min(replies) >= mix["reply"]["min"]
     assert max(replies) <= mix["reply"]["max"]
     offered = serve_traffic.offered(mix)
-    assert offered["longest"] <= 1024
+    assert MAX_SEQ[name]
+    for max_seq in MAX_SEQ[name]:       # of every configuration it runs on
+        assert offered["longest"] <= max_seq
     assert offered["prompt_tokens"] == sum(prompts)
     assert sorted(prompts)[len(prompts) // 2] == pytest.approx(
         mix["prompt"]["median"], rel=0.02)
@@ -284,8 +302,8 @@ def judged(run, found):
 
 def test_the_sound_engine_is_correct(driven):
     run, pairs = served_pairs(driven)
-    found = correct_serve.readings(run.seed, weights.sizes(run.config),
-                                   pairs, 16, chunk=16)
+    found = correct_serve.readings(run.seed, run.config, pairs, 16,
+                                   chunk=16)
     assert found["compared_requests"] == 8 and found["compared_tokens"] > 60
     run = judged(run, found)
     assert run.correct and set(run.compare) == set(TINY_LIMITS)
@@ -299,8 +317,8 @@ def test_the_control_and_each_planted_fault_are_not_correct(driven, control):
     """The reference put in the program's place: in the precision below the
     configuration's bfloat16, and with each fault a paged server can have."""
     run, pairs = served_pairs(driven)
-    found = correct_serve.readings(run.seed, weights.sizes(run.config),
-                                   pairs, 16, chunk=16, **control)
+    found = correct_serve.readings(run.seed, run.config, pairs, 16,
+                                   chunk=16, **control)
     run = judged(run, found)
     assert not run.correct
     assert any(value > limit for value, limit in run.compare.values())
@@ -309,8 +327,8 @@ def test_the_control_and_each_planted_fault_are_not_correct(driven, control):
 def test_an_unknown_fault_and_a_missing_limit_are_errors(driven):
     run, pairs = served_pairs(driven)
     with pytest.raises(ValueError):
-        correct_serve.readings(run.seed, weights.sizes(run.config), pairs,
-                               16, fault="no-such-fault")
+        correct_serve.readings(run.seed, run.config, pairs, 16,
+                               fault="no-such-fault")
     fresh = result.Run(run.cell, run.config, run.mix, run.seed, 1.0, False)
     with pytest.raises(KeyError):
         correct_serve.judge(fresh, {"worst_shortfall": 0.0}, {})

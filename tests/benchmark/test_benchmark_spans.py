@@ -115,7 +115,7 @@ def test_reader_is_silent_on_a_trace_without_the_program_s_spans(name):
 @pytest.mark.parametrize("name", sorted(NEW))
 def test_new_entry_has_its_reader_its_cells_and_a_metric_they_report(name):
     entry, = [m for m in manifest_data()["per_layer"] if m["name"] == name]
-    assert entry["workloads"] == CELLS
+    assert set(CELLS) <= set(entry["workloads"])    # a later cell appends
     assert entry["moves"] == NEW[name]
     assert callable(MANIFEST.reader(name))
     for cell in CELLS:

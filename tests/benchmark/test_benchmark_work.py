@@ -7,6 +7,7 @@ from bench_paths import ROOT  # noqa: F401
 
 from benchmark.harness import kernel_work as kw
 from benchmark.harness import peaks
+from benchmark.harness.families import gpt2
 
 V5E = peaks.peak("TPU v5 lite")
 
@@ -47,7 +48,7 @@ def test_flash_backward_is_five_products():
     (dict(vocab_size=50257, n_positions=1024, n_layer=36, n_embd=1280),
      774_030_080)])
 def test_gpt2_parameter_counts(sizes, want):
-    assert kw.gpt2_params(sizes) == want
+    assert gpt2.param_count(sizes) == want
     assert kw.train_flops_per_token(want) == 6.0 * want
 
 
