@@ -228,6 +228,98 @@ def test_serving_program_keeps_the_pool_in_place(v5e, program, kv_dtype,
     assert not moved, moved
 
 
+# -- the second served architecture: grouped heads, state beside the pool ----
+
+# 64 query heads over 8 K/V heads of 128, 64 slots of 8,192 positions in
+# blocks of 64, chunks of 1,024 (benchmark/configs/solar-open2-...json).
+GQ, GKV, GDH, GSLOTS, GBLOCK, GMAX, GCHUNK = 64, 8, 128, 64, 64, 8192, 1024
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_paged_attention_lowers_at_grouped_heads(v5e, program):
+    """The kernel with the 8 query heads of each K/V head as rows of its
+    products: decode holds all 8 K/V heads (64 rows each) a step, a chunk
+    goes a K/V head and 256 positions (2,048 rows) a step."""
+    nbps = GMAX // GBLOCK
+    rows, t = (GSLOTS, 1) if program == "decode" else (1, GCHUNK)
+    grid = pa.grid_steps(program, rows, GQ, nbps, t, GDH, GBLOCK,
+                         jnp.bfloat16, kv_heads=GKV)
+    assert grid == ((GSLOTS, 1, 1, nbps) if program == "decode"
+                    else (1, GKV, 4, nbps))
+    rep = GQ // GKV
+    t_pad = max(t, pa.QROWS)
+    pool = S((1, GSLOTS * nbps + 1, GBLOCK, GKV * GDH), jnp.bfloat16)
+    _compile(_PAGED_CALL[program], v5e,
+             S((rows, GKV, rep * t_pad, GDH), jnp.bfloat16), pool, pool,
+             None, None, S((rows, nbps), jnp.int32), S((rows,), jnp.int32),
+             S((rows, grid[2]), jnp.int32), S((1,), jnp.int32),
+             interpret=False, rep=rep)
+
+
+@pytest.mark.parametrize("program", ["chunk", "decode"])
+def test_decoder_serving_program_lowers_in_place(v5e, program):
+    """Both scheduler programs for the ``DecoderConfig`` of the benchmark's
+    configuration at its published widths, pool and recurrent state donated
+    as on the chip: they compile, hold the paged kernel and XLA's grouped
+    products, update pool AND state in the buffers they came in, and keep
+    their temporaries under 1 GB (the chunk's activations; a copy of the
+    state of one layer alone would be 0.27 GB, of the pool 2.1 GB)."""
+    import json
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.harness.families import solar_open2 as family
+    from trustworthy_dl_tpu.models import decoder
+    from trustworthy_dl_tpu.serve import scheduler as sch
+    from trustworthy_dl_tpu.serve.kv_slots import (init_paged_pool,
+                                                   init_state_pool)
+
+    with open(os.path.join(root, "benchmark", "configs",
+                           "solar-open2-250b-ep8-1of8.json")) as f:
+        config = json.load(f)
+    cfg = family.model(config)
+    nbps = GMAX // GBLOCK
+
+    def pin(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            tree)
+
+    view = pin(jax.eval_shape(lambda: decoder.decode_view(
+        family.make_weights(0, config), cfg)))
+    kv = pin(jax.eval_shape(
+        lambda: init_paged_pool(cfg, GSLOTS * nbps, GBLOCK, jnp.bfloat16)))
+    state = pin(jax.eval_shape(lambda: init_state_pool(cfg, GSLOTS)))
+    i32, f32 = jnp.int32, jnp.float32
+    if program == "chunk":
+        fn = sch._paged_chunk_impl
+        rest = (S((GCHUNK,), i32), S((1, nbps), i32), S((), i32),
+                S((), i32), S((2,), jnp.uint32), S((), f32),
+                S((), jnp.bool_))
+        extra = dict(state=state, slot=pin(S((), i32)))
+    else:
+        fn = sch._paged_decode_impl
+        rest = (S((GSLOTS,), i32), S((GSLOTS, nbps), i32), S((GSLOTS,), i32),
+                S((GSLOTS, 2), jnp.uint32), S((GSLOTS,), f32),
+                S((GSLOTS,), jnp.bool_))
+        extra = dict(state=state, active=pin(S((GSLOTS,), jnp.bool_)))
+    jitted = jax.jit(fn, static_argnums=(0,),
+                     static_argnames=("attn_impl", "adapter_impl"),
+                     donate_argnums=(1, 2, 3, 4), donate_argnames=("state",))
+    compiled = _compile(jitted, v5e, cfg, kv.k, kv.v, None, None, view,
+                        *rest, attn_impl="pallas", **extra)
+    text = compiled.as_text()
+    assert "ragged-dot" in text
+    memory = compiled.memory_analysis()
+    carried = kv.k.size * 2 * 2 + state.s.size * 4 + state.conv.size * 4
+    assert memory.alias_size_in_bytes >= carried
+    assert memory.temp_size_in_bytes < 1 << 30
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 12 << 30                      # of the chip's 16 GB
+
+
 def _flash_forward(dev, dtype, bh=H, t=1024, d=DH, causal=True):
     q = S((bh, t, d), dtype)
     _compile(_flash_fwd, dev, q, q, q, causal=causal,

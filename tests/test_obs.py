@@ -292,7 +292,7 @@ def test_step_time_reporter_phases_and_mfu():
         reporter.discard_step()
         time.sleep(0.002)
         reporter.lap("data")
-        time.sleep(0.004)
+        time.sleep(0.04)        # far apart: a loaded machine stretches a sleep
         reporter.lap("compute")
         reporter.finish_step()
     report = reporter.report()

@@ -142,8 +142,8 @@ PREDICT_FUNCTION_PATTERNS = (
 #: set is either a typo (``tenent``) or a new dimension that must be
 #: added HERE (and to the dashboards) deliberately, not slipped in.
 KNOWN_METRIC_LABELS = frozenset({
-    "action", "adapter", "device", "direction", "dtype", "kind", "metric",
-    "node", "outcome", "path", "phase", "program", "reason", "replica",
+    "action", "adapter", "device", "direction", "dtype", "expert", "kind",
+    "metric", "node", "outcome", "path", "phase", "program", "reason", "replica",
     "role", "scope", "signal", "slo", "slo_class", "stage", "state",
     "status", "tenant", "to_state", "type",
 })

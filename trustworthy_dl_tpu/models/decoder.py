@@ -1,0 +1,339 @@
+"""A decoder described by its layers, for the serving path: the second
+architecture the paged engine runs beside GPT-2 (``models/gpt2.py``, which
+keeps its own description and path; folding it in here is ROADMAP R0/D5).
+
+:class:`DecoderConfig` states the layer pattern by kind in whole periods and
+what this chip HOLDS of each layer: every layer is pre-norm residual,
+``x + mix(RMSNorm(x))`` then ``x + experts(RMSNorm(x))``, with no positional
+term of any kind (the causal mask and the recurrence carry the order).
+
+* ``"attn"``: softmax attention over the paged K/V pool, ``q_heads`` query
+  heads reading ``kv_heads`` shared K/V heads (query head ``h`` reads K/V
+  head ``h // (q_heads // kv_heads)``), its output gated elementwise by
+  ``sigmoid`` of a full-width projection of the normed input.
+* ``"kda"``: the gated delta rule (``models/kda.py``): q, k and v pass a
+  short causal depthwise convolution and SiLU, q and k are L2-normalised a
+  head, a low-rank pair gives the decay a channel, ``beta = 2 sigmoid(.)``;
+  the output is RMS-normalised a head and gated by a second low-rank pair.
+  Its cache is a state ``[dk, dv]`` a head and the convolution's last
+  ``conv_size - 1`` input rows, one row of each a slot
+  (``serve/kv_slots.RecurrentState``).
+* the second half of every layer: ``models/moe.route_top_k`` over all
+  ``n_experts`` published experts, ``models/moe.held_experts`` for the
+  ``n_experts_held`` from ``first_expert`` that live here, plus the shared
+  expert(s), added unweighted.  What absent experts would add is left out;
+  that partial sum goes on to the next layer.
+
+The weights' layout (the system's interface; ``benchmark/harness/families``
+makes trees in it): ``embed [V, D]``, ``head [D, V]``, ``final_norm [D]``
+and ``periods``, a tuple with one dict a position of the period whose every
+leaf carries a leading axis over the periods; the layer scan runs over that
+axis and a period's layers are unrolled inside it.  Matrices are served in
+``cfg.dtype``; norm scales, ``a_log``, ``dt_bias`` and the router's bias
+stay float32.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from trustworthy_dl_tpu.models import kda
+from trustworthy_dl_tpu.models import layers as L
+from trustworthy_dl_tpu.models import moe
+
+Params = Dict[str, Any]
+
+ATTN, KDA = "attn", "kda"
+#: Leaves that stay float32 in the served view.
+F32_LEAVES = ("norm1", "norm2", "final_norm", "o_norm", "a_log", "dt_bias",
+              "router_bias")
+
+
+@dataclasses.dataclass(frozen=True)
+class DecoderConfig:
+    """What shapes the decoder and what of it is held here.  Frozen and
+    hashable: the serving programs take it as a static argument."""
+
+    vocab_size: int                 # ids HELD: a slice of the vocabulary
+    hidden_size: int
+    period: Tuple[str, ...]         # the kinds of one period's layers
+    n_periods: int
+    q_heads: int                    # softmax attention: query heads
+    kv_heads: int                   # shared K/V heads
+    head_dim: int
+    kda_heads: int
+    kda_head_dim: int               # key width = value width
+    conv_size: int
+    kda_rank: int                   # width of the two low-rank pairs
+    n_experts: int                  # published: the router's width
+    n_experts_held: int
+    first_expert: int
+    experts_per_tok: int
+    n_shared_experts: int
+    moe_intermediate_size: int
+    norm_eps: float = 1e-5
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    max_positions: int = 1 << 20    # no positional term: the source's bound
+    kda_sub_chunk: int = 64
+    kda_block: int = 16
+    dtype: Any = jnp.bfloat16
+
+    def __post_init__(self) -> None:
+        if not self.period or set(self.period) - {ATTN, KDA}:
+            raise ValueError(f"a period is made of {ATTN!r} and {KDA!r} "
+                             f"layers, got {self.period!r}")
+        if self.q_heads % self.kv_heads:
+            raise ValueError(f"{self.q_heads} query heads do not divide "
+                             f"over {self.kv_heads} K/V heads")
+        if not 0 <= self.first_expert <= self.n_experts \
+                - self.n_experts_held:
+            raise ValueError(
+                f"experts {self.first_expert} to {self.first_expert} + "
+                f"{self.n_experts_held} are not among {self.n_experts}")
+
+    @property
+    def n_positions(self) -> int:
+        """The name the paged engine asks a description's depth by."""
+        return self.max_positions
+
+    @property
+    def n_layer(self) -> int:
+        return self.n_periods * len(self.period)
+
+    @property
+    def n_attn_layers(self) -> int:
+        return self.n_periods * self.period.count(ATTN)
+
+    @property
+    def n_kda_layers(self) -> int:
+        return self.n_periods * self.period.count(KDA)
+
+    @property
+    def conv_channels(self) -> int:
+        return 3 * self.kda_heads * self.kda_head_dim
+
+
+def decode_view(params: Params, cfg: DecoderConfig) -> Params:
+    """The weights as the serving programs read them: matrices in
+    ``cfg.dtype`` (a no-op for a tree that arrives in it), the leaves of
+    :data:`F32_LEAVES` in float32."""
+    def cast(path, leaf):
+        name = path[-1].key
+        return leaf.astype(jnp.float32 if name in F32_LEAVES else cfg.dtype)
+
+    return jax.tree_util.tree_map_with_path(cast, params)
+
+
+def _mm(x: jax.Array, w: jax.Array) -> jax.Array:
+    """``x @ w`` in the weight's dtype, float32 accumulation and result."""
+    return jnp.matmul(x.astype(w.dtype), w,
+                      preferred_element_type=jnp.float32)
+
+
+# -- the two kinds of mixing layer --------------------------------------------
+
+
+def _gated_attention(p: Params, xn: jax.Array, pool_k: jax.Array,
+                     pool_v: jax.Array, table: jax.Array, start: jax.Array,
+                     layer: jax.Array, cfg: DecoderConfig, attn_impl: str
+                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``xn [R, T, D]`` -> the layer's output, with this call's K and V
+    rows written into layer ``layer`` of the pool ``[L_attn, NB, BLOCK,
+    kv_heads * head_dim]`` first (write-then-attend, as GPT-2's kernel
+    path does: a row only ever writes blocks it owns)."""
+    from trustworthy_dl_tpu.models import generate as gen
+    from trustworthy_dl_tpu.ops import paged_attention as pattn
+
+    r, t, _ = xn.shape
+    heads = lambda a, n: a.reshape(r, t, n, cfg.head_dim).transpose(
+        0, 2, 1, 3)
+    q = heads(_mm(xn, p["wq"]), cfg.q_heads).astype(cfg.dtype)
+    k = heads(_mm(xn, p["wk"]), cfg.kv_heads)
+    v = heads(_mm(xn, p["wv"]), cfg.kv_heads)
+    gate = jax.nn.sigmoid(_mm(xn, p["wg"]))
+    _, phys, offs = gen._pool_write_coords(
+        table, start, r, t, pool_k.shape[2], table.shape[1])
+    pool_k = gen._pool_write_rows(pool_k, k, layer, phys, offs)
+    pool_v = gen._pool_write_rows(pool_v, v, layer, phys, offs)
+    if attn_impl == "jnp":
+        out = pattn.paged_attention_reference(q, pool_k, pool_v, table,
+                                              start, layer=layer)
+    else:
+        attend = (pattn.paged_prefill_attention if t > pattn.QROWS
+                  else pattn.paged_attention)
+        out = attend(q, pool_k, pool_v, table, start, layer=layer,
+                     interpret=(attn_impl == "interpret"))
+    out = out.astype(jnp.float32).transpose(0, 2, 1, 3).reshape(r, t, -1)
+    return _mm(out * gate, p["wo"]), pool_k, pool_v
+
+
+def _kda_inputs(p: Params, xn: jax.Array, tail: jax.Array,
+                cfg: DecoderConfig, valid: jax.Array):
+    """Everything the delta rule takes, from ``xn [R, T, D]`` and the
+    convolution's tail ``[R, K-1, ch]``: ``q, k, v, g [R, H, T, d]``,
+    ``beta [R, H, T]``, the rows ``tail ++ projections`` (the next tail is
+    cut from them) and the output gate ``[R, T, H * d]``.  A position that
+    is not ``valid [R, T]`` gets ``beta = g = 0``: it leaves the state as
+    it was."""
+    r, t, _ = xn.shape
+    h, d = cfg.kda_heads, cfg.kda_head_dim
+    pre = jnp.concatenate([_mm(xn, p["wq"]), _mm(xn, p["wk"]),
+                           _mm(xn, p["wv"])], axis=-1)       # [R, T, 3·H·d]
+    rows = jnp.concatenate([tail, pre], axis=-2)
+    mixed = jax.nn.silu(kda.causal_conv(
+        pre, tail, p["conv"].astype(jnp.float32)))
+    heads = lambda a: a.reshape(r, t, h, d).transpose(0, 2, 1, 3)
+    q, k, v = (heads(a) for a in jnp.split(mixed, 3, axis=-1))
+    unit = lambda a: a * jax.lax.rsqrt(
+        jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+    q = unit(q) * (1.0 / math.sqrt(d))
+    k = unit(k)
+    decay = jax.nn.softplus(_mm(_mm(xn, p["f_a"]), p["f_b"]) + p["dt_bias"])
+    g = -jnp.exp(p["a_log"])[None, :, None, None] * heads(decay)
+    beta = 2.0 * jax.nn.sigmoid(_mm(xn, p["w_beta"])).transpose(0, 2, 1)
+    keep = valid[:, None, :]
+    g = jnp.where(keep[..., None], g, 0.0)
+    beta = jnp.where(keep, beta, 0.0)
+    gate = jax.nn.sigmoid(_mm(_mm(xn, p["g_a"]), p["g_b"]))
+    return q, k, v, g, beta, rows, gate
+
+
+def _kda_output(p: Params, o: jax.Array, gate: jax.Array,
+                cfg: DecoderConfig) -> jax.Array:
+    """``o [R, H, T, d]`` -> RMSNorm a head, gate, output projection."""
+    r, _, t, _ = o.shape
+    o = L.rmsnorm(p["o_norm"], o, cfg.norm_eps)
+    o = o.transpose(0, 2, 1, 3).reshape(r, t, -1)
+    return _mm(o * gate, p["wo"])
+
+
+def _expert_half(p: Params, xn: jax.Array, cfg: DecoderConfig,
+                 valid: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """The routed experts held here plus the shared expert over ``xn
+    [R, T, D]``; also the pairs each held expert took."""
+    r, t, d = xn.shape
+    flat = xn.reshape(r * t, d)
+    with jax.named_scope("moe.route"):
+        chosen, weights = moe.route_top_k(
+            flat, p["router"], p["router_bias"], cfg.experts_per_tok,
+            cfg.norm_topk_prob, cfg.routed_scaling_factor)
+    with jax.named_scope("moe.experts"):
+        y, pairs = moe.held_experts(
+            flat, chosen, weights, p["w_gate_up"], p["w_down"],
+            cfg.first_expert, valid.reshape(-1))
+    with jax.named_scope("moe.shared"):
+        y = y + L.silu_gated_mlp(p["shared_gate_up"], p["shared_down"],
+                                 flat)
+    return y.reshape(r, t, d), pairs
+
+
+# -- the serving forward -------------------------------------------------------
+
+
+def apply_paged(view: Params, tokens: jax.Array, pool_k: jax.Array,
+                pool_v: jax.Array, state: Any, table: jax.Array,
+                start: jax.Array, cfg: DecoderConfig, valid: jax.Array,
+                slot: Optional[jax.Array] = None,
+                last_pos: Optional[jax.Array] = None,
+                attn_impl: str = "jnp"
+                ) -> Tuple[jax.Array, jax.Array, jax.Array, Any]:
+    """Run every layer over ``tokens [R, T]`` against the paged K/V pool and
+    the recurrent state (``serve/kv_slots.RecurrentState``); both are the
+    layer loop's carry, written in place under donation.  Returns (logits
+    ``[R, V]`` at ``last_pos``, or at the one position fed, the pool, the
+    state).
+
+    Two shapes, as the scheduler's two programs call it.  DECODE: ``T = 1``,
+    row ``r`` of the call IS slot ``r`` (``slot`` None, ``start i32[R]``):
+    the recurrence, one step a slot.  A CHUNK of prefill: ``R = 1``,
+    ``slot`` the traced row of the state, ``start`` a scalar: the chunked
+    form, from the slot's state and convolution tail and back into them.
+    ``valid bool[R, T]`` marks the real positions: an idle slot and a
+    chunk's padding leave state, tail and counters as they were (their K/V
+    rows land in the trash block through ``table``, as GPT-2's do).
+    """
+    r, t = tokens.shape
+    x = view["embed"][tokens].astype(jnp.float32)
+    kinds = cfg.period
+    attn_at = [kinds[:j].count(ATTN) for j in range(len(kinds))]
+    kda_at = [kinds[:j].count(KDA) for j in range(len(kinds))]
+    n_real = jnp.sum(valid, axis=1).astype(jnp.int32)            # [R]
+    tail_len = cfg.conv_size - 1
+
+    def read(rows: jax.Array, layer: jax.Array) -> jax.Array:
+        """Layer ``layer`` of a state array ``[L, slots, ...]``: every
+        slot's row (decode) or the one of ``slot``."""
+        if slot is None:
+            return rows[layer]
+        return rows[layer, slot][None]
+
+    def write(rows: jax.Array, layer: jax.Array, new: jax.Array
+              ) -> jax.Array:
+        if slot is None:
+            return rows.at[layer].set(new.astype(rows.dtype))
+        return rows.at[layer, slot].set(new[0].astype(rows.dtype))
+
+    def period_fn(carry, xs):
+        x, pk, pv, s_all, conv_all, pairs_all = carry
+        layers_p, index = xs
+        for j, (kind, p) in enumerate(zip(kinds, layers_p)):
+            xn = L.rmsnorm(p["norm1"], x, cfg.norm_eps)
+            if kind == ATTN:
+                layer = index * kinds.count(ATTN) + attn_at[j]
+                with jax.named_scope("attn.gqa"):
+                    y, pk, pv = _gated_attention(
+                        p["attn"], xn, pk, pv, table, start, layer, cfg,
+                        attn_impl)
+            else:
+                layer = index * kinds.count(KDA) + kda_at[j]
+                tail = read(conv_all, layer).astype(jnp.float32)
+                q, k, v, g, beta, rows, gate = _kda_inputs(
+                    p["kda"], xn, tail, cfg, valid)
+                s = read(s_all, layer)
+                if t == 1:
+                    with jax.named_scope("kda.step"):
+                        o, s = kda.kda_step(q[:, :, 0], k[:, :, 0],
+                                            v[:, :, 0], g[:, :, 0],
+                                            beta[:, :, 0], s)
+                    o = o[:, :, None]
+                else:
+                    with jax.named_scope("kda.chunk"):
+                        o, s = kda.kda_chunk(q, k, v, g, beta, s,
+                                             cfg.kda_sub_chunk,
+                                             cfg.kda_block)
+                s_all = write(s_all, layer, s)
+                # The next tail: the K-1 rows before the first unfed one.
+                new_tail = jax.vmap(
+                    lambda a, n: jax.lax.dynamic_slice_in_dim(
+                        a, n, tail_len, axis=0))(rows, n_real)
+                conv_all = write(conv_all, layer, new_tail)
+                y = _kda_output(p["kda"], o, gate, cfg)
+            x = x + y
+            y, pairs = _expert_half(
+                p["moe"], L.rmsnorm(p["norm2"], x, cfg.norm_eps), cfg,
+                valid)
+            x = x + y
+            pairs_all = pairs_all.at[index * len(kinds) + j].add(pairs)
+        return (x, pk, pv, s_all, conv_all, pairs_all), None
+
+    index = jnp.arange(cfg.n_periods, dtype=jnp.int32)
+    (x, pool_k, pool_v, s_all, conv_all, pairs_all), _ = jax.lax.scan(
+        period_fn,
+        (x, pool_k, pool_v, state.s, state.conv, state.expert_pairs),
+        (view["periods"], index))
+    state = state._replace(
+        s=s_all, conv=conv_all, expert_pairs=pairs_all,
+        expert_tokens=state.expert_tokens
+        + cfg.n_layer * jnp.sum(n_real))
+    if last_pos is not None:
+        x = jax.lax.dynamic_index_in_dim(x, last_pos, axis=1, keepdims=False)
+    else:
+        x = x[:, -1]
+    x = L.rmsnorm(view["final_norm"], x, cfg.norm_eps)
+    return _mm(x, view["head"]), pool_k, pool_v, state

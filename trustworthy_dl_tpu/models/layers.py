@@ -55,6 +55,26 @@ def layernorm(params: Params, x: jax.Array, eps: float = 1e-5) -> jax.Array:
     return y * params["scale"] + params["bias"]
 
 
+def rmsnorm(scale: jax.Array, x: jax.Array, eps: float = 1e-5) -> jax.Array:
+    """RMSNorm over the last axis, in x's dtype (feed it float32): no mean,
+    no bias, ``scale`` [dim] (or any shape that broadcasts)."""
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) \
+        * scale
+
+
+def silu_gated_mlp(w_gate_up: jax.Array, w_down: jax.Array, x: jax.Array
+                   ) -> jax.Array:
+    """``W_down(SiLU(W_gate x) * W_up x)`` with the gate and the up
+    projection side by side in ONE matrix ``[dim, 2 * width]`` (gate first);
+    products in the weights' dtype with float32 accumulation, float32 out."""
+    width = w_down.shape[0]
+    h = jnp.matmul(x.astype(w_gate_up.dtype), w_gate_up,
+                   preferred_element_type=jnp.float32)
+    a = jax.nn.silu(h[..., :width]) * h[..., width:]
+    return jnp.matmul(a.astype(w_down.dtype), w_down,
+                      preferred_element_type=jnp.float32)
+
+
 def groupnorm_init(channels: int) -> Params:
     return {"scale": jnp.ones((channels,), jnp.float32),
             "bias": jnp.zeros((channels,), jnp.float32)}

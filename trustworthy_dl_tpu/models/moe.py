@@ -388,3 +388,73 @@ def moe_ep_specs(params: Params):
 
 def num_params(params: Params) -> int:
     return sum(int(p.size) for p in jax.tree_util.tree_leaves(params))
+
+
+# ---------------------------------------------------------------------------
+# A dropless routed layer that is TOLD which experts it holds (one chip's
+# share of an expert-parallel layer).  No mesh, no capacity: it stands apart
+# from the capacity path above (ROADMAP D7).
+# ---------------------------------------------------------------------------
+
+
+def route_top_k(x: jax.Array, router: jax.Array, bias: jax.Array, k: int,
+                normalise: bool = True, scaling: float = 1.0
+                ) -> Tuple[jax.Array, jax.Array]:
+    """Sigmoid routing over EVERY published expert: ``x [N, D]`` float32,
+    ``router [D, E]``, the selection bias ``bias [E]`` -> the ``k`` experts
+    with the largest ``sigmoid(x W) + bias`` a token, ``chosen i32[N, k]``,
+    and their weights ``[N, k]``: the sigmoid scores themselves (the bias
+    only selects), over their sum where ``normalise``, times ``scaling``.
+    The product runs in float32 at ``HIGHEST``: a rounded score would move
+    a near tie between two experts, and with it a whole expert's output."""
+    scores = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(scores + bias, k)
+    weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    if normalise:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return chosen.astype(jnp.int32), weights * scaling
+
+
+def held_experts(x: jax.Array, chosen: jax.Array, weights: jax.Array,
+                 w_gate_up: jax.Array, w_down: jax.Array, first: int,
+                 valid: Optional[jax.Array] = None
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """The held experts' part of a routed layer, dropless: ``sum over the
+    (token, expert) pairs whose expert e lies in [first, first + held) of
+    weight * W_down[e](SiLU(W_gate[e] x) * W_up[e] x)``.  ``w_gate_up
+    [held, D, 2F]`` (gate first), ``w_down [held, F, D]``, ``chosen`` and
+    ``weights`` as :func:`route_top_k` gives them, ``valid bool[N]`` the
+    tokens that count (padding otherwise).  What the absent experts would
+    add is left out; no pair is dropped whatever the load.
+
+    The pairs are sorted by expert and multiplied as grouped products
+    (``jax.lax.ragged_dot``: each expert's weights meet only its own
+    rows); the pairs of absent experts and of padding sort behind the last
+    group, where no product is computed.  Returns ``(y [N, D] float32,
+    pairs i32[held])``, the pairs each held expert took."""
+    n, k = chosen.shape
+    held = w_down.shape[0]
+    width = w_down.shape[1]
+    local = chosen - first
+    here = (local >= 0) & (local < held)
+    if valid is not None:
+        here &= valid[:, None]
+    group = jnp.where(here, local, held).reshape(-1)          # [N·k]
+    order = jnp.argsort(group, stable=True)
+    pairs = jnp.zeros(held + 1, jnp.int32).at[group].add(1)[:held]
+    rows = x.astype(w_gate_up.dtype)[order // k]               # [N·k, D]
+    h = jax.lax.ragged_dot(rows, w_gate_up, pairs,
+                           preferred_element_type=jnp.float32)
+    a = jax.nn.silu(h[:, :width]) * h[:, width:]
+    y = jax.lax.ragged_dot(a.astype(w_down.dtype), w_down, pairs,
+                           preferred_element_type=jnp.float32)
+    # Rows behind the last group hold whatever the buffer held: select,
+    # do not multiply by zero.
+    live = jnp.arange(n * k) < jnp.sum(pairs)
+    y = jnp.where(live[:, None], y * weights.reshape(-1)[order][:, None],
+                  0.0)
+    back = jnp.zeros(n * k, jnp.int32).at[order].set(
+        jnp.arange(n * k, dtype=jnp.int32))
+    return y[back].reshape(n, k, -1).sum(axis=1), pairs

@@ -36,6 +36,13 @@ tokens/sec lever ROADMAP item 2 names:
   whose blocks fit :data:`VMEM_BLOCK_BUDGET` — all 20 heads and the
   chunk's 64 queries at GPT-2 large, one head at blocks of 4,096 x
   128), and :func:`grid_steps` counts what a call pays;
+* **grouped heads**: where ``rep`` query heads share each K/V head (the
+  pool's lanes hold the K/V heads; a query tensor with ``rep`` times as
+  many), a step holds a group of K/V heads with the ``rep`` query heads
+  of each as ``rep`` times the tile's rows of the SAME two products, so
+  a block is copied once for all the queries that read it
+  (:func:`_attend` lays the rows out; ``rep = 1`` is the kernel as it
+  was, byte for byte);
 * **int8 streaming**: int8 KV tiles DMA HBM→VMEM at half the bf16 bytes
   (a quarter of f32), upcast in-register, and the per-(head, position)
   scales PagedKV already pages multiply the scores/probabilities exactly
@@ -226,9 +233,13 @@ def _head_groups(heads: Optional[int], head_dim: int) -> list:
 
 
 def _step_shape(program: str, *, heads: int, head_dim: int,
-                block_size: int, kv_dtype, t: int) -> Tuple[int, int]:
+                block_size: int, kv_dtype, t: int, rep: int = 1
+                ) -> Tuple[int, int]:
     """THE rule for what one grid step of an attention program holds:
     ``(head group, query tile)``, from shapes and the pool's dtype alone.
+    ``heads`` are the pool's (K/V) heads; where ``rep`` query heads share
+    each of them, a step holds the ``rep`` query heads of every K/V head of
+    its group as ``rep`` times the tile's rows of the same product.
 
     The query tile first: the ``t`` query rows of a call, padded to the
     sublane, in ONE tile if the narrowest head group's blocks then fit
@@ -250,7 +261,7 @@ def _step_shape(program: str, *, heads: int, head_dim: int,
         return _pipelined_block_bytes(
             program, head_dim=head_dim, block_size=block_size,
             kv_dtype=kv_dtype, n_embd=heads * head_dim, group=group,
-            q_tile=q_tile) <= VMEM_BLOCK_BUDGET
+            q_tile=rep * q_tile) <= VMEM_BLOCK_BUDGET
 
     q_tile = next((qt for qt in tiles if fits(groups[-1], qt)), tiles[-1])
     group = next((g for g in groups if fits(g, q_tile)), groups[-1])
@@ -259,18 +270,22 @@ def _step_shape(program: str, *, heads: int, head_dim: int,
 
 def grid_steps(program: str, rows: int, heads: int, nbps: int, t: int,
                head_dim: int, block_size: int,
-               kv_dtype) -> Tuple[int, int, int, int]:
+               kv_dtype, kv_heads: Optional[int] = None
+               ) -> Tuple[int, int, int, int]:
     """The grid of one attention call, ``(rows, head groups, query tiles,
     logical blocks)``: ``program`` "decode" or "prefill" over ``rows``
-    block-table rows of ``nbps`` blocks, ``t`` query rows each.  Its
+    block-table rows of ``nbps`` blocks, ``t`` query rows each, ``heads``
+    query heads over ``kv_heads`` K/V heads (as many where not given; the
+    groups are groups of K/V heads).  Its
     product is the grid steps the call pays (each has a fixed cost of a
     quarter to a third of a microsecond on a v5e whatever it holds);
     :func:`_attn_pallas_call` builds its ``grid=`` from this and nothing
     else, so the count cannot drift from the kernel."""
-    group, q_tile = _step_shape(program, heads=heads, head_dim=head_dim,
+    kv_heads = kv_heads or heads
+    group, q_tile = _step_shape(program, heads=kv_heads, head_dim=head_dim,
                                 block_size=block_size, kv_dtype=kv_dtype,
-                                t=t)
-    return rows, heads // group, -(-t // q_tile), nbps
+                                t=t, rep=heads // kv_heads)
+    return rows, kv_heads // group, -(-t // q_tile), nbps
 
 
 def supports_paged_attention(*, head_dim: int, block_size: int,
@@ -383,9 +398,14 @@ def resolve_attn_impl(requested: str, *, head_dim: int, block_size: int,
 def resolve_attn_impls(requested: str, *, head_dim: int, block_size: int,
                        kv_dtype, n_embd: int,
                        adapter_rank: Optional[int] = None,
-                       rows: int = QROWS) -> dict:
+                       rows: int = QROWS,
+                       satellites: Tuple[str, ...] = ("prefill", "verify",
+                                                      "adapter")) -> dict:
     """Resolve the WHOLE serving-kernel tier at construction: one impl
-    per program in :data:`PAGED_PROGRAMS`.
+    per program in :data:`PAGED_PROGRAMS` (of the satellite programs,
+    those in ``satellites``: an engine that can never dispatch one, a
+    description the verify tail is refused for, names the others and is
+    spared a warning about it).
 
     The decode program keeps :func:`resolve_attn_impl`'s loud contract
     (explicit asks that cannot dispatch raise).  The satellite programs
@@ -407,7 +427,7 @@ def resolve_attn_impls(requested: str, *, head_dim: int, block_size: int,
     if decode == "jnp":
         return impls
     interp = decode == "interpret"
-    for program in ("prefill", "verify", "adapter"):
+    for program in satellites:
         if program == "adapter" and not adapter_rank:
             continue
         if supports_paged_attention(
@@ -469,13 +489,15 @@ def _heads_from_lanes(block_ref, group: int) -> jax.Array:
 
 def _paged_attn_kernel(table_ref, start_ref, jmax_ref, layer_ref, q_ref,
                        k_ref, v_ref, *rest, scale: float, bsz: int, qt: int,
-                       group: int, quantized: bool):
+                       group: int, quantized: bool, rep: int = 1):
     """One (row, head group, query tile, logical block) grid step of the
     online softmax: ``group`` heads of ONE physical block against ``qt``
     query rows, the heads a batch dimension of both products (per head
     the algebra is what a step of one head was; on the chip the batched
     spelling beat a static unroll of two-dimensional dots by 1.4 to 1.7
-    times, PERF.md section 6).
+    times, PERF.md section 6).  Where ``rep`` query heads share a K/V
+    head, the tile's ``qt`` positions of each of them lie one after the
+    other in the step's ``rep * qt`` rows (:func:`_attend` lays them so).
 
     Scalar-prefetch refs: ``table_ref`` i32[R, NBPS] (physical ids —
     also consumed by the index maps, which is what makes the gather part
@@ -508,9 +530,12 @@ def _paged_attn_kernel(table_ref, start_ref, jmax_ref, layer_ref, q_ref,
         # sees cache slots [0, its own position]; everything past the
         # row's true length (garbage in the final block, trash-block
         # padding) is masked.  One mask for the group.
-        kpos = j * bsz + jax.lax.broadcasted_iota(jnp.int32, (qt, bsz), 1)
-        qpos = start_ref[r] + ti * qt + jax.lax.broadcasted_iota(
-            jnp.int32, (qt, bsz), 0)
+        rows = rep * qt
+        kpos = j * bsz + jax.lax.broadcasted_iota(jnp.int32, (rows, bsz), 1)
+        qrow = jax.lax.broadcasted_iota(jnp.int32, (rows, bsz), 0)
+        if rep > 1:
+            qrow = qrow % qt
+        qpos = start_ref[r] + ti * qt + qrow
         visible = (kpos <= qpos)[None]
         q = q_ref[0].astype(jnp.float32)                 # [g, qt, Dh]
         k = _heads_from_lanes(k_ref, group)               # [g, bsz, Dh]
@@ -552,10 +577,11 @@ def _attn_pallas_call(program: str, q: jax.Array, pool_k: jax.Array,
                       pool_v: jax.Array, k_scale: Optional[jax.Array],
                       v_scale: Optional[jax.Array], table: jax.Array,
                       start: jax.Array, jmax: jax.Array, layer: jax.Array,
-                      interpret: bool) -> jax.Array:
-    """q [R, H, NT·QT, Dh] x the STACKED pool [L, NB, BLOCK, H·Dh] at
+                      interpret: bool, rep: int = 1) -> jax.Array:
+    """q [R, H, NT·rep·QT, Dh] x the STACKED pool [L, NB, BLOCK, H·Dh] at
     ``layer`` i32[1] -> out like q, on the grid :func:`grid_steps` gives
-    ``program``.  ``jmax`` i32[R, NT] is the per-(row, query-tile) last
+    ``program``; ``H`` the pool's heads, each read by ``rep`` query heads
+    whose rows lie tile by tile in q's third dimension.  ``jmax`` i32[R, NT] is the per-(row, query-tile) last
     useful logical block.  The pool is handed over whole, in the buffer
     and the layout it rests in: the layer is one more scalar-prefetch
     operand of the K/V index map, so no layer is sliced out of the pool
@@ -563,9 +589,11 @@ def _attn_pallas_call(program: str, q: jax.Array, pool_k: jax.Array,
     the LAYER's planes, heads before positions: [NB, H, BLOCK] (see
     :func:`_attend`)."""
     r, h, t_pad, dh = q.shape
+    t_pad //= rep
     nbps = table.shape[1]
     bsz = pool_k.shape[2]
-    grid = grid_steps(program, r, h, nbps, t_pad, dh, bsz, pool_k.dtype)
+    grid = grid_steps(program, r, h * rep, nbps, t_pad, dh, bsz,
+                      pool_k.dtype, kv_heads=h)
     group, qt = h // grid[1], t_pad // grid[2]
     if jmax.shape != (r, grid[2]) or qt * grid[2] != t_pad:
         raise ValueError(
@@ -578,7 +606,7 @@ def _attn_pallas_call(program: str, q: jax.Array, pool_k: jax.Array,
     quantized = k_scale is not None
     kernel = functools.partial(
         _paged_attn_kernel, scale=1.0 / math.sqrt(dh), bsz=bsz, qt=qt,
-        group=group, quantized=quantized,
+        group=group, quantized=quantized, rep=rep,
     )
 
     # Ragged early exit at the DMA level: logical block j of (row r, tile
@@ -596,7 +624,7 @@ def _attn_pallas_call(program: str, q: jax.Array, pool_k: jax.Array,
         return (ri, gi, ti, 0)
 
     in_specs = [
-        pl.BlockSpec((1, group, qt, dh), q_idx),
+        pl.BlockSpec((1, group, rep * qt, dh), q_idx),
         pl.BlockSpec((1, 1, bsz, group * dh), kv_idx),
         pl.BlockSpec((1, 1, bsz, group * dh), kv_idx),
     ]
@@ -615,46 +643,46 @@ def _attn_pallas_call(program: str, q: jax.Array, pool_k: jax.Array,
         num_scalar_prefetch=4,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, group, qt, dh), q_idx),
+        out_specs=pl.BlockSpec((1, group, rep * qt, dh), q_idx),
         scratch_shapes=[
-            pltpu.VMEM((group, qt, dh), jnp.float32),
-            pltpu.VMEM((group, qt, 128), jnp.float32),
-            pltpu.VMEM((group, qt, 128), jnp.float32),
+            pltpu.VMEM((group, rep * qt, dh), jnp.float32),
+            pltpu.VMEM((group, rep * qt, 128), jnp.float32),
+            pltpu.VMEM((group, rep * qt, 128), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((r, h, t_pad, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
     )(table, start, jmax, layer, *operands)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "rep"))
 def _paged_attn_call(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                      k_scale: Optional[jax.Array],
                      v_scale: Optional[jax.Array],
                      table: jax.Array, start: jax.Array, jmax: jax.Array,
                      layer: jax.Array,
-                     interpret: bool = False) -> jax.Array:
+                     interpret: bool = False, rep: int = 1) -> jax.Array:
     """The decode program's call (its name is what the device trace shows
     the kernel as): every query row of a slot in one tile."""
     return _attn_pallas_call("decode", q, pool_k, pool_v, k_scale, v_scale,
-                             table, start, jmax, layer, interpret)
+                             table, start, jmax, layer, interpret, rep)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "rep"))
 def _paged_prefill_call(q: jax.Array, pool_k: jax.Array,
                         pool_v: jax.Array,
                         k_scale: Optional[jax.Array],
                         v_scale: Optional[jax.Array],
                         table: jax.Array, start: jax.Array,
                         jmax: jax.Array, layer: jax.Array,
-                        interpret: bool = False) -> jax.Array:
+                        interpret: bool = False, rep: int = 1) -> jax.Array:
     """The chunked-prefill program's call (likewise named on the trace):
     the chunk's rows in as few query tiles as fit."""
     return _attn_pallas_call("prefill", q, pool_k, pool_v, k_scale, v_scale,
-                             table, start, jmax, layer, interpret)
+                             table, start, jmax, layer, interpret, rep)
 
 
 _ATTN_CALLS = {"decode": _paged_attn_call, "prefill": _paged_prefill_call}
@@ -670,14 +698,19 @@ def _attend(program: str, q: jax.Array, pool_k: jax.Array,
     r, h, t, dh = q.shape
     bsz = pool_k.shape[2]
     nbps = table.shape[1]
+    kv_heads = pool_k.shape[3] // dh
+    if h % kv_heads:
+        raise ValueError(f"{h} query heads do not divide over the pool's "
+                         f"{kv_heads} K/V heads")
+    rep = h // kv_heads
     if interpret is None:
         interpret = pallas_interpret()
     if jnp.ndim(start) == 0:
         start = jnp.broadcast_to(start, (r,))
     start = start.astype(jnp.int32)
     layer = jnp.reshape(layer, (1,)).astype(jnp.int32)
-    _, qt = _step_shape(program, heads=h, head_dim=dh, block_size=bsz,
-                        kv_dtype=pool_k.dtype, t=t)
+    _, qt = _step_shape(program, heads=kv_heads, head_dim=dh,
+                        block_size=bsz, kv_dtype=pool_k.dtype, t=t, rep=rep)
     nt = -(-t // qt)
     # Tile ti's last useful logical block: that of its last REAL query,
     # at start + min((ti+1)·qt, t) − 1 (pad rows compute a finite, masked
@@ -700,10 +733,23 @@ def _attend(program: str, q: jax.Array, pool_k: jax.Array,
         # serving cell; PERF.md section 7 has what else was tried).
         k_scale = k_scale[layer[0]].transpose(0, 2, 1)
         v_scale = v_scale[layer[0]].transpose(0, 2, 1)
-    out = _ATTN_CALLS[program](q, pool_k, pool_v, k_scale, v_scale,
-                               table, start, jmax, layer,
-                               interpret=interpret)
-    return out[:, :, :t]
+    if rep == 1:
+        out = _ATTN_CALLS[program](q, pool_k, pool_v, k_scale, v_scale,
+                                   table, start, jmax, layer,
+                                   interpret=interpret)
+        return out[:, :, :t]
+    # Grouped heads: the rep query heads of a K/V head become rows of ITS
+    # products, tile by tile: [R, KV, rep, NT, QT, Dh] -> [R, KV, NT, rep,
+    # QT, Dh] -> [R, KV, NT·rep·QT, Dh], and back.
+    def tiled(a, inner, outer):
+        a = a.reshape((r, kv_heads) + inner + (qt, dh))
+        return jnp.swapaxes(a, 2, 3).reshape((r, kv_heads) + outer)
+
+    out = _ATTN_CALLS[program](
+        tiled(q, (rep, nt), (nt * rep * qt, dh)), pool_k, pool_v, k_scale,
+        v_scale, table, start, jmax, layer, interpret=interpret, rep=rep)
+    return tiled(out, (nt, rep), (rep * nt * qt, dh)).reshape(
+        r, h, nt * qt, dh)[:, :, :t]
 
 
 def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
@@ -717,8 +763,10 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
 
     ``q`` [R, H, T, Dh] queries at absolute positions ``start[r] + t``
     (``start`` i32[R] or scalar); ``pool_k``/``pool_v`` [L, NB, BLOCK,
-    H·Dh] (a position's heads side by side in one row) with optional
-    int8 tier scales [L, NB, BLOCK, H]; ``layer`` an i32 scalar (a traced
+    KV·Dh] (a position's heads side by side in one row; ``KV`` = ``H``, or
+    a divisor of it: query head ``h`` then reads K/V head ``h // (H /
+    KV)``) with optional int8 tier scales [L, NB, BLOCK, H] (at ``KV`` =
+    ``H`` only); ``layer`` an i32 scalar (a traced
     value: the layer loop's index); ``table`` i32 [R, NBPS] physical
     block ids (traced values — block churn never recompiles).  The row's
     K/V for positions [0, start+T) — INCLUDING the freshly written
@@ -754,6 +802,13 @@ def paged_attention_reference(q: jax.Array, pool_k: jax.Array,
     def gather(pool):                       # -> [R, H, NBPS*BLOCK, X]
         g = pool[layer][table]              # [R, NBPS, BLOCK, H*X]
         return g.reshape(r, -1, h, g.shape[-1] // h).transpose(0, 2, 1, 3)
+
+    kv_heads = pool_k.shape[-1] // dh
+    if kv_heads != h:
+        # Grouped heads: query head i reads K/V head i // (h / kv_heads).
+        def gather(pool):
+            g = pool[layer][table].reshape(r, -1, kv_heads, dh)
+            return jnp.repeat(g.transpose(0, 2, 1, 3), h // kv_heads, axis=1)
 
     view_k = gather(pool_k).astype(jnp.float32)
     view_v = gather(pool_v).astype(jnp.float32)

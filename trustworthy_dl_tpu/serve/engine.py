@@ -48,11 +48,13 @@ from trustworthy_dl_tpu.quant import int8 as q8
 from trustworthy_dl_tpu.serve.kv_slots import (
     kv_bytes_per_token,
     resolve_prefill_chunk,
+    state_bytes_per_slot,
     validate_paged_geometry,
 )
 from trustworthy_dl_tpu.serve.scheduler import (
     PagedBatchingScheduler,
     SlotTask,
+    refuse_unsupported,
     request_key_stream,
 )
 from trustworthy_dl_tpu.utils.metrics import MetricsCollector
@@ -206,7 +208,7 @@ class ServingEngine:
     reads them — use ``drain_results()`` on a production loop so host
     memory stays bounded."""
 
-    def __init__(self, params: Any, cfg: gpt2.GPT2Config,
+    def __init__(self, params: Any, cfg: Any,
                  max_slots: int = 8, max_seq: int = 256,
                  queue_limit: int = 64,
                  rng: Optional[jax.Array] = None,
@@ -240,6 +242,13 @@ class ServingEngine:
         # replica must lose its slot, not keep serving).
         self.chaos = chaos
         self.cfg = cfg
+        # A description with recurrent state (models.decoder): what it
+        # cannot be served with yet is refused HERE, one ValueError a
+        # mechanism, before any weight is touched.
+        refuse_unsupported(
+            cfg, prefix_cache=prefix_cache, spec_k=spec_k,
+            adapter_rank=adapter_rank, kv_dtype=kv_dtype,
+            weight_dtype=weight_dtype, tp_size=tp_size)
         # Tensor-parallel replica: the engine owns a TP submesh over the
         # 'model' axis and the params carry the model's registry-declared
         # TP layout (core/sharding.py:serve_tp_mesh/place_serve_tp — the
@@ -280,12 +289,17 @@ class ServingEngine:
             # is what lets a scale-UP (bigger TP group) fit more blocks
             # into the same per-chip budget.
             bpt = max(bpt // max(self.tp_size, 1), 1)
-            requested = num_blocks * block_size * bpt
+            # The byte budget divides between the two kinds of cache: the
+            # state rows are fixed by ``max_slots`` (0 for GPT-2), so
+            # they are asked for with the pool and the POOL shrinks into
+            # what they leave.
+            state_bytes = max_slots * state_bytes_per_slot(cfg)
+            requested = num_blocks * block_size * bpt + state_bytes
             if not hbm.admit(requested, what="serve_paged_pool"):
                 # Size the shrunk pool from the SAME sweep that made
                 # the deny decision (admit() stored it) — a second
                 # sweep could report headroom the gate never saw.
-                headroom = max(hbm.last_headroom or 0, 0)
+                headroom = max((hbm.last_headroom or 0) - state_bytes, 0)
                 floor = max_seq // block_size
                 allowed = max(int(headroom // (block_size * bpt)),
                               floor)
@@ -489,6 +503,30 @@ class ServingEngine:
             "KV slot-pool HBM footprint (values + quant scales)",
             labels=self._rlabel_names,
         ).set(float(kv.pool_bytes), **self._rlabels)
+        state = self.scheduler.state
+        self.state_pool_bytes = state.pool_bytes if state is not None else 0
+        _metric(
+            registry.gauge, "tddl_serve_state_pool_bytes",
+            "Recurrent-state rows' HBM footprint (0 where every layer "
+            "keeps keys and values)",
+            labels=self._rlabel_names,
+        ).set(float(self.state_pool_bytes), **self._rlabels)
+        # Expert-layer counters (models.decoder descriptions): pulled off
+        # the device by metrics_summary() alone.  The registry holds the
+        # running totals and what was counted BETWEEN the last two
+        # summaries, so a reader with no handle on the engine can take a
+        # window's counts.
+        self._expert_pairs_gauge = _metric(
+            registry.gauge, "tddl_serve_moe_held_expert_pairs",
+            "(token, expert) pairs routed to each held expert, all layers",
+            labels=("expert", "scope") + self._rlabel_names,
+        )
+        self._expert_tokens_gauge = _metric(
+            registry.gauge, "tddl_serve_moe_tokens_fed",
+            "Tokens fed through an expert layer, a layer each",
+            labels=("scope",) + self._rlabel_names,
+        )
+        self._expert_seen: Optional[Dict[str, Any]] = None
         _metric(
             registry.gauge, "tddl_serve_slots_total",
             "KV slots in the pool, by storage dtype",
@@ -669,10 +707,12 @@ class ServingEngine:
         self.shed_slo = 0
 
     @classmethod
-    def from_config(cls, params: Any, cfg: gpt2.GPT2Config,
+    def from_config(cls, params: Any, cfg: Any,
                     serve_config: Any, **kwargs: Any) -> "ServingEngine":
         """Build an engine from a ``core.config.ServeConfig`` (whose
-        construction already validated the dtype knobs loudly);
+        construction already validated the dtype knobs loudly) and a
+        model description, ``gpt2.GPT2Config`` or
+        ``models.decoder.DecoderConfig``: its type selects the programs;
         ``kwargs`` pass through for the non-config surfaces (rng,
         monitor, trace, registry, ...)."""
         return cls(
@@ -1465,6 +1505,11 @@ class ServingEngine:
             sched.prefix_hits / sched.prefix_lookups
             if sched.prefix_lookups else 0.0
         )
+        out["kv_pool_bytes"] = sched.kv.pool_bytes
+        out["state_pool_bytes"] = self.state_pool_bytes
+        experts = sched.expert_counters()
+        if experts is not None:
+            out["moe"] = self._expert_summary(experts)
         if self.spec_k:
             out["spec_k"] = self.spec_k
             out["spec_proposed"] = sched.spec_proposed
@@ -1491,6 +1536,30 @@ class ServingEngine:
                 out[f"{name}_p50_ms"] = float(p50 * 1e3)
                 out[f"{name}_p99_ms"] = float(p99 * 1e3)
         return out
+
+    def _expert_summary(self, now: Dict[str, Any]) -> Dict[str, Any]:
+        """The expert counters as a summary gives them: the running totals
+        and, under ``since_last_summary``, what was counted since the
+        summary before this one (the whole run for the first); both go to
+        the registry too."""
+        seen = self._expert_seen or {
+            "held_expert_pairs": [0] * len(now["held_expert_pairs"]),
+            "tokens_fed": 0}
+        since = {
+            "held_expert_pairs": [a - b for a, b in zip(
+                now["held_expert_pairs"], seen["held_expert_pairs"])],
+            "tokens_fed": now["tokens_fed"] - seen["tokens_fed"]}
+        self._expert_seen = now
+        first = self.cfg.first_expert
+        for scope, counts in (("total", now),
+                              ("since_last_summary", since)):
+            for i, n in enumerate(counts["held_expert_pairs"]):
+                self._expert_pairs_gauge.set(
+                    float(n), expert=str(first + i), scope=scope,
+                    **self._rlabels)
+            self._expert_tokens_gauge.set(
+                float(counts["tokens_fed"]), scope=scope, **self._rlabels)
+        return {"first_expert": first, **now, "since_last_summary": since}
 
     def analyze_programs(self, ledger: Any,
                          memory: Optional[bool] = None) -> Any:

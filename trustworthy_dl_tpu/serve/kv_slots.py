@@ -35,6 +35,15 @@ many requests are live.  Admission/retirement only change the host-side
 refcount bookkeeping are pure host work (SlotAllocator / BlockAllocator
 below).
 
+A SECOND KIND OF CACHE lives beside the blocks for descriptions whose
+layers keep a state rather than keys and values (``RecurrentState`` below:
+linear-attention state and a short convolution's tail): one row a decode
+slot a layer, indexed by the slot itself, because a state does not grow
+with the sequence.  The pool then has layers only for the softmax attention
+layers, ``[L_attn, NUM_BLOCKS + 1, BLOCK, KV_HEADS·Dh]``.  Unlike a block, a
+state row IS scrubbed: every position reads it, so it is zeroed when its
+slot is admitted and when a quarantined slot is released.
+
 Block hygiene: a freed block is NOT scrubbed.  That is safe by
 construction: a block is only re-used after prefill or decode writes
 every position a new request attends to before it first becomes visible
@@ -50,26 +59,35 @@ from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Set, Tuple
 import jax
 import jax.numpy as jnp
 
-from trustworthy_dl_tpu.models import gpt2
+from trustworthy_dl_tpu.models import decoder
 
 
-def kv_bytes_per_token(cfg: gpt2.GPT2Config,
-                       kv_dtype: Optional[Any] = None) -> int:
+def kv_geometry(cfg: Any) -> Tuple[int, int, int]:
+    """``(layers that keep keys and values, K/V heads, head width)`` of a
+    model description: every layer and every head of a ``GPT2Config``, the
+    softmax attention layers and their shared K/V heads of a
+    ``models.decoder.DecoderConfig``."""
+    if isinstance(cfg, decoder.DecoderConfig):
+        return cfg.n_attn_layers, cfg.kv_heads, cfg.head_dim
+    return cfg.n_layer, cfg.n_head, cfg.n_embd // cfg.n_head
+
+
+def kv_bytes_per_token(cfg: Any, kv_dtype: Optional[Any] = None) -> int:
     """Bytes ONE cached token position costs under ``kv_dtype`` WITHOUT
     allocating — the HBM-budget primitive (a block costs ``block_size``
     of these, a full sequence ``max_seq``).
     int8 counts 1 byte/element plus the 4-byte per-(head, position)
     scale, K and V each."""
     kv_dtype = cfg.dtype if kv_dtype is None else kv_dtype
-    heads = cfg.n_layer * cfg.n_head
-    dh = cfg.n_embd // cfg.n_head
+    layers, kv_heads, dh = kv_geometry(cfg)
+    heads = layers * kv_heads
     if kv_dtype == jnp.int8:
         return 2 * heads * (dh + 4)
     itemsize = jnp.zeros((), kv_dtype).dtype.itemsize
     return 2 * heads * dh * itemsize
 
 
-def paged_pool_blocks(cfg: gpt2.GPT2Config, hbm_bytes: int, block_size: int,
+def paged_pool_blocks(cfg: Any, hbm_bytes: int, block_size: int,
                       kv_dtype: Optional[Any] = None) -> int:
     """Largest USABLE block count whose paged pool (including the +1
     trash block the layout always carries) fits in ``hbm_bytes`` — the
@@ -228,7 +246,7 @@ class PagedKV(NamedTuple):
         return self.pool_bytes // (self.num_blocks + 1)
 
 
-def init_paged_pool(cfg: gpt2.GPT2Config, num_blocks: int, block_size: int,
+def init_paged_pool(cfg: Any, num_blocks: int, block_size: int,
                     kv_dtype: Optional[Any] = None) -> PagedKV:
     """Allocate ``num_blocks`` usable blocks (+1 trash).  ``kv_dtype``
     None follows the model compute dtype, ``jnp.int8`` allocates the
@@ -242,17 +260,80 @@ def init_paged_pool(cfg: gpt2.GPT2Config, num_blocks: int, block_size: int,
             f"(n_positions={cfg.n_positions})"
         )
     kv_dtype = cfg.dtype if kv_dtype is None else kv_dtype
-    shape = (cfg.n_layer, num_blocks + 1, block_size, cfg.n_embd)
+    layers, kv_heads, dh = kv_geometry(cfg)
+    shape = (layers, num_blocks + 1, block_size, kv_heads * dh)
     if kv_dtype == jnp.int8:
         # Two buffers, not one array twice: the serving programs donate
         # all four pool arrays, and one buffer cannot be donated twice.
-        scale_shape = shape[:3] + (cfg.n_head,)
+        scale_shape = shape[:3] + (kv_heads,)
         return PagedKV(k=jnp.zeros(shape, jnp.int8),
                        v=jnp.zeros(shape, jnp.int8),
                        k_scale=jnp.zeros(scale_shape, jnp.float32),
                        v_scale=jnp.zeros(scale_shape, jnp.float32))
     return PagedKV(k=jnp.zeros(shape, kv_dtype),
                    v=jnp.zeros(shape, kv_dtype))
+
+
+# ---------------------------------------------------------------------------
+# Recurrent state: the second kind of cache, rows by SLOT beside blocks by
+# table
+# ---------------------------------------------------------------------------
+
+
+class RecurrentState(NamedTuple):
+    """What the layers that keep a STATE (``models/kda.py``) cache, one row
+    a decode slot a layer, beside the block pool: the state does not grow
+    with the sequence, so a slot owns its row for as long as it owns the
+    slot and no table maps it.  The serving programs take it donated and
+    write it in place like the pool; a row is zeroed when its slot is
+    admitted and when a quarantined slot is released
+    (:func:`zero_state_rows`).
+
+    Beside it ride the expert layers' counters, accumulated on the device
+    by the same programs and pulled only when a summary is asked for."""
+
+    s: jax.Array              # f32 [L_state, SLOTS, H, dk, dv]
+    conv: jax.Array           # f32 [L_state, SLOTS, K - 1, 3·H·dk]
+    expert_pairs: jax.Array   # i32 [L, held]: (token, expert) pairs taken
+    expert_tokens: jax.Array  # i32 []: tokens fed through an expert layer
+
+    @property
+    def pool_bytes(self) -> int:
+        """HBM the state rows hold (the counters are a few hundred bytes)."""
+        return self.s.nbytes + self.conv.nbytes
+
+
+def state_bytes_per_slot(cfg: Any) -> int:
+    """Bytes ONE slot's recurrent state costs WITHOUT allocating (0 for a
+    description with no such layer): the HBM budget's other term."""
+    if not isinstance(cfg, decoder.DecoderConfig):
+        return 0
+    per_layer = cfg.kda_heads * cfg.kda_head_dim ** 2 \
+        + (cfg.conv_size - 1) * cfg.conv_channels
+    return 4 * cfg.n_kda_layers * per_layer
+
+
+def init_state_pool(cfg: Any, max_slots: int) -> Optional[RecurrentState]:
+    """Zeroed state rows for ``max_slots`` slots, or None for a description
+    whose every layer keeps keys and values."""
+    if not isinstance(cfg, decoder.DecoderConfig):
+        return None
+    h, d = cfg.kda_heads, cfg.kda_head_dim
+    layers = cfg.n_kda_layers
+    return RecurrentState(
+        s=jnp.zeros((layers, max_slots, h, d, d), jnp.float32),
+        conv=jnp.zeros((layers, max_slots, cfg.conv_size - 1,
+                        cfg.conv_channels), jnp.float32),
+        expert_pairs=jnp.zeros((cfg.n_layer, cfg.n_experts_held),
+                               jnp.int32),
+        expert_tokens=jnp.zeros((), jnp.int32))
+
+
+def zero_state_rows(state: RecurrentState, slot: jax.Array
+                    ) -> RecurrentState:
+    """``state`` with slot ``slot``'s rows of every layer zeroed."""
+    return state._replace(s=state.s.at[:, slot].set(0.0),
+                          conv=state.conv.at[:, slot].set(0.0))
 
 
 class BlockAllocator:

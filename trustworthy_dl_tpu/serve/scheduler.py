@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from trustworthy_dl_tpu.obs.compilewatch import guarded
+from trustworthy_dl_tpu.models import decoder
 from trustworthy_dl_tpu.models import generate as gen
 from trustworthy_dl_tpu.models import gpt2
 from trustworthy_dl_tpu.quant import int8 as q8
@@ -61,8 +62,11 @@ from trustworthy_dl_tpu.serve.kv_slots import (
     TRASH_BLOCK,
     blocks_for_span,
     init_paged_pool,
+    init_state_pool,
+    kv_geometry,
     resolve_prefill_chunk,
     validate_paged_geometry,
+    zero_state_rows,
 )
 
 logger = logging.getLogger(__name__)
@@ -204,7 +208,8 @@ def _paged_chunk_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
                       attn_impl: str = "jnp", adapter_impl: str = "jnp",
                       adapter_a: Any = None, adapter_b: Any = None,
                       adapter_as: Any = None, adapter_bs: Any = None,
-                      apages: Any = None):
+                      apages: Any = None, state: Any = None,
+                      slot: Any = None):
     """One CHUNK of a paged prefill: C prompt positions starting at
     ``start`` (block-aligned — a prefix-cache hit starts the suffix at a
     block boundary), attending to everything already in the slot's
@@ -219,7 +224,21 @@ def _paged_chunk_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
     — None on adapterless engines, where they contribute zero pytree
     leaves and the trace is the pre-adapter one (bit-identity).
     ``adapter_impl`` (static, like ``attn_impl``) routes the per-layer
-    page gather through the in-grid ``ops.adapter_delta`` kernel."""
+    page gather through the in-grid ``ops.adapter_delta`` kernel.
+
+    A ``models.decoder.DecoderConfig`` (the description's type decides)
+    runs ``decoder.apply_paged`` instead: ``state`` is the recurrent state
+    beside the pool (``kv_slots.RecurrentState``, donated like the pool)
+    and ``slot`` i32[] the row of it this chunk reads and writes; the
+    chunk's real positions are those up to ``last_idx``.  The updated
+    state is returned last."""
+    if isinstance(cfg, decoder.DecoderConfig):
+        valid = (jnp.arange(tokens.shape[0]) <= last_idx)[None, :]
+        logits, new_k, new_v, state = decoder.apply_paged(
+            view, tokens[None, :], pool_k, pool_v, state, table, start,
+            cfg, valid, slot=slot, last_pos=last_idx, attn_impl=attn_impl)
+        return new_k, new_v, None, None, _sample_pack(
+            logits, key, temp, greedy, attn_impl), state
     adapter = (None if adapter_a is None
                else (adapter_a, adapter_b, adapter_as, adapter_bs, apages))
     logits, new_k, new_v, new_ks, new_vs = gen._apply_with_cache_paged(
@@ -239,7 +258,8 @@ def _paged_decode_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
                        attn_impl: str = "jnp", adapter_impl: str = "jnp",
                        adapter_a: Any = None, adapter_b: Any = None,
                        adapter_as: Any = None, adapter_bs: Any = None,
-                       apages: Any = None):
+                       apages: Any = None, state: Any = None,
+                       active: Any = None):
     """THE fused paged decode step: one token for every slot, live or
     not.  ``tables`` i32[MAX_SLOTS, NBPS] are the per-slot block maps
     (inactive rows all-trash — their garbage writes land in block 0) and
@@ -254,18 +274,32 @@ def _paged_decode_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
     (serve/adapters.py; ZERO_PAGE rows add an exact-zero delta).  All
     traced values: adapter churn, eviction and tenant-mix changes never
     change this program.  None (adapterless engine) contributes zero
-    pytree leaves — the compiled program IS the pre-adapter one."""
-    adapter = (None if adapter_a is None
-               else (adapter_a, adapter_b, adapter_as, adapter_bs, apages))
-    logits, new_k, new_v, new_ks, new_vs = gen._apply_with_cache_paged(
-        view, tokens[:, None], pool_k, pool_v, pool_ks, pool_vs,
-        tables, lengths, cfg, attn_impl=attn_impl, adapter=adapter,
-        adapter_impl=adapter_impl,
-    )
+    pytree leaves — the compiled program IS the pre-adapter one.
+
+    A ``models.decoder.DecoderConfig`` runs ``decoder.apply_paged``: row
+    ``r`` of the call is row ``r`` of the recurrent ``state`` (donated like
+    the pool, returned last), and ``active`` bool[MAX_SLOTS] says which
+    rows decode this tick: a slot that is free or mid-prefill keeps its
+    state, where a K/V row would go to the trash block."""
+    if isinstance(cfg, decoder.DecoderConfig):
+        logits, new_k, new_v, state = decoder.apply_paged(
+            view, tokens[:, None], pool_k, pool_v, state, tables, lengths,
+            cfg, active[:, None], attn_impl=attn_impl)
+        new_ks = new_vs = None
+    else:
+        adapter = (None if adapter_a is None else
+                   (adapter_a, adapter_b, adapter_as, adapter_bs, apages))
+        logits, new_k, new_v, new_ks, new_vs = gen._apply_with_cache_paged(
+            view, tokens[:, None], pool_k, pool_v, pool_ks, pool_vs,
+            tables, lengths, cfg, attn_impl=attn_impl, adapter=adapter,
+            adapter_impl=adapter_impl,
+        )
     next_tok = _sample_tokens(logits, keys, temps, greedy)
     ent, margin = _logit_signals(logits, attn_impl)
-    return (_pack_step_outputs(next_tok, ent, margin), new_k, new_v,
-            new_ks, new_vs)
+    packed = _pack_step_outputs(next_tok, ent, margin)
+    if state is not None:
+        return packed, new_k, new_v, new_ks, new_vs, state
+    return packed, new_k, new_v, new_ks, new_vs
 
 
 def _spec_draft_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
@@ -361,16 +395,21 @@ def _programs() -> Dict[str, Any]:
             _paged_prefill_impl, static_argnums=(0,),
             static_argnames=("attn_impl",), donate_argnums=donate
         )
+        # The recurrent state rides as a keyword (None, and no buffer, for
+        # a description without one) and is donated by name.
+        donate_state = ("state",) if donate else ()
         _PROGRAMS["paged_chunk"] = jax.jit(
             _paged_chunk_impl, static_argnums=(0,),
             static_argnames=("attn_impl", "adapter_impl"),
-            donate_argnums=donate
+            donate_argnums=donate, donate_argnames=donate_state
         )
         _PROGRAMS["paged_decode"] = jax.jit(
             _paged_decode_impl, static_argnums=(0,),
             static_argnames=("attn_impl", "adapter_impl"),
-            donate_argnums=donate
+            donate_argnums=donate, donate_argnames=donate_state
         )
+        _PROGRAMS["zero_state"] = jax.jit(
+            zero_state_rows, donate_argnums=(0,) if donate else ())
         # Speculative tier: draft + verify get their OWN jit wrappers so
         # the fused-decode compile-once pin (decode_cache_size == 1)
         # stays meaningful — a spec engine runs exactly THREE
@@ -395,9 +434,16 @@ def request_key_stream(rng: jax.Array, max_new_tokens: int) -> np.ndarray:
     ``split(fold_in(key, 1), max_new-1)[i-1]``."""
     keys = [np.asarray(rng, np.uint32)]
     if max_new_tokens > 1:
-        rest = jax.random.split(jax.random.fold_in(rng, 1),
-                                max_new_tokens - 1)
-        keys.extend(np.asarray(rest, np.uint32))
+        # ``split(key, n)`` is a prefix of ``split(key, m)`` for n <= m
+        # (the partitionable threefry this jax defaults to), so the split
+        # is made at the next power of two and cut: the same keys from a
+        # handful of XLA programs, not one a distinct reply length.
+        count = max_new_tokens - 1
+        padded = 1 << (count - 1).bit_length()
+        if not jax.config.jax_threefry_partitionable:
+            padded = count
+        rest = jax.random.split(jax.random.fold_in(rng, 1), padded)
+        keys.extend(np.asarray(rest, np.uint32)[:count])
     return np.stack(keys)
 
 
@@ -454,6 +500,39 @@ class SlotTask:
 # ---------------------------------------------------------------------------
 
 
+def refuse_unsupported(cfg: Any, *, prefix_cache: bool, spec_k: int,
+                       adapter_rank: int, kv_dtype: str, weight_dtype: str,
+                       tp_size: int = 1) -> None:
+    """Loud construction-time refusal of what a description with recurrent
+    state (``models.decoder.DecoderConfig``) cannot be served with yet, one
+    ``ValueError`` a mechanism; each stays queued in ROADMAP.md.  A
+    ``GPT2Config`` passes."""
+    if not isinstance(cfg, decoder.DecoderConfig):
+        return
+    if prefix_cache:
+        raise ValueError(
+            "prefix_cache: a recurrent state cannot be truncated to a "
+            "shared prefix (no state snapshots at block edges yet); build "
+            "the engine with prefix_cache=False")
+    if spec_k > 0:
+        raise ValueError(
+            f"spec_k={spec_k}: speculative decoding needs a rollback of "
+            "rejected drafts, and a recurrent state has none yet")
+    if adapter_rank > 0:
+        raise ValueError(
+            f"adapter_rank={adapter_rank}: the paged adapter tier has no "
+            "sites in this decoder's layers yet")
+    if kv_dtype == "int8" or weight_dtype == "int8":
+        raise ValueError(
+            f"kv_dtype={kv_dtype!r}, weight_dtype={weight_dtype!r}: the "
+            "int8 KV and weight tiers are GPT-2's (quant/int8.py); this "
+            "decoder serves in its model dtype")
+    if tp_size > 1:
+        raise ValueError(
+            f"tp_size={tp_size}: this decoder has no tensor-parallel "
+            "layout in the sharding registry yet")
+
+
 @dataclasses.dataclass
 class _PrefillProgress:
     """Host record of a slot mid-prefill (chunked): ``pos`` is the next
@@ -480,7 +559,7 @@ class PagedBatchingScheduler:
     lifetime: block tables are traced gather indices.
     """
 
-    def __init__(self, params: Any, cfg: gpt2.GPT2Config, max_slots: int,
+    def __init__(self, params: Any, cfg: Any, max_slots: int,
                  max_seq: int,
                  kv_dtype: str = "model", weight_dtype: str = "model",
                  view: Any = None,
@@ -493,6 +572,13 @@ class PagedBatchingScheduler:
         q8.validate_dtypes(kv_dtype, weight_dtype)
         validate_paged_geometry(max_seq, block_size, num_blocks,
                                 prefill_chunk)
+        refuse_unsupported(
+            cfg, prefix_cache=prefix_cache, spec_k=spec_k,
+            adapter_rank=getattr(adapters, "rank", 0) or 0,
+            kv_dtype=kv_dtype, weight_dtype=weight_dtype)
+        #: True where the description keeps recurrent state beside the
+        #: pool: the programs then carry ``self.state`` too.
+        self.recurrent = isinstance(cfg, decoder.DecoderConfig)
         if max_seq > cfg.n_positions:
             # The pool allocates per-block, so check the LOGICAL depth
             # here — a sequence past the position table would silently
@@ -506,6 +592,8 @@ class PagedBatchingScheduler:
         self.weight_dtype = weight_dtype
         if view is not None:
             self.view = view
+        elif self.recurrent:
+            self.view = decoder.decode_view(params, cfg)
         elif weight_dtype == "int8":
             self.view = q8.quantize_decode_view(params, cfg)
         else:
@@ -531,6 +619,8 @@ class PagedBatchingScheduler:
         self.kv = init_paged_pool(cfg, self.num_blocks, block_size,
                                   kv_dtype=q8.resolve_kv_dtype(kv_dtype,
                                                                cfg))
+        # The second kind of cache: state rows by slot (None for GPT-2).
+        self.state = init_state_pool(cfg, max_slots)
         # Serving-kernel paths, resolved ONCE here (never inside a
         # traced program) and baked into the paged programs as statics:
         # "pallas" (compiled Mosaic kernels, TPU), "interpret" (same
@@ -544,13 +634,15 @@ class PagedBatchingScheduler:
         # ``self.attn_impl`` stays the decode path, the tier's anchor.
         from trustworthy_dl_tpu.ops import paged_attention as pattn
 
+        _, kv_heads, head_dim = kv_geometry(cfg)
         self.attn_impls = pattn.resolve_attn_impls(
-            attn_impl, head_dim=cfg.n_embd // cfg.n_head,
+            attn_impl, head_dim=head_dim,
             block_size=block_size,
             kv_dtype=q8.resolve_kv_dtype(kv_dtype, cfg),
-            n_embd=cfg.n_embd,
+            n_embd=kv_heads * head_dim,
             adapter_rank=getattr(adapters, "rank", None),
             rows=max(self.chunk, max_slots * (spec_k + 1)),
+            **({"satellites": ("prefill",)} if self.recurrent else {}),
         )
         self.attn_impl = self.attn_impls["decode"]
         self.allocator = SlotAllocator(max_slots)  # decode rows
@@ -721,6 +813,7 @@ class PagedBatchingScheduler:
             self.prefix_tokens_reused += len(shared) * self.block_size
         self.tables[slot] = shared + fresh
         self.lengths[slot] = 0
+        self._zero_state(slot)
         task.slot = slot
         self.tasks[slot] = task
         self._attrib[slot] = {
@@ -737,6 +830,13 @@ class PagedBatchingScheduler:
             shared_len=len(shared) * self.block_size,
         )
         return True
+
+    def _zero_state(self, slot: int) -> None:
+        """A slot's recurrent state starts from zero: at admission, and
+        when a quarantined slot goes back into service."""
+        if self.state is not None:
+            self.state = _programs()["zero_state"](
+                self.state, jnp.asarray(slot, jnp.int32))
 
     # -- decode ------------------------------------------------------------
 
@@ -760,7 +860,8 @@ class PagedBatchingScheduler:
         chunk[:n_real] = task.prompt[st.pos:st.pos + n_real]
         final = st.pos + n_real >= st.plen
         kv = self.kv
-        if st.pos == 0 and st.plen <= c and task.adapter_page == ZERO_PAGE:
+        if (st.pos == 0 and st.plen <= c and task.adapter_page == ZERO_PAGE
+                and not self.recurrent):
             # Whole prompt in one chunk, nothing shared: full-precision
             # local prefill (``generate()``'s numerics, bit-for-bit —
             # the int8 tier quantizes once at the block write).  An
@@ -790,7 +891,10 @@ class PagedBatchingScheduler:
                     adapter_bs=b_s,
                     apages=jnp.asarray([task.adapter_page], jnp.int32),
                 )
-            new_k, new_v, new_ks, new_vs, packed = _programs()[
+            if self.recurrent:
+                extra = dict(state=self.state,
+                             slot=jnp.asarray(slot, jnp.int32))
+            new_k, new_v, new_ks, new_vs, packed, *state = _programs()[
                 "paged_chunk"](
                 self.cfg, kv.k, kv.v, kv.k_scale, kv.v_scale, self.view,
                 jnp.asarray(chunk), jnp.asarray(self._table_row(slot)[None]),
@@ -804,6 +908,8 @@ class PagedBatchingScheduler:
                 **extra,
             )
         self.kv = PagedKV(k=new_k, v=new_v, k_scale=new_ks, v_scale=new_vs)
+        if self.recurrent:
+            (self.state,) = state
         self.prefill_chunk_s += _time.perf_counter() - t_chunk
         if self.spans is not None:
             self.spans.add("serve.prefill_chunk", t_chunk,
@@ -886,8 +992,12 @@ class PagedBatchingScheduler:
                 {s: t.adapter_page for s, t in active.items()}, ms)
             extra = dict(adapter_a=a, adapter_b=b, adapter_as=a_s,
                          adapter_bs=b_s, apages=jnp.asarray(row))
+        if self.recurrent:
+            live = np.zeros(ms, bool)
+            live[list(active)] = True
+            extra = dict(state=self.state, active=jnp.asarray(live))
         with guarded(self.compilewatch, "serve_decode"):
-            packed, new_k, new_v, new_ks, new_vs = \
+            packed, new_k, new_v, new_ks, new_vs, *state = \
                 _programs()["paged_decode"](
                     self.cfg, kv.k, kv.v, kv.k_scale, kv.v_scale,
                     self.view,
@@ -900,6 +1010,8 @@ class PagedBatchingScheduler:
                     **extra,
                 )
         self.kv = PagedKV(k=new_k, v=new_v, k_scale=new_ks, v_scale=new_vs)
+        if self.recurrent:
+            (self.state,) = state
         # tddl-lint: disable=host-sync — the tick's single intentional pull
         host = np.asarray(packed)
         next_tok, ent, margin = host[0], host[1], host[2]
@@ -1125,6 +1237,7 @@ class PagedBatchingScheduler:
         self.allocator.release(slot)
         for b in self._q_blocks_by_slot.pop(slot, []):
             self.blocks.unquarantine(b)
+        self._zero_state(slot)
 
     # -- live migration (serve/migrate.py) ---------------------------------
 
@@ -1138,6 +1251,11 @@ class PagedBatchingScheduler:
         unwind FIRST (abort semantics, same ordering rule as retire):
         a migration never travels with un-verified draft claims, and
         the accepted ``lengths`` already exclude rejected draft KV."""
+        if self.recurrent:
+            raise ValueError(
+                "live migration snapshots blocks, and this description "
+                "keeps recurrent state beside them: no state snapshot "
+                "exists yet (serve/migrate.can_migrate says so first)")
         slot = task.slot
         if slot < 0 or self.tasks.get(slot) is not task:
             return None
@@ -1223,6 +1341,19 @@ class PagedBatchingScheduler:
             info["migrated_from"] = dict(migrated_from)
         self._attrib[slot] = info
 
+    def expert_counters(self) -> Optional[Dict[str, Any]]:
+        """The expert layers' device counters, pulled now (one sync: ask
+        when a summary is wanted, not every tick): the (token, expert)
+        pairs each HELD expert took, summed over the layers, and the
+        tokens fed through an expert layer, a layer each.  None for a
+        description without routed experts."""
+        if self.state is None:
+            return None
+        # tddl-lint: disable=host-sync — pulled only for a summary
+        pairs = np.asarray(self.state.expert_pairs).sum(axis=0)
+        return {"held_expert_pairs": [int(n) for n in pairs],
+                "tokens_fed": int(self.state.expert_tokens)}
+
     def decode_cache_size(self) -> int:
         """Number of compiled paged-decode programs (the compile-once
         pin: block-table churn must keep this at 1)."""
@@ -1263,14 +1394,24 @@ class PagedBatchingScheduler:
         bsz = self.block_size
         prog = _programs()
         pool = (kv.k, kv.v, kv.k_scale, kv.v_scale)
-        ledger.analyze(
-            "serve.paged_prefill", prog["paged_prefill"], self.cfg,
-            *pool, self.view, jnp.zeros(c, jnp.int32),
-            jnp.asarray(1, jnp.int32),
-            jnp.zeros(c // bsz, jnp.int32), jnp.zeros(2, jnp.uint32),
-            jnp.asarray(1.0, jnp.float32), jnp.asarray(True),
-            memory=memory, attn_impl=self.attn_impl,
-        )
+        # A description with recurrent state has no whole-prompt program,
+        # and its two programs take the state beside the pool.
+        chunk_state: Dict[str, Any] = {}
+        decode_state: Dict[str, Any] = {}
+        if self.recurrent:
+            chunk_state = dict(state=self.state,
+                               slot=jnp.asarray(0, jnp.int32))
+            decode_state = dict(state=self.state,
+                                active=jnp.ones(ms, bool))
+        if not self.recurrent:
+            ledger.analyze(
+                "serve.paged_prefill", prog["paged_prefill"], self.cfg,
+                *pool, self.view, jnp.zeros(c, jnp.int32),
+                jnp.asarray(1, jnp.int32),
+                jnp.zeros(c // bsz, jnp.int32), jnp.zeros(2, jnp.uint32),
+                jnp.asarray(1.0, jnp.float32), jnp.asarray(True),
+                memory=memory, attn_impl=self.attn_impl,
+            )
         ledger.analyze(
             "serve.paged_chunk", prog["paged_chunk"], self.cfg,
             *pool, self.view, jnp.zeros(c, jnp.int32),
@@ -1278,7 +1419,7 @@ class PagedBatchingScheduler:
             jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32),
             jnp.zeros(2, jnp.uint32), jnp.asarray(1.0, jnp.float32),
             jnp.asarray(True), memory=memory,
-            attn_impl=self.attn_impls["prefill"],
+            attn_impl=self.attn_impls["prefill"], **chunk_state,
         )
         ledger.analyze(
             "serve.paged_decode", prog["paged_decode"], self.cfg,
@@ -1286,5 +1427,5 @@ class PagedBatchingScheduler:
             jnp.zeros((ms, self.nbps), jnp.int32),
             jnp.asarray(self.lengths), jnp.zeros((ms, 2), jnp.uint32),
             jnp.ones(ms, jnp.float32), jnp.ones(ms, bool),
-            memory=memory, attn_impl=self.attn_impl,
+            memory=memory, attn_impl=self.attn_impl, **decode_state,
         )
