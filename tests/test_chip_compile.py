@@ -26,6 +26,7 @@ from jax.sharding import SingleDeviceSharding
 
 from trustworthy_dl_tpu.ops import fused_dequant_matmul as dq
 from trustworthy_dl_tpu.ops import fused_stats
+from trustworthy_dl_tpu.ops import grouped_matmul as gm
 from trustworthy_dl_tpu.ops import paged_attention as pa
 # (``ops.flash_attention`` the attribute is the entry function, which
 # shadows its submodule.)
@@ -257,11 +258,12 @@ def test_paged_attention_lowers_at_grouped_heads(v5e, program):
 
 
 @pytest.mark.parametrize("program", ["chunk", "decode"])
-def test_decoder_serving_program_lowers_in_place(v5e, program):
+def test_decoder_serving_program_lowers_in_place(v5e, monkeypatch, program):
     """Both scheduler programs for the ``DecoderConfig`` of the benchmark's
     configuration at its published widths, pool and recurrent state donated
-    as on the chip: they compile, hold the paged kernel and XLA's grouped
-    products, update pool AND state in the buffers they came in, and keep
+    as on the chip: they compile, hold the paged kernel and the grouped
+    products' kernel (two calls a layer, none of XLA's ``ragged-dot``),
+    update pool AND state in the buffers they came in, and keep
     their temporaries under 1 GB (the chunk's activations; a copy of the
     state of one layer alone would be 0.27 GB, of the pool 2.1 GB)."""
     import json
@@ -308,10 +310,13 @@ def test_decoder_serving_program_lowers_in_place(v5e, program):
     jitted = jax.jit(fn, static_argnums=(0,),
                      static_argnames=("attn_impl", "adapter_impl"),
                      donate_argnums=(1, 2, 3, 4), donate_argnames=("state",))
+    # The dispatch predicates ask the backend; the trace is for the chip.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     compiled = _compile(jitted, v5e, cfg, kv.k, kv.v, None, None, view,
                         *rest, attn_impl="pallas", **extra)
     text = compiled.as_text()
-    assert "ragged-dot" in text
+    assert "ragged-dot" not in text
+    assert text.count("_gmm_call") >= 2
     memory = compiled.memory_analysis()
     carried = kv.k.size * 2 * 2 + state.s.size * 4 + state.conv.size * 4
     assert memory.alias_size_in_bytes >= carried
@@ -413,6 +418,32 @@ def test_kernel_entry_lowers(v5e, entry):
     tile, the int8 dequant-matmul, the trust epilogue, the speculative
     verify tail and the adapter gather."""
     entry(v5e)
+
+
+#: The held experts of the benchmark's second configuration: 40 of
+#: ``[4096, 2 x 1280]`` and ``[1280, 4096]``, 8 pairs a token.
+GM_HELD, GM_D, GM_F, GM_K = 40, 4096, 1280, 8
+
+
+@pytest.mark.parametrize("product", ["gate-up", "down"])
+@pytest.mark.parametrize("tokens", [GSLOTS, GCHUNK],
+                         ids=["decode-512-rows", "chunk-8192-rows"])
+def test_grouped_products_lower_at_the_cell_geometry(v5e, tokens, product):
+    """Both grouped products of ``held_experts`` at the tiling the rule
+    gives a decode call's 512 sorted rows (tiles of 16) and a chunk call's
+    8,192 (tiles of 128): the double-buffered weights tile, the rows' and
+    the output's blocks and the accumulator fit what a kernel may use."""
+    m = tokens * GM_K
+    k, n = (GM_D, 2 * GM_F) if product == "gate-up" else (GM_F, GM_D)
+    tiles = gm.tiling(m, k, n, GM_HELD, 2)
+    assert tiles[0] == (16 if tokens == GSLOTS else 128)
+    tm, tk, tn = tiles
+    pinned = 2 * (tk * tn * 2 + tm * tk * 2 + tm * tn * 4) + tm * tn * 4
+    assert 2 * tk * tn * 2 <= pa.VMEM_BLOCK_BUDGET
+    assert pinned < pa.VMEM_LIMIT_BYTES
+    _compile(gm._gmm_call, v5e, S((m, k), jnp.bfloat16),
+             S((GM_HELD, k, n), jnp.bfloat16), S((GM_HELD,), jnp.int32),
+             tiles=tiles, interpret=False)
 
 
 # Geometries off the dtype's sublane, off the 128 lanes, and large: what

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 from trustworthy_dl_tpu.core.mesh import EXPERT_AXIS
 from trustworthy_dl_tpu.models import gpt2
 from trustworthy_dl_tpu.models import layers as L
+from trustworthy_dl_tpu.ops.grouped_matmul import grouped_matmul
 
 Params = Dict[str, Any]
 
@@ -430,10 +431,14 @@ def held_experts(x: jax.Array, chosen: jax.Array, weights: jax.Array,
     add is left out; no pair is dropped whatever the load.
 
     The pairs are sorted by expert and multiplied as grouped products
-    (``jax.lax.ragged_dot``: each expert's weights meet only its own
+    (``ops.grouped_matmul``: each expert's weights meet only its own
     rows); the pairs of absent experts and of padding sort behind the last
-    group, where no product is computed.  Returns ``(y [N, D] float32,
-    pairs i32[held])``, the pairs each held expert took."""
+    group, where no product is computed.  On one TPU chip the products
+    are this repo's Pallas kernel, whose row tile follows ``N * k / held``
+    (16 rows for a decode call, 128 for a chunk); on the CPU and in a
+    program GSPMD partitions they are ``jax.lax.ragged_dot``, the same
+    sums.  Returns ``(y [N, D] float32, pairs i32[held])``, the pairs
+    each held expert took."""
     n, k = chosen.shape
     held = w_down.shape[0]
     width = w_down.shape[1]
@@ -445,11 +450,9 @@ def held_experts(x: jax.Array, chosen: jax.Array, weights: jax.Array,
     order = jnp.argsort(group, stable=True)
     pairs = jnp.zeros(held + 1, jnp.int32).at[group].add(1)[:held]
     rows = x.astype(w_gate_up.dtype)[order // k]               # [N·k, D]
-    h = jax.lax.ragged_dot(rows, w_gate_up, pairs,
-                           preferred_element_type=jnp.float32)
+    h = grouped_matmul(rows, w_gate_up, pairs)
     a = jax.nn.silu(h[:, :width]) * h[:, width:]
-    y = jax.lax.ragged_dot(a.astype(w_down.dtype), w_down, pairs,
-                           preferred_element_type=jnp.float32)
+    y = grouped_matmul(a.astype(w_down.dtype), w_down, pairs)
     # Rows behind the last group hold whatever the buffer held: select,
     # do not multiply by zero.
     live = jnp.arange(n * k) < jnp.sum(pairs)

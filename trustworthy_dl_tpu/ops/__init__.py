@@ -20,8 +20,13 @@ isn't enough.  Current kernels:
   streaming vocab pass), the in-grid adapter low-rank gather (per-slot
   page table as scalar prefetch) and the fused logit trust epilogue
   (entropy / top-1 margin in one pass over the vocab).
+* ``grouped_matmul`` — the grouped products of the dropless expert layer
+  (``models.moe.held_experts``): rows sorted by expert, each expert's
+  weights streamed once against a row tile chosen from the shapes (16
+  rows for a decode call, 128 for a chunk), group offsets and the
+  visits' groups as scalar prefetch; ``jax.lax.ragged_dot`` elsewhere.
 
-All four dispatch through the ONE shared gate below: :func:`pallas_enabled`
+All five dispatch through the ONE shared gate below: :func:`pallas_enabled`
 (env-var opt-in/out, TPU-backend default) and :func:`pallas_interpret`
 (off-TPU kernels run in Pallas interpret mode — tests only).  The gate
 lives HERE, above the kernel imports, so the kernels can import it from
@@ -83,7 +88,8 @@ def pallas_enabled(env: str = "TDDL_FUSED_STATS") -> bool:
     Env-var map: ``TDDL_FUSED_STATS`` gates fused_stats AND
     dequant_matmul (the int8 decode tier shipped riding the stats gate
     and keeps that coupling — flipping it off disables both kernels);
-    ``TDDL_PAGED_ATTN`` gates paged_attention.  The policy is
+    ``TDDL_PAGED_ATTN`` gates paged_attention; ``TDDL_GROUPED_MATMUL``
+    gates grouped_matmul.  The policy is
     deliberately identical everywhere: the jnp/XLA path stays the
     always-available reference semantics, and the CPU container tier
     never compiles Mosaic."""
@@ -114,7 +120,9 @@ from trustworthy_dl_tpu.ops.fused_stats import (
 # resolving to the submodule — generate/scheduler import it as a module
 # for the whole kernel surface (attention + trust epilogue + resolver),
 # unlike ``flash_attention`` where the function deliberately shadows its
-# submodule and callers only ever want the one entry point.
+# submodule and callers only ever want the one entry point.  Likewise
+# ``grouped_matmul``: ``ops.grouped_matmul`` is the submodule (its entry
+# point, tile rule and ``scheduled_rows`` counter), imported where used.
 from trustworthy_dl_tpu.ops.paged_attention import (
     adapter_delta,
     fused_verify_tail,
