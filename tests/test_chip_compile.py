@@ -257,6 +257,62 @@ def test_paged_attention_lowers_at_grouped_heads(v5e, program):
              interpret=False, rep=rep)
 
 
+def _decoder_program(dev, monkeypatch, program, family, config_name, slots,
+                     block, max_seq, chunk):
+    """One of the two scheduler programs for the ``DecoderConfig`` of a
+    configuration of the benchmark at its published widths, pool and
+    recurrent state donated as on the chip, compiled for the described
+    chip: ``(compiled, pool, state)``."""
+    import json
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from trustworthy_dl_tpu.models import decoder
+    from trustworthy_dl_tpu.serve import scheduler as sch
+    from trustworthy_dl_tpu.serve.kv_slots import (init_paged_pool,
+                                                   init_state_pool)
+
+    with open(os.path.join(root, "benchmark", "configs",
+                           config_name + ".json")) as f:
+        config = json.load(f)
+    cfg = family.model(config)
+    nbps = max_seq // block
+
+    def pin(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev),
+            tree)
+
+    view = pin(jax.eval_shape(lambda: decoder.decode_view(
+        family.make_weights(0, config), cfg)))
+    kv = pin(jax.eval_shape(
+        lambda: init_paged_pool(cfg, slots * nbps, block, jnp.bfloat16)))
+    state = pin(jax.eval_shape(lambda: init_state_pool(cfg, slots)))
+    i32, f32 = jnp.int32, jnp.float32
+    if program == "chunk":
+        fn = sch._paged_chunk_impl
+        rest = (S((chunk,), i32), S((1, nbps), i32), S((), i32),
+                S((), i32), S((2,), jnp.uint32), S((), f32),
+                S((), jnp.bool_))
+        extra = dict(state=state, slot=pin(S((), i32)))
+    else:
+        fn = sch._paged_decode_impl
+        rest = (S((slots,), i32), S((slots, nbps), i32), S((slots,), i32),
+                S((slots, 2), jnp.uint32), S((slots,), f32),
+                S((slots,), jnp.bool_))
+        extra = dict(state=state, active=pin(S((slots,), jnp.bool_)))
+    jitted = jax.jit(fn, static_argnums=(0,),
+                     static_argnames=("attn_impl", "adapter_impl"),
+                     donate_argnums=(1, 2, 3, 4), donate_argnames=("state",))
+    # The dispatch predicates ask the backend; the trace is for the chip.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _compile(jitted, dev, cfg, kv.k, kv.v, None, None, view,
+                        *rest, attn_impl="pallas", **extra)
+    return compiled, kv, state
+
+
 @pytest.mark.parametrize("program", ["chunk", "decode"])
 def test_decoder_serving_program_lowers_in_place(v5e, monkeypatch, program):
     """Both scheduler programs for the ``DecoderConfig`` of the benchmark's
@@ -266,54 +322,11 @@ def test_decoder_serving_program_lowers_in_place(v5e, monkeypatch, program):
     update pool AND state in the buffers they came in, and keep
     their temporaries under 1 GB (the chunk's activations; a copy of the
     state of one layer alone would be 0.27 GB, of the pool 2.1 GB)."""
-    import json
-    import sys
+    from benchmark.harness.families import solar_open2
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if root not in sys.path:
-        sys.path.insert(0, root)
-    from benchmark.harness.families import solar_open2 as family
-    from trustworthy_dl_tpu.models import decoder
-    from trustworthy_dl_tpu.serve import scheduler as sch
-    from trustworthy_dl_tpu.serve.kv_slots import (init_paged_pool,
-                                                   init_state_pool)
-
-    with open(os.path.join(root, "benchmark", "configs",
-                           "solar-open2-250b-ep8-1of8.json")) as f:
-        config = json.load(f)
-    cfg = family.model(config)
-    nbps = GMAX // GBLOCK
-
-    def pin(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
-            tree)
-
-    view = pin(jax.eval_shape(lambda: decoder.decode_view(
-        family.make_weights(0, config), cfg)))
-    kv = pin(jax.eval_shape(
-        lambda: init_paged_pool(cfg, GSLOTS * nbps, GBLOCK, jnp.bfloat16)))
-    state = pin(jax.eval_shape(lambda: init_state_pool(cfg, GSLOTS)))
-    i32, f32 = jnp.int32, jnp.float32
-    if program == "chunk":
-        fn = sch._paged_chunk_impl
-        rest = (S((GCHUNK,), i32), S((1, nbps), i32), S((), i32),
-                S((), i32), S((2,), jnp.uint32), S((), f32),
-                S((), jnp.bool_))
-        extra = dict(state=state, slot=pin(S((), i32)))
-    else:
-        fn = sch._paged_decode_impl
-        rest = (S((GSLOTS,), i32), S((GSLOTS, nbps), i32), S((GSLOTS,), i32),
-                S((GSLOTS, 2), jnp.uint32), S((GSLOTS,), f32),
-                S((GSLOTS,), jnp.bool_))
-        extra = dict(state=state, active=pin(S((GSLOTS,), jnp.bool_)))
-    jitted = jax.jit(fn, static_argnums=(0,),
-                     static_argnames=("attn_impl", "adapter_impl"),
-                     donate_argnums=(1, 2, 3, 4), donate_argnames=("state",))
-    # The dispatch predicates ask the backend; the trace is for the chip.
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    compiled = _compile(jitted, v5e, cfg, kv.k, kv.v, None, None, view,
-                        *rest, attn_impl="pallas", **extra)
+    compiled, kv, state = _decoder_program(
+        v5e, monkeypatch, program, solar_open2, "solar-open2-250b-ep8-1of8",
+        GSLOTS, GBLOCK, GMAX, GCHUNK)
     text = compiled.as_text()
     assert "ragged-dot" not in text
     assert text.count("_gmm_call") >= 2
@@ -323,6 +336,99 @@ def test_decoder_serving_program_lowers_in_place(v5e, monkeypatch, program):
     assert memory.temp_size_in_bytes < 1 << 30
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
         < 12 << 30                      # of the chip's 16 GB
+
+
+# The latent cache at the long-context cell's geometry: 32 query heads over
+# ONE shared row of 576 values in 640 lanes whose first 512 are the values.
+LHEADS, LLANES, LVALUES = 32, 640, 512
+LSLOTS, LBLOCK, LMAX, LCHUNK = 64, 256, 32768, 1024
+
+
+def _latent_decode(dev, lanes):
+    nbps = LMAX // LBLOCK
+    grid = pa.grid_steps("decode", LSLOTS, LHEADS, nbps, 1, lanes, LBLOCK,
+                         jnp.bfloat16, kv_heads=1, v_lanes=LVALUES)
+    pool = S((1, LSLOTS * nbps + 1, LBLOCK, lanes), jnp.bfloat16)
+    compiled = _compile(
+        pa._latent_decode_call, dev,
+        S((LSLOTS, 1, LHEADS, lanes), jnp.bfloat16), pool,
+        S((LSLOTS, nbps), jnp.int32), S((LSLOTS,), jnp.int32),
+        S((LSLOTS, grid[2]), jnp.int32), S((1,), jnp.int32),
+        interpret=False, rep=LHEADS, v_lanes=LVALUES, scale=192 ** -0.5)
+    return grid, pool, compiled
+
+
+def test_paged_attention_lowers_in_the_latent_shape(v5e):
+    """The ONE paged kernel with no V operand: a decode call's tile is the
+    32 heads of one position; the pool of 640 lanes is read where it lies
+    (no temporary), where one of 576 lanes is copied WHOLE into a padded
+    layout first: why the pool's rows are padded to whole lane columns."""
+    grid, pool, compiled = _latent_decode(v5e, LLANES)
+    assert grid == (LSLOTS, 1, 1, 128)
+    assert "latent_decode" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    _, narrow, copied = _latent_decode(v5e, 576)
+    assert copied.memory_analysis().temp_size_in_bytes >= narrow.size * 2
+
+
+def test_latent_prefill_lowers_at_the_cell_geometry(v5e):
+    """The chunk's expanded kernel: 2 heads' 1,024 queries resident, a grid
+    of 16 head groups x 128 blocks, the pool read where it lies, inside the
+    scoped VMEM limit; the predicate admits it."""
+    from trustworthy_dl_tpu.ops import latent_attention as la
+
+    shape = dict(nope=128, value=128, rank=LVALUES, lanes=LLANES,
+                 block_size=LBLOCK, dtype=jnp.bfloat16)
+    assert la.supports_latent_prefill(heads=LHEADS, rows=LCHUNK,
+                                      interpret=False, **shape)
+    assert la.head_group(LHEADS, LCHUNK, **shape) == 2
+    nbps = LMAX // LBLOCK
+    compiled = _compile(
+        la._latent_prefill_call, v5e,
+        S((LHEADS, LCHUNK, 128 + LLANES - LVALUES), jnp.bfloat16),
+        S((LVALUES, LHEADS * 256), jnp.bfloat16),
+        S((1, LSLOTS * nbps + 1, LBLOCK, LLANES), jnp.bfloat16),
+        S((1, nbps), jnp.int32), S((1,), jnp.int32), S((1,), jnp.int32),
+        S((1,), jnp.int32), interpret=False, nope=128)
+    assert "latent_prefill" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("program", ["chunk", "decode"])
+def test_latent_serving_program_lowers_in_place(v5e, monkeypatch, program):
+    """Both scheduler programs for the long-context configuration at its
+    published widths: they compile with the latent kernel under its own
+    name; the pool is ONE array of 640-lane rows with no V half, written
+    in place and read through the layer index (nothing of the pool's shape
+    is produced but the parameter and its bitcast); pool and state are
+    updated in the buffers they came in; NO per-head K or V of the cached
+    positions exists among the arguments or the temporaries (one slot's at
+    32k positions would be 0.67 GB, the pool's 43 GB)."""
+    import re
+
+    from benchmark.harness.families import kimi_linear
+
+    compiled, kv, state = _decoder_program(
+        v5e, monkeypatch, program, kimi_linear, "kimi-linear-48b-ep2-1of2",
+        LSLOTS, LBLOCK, LMAX, LCHUNK)
+    assert kv.v is None
+    assert kv.k.shape == (1, LSLOTS * 128 + 1, LBLOCK, LLANES)
+    text = compiled.as_text()
+    kernel = "latent_prefill" if program == "chunk" else "latent_decode"
+    assert kernel in text and "_paged_attn_call" not in text
+    assert "ragged-dot" not in text and text.count("_gmm_call") >= 2
+    shape = re.escape("bf16[%d,%d,%d,%d]" % kv.k.shape)
+    made = set(re.findall(shape + r"\S* ([a-z\-]+)\(", text))
+    assert made <= {"parameter", "bitcast"}, made
+    # No array over the pool's blocks but the pool itself.
+    assert not re.findall(r"\[(?:\d+,)*%d,%d,(?!%d\])\d+\]" % (
+        kv.k.shape[1], LBLOCK, LLANES), text)
+    memory = compiled.memory_analysis()
+    carried = kv.k.size * 2 + state.s.size * 4 + state.conv.size * 4
+    assert memory.alias_size_in_bytes >= carried
+    assert memory.temp_size_in_bytes < 512 << 20
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 13 << 30                      # of the chip's 16 GB
 
 
 def _flash_forward(dev, dtype, bh=H, t=1024, d=DH, causal=True):

@@ -460,8 +460,10 @@ def _pool_write_rows(pool: jax.Array, rows: jax.Array, layer: jax.Array,
                      phys: jax.Array, offs: jax.Array) -> jax.Array:
     """Write the call's R·T new positions into a stacked pool array in
     place, one contiguous row a position at (layer, phys, offs): ``rows``
-    [R, H, T, Dh] (K or V) into [L, NB, BLOCK, H·Dh], or the int8 tier's
-    scales [R, H, T] into [L, NB, BLOCK, H]."""
+    [R, H, T, Dh] (K or V) into [L, NB, BLOCK, H·Dh], the int8 tier's
+    scales [R, H, T] into [L, NB, BLOCK, H], or a latent layer's ONE row a
+    position [R, 1, T, lanes] into [L, NB, BLOCK, lanes]
+    (``models/decoder.py``)."""
     r, _, t = rows.shape[:3]
     rows = jnp.moveaxis(rows, 1, 2).reshape(r * t, -1)
     return pool.at[layer, phys, offs].set(rows.astype(pool.dtype))

@@ -20,13 +20,19 @@ isn't enough.  Current kernels:
   streaming vocab pass), the in-grid adapter low-rank gather (per-slot
   page table as scalar prefetch) and the fused logit trust epilogue
   (entropy / top-1 margin in one pass over the vocab).
+* ``latent_attention`` — the chunk program's latent attention over the
+  paged LATENT cache (one shared row a position, no per-head K or V), in
+  the expanded form: a block's rows times ``W_kb`` inside the kernel, the
+  scores held transposed so that the softmax reduces down the sublanes;
+  dispatched with ``paged_attention``'s gate and path names (the decode
+  program's half is the paged kernel's latent shape).
 * ``grouped_matmul`` — the grouped products of the dropless expert layer
   (``models.moe.held_experts``): rows sorted by expert, each expert's
   weights streamed once against a row tile chosen from the shapes (16
   rows for a decode call, 128 for a chunk), group offsets and the
   visits' groups as scalar prefetch; ``jax.lax.ragged_dot`` elsewhere.
 
-All five dispatch through the ONE shared gate below: :func:`pallas_enabled`
+All six dispatch through the ONE shared gate below: :func:`pallas_enabled`
 (env-var opt-in/out, TPU-backend default) and :func:`pallas_interpret`
 (off-TPU kernels run in Pallas interpret mode — tests only).  The gate
 lives HERE, above the kernel imports, so the kernels can import it from

@@ -182,7 +182,8 @@ def _pipelined_block_bytes(program: str, *, head_dim: int,
                            n_embd: Optional[int] = None,
                            adapter_rank: Optional[int] = None,
                            rows: int = QROWS, group: int = 1,
-                           q_tile: int = QROWS) -> int:
+                           q_tile: int = QROWS,
+                           v_lanes: Optional[int] = None) -> int:
     """VMEM bytes one grid step of ``program`` pins — the quantity the
     compiler's refusal is about: its operand and output blocks, double
     buffered.  Activations count as f32 (the widest the engine feeds);
@@ -195,8 +196,20 @@ def _pipelined_block_bytes(program: str, *, head_dim: int,
     pool's dtype, on the int8 tier the two scale planes of every head
     (see :func:`_paged_attn_kernel`), and the f32 scratch ``[group,
     q_tile, .]`` of the online softmax, which grows with the same two
-    numbers and is therefore counted here (once: it is not pipelined)."""
+    numbers and is therefore counted here (once: it is not pipelined).
+
+    The LATENT shape (``v_lanes`` given: one shared row of ``head_dim``
+    lanes a position whose first ``v_lanes`` are also the values) pins q
+    ``[q_tile, head_dim]`` and out ``[q_tile, v_lanes]`` in the pool's dtype
+    (they feed the MXU as they are) and ONE block, there being no V."""
     f32 = jnp.float32
+    if v_lanes is not None:
+        blocks = (_tile_bytes(q_tile, head_dim, kv_dtype)
+                  + _tile_bytes(q_tile, v_lanes, kv_dtype)
+                  + _tile_bytes(block_size, head_dim, kv_dtype))
+        scratch = (_tile_bytes(q_tile, v_lanes, f32)
+                   + 2 * _tile_bytes(q_tile, 128, f32))
+        return 2 * blocks + scratch
     if program in ("decode", "prefill"):
         blocks = 2 * group * _tile_bytes(q_tile, head_dim, f32)  # q, out
         blocks += 2 * group * _tile_bytes(block_size, head_dim, kv_dtype)
@@ -233,8 +246,8 @@ def _head_groups(heads: Optional[int], head_dim: int) -> list:
 
 
 def _step_shape(program: str, *, heads: int, head_dim: int,
-                block_size: int, kv_dtype, t: int, rep: int = 1
-                ) -> Tuple[int, int]:
+                block_size: int, kv_dtype, t: int, rep: int = 1,
+                v_lanes: Optional[int] = None) -> Tuple[int, int]:
     """THE rule for what one grid step of an attention program holds:
     ``(head group, query tile)``, from shapes and the pool's dtype alone.
     ``heads`` are the pool's (K/V) heads; where ``rep`` query heads share
@@ -249,8 +262,14 @@ def _step_shape(program: str, *, heads: int, head_dim: int,
     widest group of :func:`_head_groups` that fits beside that tile: the
     pool keeps a physical block's heads side by side in its rows, so a
     group is one copy.  A geometry nothing fits gets the narrowest step,
-    which :func:`supports_paged_attention` refuses."""
-    t8 = -(-t // QROWS) * QROWS
+    which :func:`supports_paged_attention` refuses.
+
+    In the latent shape (``v_lanes``; the decode program's) the ``rep``
+    query heads of the ONE shared row are the tile's rows, so the positions
+    pad only as far as ``rep`` times them is whole sublanes: a decode
+    call's one position of 32 heads is a tile of 32 rows, not of 256."""
+    unit = QROWS // math.gcd(QROWS, rep) if v_lanes is not None else QROWS
+    t8 = -(-t // unit) * unit
     tiles = [t8]
     if program == "prefill":
         tiles += [QROWS << i for i in reversed(range(t8.bit_length()))
@@ -261,7 +280,7 @@ def _step_shape(program: str, *, heads: int, head_dim: int,
         return _pipelined_block_bytes(
             program, head_dim=head_dim, block_size=block_size,
             kv_dtype=kv_dtype, n_embd=heads * head_dim, group=group,
-            q_tile=rep * q_tile) <= VMEM_BLOCK_BUDGET
+            q_tile=rep * q_tile, v_lanes=v_lanes) <= VMEM_BLOCK_BUDGET
 
     q_tile = next((qt for qt in tiles if fits(groups[-1], qt)), tiles[-1])
     group = next((g for g in groups if fits(g, q_tile)), groups[-1])
@@ -270,13 +289,15 @@ def _step_shape(program: str, *, heads: int, head_dim: int,
 
 def grid_steps(program: str, rows: int, heads: int, nbps: int, t: int,
                head_dim: int, block_size: int,
-               kv_dtype, kv_heads: Optional[int] = None
+               kv_dtype, kv_heads: Optional[int] = None,
+               v_lanes: Optional[int] = None
                ) -> Tuple[int, int, int, int]:
     """The grid of one attention call, ``(rows, head groups, query tiles,
     logical blocks)``: ``program`` "decode" or "prefill" over ``rows``
     block-table rows of ``nbps`` blocks, ``t`` query rows each, ``heads``
     query heads over ``kv_heads`` K/V heads (as many where not given; the
-    groups are groups of K/V heads).  Its
+    groups are groups of K/V heads; ``v_lanes`` the latent shape, whose
+    ``kv_heads`` is 1).  Its
     product is the grid steps the call pays (each has a fixed cost of a
     quarter to a third of a microsecond on a v5e whatever it holds);
     :func:`_attn_pallas_call` builds its ``grid=`` from this and nothing
@@ -284,7 +305,7 @@ def grid_steps(program: str, rows: int, heads: int, nbps: int, t: int,
     kv_heads = kv_heads or heads
     group, q_tile = _step_shape(program, heads=kv_heads, head_dim=head_dim,
                                 block_size=block_size, kv_dtype=kv_dtype,
-                                t=t, rep=heads // kv_heads)
+                                t=t, rep=heads // kv_heads, v_lanes=v_lanes)
     return rows, kv_heads // group, -(-t // q_tile), nbps
 
 
@@ -293,7 +314,8 @@ def supports_paged_attention(*, head_dim: int, block_size: int,
                              program: str = "decode",
                              n_embd: Optional[int] = None,
                              adapter_rank: Optional[int] = None,
-                             rows: int = QROWS) -> bool:
+                             rows: int = QROWS,
+                             v_lanes: Optional[int] = None) -> bool:
     """THE kernel-eligibility predicate (the ``supports_flash`` pattern),
     PER PROGRAM: every dispatch site must consult it so the fallback
     condition can never drift from a kernel's real constraints.  True
@@ -313,7 +335,8 @@ def supports_paged_attention(*, head_dim: int, block_size: int,
     it bounds ``n_embd`` (the [TRUST_TILE, n_embd] head tile); for the
     adapter gather ``rows x n_embd`` (``rows`` = the most query rows one
     call carries, the prefill chunk).  ``verify`` and ``adapter`` need
-    ``n_embd``; ``adapter`` a positive ``adapter_rank``.
+    ``n_embd``; ``adapter`` a positive ``adapter_rank``.  ``v_lanes``
+    asks about the latent shape (``head_dim`` then the shared row's lanes).
 
     Interpret mode (CPU tests) has no such limit — only sanity bounds —
     so the equality pins run at the small geometries the test pools
@@ -336,11 +359,12 @@ def supports_paged_attention(*, head_dim: int, block_size: int,
     return _pipelined_block_bytes(
         program, head_dim=head_dim, block_size=block_size,
         kv_dtype=kv_dtype, n_embd=n_embd, adapter_rank=adapter_rank,
-        rows=rows, group=group) <= VMEM_BLOCK_BUDGET
+        rows=rows, group=group, v_lanes=v_lanes) <= VMEM_BLOCK_BUDGET
 
 
 def resolve_attn_impl(requested: str, *, head_dim: int, block_size: int,
-                      kv_dtype, n_embd: Optional[int] = None) -> str:
+                      kv_dtype, n_embd: Optional[int] = None,
+                      v_lanes: Optional[int] = None) -> str:
     """Resolve the engine's ``attn_impl`` knob ONCE, at construction —
     never inside a traced program — to the path its compiled programs
     will bake in: ``"pallas"`` (compiled Mosaic, TPU), ``"interpret"``
@@ -375,7 +399,8 @@ def resolve_attn_impl(requested: str, *, head_dim: int, block_size: int,
                            or pallas_interpret()) else "pallas"
     if supports_paged_attention(head_dim=head_dim, block_size=block_size,
                                 kv_dtype=kv_dtype, n_embd=n_embd,
-                                interpret=(mode == "interpret")):
+                                interpret=(mode == "interpret"),
+                                v_lanes=v_lanes):
         return mode
     detail = (
         f"head_dim={head_dim}, block_size={block_size}, "
@@ -400,7 +425,8 @@ def resolve_attn_impls(requested: str, *, head_dim: int, block_size: int,
                        adapter_rank: Optional[int] = None,
                        rows: int = QROWS,
                        satellites: Tuple[str, ...] = ("prefill", "verify",
-                                                      "adapter")) -> dict:
+                                                      "adapter"),
+                       v_lanes: Optional[int] = None) -> dict:
     """Resolve the WHOLE serving-kernel tier at construction: one impl
     per program in :data:`PAGED_PROGRAMS` (of the satellite programs,
     those in ``satellites``: an engine that can never dispatch one, a
@@ -421,7 +447,7 @@ def resolve_attn_impls(requested: str, *, head_dim: int, block_size: int,
     chunk, or the verify window over every slot)."""
     decode = resolve_attn_impl(requested, head_dim=head_dim,
                                block_size=block_size, kv_dtype=kv_dtype,
-                               n_embd=n_embd)
+                               n_embd=n_embd, v_lanes=v_lanes)
     impls = {p: "jnp" for p in PAGED_PROGRAMS}
     impls["decode"] = decode
     if decode == "jnp":
@@ -433,7 +459,8 @@ def resolve_attn_impls(requested: str, *, head_dim: int, block_size: int,
         if supports_paged_attention(
                 head_dim=head_dim, block_size=block_size,
                 kv_dtype=kv_dtype, interpret=interp, program=program,
-                n_embd=n_embd, adapter_rank=adapter_rank, rows=rows):
+                n_embd=n_embd, adapter_rank=adapter_rank, rows=rows,
+                v_lanes=v_lanes):
             impls[program] = decode
         else:
             logger.warning(
@@ -488,8 +515,9 @@ def _heads_from_lanes(block_ref, group: int) -> jax.Array:
 
 
 def _paged_attn_kernel(table_ref, start_ref, jmax_ref, layer_ref, q_ref,
-                       k_ref, v_ref, *rest, scale: float, bsz: int, qt: int,
-                       group: int, quantized: bool, rep: int = 1):
+                       k_ref, *rest, scale: float, bsz: int, qt: int,
+                       group: int, quantized: bool, rep: int = 1,
+                       v_lanes: Optional[int] = None):
     """One (row, head group, query tile, logical block) grid step of the
     online softmax: ``group`` heads of ONE physical block against ``qt``
     query rows, the heads a batch dimension of both products (per head
@@ -506,10 +534,22 @@ def _paged_attn_kernel(table_ref, start_ref, jmax_ref, layer_ref, q_ref,
     of each (row, query tile) — the ragged early-exit bound: tile
     ``ti``'s causal window ends at its own last query, so an early tile
     of a long chunk streams a fraction of the blocks the chunk touches).
-    ``rest`` is ``(ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref)`` on the
-    int8 tier and the last four otherwise: the scale operands exist only
-    when there are scales."""
-    ks_ref, vs_ref = rest[:2] if quantized else (None, None)
+    ``rest`` is ``(v_ref, ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref)``
+    on the int8 tier and ``(v_ref, ...)`` with the last four otherwise:
+    the scale operands exist only when there are scales.
+
+    The LATENT shape (``v_lanes`` given; one shared row a position, so
+    ``group`` is 1 and the ``rep`` query heads are the rows): there is no
+    ``v_ref``, the values being the first ``v_lanes`` lanes of the SAME
+    block the scores were taken against, so ONE copy feeds both products;
+    and both products take their operands in the pool's dtype (the MXU's
+    own in bfloat16, accumulated in float32) instead of upcasting them:
+    the step is ``[rep * qt, lanes] x [bsz, lanes]^T`` then ``[rep * qt,
+    bsz] x [bsz, v_lanes]``, three times the operations of a per-head
+    K/V pair, and float32 operands would cost several passes each."""
+    latent = v_lanes is not None
+    v_ref = None if latent else rest[0]
+    ks_ref, vs_ref = rest[1:3] if quantized else (None, None)
     o_ref, acc_ref, m_ref, l_ref = rest[-4:]
     r = pl.program_id(0)
     hg = pl.program_id(1)
@@ -537,8 +577,12 @@ def _paged_attn_kernel(table_ref, start_ref, jmax_ref, layer_ref, q_ref,
             qrow = qrow % qt
         qpos = start_ref[r] + ti * qt + qrow
         visible = (kpos <= qpos)[None]
-        q = q_ref[0].astype(jnp.float32)                 # [g, qt, Dh]
-        k = _heads_from_lanes(k_ref, group)               # [g, bsz, Dh]
+        if latent:
+            q = q_ref[0]                                 # [1, rows, lanes]
+            k = k_ref[0, 0][None]                        # [1, bsz, lanes]
+        else:
+            q = q_ref[0].astype(jnp.float32)             # [g, qt, Dh]
+            k = _heads_from_lanes(k_ref, group)          # [g, bsz, Dh]
         s = _dot(q, k, trans_b=True) * scale             # [g, qt, bsz] f32
         if quantized:
             # Per-(head, position) K scale: constant along the contracted
@@ -559,8 +603,11 @@ def _paged_attn_kernel(table_ref, start_ref, jmax_ref, layer_ref, q_ref,
             # V scale folds into the probabilities before the PV
             # contraction — again the gathered-view algebra, in-register.
             p = _times_head_scales(p, vs_ref, hg * group)
-        v = _heads_from_lanes(v_ref, group)               # [g, bsz, Dh]
-        acc_ref[:] = acc_ref[:] * corr + _dot(p, v)
+        if latent:
+            v = k[:, :, :v_lanes]
+        else:
+            v = _heads_from_lanes(v_ref, group)          # [g, bsz, Dh]
+        acc_ref[:] = acc_ref[:] * corr + _dot(p.astype(v.dtype), v)
         m_ref[:] = jnp.broadcast_to(m_cur, m_ref.shape)
 
     @pl.when(j == jmax)
@@ -574,10 +621,13 @@ def _paged_attn_kernel(table_ref, start_ref, jmax_ref, layer_ref, q_ref,
 
 
 def _attn_pallas_call(program: str, q: jax.Array, pool_k: jax.Array,
-                      pool_v: jax.Array, k_scale: Optional[jax.Array],
+                      pool_v: Optional[jax.Array],
+                      k_scale: Optional[jax.Array],
                       v_scale: Optional[jax.Array], table: jax.Array,
                       start: jax.Array, jmax: jax.Array, layer: jax.Array,
-                      interpret: bool, rep: int = 1) -> jax.Array:
+                      interpret: bool, rep: int = 1,
+                      v_lanes: Optional[int] = None,
+                      scale: Optional[float] = None) -> jax.Array:
     """q [R, H, NT·rep·QT, Dh] x the STACKED pool [L, NB, BLOCK, H·Dh] at
     ``layer`` i32[1] -> out like q, on the grid :func:`grid_steps` gives
     ``program``; ``H`` the pool's heads, each read by ``rep`` query heads
@@ -587,13 +637,16 @@ def _attn_pallas_call(program: str, q: jax.Array, pool_k: jax.Array,
     operand of the K/V index map, so no layer is sliced out of the pool
     and none relaid out for the call.  The int8 tier's scales come as
     the LAYER's planes, heads before positions: [NB, H, BLOCK] (see
-    :func:`_attend`)."""
+    :func:`_attend`).  In the latent shape (``v_lanes``; ``pool_v`` None,
+    ``H`` 1) the pool's rows are ``[lanes]`` wide, the output ``[v_lanes]``
+    wide, and the call carries its own name on the device trace."""
     r, h, t_pad, dh = q.shape
     t_pad //= rep
     nbps = table.shape[1]
     bsz = pool_k.shape[2]
+    latent = v_lanes is not None
     grid = grid_steps(program, r, h * rep, nbps, t_pad, dh, bsz,
-                      pool_k.dtype, kv_heads=h)
+                      pool_k.dtype, kv_heads=h, v_lanes=v_lanes)
     group, qt = h // grid[1], t_pad // grid[2]
     if jmax.shape != (r, grid[2]) or qt * grid[2] != t_pad:
         raise ValueError(
@@ -605,9 +658,11 @@ def _attn_pallas_call(program: str, q: jax.Array, pool_k: jax.Array,
             f"{h} x {dh}]")
     quantized = k_scale is not None
     kernel = functools.partial(
-        _paged_attn_kernel, scale=1.0 / math.sqrt(dh), bsz=bsz, qt=qt,
-        group=group, quantized=quantized, rep=rep,
+        _paged_attn_kernel, bsz=bsz, qt=qt, group=group,
+        scale=1.0 / math.sqrt(dh) if scale is None else scale,
+        quantized=quantized, rep=rep, v_lanes=v_lanes,
     )
+    out_dh = v_lanes if latent else dh
 
     # Ragged early exit at the DMA level: logical block j of (row r, tile
     # ti) maps to physical block table[r, min(j, jmax[r, ti])] of the
@@ -626,9 +681,11 @@ def _attn_pallas_call(program: str, q: jax.Array, pool_k: jax.Array,
     in_specs = [
         pl.BlockSpec((1, group, rep * qt, dh), q_idx),
         pl.BlockSpec((1, 1, bsz, group * dh), kv_idx),
-        pl.BlockSpec((1, 1, bsz, group * dh), kv_idx),
     ]
-    operands = [q, pool_k, pool_v]
+    operands = [q, pool_k]
+    if not latent:
+        in_specs.append(pl.BlockSpec((1, 1, bsz, group * dh), kv_idx))
+        operands.append(pool_v)
     if quantized:
         # Mosaic tiles the last two dims, so a (group, bsz) window over
         # a block's [H, BLOCK] scales lowers only where the group is
@@ -643,9 +700,9 @@ def _attn_pallas_call(program: str, q: jax.Array, pool_k: jax.Array,
         num_scalar_prefetch=4,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, group, rep * qt, dh), q_idx),
+        out_specs=pl.BlockSpec((1, group, rep * qt, out_dh), q_idx),
         scratch_shapes=[
-            pltpu.VMEM((group, rep * qt, dh), jnp.float32),
+            pltpu.VMEM((group, rep * qt, out_dh), jnp.float32),
             pltpu.VMEM((group, rep * qt, 128), jnp.float32),
             pltpu.VMEM((group, rep * qt, 128), jnp.float32),
         ],
@@ -653,8 +710,9 @@ def _attn_pallas_call(program: str, q: jax.Array, pool_k: jax.Array,
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape[:3] + (out_dh,), q.dtype),
         interpret=interpret,
+        name="latent_decode" if latent else None,
     )(table, start, jmax, layer, *operands)
 
 
@@ -685,16 +743,38 @@ def _paged_prefill_call(q: jax.Array, pool_k: jax.Array,
                              table, start, jmax, layer, interpret, rep)
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("interpret", "rep", "v_lanes", "scale"))
+def _latent_decode_call(q: jax.Array, pool: jax.Array, table: jax.Array,
+                        start: jax.Array, jmax: jax.Array, layer: jax.Array,
+                        interpret: bool, rep: int, v_lanes: int,
+                        scale: Optional[float]) -> jax.Array:
+    """The decode program's call in the latent shape, under a name of its
+    own on the device trace (the benchmark's latent readers find it by).
+    The chunk program's latent attention is another kernel, the expanded
+    form (``ops/latent_attention.py``)."""
+    return _attn_pallas_call("decode", q, pool, None, None, None, table,
+                             start, jmax, layer, interpret, rep, v_lanes,
+                             scale)
+
+
 _ATTN_CALLS = {"decode": _paged_attn_call, "prefill": _paged_prefill_call}
 
 
 def _attend(program: str, q: jax.Array, pool_k: jax.Array,
-            pool_v: jax.Array, table: jax.Array, start: jax.Array,
+            pool_v: Optional[jax.Array], table: jax.Array, start: jax.Array,
             layer: jax.Array, k_scale: Optional[jax.Array],
             v_scale: Optional[jax.Array],
-            interpret: Optional[bool]) -> jax.Array:
+            interpret: Optional[bool], v_lanes: Optional[int] = None,
+            scale: Optional[float] = None) -> jax.Array:
     """Pad ``q`` to ``program``'s query tiles, bound each tile's walk and
     call the kernel."""
+    if (pool_v is None) != (v_lanes is not None):
+        raise ValueError("a pool with no V half is the latent shape and "
+                         "states v_lanes; any other pool has a V half")
+    if v_lanes is not None and program != "decode":
+        raise ValueError("the latent shape is the decode program's; a chunk "
+                         "runs ops.latent_attention")
     r, h, t, dh = q.shape
     bsz = pool_k.shape[2]
     nbps = table.shape[1]
@@ -710,7 +790,8 @@ def _attend(program: str, q: jax.Array, pool_k: jax.Array,
     start = start.astype(jnp.int32)
     layer = jnp.reshape(layer, (1,)).astype(jnp.int32)
     _, qt = _step_shape(program, heads=kv_heads, head_dim=dh,
-                        block_size=bsz, kv_dtype=pool_k.dtype, t=t, rep=rep)
+                        block_size=bsz, kv_dtype=pool_k.dtype, t=t, rep=rep,
+                        v_lanes=v_lanes)
     nt = -(-t // qt)
     # Tile ti's last useful logical block: that of its last REAL query,
     # at start + min((ti+1)·qt, t) − 1 (pad rows compute a finite, masked
@@ -733,7 +814,7 @@ def _attend(program: str, q: jax.Array, pool_k: jax.Array,
         # serving cell; PERF.md section 7 has what else was tried).
         k_scale = k_scale[layer[0]].transpose(0, 2, 1)
         v_scale = v_scale[layer[0]].transpose(0, 2, 1)
-    if rep == 1:
+    if rep == 1 and v_lanes is None:
         out = _ATTN_CALLS[program](q, pool_k, pool_v, k_scale, v_scale,
                                    table, start, jmax, layer,
                                    interpret=interpret)
@@ -742,22 +823,32 @@ def _attend(program: str, q: jax.Array, pool_k: jax.Array,
     # products, tile by tile: [R, KV, rep, NT, QT, Dh] -> [R, KV, NT, rep,
     # QT, Dh] -> [R, KV, NT·rep·QT, Dh], and back.
     def tiled(a, inner, outer):
-        a = a.reshape((r, kv_heads) + inner + (qt, dh))
+        a = a.reshape((r, kv_heads) + inner + (qt, a.shape[-1]))
         return jnp.swapaxes(a, 2, 3).reshape((r, kv_heads) + outer)
 
-    out = _ATTN_CALLS[program](
-        tiled(q, (rep, nt), (nt * rep * qt, dh)), pool_k, pool_v, k_scale,
-        v_scale, table, start, jmax, layer, interpret=interpret, rep=rep)
-    return tiled(out, (nt, rep), (rep * nt * qt, dh)).reshape(
-        r, h, nt * qt, dh)[:, :, :t]
+    rows = tiled(q, (rep, nt), (nt * rep * qt, dh))
+    if v_lanes is not None:
+        out = _latent_decode_call(
+            rows, pool_k, table, start, jmax, layer, interpret=interpret,
+            rep=rep, v_lanes=v_lanes, scale=scale)
+    else:
+        out = _ATTN_CALLS[program](
+            rows, pool_k, pool_v, k_scale, v_scale, table, start, jmax,
+            layer, interpret=interpret, rep=rep)
+    width = out.shape[-1]
+    return tiled(out, (nt, rep), (rep * nt * qt, width)).reshape(
+        r, h, nt * qt, width)[:, :, :t]
 
 
-def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
+def paged_attention(q: jax.Array, pool_k: jax.Array,
+                    pool_v: Optional[jax.Array],
                     table: jax.Array, start: jax.Array, *,
                     layer: jax.Array = 0,
                     k_scale: Optional[jax.Array] = None,
                     v_scale: Optional[jax.Array] = None,
-                    interpret: Optional[bool] = None) -> jax.Array:
+                    interpret: Optional[bool] = None,
+                    v_lanes: Optional[int] = None,
+                    scale: Optional[float] = None) -> jax.Array:
     """Ragged paged-decode attention over layer ``layer`` of the STACKED
     block pool.
 
@@ -775,6 +866,16 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     attends, where the jnp path writes into its gathered view.  Returns
     [R, H, T, Dh] in q's dtype with f32 accumulation throughout.
 
+    THE LATENT SHAPE (latent attention in its absorbed form, which the
+    decode program runs, ``models/decoder.py``): ``pool_v`` None and
+    ``v_lanes`` given.
+    ``pool_k`` [L, NB, BLOCK, lanes] keeps ONE shared row a position;
+    ``q`` [R, H, T, lanes] are the H heads' absorbed queries against it,
+    the scores are ``scale * q . row`` over all the lanes (``scale`` given
+    by the caller: the width the queries were made at is not the row's)
+    and the values are the row's first ``v_lanes`` lanes, so the result is
+    [R, H, T, v_lanes]: one copy of a block feeds both products.
+
     Semantics contract (pinned by tests/test_paged_attention.py against
     :func:`paged_attention_reference` and the jnp serve path): causal
     mask ``kpos <= start+t`` in absolute positions, int8 scales applied
@@ -782,38 +883,44 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     never read — neither compute nor DMA — and no layer but ``layer``
     read at all."""
     return _attend("decode", q, pool_k, pool_v, table, start, layer,
-                   k_scale, v_scale, interpret)
+                   k_scale, v_scale, interpret, v_lanes, scale)
 
 
 def paged_attention_reference(q: jax.Array, pool_k: jax.Array,
-                              pool_v: jax.Array, table: jax.Array,
+                              pool_v: Optional[jax.Array],
+                              table: jax.Array,
                               start: jax.Array, *, layer: jax.Array = 0,
                               k_scale: Optional[jax.Array] = None,
-                              v_scale: Optional[jax.Array] = None
+                              v_scale: Optional[jax.Array] = None,
+                              v_lanes: Optional[int] = None,
+                              scale: Optional[float] = None
                               ) -> jax.Array:
     """The jnp gather semantics the kernel is pinned against — the same
     math models/generate routes through ``_paged_gather`` +
     ``_block_with_cache``, spelled standalone (f32 softmax, full-width
-    mask) so the kernel test does not depend on the transformer block."""
+    mask) so the kernel test does not depend on the transformer block.
+    The latent shape (``pool_v`` None, ``v_lanes``, ``scale``) as
+    :func:`paged_attention` states it: every head reads the ONE shared
+    row, the values its first ``v_lanes`` lanes."""
     r, h, t, dh = q.shape
     if jnp.ndim(start) == 0:
         start = jnp.broadcast_to(start, (r,))
-
-    def gather(pool):                       # -> [R, H, NBPS*BLOCK, X]
-        g = pool[layer][table]              # [R, NBPS, BLOCK, H*X]
-        return g.reshape(r, -1, h, g.shape[-1] // h).transpose(0, 2, 1, 3)
+    if pool_v is None:
+        pool_v = pool_k[..., :v_lanes]
 
     kv_heads = pool_k.shape[-1] // dh
-    if kv_heads != h:
+
+    def gather(pool):                       # -> [R, H, NBPS*BLOCK, X]
+        g = pool[layer][table]              # [R, NBPS, BLOCK, KV*X]
+        g = g.reshape(r, -1, kv_heads, g.shape[-1] // kv_heads) \
+            .transpose(0, 2, 1, 3)
         # Grouped heads: query head i reads K/V head i // (h / kv_heads).
-        def gather(pool):
-            g = pool[layer][table].reshape(r, -1, kv_heads, dh)
-            return jnp.repeat(g.transpose(0, 2, 1, 3), h // kv_heads, axis=1)
+        return g if kv_heads == h else jnp.repeat(g, h // kv_heads, axis=1)
 
     view_k = gather(pool_k).astype(jnp.float32)
     view_v = gather(pool_v).astype(jnp.float32)
     s = jnp.einsum("rhtd,rhkd->rhtk", q.astype(jnp.float32), view_k)
-    s = s / math.sqrt(dh)
+    s = s * (1.0 / math.sqrt(q.shape[-1]) if scale is None else scale)
     if k_scale is not None:
         s = s * gather(k_scale)[:, :, None, :, 0]
     kpos = jnp.arange(view_k.shape[2])[None, None, None, :]

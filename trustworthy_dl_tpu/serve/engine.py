@@ -503,6 +503,15 @@ class ServingEngine:
             "KV slot-pool HBM footprint (values + quant scales)",
             labels=self._rlabel_names,
         ).set(float(kv.pool_bytes), **self._rlabels)
+        #: What of the pool is LATENT rows (one shared row a position, no
+        #: V half): all of it for a description with latent layers, else 0.
+        self.latent_pool_bytes = kv.pool_bytes if kv.v is None else 0
+        _metric(
+            registry.gauge, "tddl_serve_latent_pool_bytes",
+            "Latent-attention rows' HBM footprint (0 where the pool keeps "
+            "per-head K and V)",
+            labels=self._rlabel_names,
+        ).set(float(self.latent_pool_bytes), **self._rlabels)
         state = self.scheduler.state
         self.state_pool_bytes = state.pool_bytes if state is not None else 0
         _metric(
@@ -1506,6 +1515,7 @@ class ServingEngine:
             if sched.prefix_lookups else 0.0
         )
         out["kv_pool_bytes"] = sched.kv.pool_bytes
+        out["latent_pool_bytes"] = self.latent_pool_bytes
         out["state_pool_bytes"] = self.state_pool_bytes
         experts = sched.expert_counters()
         if experts is not None:
@@ -1541,14 +1551,16 @@ class ServingEngine:
         """The expert counters as a summary gives them: the running totals
         and, under ``since_last_summary``, what was counted since the
         summary before this one (the whole run for the first); both go to
-        the registry too."""
+        the registry too.  The device's token counter is an int32 that
+        wraps, so its difference is taken modulo 2**32."""
         seen = self._expert_seen or {
             "held_expert_pairs": [0] * len(now["held_expert_pairs"]),
             "tokens_fed": 0}
         since = {
             "held_expert_pairs": [a - b for a, b in zip(
                 now["held_expert_pairs"], seen["held_expert_pairs"])],
-            "tokens_fed": now["tokens_fed"] - seen["tokens_fed"]}
+            "tokens_fed": (now["tokens_fed"] - seen["tokens_fed"])
+            % (1 << 32)}
         self._expert_seen = now
         first = self.cfg.first_expert
         for scope, counts in (("total", now),

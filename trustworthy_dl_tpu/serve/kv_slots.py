@@ -35,12 +35,25 @@ many requests are live.  Admission/retirement only change the host-side
 refcount bookkeeping are pure host work (SlotAllocator / BlockAllocator
 below).
 
+NOT EVERY CACHED LAYER KEEPS K AND V.  A latent-attention layer
+(``models/decoder.py`` ``"mla"``) keeps ONE row a position, ``c~ || k_r``
+padded to whole 128-lane columns, shared by all its heads, and no values at
+all (they are the row's first lanes): its pool is ONE array
+
+    k: [L_mla, NUM_BLOCKS + 1, BLOCK, latent_lanes],   v: None
+
+on which blocks, tables, the trash block, admission, retirement and
+quarantine work unchanged.  :func:`kv_geometry` and :func:`kv_arrays` say
+which shape a description has; the byte budget, the pool's shape and the
+kernels' eligibility all read them.
+
 A SECOND KIND OF CACHE lives beside the blocks for descriptions whose
 layers keep a state rather than keys and values (``RecurrentState`` below:
 linear-attention state and a short convolution's tail): one row a decode
 slot a layer, indexed by the slot itself, because a state does not grow
 with the sequence.  The pool then has layers only for the softmax attention
-layers, ``[L_attn, NUM_BLOCKS + 1, BLOCK, KV_HEADS·Dh]``.  Unlike a block, a
+layers, ``[L_attn, NUM_BLOCKS + 1, BLOCK, KV_HEADS·Dh]`` (or the latent
+layers, as above).  Unlike a block, a
 state row IS scrubbed: every position reads it, so it is zeroed when its
 slot is admitted and when a quarantined slot is released.
 
@@ -63,13 +76,29 @@ from trustworthy_dl_tpu.models import decoder
 
 
 def kv_geometry(cfg: Any) -> Tuple[int, int, int]:
-    """``(layers that keep keys and values, K/V heads, head width)`` of a
-    model description: every layer and every head of a ``GPT2Config``, the
+    """``(layers that keep rows in the pool, heads a row, head width)`` of
+    a model description: every layer and every head of a ``GPT2Config``, the
     softmax attention layers and their shared K/V heads of a
-    ``models.decoder.DecoderConfig``."""
+    ``models.decoder.DecoderConfig``, or its latent layers with ONE head of
+    ``latent_lanes`` (the shared row)."""
     if isinstance(cfg, decoder.DecoderConfig):
+        if cfg.n_mla_layers:
+            return cfg.n_mla_layers, 1, cfg.latent_lanes
         return cfg.n_attn_layers, cfg.kv_heads, cfg.head_dim
     return cfg.n_layer, cfg.n_head, cfg.n_embd // cfg.n_head
+
+
+def kv_arrays(cfg: Any) -> int:
+    """Arrays of that geometry the pool keeps: K and V, or the latent rows
+    alone."""
+    latent = isinstance(cfg, decoder.DecoderConfig) and cfg.n_mla_layers
+    return 1 if latent else 2
+
+
+def latent_value_lanes(cfg: Any) -> Optional[int]:
+    """The lanes of a latent row that are also its values (the paged
+    kernels' ``v_lanes``); None for a description that keeps K and V."""
+    return cfg.kv_lora_rank if kv_arrays(cfg) == 1 else None
 
 
 def kv_bytes_per_token(cfg: Any, kv_dtype: Optional[Any] = None) -> int:
@@ -77,14 +106,15 @@ def kv_bytes_per_token(cfg: Any, kv_dtype: Optional[Any] = None) -> int:
     allocating — the HBM-budget primitive (a block costs ``block_size``
     of these, a full sequence ``max_seq``).
     int8 counts 1 byte/element plus the 4-byte per-(head, position)
-    scale, K and V each."""
+    scale, K and V each.  A latent layer costs its ONE row, padding
+    included, and no V term."""
     kv_dtype = cfg.dtype if kv_dtype is None else kv_dtype
     layers, kv_heads, dh = kv_geometry(cfg)
     heads = layers * kv_heads
     if kv_dtype == jnp.int8:
-        return 2 * heads * (dh + 4)
+        return kv_arrays(cfg) * heads * (dh + 4)
     itemsize = jnp.zeros((), kv_dtype).dtype.itemsize
-    return 2 * heads * dh * itemsize
+    return kv_arrays(cfg) * heads * dh * itemsize
 
 
 def paged_pool_blocks(cfg: Any, hbm_bytes: int, block_size: int,
@@ -212,10 +242,13 @@ class PagedKV(NamedTuple):
     in ``k_scale``/``v_scale`` ``[L, NUM_BLOCKS + 1, BLOCK, H]`` (the
     values' shape with one number a head) — the pool pages values and
     scales identically, so the equal-HBM ~1.9x
-    capacity win of the int8 tier compounds with paging."""
+    capacity win of the int8 tier compounds with paging.  A description
+    with latent layers keeps its rows in ``k`` ``[L, NUM_BLOCKS + 1, BLOCK,
+    latent_lanes]`` and has ``v`` None (no leaf: nothing is allocated,
+    carried or donated for it)."""
 
     k: jax.Array  # [L, NUM_BLOCKS + 1, BLOCK, H·Dh]
-    v: jax.Array
+    v: Optional[jax.Array]  # None: latent rows, which keep no V half
     k_scale: Optional[jax.Array] = None  # [L, NUM_BLOCKS + 1, BLOCK, H]
     v_scale: Optional[jax.Array] = None
 
@@ -236,7 +269,7 @@ class PagedKV(NamedTuple):
     def pool_bytes(self) -> int:
         """Total HBM the pool holds (values + scales, INCLUDING the trash
         block) — the honest number ``tddl_serve_kv_bytes`` reports."""
-        total = self.k.nbytes + self.v.nbytes
+        total = self.k.nbytes + (0 if self.v is None else self.v.nbytes)
         if self.k_scale is not None:
             total += self.k_scale.nbytes + self.v_scale.nbytes
         return total
@@ -262,6 +295,8 @@ def init_paged_pool(cfg: Any, num_blocks: int, block_size: int,
     kv_dtype = cfg.dtype if kv_dtype is None else kv_dtype
     layers, kv_heads, dh = kv_geometry(cfg)
     shape = (layers, num_blocks + 1, block_size, kv_heads * dh)
+    if kv_arrays(cfg) == 1:
+        return PagedKV(k=jnp.zeros(shape, kv_dtype), v=None)
     if kv_dtype == jnp.int8:
         # Two buffers, not one array twice: the serving programs donate
         # all four pool arrays, and one buffer cannot be donated twice.
@@ -294,8 +329,13 @@ class RecurrentState(NamedTuple):
 
     s: jax.Array              # f32 [L_state, SLOTS, H, dk, dv]
     conv: jax.Array           # f32 [L_state, SLOTS, K - 1, 3·H·dk]
-    expert_pairs: jax.Array   # i32 [L, held]: (token, expert) pairs taken
-    expert_tokens: jax.Array  # i32 []: tokens fed through an expert layer
+    expert_pairs: jax.Array   # i32 [L_expert, held]: (token, expert) pairs
+    #: i32 []: tokens fed through an EXPERT layer, a layer each (a leading
+    #: dense layer feeds none).  It grows by ``expert layers x tokens`` a
+    #: call and WRAPS (after some 500k chunk calls of 1,024 tokens over 4
+    #: layers): it is only ever read as the difference of two summaries,
+    #: which the engine takes modulo 2**32.
+    expert_tokens: jax.Array
 
     @property
     def pool_bytes(self) -> int:
@@ -324,7 +364,7 @@ def init_state_pool(cfg: Any, max_slots: int) -> Optional[RecurrentState]:
         s=jnp.zeros((layers, max_slots, h, d, d), jnp.float32),
         conv=jnp.zeros((layers, max_slots, cfg.conv_size - 1,
                         cfg.conv_channels), jnp.float32),
-        expert_pairs=jnp.zeros((cfg.n_layer, cfg.n_experts_held),
+        expert_pairs=jnp.zeros((cfg.n_expert_layers, cfg.n_experts_held),
                                jnp.int32),
         expert_tokens=jnp.zeros((), jnp.int32))
 

@@ -64,6 +64,7 @@ from trustworthy_dl_tpu.serve.kv_slots import (
     init_paged_pool,
     init_state_pool,
     kv_geometry,
+    latent_value_lanes,
     resolve_prefill_chunk,
     validate_paged_geometry,
     zero_state_rows,
@@ -231,7 +232,9 @@ def _paged_chunk_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
     beside the pool (``kv_slots.RecurrentState``, donated like the pool)
     and ``slot`` i32[] the row of it this chunk reads and writes; the
     chunk's real positions are those up to ``last_idx``.  The updated
-    state is returned last."""
+    state is returned last.  Where its layers keep LATENT rows, ``pool_k``
+    is the one array of them and ``pool_v`` None, in and out (no leaf: none
+    is carried or donated)."""
     if isinstance(cfg, decoder.DecoderConfig):
         valid = (jnp.arange(tokens.shape[0]) <= last_idx)[None, :]
         logits, new_k, new_v, state = decoder.apply_paged(
@@ -280,7 +283,8 @@ def _paged_decode_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
     ``r`` of the call is row ``r`` of the recurrent ``state`` (donated like
     the pool, returned last), and ``active`` bool[MAX_SLOTS] says which
     rows decode this tick: a slot that is free or mid-prefill keeps its
-    state, where a K/V row would go to the trash block."""
+    state, where a K/V row would go to the trash block.  ``pool_v`` is None
+    where the pool keeps latent rows, as in ``_paged_chunk_impl``."""
     if isinstance(cfg, decoder.DecoderConfig):
         logits, new_k, new_v, state = decoder.apply_paged(
             view, tokens[:, None], pool_k, pool_v, state, tables, lengths,
@@ -635,6 +639,7 @@ class PagedBatchingScheduler:
         from trustworthy_dl_tpu.ops import paged_attention as pattn
 
         _, kv_heads, head_dim = kv_geometry(cfg)
+        v_lanes = latent_value_lanes(cfg)
         self.attn_impls = pattn.resolve_attn_impls(
             attn_impl, head_dim=head_dim,
             block_size=block_size,
@@ -642,9 +647,27 @@ class PagedBatchingScheduler:
             n_embd=kv_heads * head_dim,
             adapter_rank=getattr(adapters, "rank", None),
             rows=max(self.chunk, max_slots * (spec_k + 1)),
-            **({"satellites": ("prefill",)} if self.recurrent else {}),
+            **({"satellites": () if v_lanes else ("prefill",),
+                "v_lanes": v_lanes} if self.recurrent else {}),
         )
         self.attn_impl = self.attn_impls["decode"]
+        if v_lanes and self.attn_impl != "jnp":
+            # A chunk of latent attention is a kernel of its own (the
+            # expanded form) with its own eligibility.
+            from trustworthy_dl_tpu.ops import latent_attention as lattn
+
+            if lattn.supports_latent_prefill(
+                    heads=cfg.q_heads, rows=self.chunk,
+                    nope=cfg.qk_nope_head_dim, value=cfg.v_head_dim,
+                    rank=cfg.kv_lora_rank, lanes=cfg.latent_lanes,
+                    block_size=block_size, dtype=self.kv.k.dtype,
+                    interpret=(self.attn_impl == "interpret")):
+                self.attn_impls["prefill"] = self.attn_impl
+            else:
+                logger.warning(
+                    "latent prefill kernel unsupported at chunk %d, block "
+                    "%d; the chunk program falls back to jnp", self.chunk,
+                    block_size)
         self.allocator = SlotAllocator(max_slots)  # decode rows
         self.blocks = BlockAllocator(self.num_blocks)
         self.prefix = (PrefixCache(block_size, self.blocks)
