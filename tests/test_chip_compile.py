@@ -95,13 +95,23 @@ def _paged_args(program, kv_dtype, block=BLOCK, head_dim=DH, heads=H,
     """Argument shapes of ``_paged_attn_call`` (fused decode over every
     slot) or ``_paged_prefill_call`` (one slot's chunk) over the stacked
     pool, ``jmax`` a query tile of the program's own rule; the int8
-    tier's scales are the layer's planes."""
+    tier's scales are the rows' planes wave by wave, as ``_attend`` lays
+    them (``_wave_planes``)."""
     nbps = max_seq // block
     nb = slots * nbps + 1
     pool = S((layers, nb, block, heads * head_dim), kv_dtype)
-    scale = (S((nb, heads, block), jnp.float32)
-             if jnp.dtype(kv_dtype) == jnp.int8 else None)
     r, t = (slots, pa.QROWS) if program == "decode" else (1, CHUNK)
+    scale = None
+    if jnp.dtype(kv_dtype) == jnp.int8:
+        wave = min(nbps, pa._step_shape(
+            program, heads=heads, head_dim=head_dim, block_size=block,
+            kv_dtype=kv_dtype, t=t)[2])
+        scale = jax.eval_shape(
+            lambda s, tbl: pa._wave_planes(
+                s, 0, tbl, wave,
+                pa._copies_its_blocks(block, heads * head_dim)),
+            S((layers, nb, block, heads), jnp.float32),
+            S((r, nbps), jnp.int32))
     tiles = pa.grid_steps(program, r, heads, nbps, t, head_dim, block,
                           kv_dtype)[2]
     return (S((r, heads, t, head_dim), q_dtype), pool, pool, scale, scale,
@@ -361,12 +371,15 @@ def _latent_decode(dev, lanes):
 def test_paged_attention_lowers_in_the_latent_shape(v5e):
     """The ONE paged kernel with no V operand: a decode call's tile is the
     32 heads of one position; the pool of 640 lanes is read where it lies
-    (no temporary), where one of 576 lanes is copied WHOLE into a padded
+    (no temporary), where one of 576 lanes (which the step cannot copy out
+    of HBM itself, so the grid walks it) is copied WHOLE into a padded
     layout first: why the pool's rows are padded to whole lane columns."""
     grid, pool, compiled = _latent_decode(v5e, LLANES)
     assert grid == (LSLOTS, 1, 1, 128)
     assert "latent_decode" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    assert pa._copies_its_blocks(LBLOCK, LLANES)
+    assert not pa._copies_its_blocks(LBLOCK, 576)
     _, narrow, copied = _latent_decode(v5e, 576)
     assert copied.memory_analysis().temp_size_in_bytes >= narrow.size * 2
 
@@ -571,6 +584,18 @@ _ADMITTED = [
 _REFUSED = [
     ("decode", jnp.float32, 4096, 512), ("prefill", jnp.bfloat16, 8192, 512),
 ]
+# Pools the step cannot copy out of HBM itself, so that the grid walks
+# them: a block off the 8 sublanes, rows off the 128 lanes (12 heads of 80,
+# 25 of 64, the 3 of 64 a narrow tensor-parallel shard keeps), with the int8
+# tier's planes among them; and beside them blocks of 24 out of rows of 640
+# lanes, which the step walks three to the wave's tile.
+_GRID_WALKED = [
+    ("decode", jnp.float32, 12, 80), ("prefill", jnp.bfloat16, 16, 80),
+    ("decode", jnp.bfloat16, 16, 64, 25), ("prefill", jnp.int8, 16, 64, 3),
+    ("decode", jnp.int8, 32, 80),
+]
+_STEP_WALKED = [("decode", jnp.float32, 24, 80, 8),
+                ("prefill", jnp.int8, 32, 64, 6)]
 
 
 def _geometry_id(case):
@@ -601,6 +626,26 @@ def test_refused_geometry_is_refused_by_both(v5e, case):
         _compile(_PAGED_CALL[program], v5e,
                  *_paged_args(program, kv_dtype, block, head_dim,
                               max_seq=block), interpret=False)
+
+
+@pytest.mark.parametrize("case", _GRID_WALKED + _STEP_WALKED,
+                         ids=_geometry_id)
+def test_either_walk_lowers(v5e, case):
+    """A pool the step cannot copy lowers with the walk in the grid (four
+    grid dimensions, no copy of the step's own), any other with the walk in
+    the step (three, and its DMAs)."""
+    program, kv_dtype, block, head_dim, *heads = case
+    (heads,) = heads or (H,)
+    in_step = pa._copies_its_blocks(block, heads * head_dim)
+    assert in_step == (case in _STEP_WALKED)
+    assert pa.supports_paged_attention(
+        head_dim=head_dim, block_size=block, kv_dtype=kv_dtype,
+        interpret=False, program=program, n_embd=heads * head_dim)
+    args = _paged_args(program, kv_dtype, block, head_dim, heads=heads)
+    _compile(_PAGED_CALL[program], v5e, *args, interpret=False)
+    kernel = str(jax.make_jaxpr(
+        lambda *a: _PAGED_CALL[program](*a, interpret=False))(*args))
+    assert ("dma_start" in kernel) == in_step
 
 
 def test_verify_and_adapter_rules_match_the_compiler(v5e):

@@ -147,6 +147,62 @@ def test_the_paged_kernel_at_grouped_heads_is_the_gathered_path(
         [:2])) < ATTN_TOL
 
 
+@pytest.mark.parametrize("heads,kv_heads,dh", [(8, 2, 64), (4, 1, 128),
+                                               (6, 6, 64)])
+def test_the_walk_at_grouped_heads_ends_where_the_row_does(heads, kv_heads,
+                                                           dh):
+    """The walk inside the step with the query heads of a K/V head as rows
+    of one product (pools of whole 128-lane rows): a wave is the rule's, 64
+    or 128 blocks of 8, the table two and a half waves; rows of length 0
+    (all trash: one wave all the same), 1, exactly a wave, one position
+    into the second, and the full table."""
+    bsz = 8
+    assert pa._copies_its_blocks(bsz, kv_heads * dh)
+    wave = pa._step_shape("decode", heads=kv_heads, head_dim=dh,
+                          block_size=bsz, kv_dtype="float32", t=1,
+                          rep=heads // kv_heads)[2]
+    assert wave in (64, 128)
+    nbps = 5 * wave // 2
+    lengths = [0, 1, wave * bsz, wave * bsz + 1, nbps * bsz]
+    rng = np.random.default_rng(heads)
+    pool_k, pool_v = (jnp.asarray(rng.normal(
+        size=(2, 31, bsz, kv_heads * dh)), jnp.float32) for _ in range(2))
+    table = np.asarray(1 + rng.integers(0, 30, size=(5, nbps)), np.int32)
+    table[0] = 0
+    table = jnp.asarray(table)
+    start = jnp.asarray([max(n - 1, 0) for n in lengths], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(5, heads, 1, dh)), jnp.float32)
+    want = pa.paged_attention_reference(q, pool_k, pool_v, table, start,
+                                        layer=1)
+    got = pa.paged_attention(q, pool_k, pool_v, table, start, layer=1,
+                             interpret=True)
+    assert float(jnp.max(jnp.abs(got - want))) < 2 * ATTN_TOL
+    assert [pa.walked_blocks("decode", n, heads, nbps, 1, dh, bsz, "float32",
+                             kv_heads=kv_heads) for n in lengths] \
+        == [wave, wave, wave, 2 * wave, 3 * wave]
+
+
+def test_walked_blocks_at_the_long_document_cell_by_hand():
+    """64 query heads over 8 K/V heads of 128, blocks of 64, 128 a row: a
+    wave is 8 blocks.  A decode row of 4,096 positions holds 64 blocks and
+    walks 8 waves = 64; one of 4,097 holds 65 and walks 9 waves = 72, as one
+    of 4,316 (68 blocks) does.  A chunk of 1,024 from position 2,048 goes in
+    four tiles of 256 queries whose walks end at blocks 35, 39, 43 and 47:
+    5 + 5 + 6 + 6 waves = 176 blocks for the 48 that hold what the chunk
+    sees (each tile walks its own causal window: an early tile ends a wave
+    before the last)."""
+    bf16 = jnp.bfloat16
+    assert pa._step_shape("decode", heads=8, head_dim=128, block_size=64,
+                          kv_dtype=bf16, t=1, rep=8) == (8, 8, 8)
+    assert pa._step_shape("prefill", heads=8, head_dim=128, block_size=64,
+                          kv_dtype=bf16, t=1024, rep=8) == (1, 256, 8)
+    kw = dict(kv_heads=8)
+    assert [pa.walked_blocks("decode", n, 64, 128, 1, 128, 64, bf16, **kw)
+            for n in (4096, 4097, 4316, 0)] == [64, 72, 72, 8]
+    assert pa.walked_blocks("prefill", (2048, 1024), 64, 128, 1024, 128, 64,
+                            bf16, **kw) == (5 + 5 + 6 + 6) * 8
+
+
 def test_grid_steps_counts_groups_of_kv_heads():
     """The eight positional arguments the benchmark's reader passes, and the
     K/V head count as a keyword that defaults to the query heads."""

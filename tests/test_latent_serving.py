@@ -230,6 +230,42 @@ def test_the_latent_decode_kernel_is_the_gathered_reference(rows, starts):
                    jnp.asarray(1), None, None, True, v_lanes, 0.3)
 
 
+def test_the_latent_walk_ends_where_the_row_does():
+    """The walk inside the step in the latent shape (one tile a wave, no
+    V): rows of length 0 (all trash: one wave all the same), 1, exactly a
+    wave, one position into the second, and the full table of two and a
+    half waves, against the gathered reference; and the blocks walked at
+    the long-context cell's geometry by hand (blocks of 256, 128 a row: a
+    row of 9,275 positions holds 37 blocks)."""
+    heads, lanes, v_lanes, bsz = 4, 128, 16, 8
+    wave = pa.WAVE_POSITIONS // bsz
+    nbps = 5 * wave // 2
+    assert pa._step_shape("decode", heads=1, head_dim=lanes, block_size=bsz,
+                          kv_dtype="float32", t=1, rep=heads,
+                          v_lanes=v_lanes) == (1, 2, wave)
+    lengths = [0, 1, wave * bsz, wave * bsz + 1, nbps * bsz]
+    rng = np.random.default_rng(11)
+    pool = jnp.asarray(rng.normal(size=(2, 41, bsz, lanes)), jnp.float32)
+    table = np.asarray(1 + rng.integers(0, 40, size=(5, nbps)), np.int32)
+    table[0] = kv_slots.TRASH_BLOCK
+    start = jnp.asarray([max(n - 1, 0) for n in lengths], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(5, heads, 1, lanes)), jnp.float32)
+    shape = dict(layer=jnp.asarray(1), v_lanes=v_lanes, scale=0.3)
+    want = pa.paged_attention_reference(q, pool, None, jnp.asarray(table),
+                                        start, **shape)
+    got = pa.paged_attention(q, pool, None, jnp.asarray(table), start,
+                             interpret=True, **shape)
+    assert float(jnp.max(jnp.abs(got - want))) < 4e-6
+    kw = dict(kv_heads=1, v_lanes=v_lanes)
+    assert [pa.walked_blocks("decode", n, heads, nbps, 1, lanes, bsz,
+                             "float32", **kw) for n in lengths] \
+        == [wave, wave, wave, 2 * wave, 3 * wave]
+    cell = (32, 128, 1, 640, 256, jnp.bfloat16)
+    w = pa.WAVE_POSITIONS // 256
+    assert [pa.walked_blocks("decode", n, *cell, kv_heads=1, v_lanes=512)
+            for n in (9275, 0, 32768)] == [-(-37 // w) * w, w, 128]
+
+
 @pytest.mark.parametrize("t,start", [(16, 0), (16, 24), (24, 8), (5, 3),
                                      (16, 40)], ids=str)
 def test_the_latent_chunk_kernel_is_the_gathered_reference(t, start):
@@ -267,7 +303,8 @@ def test_the_rules_for_what_a_step_holds_at_the_cell_s_shape():
     assert pa.grid_steps("decode", 64, 32, 128, 1, 640, 256, bf16,
                          kv_heads=1, v_lanes=512) == (64, 1, 1, 128)
     assert pa._step_shape("decode", heads=1, head_dim=640, block_size=256,
-                          kv_dtype=bf16, t=1, rep=32, v_lanes=512) == (1, 1)
+                          kv_dtype=bf16, t=1, rep=32,
+                          v_lanes=512) == (1, 1, pa.WAVE_POSITIONS // 256)
     with_v = pa._pipelined_block_bytes(
         "decode", head_dim=640, block_size=256, kv_dtype=bf16, q_tile=32)
     latent = pa._pipelined_block_bytes(

@@ -161,8 +161,11 @@ def test_grouped_step_matches_reference(case, t):
     r, nbps = 3, -(-(aligned + 5 + t) // bsz) + 1
     nb = r * nbps + 1
     assert pattn._step_shape(program, heads=h, head_dim=dh, block_size=bsz,
-                             kv_dtype=dtype, t=t) == (
+                             kv_dtype=dtype, t=t)[:2] == (
         group, -(-t // pattn.QROWS) * pattn.QROWS)
+    # (The fourth number is the static bound of a row's walk: a loop inside
+    # the step, or the grid's fourth dimension at a pool the step cannot
+    # copy.)
     assert pattn.grid_steps(program, r, h, nbps, t, dh, bsz, dtype) == (
         r, h // group, 1, nbps)
     rng = np.random.default_rng(h * bsz + t)
@@ -251,9 +254,13 @@ def test_chunk_in_query_tiles_matches_reference():
     h, dh, bsz, t, nbps = 2, 128, 4032, 72, 2
     assert pattn._step_shape("prefill", heads=h, head_dim=dh,
                              block_size=bsz, kv_dtype="float32",
-                             t=t) == (1, 32)
+                             t=t) == (1, 32, 1)
     assert pattn.grid_steps("prefill", 1, h, nbps, t, dh, bsz,
                             "float32") == (1, h, 3, nbps)
+    # The first tile's last query stands 9 positions short of block 1: its
+    # walk ends a wave (of one block here) before the other two tiles'.
+    assert pattn.walked_blocks("prefill", (bsz - 40, t), h, nbps, t, dh,
+                               bsz, "float32") == 1 + 2 + 2
     rng = np.random.default_rng(72)
     pool_k, pool_v, _ = _pools(rng, 2, 3, h, bsz, dh, jnp.float32)
     table = jnp.asarray([[2, 1]], jnp.int32)
@@ -266,6 +273,98 @@ def test_chunk_in_query_tiles_matches_reference():
                                           layer=1)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+# The walk inside the step: (pool dtype, block), rows of 2 x 64 = 128
+# lanes.  A wave is WAVE_POSITIONS positions of blocks, whole packed tiles
+# of their dtype or half of one (16 rows of int8, 8 of bfloat16: the
+# blocks are laid end to end after the upcast), so a table of two and a
+# half waves walks one, two or three.
+_WALKS = [("float32", 8), ("bfloat16", 16), ("int8", 32), ("int8", 16),
+          ("bfloat16", 8)]
+
+
+@pytest.mark.parametrize("dtype,bsz", _WALKS, ids=lambda v: str(v))
+def test_the_walk_ends_where_the_row_does(dtype, bsz):
+    """The decode program over rows whose walks differ: a row that holds
+    nothing (an all-trash table row, length 0: one wave all the same)
+    beside live ones of length 1, of exactly one wave, of one position
+    into the second wave, and of the full table (the last wave's tail
+    clamped to the row's last block); then a chunk that starts in the
+    first wave and ends in the second.  Against the gathered reference,
+    and the blocks walked by hand."""
+    h, dh = 2, 64
+    wave = pattn.WAVE_POSITIONS // bsz
+    nbps = 5 * wave // 2
+    kw = dict(heads=h, head_dim=dh, block_size=bsz, kv_dtype=dtype)
+    assert pattn._copies_its_blocks(bsz, h * dh)
+    assert pattn._step_shape("decode", t=1, **kw) == (h, 8, wave)
+    assert pattn._step_shape("prefill", t=64, **kw) == (h, 64, wave)
+    full = nbps * bsz
+    lengths = [0, 1, wave * bsz, wave * bsz + 1, full]
+    rng = np.random.default_rng(bsz)
+    nb = 2 * nbps + 1
+    pool_k, pool_v, scales = _pools(rng, 2, nb, h, bsz, dh, dtype)
+    table = np.asarray(1 + rng.integers(0, nb - 1, size=(5, nbps)), np.int32)
+    table[0] = 0                        # the trash block, all along
+    table = jnp.asarray(table)
+    start = jnp.asarray([max(n - 1, 0) for n in lengths], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(5, h, 1, dh)), jnp.float32)
+    got = pattn.paged_attention(q, pool_k, pool_v, table, start, layer=1,
+                                interpret=True, **scales)
+    ref = pattn.paged_attention_reference(q, pool_k, pool_v, table, start,
+                                          layer=1, **scales)
+    tol = 5e-5 if dtype == "int8" else 2e-5
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=tol, atol=tol)
+    walked = [pattn.walked_blocks("decode", n, h, nbps, 1, dh, bsz, dtype)
+              for n in lengths]
+    assert walked == [wave, wave, wave, 2 * wave, 3 * wave]
+    # the chunk: 64 rows from 40 short of the first wave's end
+    pos = wave * bsz - 40
+    q = jnp.asarray(rng.normal(size=(1, h, 64, dh)), jnp.float32)
+    got = pattn.paged_prefill_attention(
+        q, pool_k, pool_v, table[4:], jnp.asarray(pos, jnp.int32), layer=1,
+        interpret=True, **scales)
+    ref = pattn.paged_attention_reference(
+        q, pool_k, pool_v, table[4:], jnp.asarray(pos, jnp.int32), layer=1,
+        **scales)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=tol, atol=tol)
+    assert pattn.walked_blocks("prefill", (pos, 64), h, nbps, 64, dh, bsz,
+                               dtype) == 2 * wave
+    assert pattn.walked_blocks("prefill", (0, 13), h, nbps, 64, dh, bsz,
+                               dtype) == wave
+
+
+def test_walked_blocks_at_the_serving_cell_by_hand():
+    """GPT-2 large's pool (24 rows of 64 blocks of 16, 20 heads of 64,
+    bfloat16): a wave is 16 blocks in the decode call and 8 beside the
+    chunk's 64 query rows.  A decode row of 800 cached positions holds 50
+    live blocks and walks 4 waves = the whole table's 64; one of 700 walks
+    3 waves = 48 for its 44; a mid-prefill slot's trash row walks one wave,
+    16 blocks for none.  A chunk of 64 rows from position 640 sees 704
+    positions = 44 blocks and walks 6 waves of 8."""
+    kw = (20, 64, 1, 64, 16, "bfloat16")
+    assert pattn._step_shape("decode", heads=20, head_dim=64, block_size=16,
+                             kv_dtype="bfloat16", t=1) == (20, 8, 16)
+    assert pattn._step_shape("prefill", heads=20, head_dim=64,
+                             block_size=16, kv_dtype="bfloat16",
+                             t=64) == (20, 64, 8)
+    assert [pattn.walked_blocks("decode", n, *kw)
+            for n in (800, 700, 0, 1, 1024)] == [64, 48, 16, 16, 64]
+    chunk = (20, 64, 64, 64, 16, "bfloat16")
+    assert pattn.walked_blocks("prefill", (640, 64), *chunk) == 48
+    assert pattn.walked_blocks("prefill", (0, 64), *chunk) == 8
+    # a partial last chunk walks for the rows the program pads it to
+    assert pattn.walked_blocks("prefill", (896, 5), *chunk) == 64
+    # GPT-2 XL's rows (25 heads of 64 = 1,600 lanes, off the 128) are walked
+    # by the grid, a block a step: a row's live blocks and not one more.
+    xl = (25, 64, 1, 64, 16, "bfloat16")
+    assert pattn._step_shape("decode", heads=25, head_dim=64, block_size=16,
+                             kv_dtype="bfloat16", t=1)[2] == 1
+    assert [pattn.walked_blocks("decode", n, *xl)
+            for n in (800, 700, 0, 1, 1024)] == [50, 44, 1, 1, 64]
 
 
 # Every attention geometry the other tests of the tier run or compile:
@@ -298,12 +397,29 @@ def test_rule_divides_heads_and_fits_budget(case, t):
     # (The decode program never tiles: the engine hands it a sublane of
     # query rows at most, models/generate._paged_block_kernel.)
     for program in ("decode", "prefill")[t > pattn.QROWS:]:
-        group, tile = pattn._step_shape(program, heads=h, t=t, **kw)
+        group, tile, wave = pattn._step_shape(program, heads=h, t=t, **kw)
         t8 = -(-t // pattn.QROWS) * pattn.QROWS
 
-        def pinned(g, qt):
+        def pinned(g, qt, w=1):
             return pattn._pipelined_block_bytes(
-                program, n_embd=h * dh, group=g, q_tile=qt, **kw)
+                program, n_embd=h * dh, group=g, q_tile=qt, wave=w, **kw)
+
+        # The wave: a power of two of blocks out of a pool the step can
+        # copy (whatever the pool's dtype: the blocks are laid end to end
+        # in float32), inside the budget and the cap; twice it is not, by
+        # the pinned bytes or by what the kernel makes of a wave (its score
+        # tile and f32 cuts: the other half of VMEM).
+        in_step = pattn._copies_its_blocks(bsz, h * dh)
+        assert wave & (wave - 1) == 0
+        assert wave == 1 or in_step
+        assert pinned(group, tile, wave) <= pattn.VMEM_BLOCK_BUDGET
+        assert wave == 1 or wave * bsz <= pattn.WAVE_POSITIONS
+        span = 2 * wave * bsz
+        made = group * (pattn._tile_bytes(tile, span, jnp.float32)
+                        + 2 * pattn._tile_bytes(span, dh, jnp.float32))
+        assert (not in_step or span > pattn.WAVE_POSITIONS
+                or pinned(group, tile, 2 * wave) > pattn.VMEM_BLOCK_BUDGET
+                or made > pattn.VMEM_LIMIT_BYTES - pattn.VMEM_BLOCK_BUDGET)
 
         assert h % group == 0 and tile % pattn.QROWS == 0
         assert tile == t8 or (program == "prefill" and tile < t8)
@@ -318,7 +434,7 @@ def test_rule_divides_heads_and_fits_budget(case, t):
         assert pattn.grid_steps(program, 7, h, 5, t, dh, bsz, dtype) == (
             7, h // group, tiles, 5)
         assert pattn._step_shape(program, heads=h, t=tiles * tile,
-                                 **kw) == (group, tile)
+                                 **kw) == (group, tile, wave)
 
 
 # --------------------------------------------------------------------------
@@ -326,8 +442,15 @@ def test_rule_divides_heads_and_fits_budget(case, t):
 # --------------------------------------------------------------------------
 
 
+# Two heads of 64: a pool of whole 128-lane rows, which the step walks
+# itself where CFG's 32 lanes are walked by the grid.
+WIDE = gpt2.GPT2Config(vocab_size=157, n_positions=64, n_layer=2, n_embd=128,
+                       n_head=2, dtype=jnp.float32)
+
+
+@pytest.mark.parametrize("cfg", [CFG, WIDE], ids=["grid-walk", "step-walk"])
 @pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
-def test_paged_apply_kernel_vs_jnp_logits_and_pools(params, kv_dtype):
+def test_paged_apply_kernel_vs_jnp_logits_and_pools(params, kv_dtype, cfg):
     """``_apply_with_cache_paged`` with attn_impl="interpret" vs "jnp"
     over identical pools: decode logits agree to f32 epsilon, verify-
     window (all_logits) logits agree, and the pool writes agree to the
@@ -339,9 +462,12 @@ def test_paged_apply_kernel_vs_jnp_logits_and_pools(params, kv_dtype):
     a block boundary."""
     from trustworthy_dl_tpu.serve.kv_slots import init_paged_pool
 
+    assert pattn._copies_its_blocks(8, cfg.n_embd) == (cfg is WIDE)
+    if cfg is WIDE:
+        params = gpt2.init_params(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(2)
     bsz, num_blocks, r, nbps = 8, 12, 3, 4
-    kv = init_paged_pool(CFG, num_blocks, bsz,
+    kv = init_paged_pool(cfg, num_blocks, bsz,
                          kv_dtype=jnp.int8 if kv_dtype == "int8"
                          else jnp.float32)
     # Seed the pool with content so history actually matters.
@@ -365,13 +491,13 @@ def test_paged_apply_kernel_vs_jnp_logits_and_pools(params, kv_dtype):
     table = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8], [9, 10, 11, 12]],
                         jnp.int32)
     lengths = jnp.asarray([1, 11, 26], jnp.int32)
-    view = gen._decode_view(params, CFG)
-    tokens = jnp.asarray(rng.integers(0, CFG.vocab_size, size=(r, 1)),
+    view = gen._decode_view(params, cfg)
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, size=(r, 1)),
                          jnp.int32)
     outs = {}
     for impl in ("jnp", "interpret"):
         outs[impl] = gen._apply_with_cache_paged(
-            view, tokens, *pools, table, lengths, CFG, attn_impl=impl)
+            view, tokens, *pools, table, lengths, cfg, attn_impl=impl)
     np.testing.assert_allclose(np.asarray(outs["jnp"][0]),
                                np.asarray(outs["interpret"][0]),
                                rtol=2e-4, atol=2e-4)
@@ -387,12 +513,12 @@ def test_paged_apply_kernel_vs_jnp_logits_and_pools(params, kv_dtype):
             np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
     # Verify-window shape (the spec_verify program's read): T=4 starting
     # at the pre-draft lengths, all-position logits.
-    tokens_w = jnp.asarray(rng.integers(0, CFG.vocab_size, size=(r, 4)),
+    tokens_w = jnp.asarray(rng.integers(0, cfg.vocab_size, size=(r, 4)),
                            jnp.int32)
     outs_w = {}
     for impl in ("jnp", "interpret"):
         outs_w[impl] = gen._apply_with_cache_paged(
-            view, tokens_w, *pools, table, lengths, CFG,
+            view, tokens_w, *pools, table, lengths, cfg,
             all_logits=True, attn_impl=impl)
     np.testing.assert_allclose(np.asarray(outs_w["jnp"][0]),
                                np.asarray(outs_w["interpret"][0]),
@@ -467,6 +593,16 @@ def test_resolve_and_supports_gate(monkeypatch):
                          (jnp.int8, 16)):
         assert pattn.supports_paged_attention(
             head_dim=64, block_size=block, kv_dtype=dtype, interpret=False)
+    # A pool the step cannot copy out of HBM itself (a block off the 8
+    # sublanes, rows off the 128 lanes: 25 heads of 64) is admitted all the
+    # same: the grid walks it.
+    for block, n_embd in ((12, None), (16, 25 * 64)):
+        assert pattn.supports_paged_attention(
+            head_dim=64, block_size=block, kv_dtype=jnp.float32,
+            interpret=False, n_embd=n_embd)
+    assert not pattn._copies_its_blocks(12, 1280)
+    assert not pattn._copies_its_blocks(16, 25 * 64)
+    assert pattn._copies_its_blocks(16, 1280)
     assert not pattn.supports_paged_attention(
         head_dim=512, block_size=4096, kv_dtype=jnp.float32,
         interpret=False)

@@ -10,32 +10,51 @@ the row's true length, and dequantises the int8 tier by algebra over that
 view.  This kernel makes the stream explicit — the single biggest
 tokens/sec lever ROADMAP item 2 names:
 
-* **one program per block-table row** (grid ``(R, H/g, NT, NBPS)``):
-  the block table and per-row lengths ride as scalar-prefetch operands,
-  so the KV BlockSpec index map resolves ``logical block j -> physical
-  block table[r, j]`` before the DMA is issued — the gather IS the
-  pipeline, no [R, H, S, Dh] view is ever materialised;
+* **one program per block-table row** (grid ``(R, H/g, NT)``): the
+  block table and per-row lengths ride as scalar-prefetch operands, and
+  the step WALKS its row's blocks itself, in a loop whose trip count
+  follows the row's length: it resolves ``logical block j -> physical
+  block table[r, j]`` as it aims each copy (``pltpu.make_async_copy`` out
+  of the pool in HBM into a VMEM tile) — the gather IS the copy, no [R, H,
+  S, Dh] view is ever materialised;
 * **the pool is read where it lies**: the kernels take the STACKED pool
   ``[L, NB, BLOCK, H·Dh]`` (every layer; a position's K, or V, of every
-  head one contiguous row) and the layer as a fourth scalar-prefetch
-  operand: the K/V index map returns ``(layer, table[r, j], 0, head
-  group)``.  On this shape the in-place row write ``pool.at[layer,
-  block, offset]``, the kernel's block and the array's resting layout
-  agree, so a serving program carries the pool through its layer loop in
-  one buffer and one layout and nothing copies it
-  (tests/test_chip_compile.py holds the compiler to that);
-* **a step holds a block's heads**: a physical block's rows keep the
-  heads side by side in the lanes, so one step copies the block for a
+  head one contiguous row) whole, in HBM (``memory_space=pl.ANY``), and
+  the layer as a fourth scalar-prefetch operand: a copy's source is
+  ``pool[layer, table[r, j], :, the head group's lanes]``.  On this shape
+  the in-place row write ``pool.at[layer, block, offset]``, the kernel's
+  copies and the array's resting layout agree, so a serving program
+  carries the pool through its layer loop in one buffer and one layout
+  and nothing copies it (tests/test_chip_compile.py holds the compiler to
+  that);
+* **a pool the step cannot copy is walked by the grid**: a copy out of HBM
+  takes whole (8, 128) tiles, so a block off the 8 sublanes or a pool whose
+  rows are off the 128 lanes (:func:`_copies_its_blocks`: 25 heads of 64,
+  a narrow tensor-parallel shard, a latent row left unpadded) cannot be
+  walked inside the step.  The SAME body then takes its blocks from the
+  grid's own pipeline, which pads the tile: the walk is the grid's fourth
+  dimension ``NBPS``, one block a step, the K/V index map resolves
+  ``(layer, table[r, min(j, jmax)], 0, head group)`` before the copy is
+  issued, and a step past ``jmax`` copies and computes nothing but is a
+  step all the same;
+* **a step holds a wave of blocks' heads**: a physical block's rows keep
+  the heads side by side in the lanes, so a step copies its blocks for a
   GROUP of ``g`` heads (a window of the lanes: whole 128-lane columns,
-  or every head — :func:`_head_groups`), cuts the heads out of the lanes
-  (:func:`_heads_from_lanes`) and runs their products as one batch.  A
-  grid step costs a quarter to a third of a microsecond on a v5e
-  whatever it holds, so the kernels' time is their step count: ``g`` and
-  the query tile come from ONE rule over shapes and the pool's dtype
-  (:func:`_step_shape`: the widest tile, then the widest head group,
-  whose blocks fit :data:`VMEM_BLOCK_BUDGET` — all 20 heads and the
-  chunk's 64 queries at GPT-2 large, one head at blocks of 4,096 x
-  128), and :func:`grid_steps` counts what a call pays;
+  or every head — :func:`_head_groups`), a WAVE of ``W`` blocks at a time
+  into one tile ``[W·BLOCK, g·Dh]`` (the next wave's copies in flight
+  while this one is multiplied), cuts the heads out of the lanes ONCE a
+  wave (:func:`_heads_from_lanes`) and runs their products as one batch
+  over the wave's ``W·BLOCK`` positions: a product of 256 or 512
+  positions fills the MXU's width where one of a block's 16 fills an
+  eighth, and a pass of the loop costs what a grid step costs whatever it
+  holds.  ``g``, the query tile and ``W`` come from ONE rule over shapes
+  and the pool's dtype (:func:`_step_shape`: the widest tile, then the
+  widest head group, then the most blocks, whose tiles fit
+  :data:`VMEM_BLOCK_BUDGET`, the wave held to :data:`WAVE_POSITIONS` — all
+  20 heads, the chunk's 64 queries and 16 blocks of 16 at GPT-2 large,
+  one head and one block at blocks of 4,096 x 128); :func:`grid_steps`
+  gives the grid and the static bound of a walk, :func:`walked_blocks`
+  the blocks a row's walk copies (PERF.md section 6 has the readings);
 * **grouped heads**: where ``rep`` query heads share each K/V head (the
   pool's lanes hold the K/V heads; a query tensor with ``rep`` times as
   many), a step holds a group of K/V heads with the ``rep`` query heads
@@ -46,14 +65,19 @@ tokens/sec lever ROADMAP item 2 names:
 * **int8 streaming**: int8 KV tiles DMA HBM→VMEM at half the bf16 bytes
   (a quarter of f32), upcast in-register, and the per-(head, position)
   scales PagedKV already pages multiply the scores/probabilities exactly
-  where the algebraic jnp path applies them;
+  where the algebraic jnp path applies them; they ride the same waves, a
+  row's scales gathered by its table once a call and laid wave by wave
+  (:func:`_wave_planes`), one copy a plane a wave;
 * **online softmax** (flash-attention style (m, l, acc) accumulators,
   f32 regardless of input dtype);
-* **ragged early exit**: a row with ``start + T`` valid positions streams
-  ``ceil((start+T)/BLOCK)`` blocks and not one more — the index map
-  CLAMPS masked iterations to the row's last useful block (a repeated
-  block index issues no copy, the same bandwidth trick as
-  ``flash_attention``'s causal skip) and ``pl.when`` skips their compute.
+* **ragged early exit**: a row with ``start + T`` valid positions walks
+  ``ceil((start+T)/BLOCK)`` blocks rounded up to whole waves and not one
+  wave more — the loop's trip count is ``jmax // W + 1``, a traced value.
+  The blocks of the last wave past the row's last useful block are that
+  block copied again (never another row's), and the mask in absolute
+  positions hides them with everything else past the row's length; a row
+  that holds nothing (a mid-prefill slot's all-trash row in the decode
+  call) walks one wave.
 
 **Chunked-prefill program** (:func:`paged_prefill_attention`): the
 multi-query-row extension, on the same kernel.  The T chunk rows of a
@@ -61,9 +85,9 @@ slot go in ONE query tile where that fits (the rule above) and else in
 tiles (the grid's ``NT``), attending over the SAME scalar-prefetch block
 tables with the ragged causal mask in absolute positions.  The per-(row,
 tile) last-useful-block bound rides as the third scalar-prefetch
-operand, so an early query tile streams only the KV blocks its causal
-window can see — the flash-attention causal skip applied ACROSS query
-tiles of a paged table.  This replaces ``paged_chunk``'s gathered-view
+operand, so an early query tile's walk ends with the KV blocks its
+causal window can see — the flash-attention causal skip applied ACROSS
+query tiles of a paged table.  This replaces ``paged_chunk``'s gathered-view
 attention (the whole-prompt [R, H, S, Dh] view per chunk per layer).
 
 **Fused speculative-verify tail** (:func:`fused_verify_tail`): the spec
@@ -130,6 +154,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -156,6 +181,11 @@ VMEM_LIMIT_BYTES = 16 << 20
 #: the limit, the rest being the kernel's own scratch and temporaries
 #: (the f32 upcast of a K/V tile, the score tile).
 VMEM_BLOCK_BUDGET = VMEM_LIMIT_BYTES // 2
+#: The most cached positions one wave of a row's walk holds (a wave is that
+#: many positions' worth of physical blocks, :func:`_step_shape`): the one
+#: geometry the VMEM rule leaves a longer wave, the latent shape's blocks of
+#: 256, read slower at 2,048 positions than at this (PERF.md section 6).
+WAVE_POSITIONS = 1024
 
 #: Engine-facing path names.  "auto" resolves through the shared gate;
 #: the resolved value is one of the other three.
@@ -183,40 +213,47 @@ def _pipelined_block_bytes(program: str, *, head_dim: int,
                            adapter_rank: Optional[int] = None,
                            rows: int = QROWS, group: int = 1,
                            q_tile: int = QROWS,
-                           v_lanes: Optional[int] = None) -> int:
+                           v_lanes: Optional[int] = None,
+                           wave: int = 1) -> int:
     """VMEM bytes one grid step of ``program`` pins — the quantity the
     compiler's refusal is about: its operand and output blocks, double
     buffered.  Activations count as f32 (the widest the engine feeds);
     ``rows`` is the query rows of one call of the verify tail or the
     adapter gather.
 
-    A step of the attention programs holds ``group`` heads of ONE
-    physical block and ``q_tile`` query rows: q and out ``[group,
-    q_tile, head_dim]``, K and V ``[group, block_size, head_dim]`` in the
-    pool's dtype, on the int8 tier the two scale planes of every head
-    (see :func:`_paged_attn_kernel`), and the f32 scratch ``[group,
-    q_tile, .]`` of the online softmax, which grows with the same two
-    numbers and is therefore counted here (once: it is not pipelined).
+    A step of the attention programs holds ``group`` heads and ``q_tile``
+    query rows and walks its row's blocks ``wave`` physical blocks at a
+    time: q and out ``[group, q_tile, head_dim]`` (pipelined by the grid),
+    the wave's K and V tiles ``[wave, block_size, group * head_dim]`` in
+    the pool's dtype, two of each (the next wave is copied while this one
+    is multiplied; where the grid walks, its pipeline holds the same two
+    of one block), on the int8 tier likewise the wave's two scale planes
+    ``[heads, wave * block_size]`` of every head (:func:`_wave_planes`), and
+    the f32 scratch ``[group, q_tile, .]`` of the online softmax, which
+    grows with the same two numbers and is therefore counted here (once: it
+    is not pipelined).
 
     The LATENT shape (``v_lanes`` given: one shared row of ``head_dim``
     lanes a position whose first ``v_lanes`` are also the values) pins q
     ``[q_tile, head_dim]`` and out ``[q_tile, v_lanes]`` in the pool's dtype
-    (they feed the MXU as they are) and ONE block, there being no V."""
+    (they feed the MXU as they are) and ONE tile a wave, there being no
+    V."""
     f32 = jnp.float32
     if v_lanes is not None:
         blocks = (_tile_bytes(q_tile, head_dim, kv_dtype)
                   + _tile_bytes(q_tile, v_lanes, kv_dtype)
-                  + _tile_bytes(block_size, head_dim, kv_dtype))
+                  + wave * _tile_bytes(block_size, head_dim, kv_dtype))
         scratch = (_tile_bytes(q_tile, v_lanes, f32)
                    + 2 * _tile_bytes(q_tile, 128, f32))
         return 2 * blocks + scratch
     if program in ("decode", "prefill"):
         blocks = 2 * group * _tile_bytes(q_tile, head_dim, f32)  # q, out
-        blocks += 2 * group * _tile_bytes(block_size, head_dim, kv_dtype)
+        blocks += 2 * wave * group * _tile_bytes(block_size, head_dim,
+                                                 kv_dtype)
         if jnp.dtype(kv_dtype) == jnp.int8:
             # Without n_embd the head count is unknown: the group's.
             heads = n_embd // head_dim if n_embd else group
-            blocks += 2 * _tile_bytes(heads, block_size, f32)
+            blocks += 2 * _tile_bytes(heads, wave * block_size, f32)
         scratch = group * (_tile_bytes(q_tile, head_dim, f32)
                            + 2 * _tile_bytes(q_tile, 128, f32))
         return 2 * blocks + scratch
@@ -245,14 +282,23 @@ def _head_groups(heads: Optional[int], head_dim: int) -> list:
             if heads % g == 0 and (g == heads or (g * head_dim) % 128 == 0)]
 
 
+def _copies_its_blocks(block_size: int, lanes: int) -> bool:
+    """Whether a step can copy a pool's blocks ``[block_size, lanes]`` out
+    of HBM itself, and so walk its row inside the step: Mosaic slices an
+    operand that lies in HBM in whole (8, 128) tiles only, whatever the
+    dtype (tests/test_chip_compile.py has the refusals).  Any other pool is
+    walked by the grid, whose own pipeline pads the tile."""
+    return block_size % QROWS == 0 and lanes % 128 == 0
+
+
 def _step_shape(program: str, *, heads: int, head_dim: int,
                 block_size: int, kv_dtype, t: int, rep: int = 1,
-                v_lanes: Optional[int] = None) -> Tuple[int, int]:
+                v_lanes: Optional[int] = None) -> Tuple[int, int, int]:
     """THE rule for what one grid step of an attention program holds:
-    ``(head group, query tile)``, from shapes and the pool's dtype alone.
-    ``heads`` are the pool's (K/V) heads; where ``rep`` query heads share
-    each of them, a step holds the ``rep`` query heads of every K/V head of
-    its group as ``rep`` times the tile's rows of the same product.
+    ``(head group, query tile, wave)``, from shapes and the pool's dtype
+    alone.  ``heads`` are the pool's (K/V) heads; where ``rep`` query heads
+    share each of them, a step holds the ``rep`` query heads of every K/V
+    head of its group as ``rep`` times the tile's rows of the same product.
 
     The query tile first: the ``t`` query rows of a call, padded to the
     sublane, in ONE tile if the narrowest head group's blocks then fit
@@ -263,6 +309,18 @@ def _step_shape(program: str, *, heads: int, head_dim: int,
     pool keeps a physical block's heads side by side in its rows, so a
     group is one copy.  A geometry nothing fits gets the narrowest step,
     which :func:`supports_paged_attention` refuses.
+
+    Then the WAVE, the physical blocks a pass of the step's walk copies
+    and multiplies at once: the largest power of two whose two tiles of K
+    and of V fit the budget beside that tile and group and whose score
+    tile and f32 cuts fit the other half of VMEM, held to
+    :data:`WAVE_POSITIONS` positions (a caller that knows the table holds
+    it to the row's blocks besides); one block where the grid walks
+    (:func:`_copies_its_blocks`) and, in the latent shape, where the block
+    is not whole sublanes of the pool's dtype: its products take the wave's
+    rows as they lie, and the blocks are then not one tile's rows (the
+    other shapes lay the blocks end to end in float32, whose 8 sublanes
+    every block a step can copy is whole).
 
     In the latent shape (``v_lanes``; the decode program's) the ``rep``
     query heads of the ONE shared row are the tile's rows, so the positions
@@ -276,15 +334,34 @@ def _step_shape(program: str, *, heads: int, head_dim: int,
                   if QROWS << i < t8]
     groups = _head_groups(heads, head_dim)
 
-    def fits(group: int, q_tile: int) -> bool:
+    def fits(group: int, q_tile: int, wave: int = 1) -> bool:
         return _pipelined_block_bytes(
             program, head_dim=head_dim, block_size=block_size,
             kv_dtype=kv_dtype, n_embd=heads * head_dim, group=group,
-            q_tile=rep * q_tile, v_lanes=v_lanes) <= VMEM_BLOCK_BUDGET
+            q_tile=rep * q_tile, v_lanes=v_lanes,
+            wave=wave) <= VMEM_BLOCK_BUDGET
 
     q_tile = next((qt for qt in tiles if fits(groups[-1], qt)), tiles[-1])
     group = next((g for g in groups if fits(g, q_tile)), groups[-1])
-    return group, q_tile
+
+    def wave_fits(wave: int) -> bool:
+        # Beside the pinned tiles, what the kernel makes of a wave: its
+        # score tile and, per head, the f32 cut of its K and of its V.
+        span = wave * block_size
+        made = group * _tile_bytes(rep * q_tile, span, jnp.float32)
+        if v_lanes is None:
+            made += 2 * group * _tile_bytes(span, head_dim, jnp.float32)
+        return (fits(group, q_tile, wave)
+                and made <= VMEM_LIMIT_BYTES - VMEM_BLOCK_BUDGET)
+
+    wave = 1
+    packed = 1 if v_lanes is None else 32 // jnp.dtype(kv_dtype).itemsize
+    if (block_size % max(QROWS, packed) == 0
+            and _copies_its_blocks(block_size, heads * head_dim)):
+        while (2 * wave * block_size <= WAVE_POSITIONS
+               and wave_fits(2 * wave)):
+            wave *= 2
+    return group, q_tile, wave
 
 
 def grid_steps(program: str, rows: int, heads: int, nbps: int, t: int,
@@ -292,21 +369,62 @@ def grid_steps(program: str, rows: int, heads: int, nbps: int, t: int,
                kv_dtype, kv_heads: Optional[int] = None,
                v_lanes: Optional[int] = None
                ) -> Tuple[int, int, int, int]:
-    """The grid of one attention call, ``(rows, head groups, query tiles,
+    """The steps of one attention call, ``(rows, head groups, query tiles,
     logical blocks)``: ``program`` "decode" or "prefill" over ``rows``
     block-table rows of ``nbps`` blocks, ``t`` query rows each, ``heads``
     query heads over ``kv_heads`` K/V heads (as many where not given; the
     groups are groups of K/V heads; ``v_lanes`` the latent shape, whose
-    ``kv_heads`` is 1).  Its
-    product is the grid steps the call pays (each has a fixed cost of a
-    quarter to a third of a microsecond on a v5e whatever it holds);
-    :func:`_attn_pallas_call` builds its ``grid=`` from this and nothing
-    else, so the count cannot drift from the kernel."""
+    ``kv_heads`` is 1).  The first three are the call's ``grid=`` where the
+    step walks its row itself (:func:`_attn_pallas_call` takes them from the
+    same rule) and the fourth is the STATIC BOUND of that walk, the blocks a
+    full row holds: the walk is a loop inside the step whose trip count
+    follows the row's length (:func:`walked_blocks` counts what it copies),
+    so a block past it costs nothing.  Where the grid walks
+    (:func:`_copies_its_blocks`) all four are the grid."""
     kv_heads = kv_heads or heads
-    group, q_tile = _step_shape(program, heads=kv_heads, head_dim=head_dim,
-                                block_size=block_size, kv_dtype=kv_dtype,
-                                t=t, rep=heads // kv_heads, v_lanes=v_lanes)
+    group, q_tile, _ = _step_shape(
+        program, heads=kv_heads, head_dim=head_dim, block_size=block_size,
+        kv_dtype=kv_dtype, t=t, rep=heads // kv_heads, v_lanes=v_lanes)
     return rows, kv_heads // group, -(-t // q_tile), nbps
+
+
+def _tile_bounds(start, t: int, q_tile: int, block_size: int, nbps: int):
+    """``jmax[..., ti]``, the last logical block the walk of query tile
+    ``ti`` needs, for rows whose first query stands at ``start`` (an
+    integer array): that of the tile's last REAL query, at ``start +
+    min((ti+1)·q_tile, t) − 1`` (pad rows compute a finite, masked
+    attention nobody reads), clipped into the table: a padded prefill chunk
+    can extend past the slot's allocation — those query rows are discarded
+    by the caller, and the mask keeps them finite."""
+    tiles = np.arange(-(-t // q_tile), dtype=np.int32)
+    last = np.minimum((tiles + 1) * q_tile, t) - 1
+    return jnp.clip((start[..., None] + last) // block_size, 0, nbps - 1)
+
+
+def walked_blocks(program: str, work, heads: int, nbps: int, t: int,
+                  head_dim: int, block_size: int, kv_dtype,
+                  kv_heads: Optional[int] = None,
+                  v_lanes: Optional[int] = None) -> int:
+    """The blocks the walk of ONE block-table row copies in a call whose
+    shapes are :func:`grid_steps`'s: waves times the wave's blocks, summed
+    over the row's query tiles, from the rule and the bounds the kernel
+    uses.  ``work`` is the row's cached length, the call's ``t`` new
+    positions included (``program`` "decode"; 0 a row that holds nothing,
+    whose walk is one wave all the same), or ``(pos, rows)``, the chunk's
+    first position and its real rows (``"prefill"``: the program pads the
+    chunk to ``t`` rows and walks for all of them).  In whole blocks: each
+    head group copies its window of the lanes of every one, the groups
+    together the block.  Over the blocks that hold the row's live positions
+    this is what the schedule costs the memory, 1 at best."""
+    kv_heads = kv_heads or heads
+    _, q_tile, wave = _step_shape(
+        program, heads=kv_heads, head_dim=head_dim, block_size=block_size,
+        kv_dtype=kv_dtype, t=t, rep=heads // kv_heads, v_lanes=v_lanes)
+    wave = min(wave, nbps)
+    start = work[0] if program == "prefill" else max(int(work) - t, 0)
+    jmax = np.asarray(_tile_bounds(np.asarray(start, np.int32), t, q_tile,
+                                   block_size, nbps))
+    return int(np.sum(jmax // wave + 1)) * wave
 
 
 def supports_paged_attention(*, head_dim: int, block_size: int,
@@ -328,10 +446,13 @@ def supports_paged_attention(*, head_dim: int, block_size: int,
     :data:`VMEM_BLOCK_BUDGET`.  For the attention programs the step
     asked about is the narrowest :func:`_step_shape` can fall to, the
     narrowest head group the pool's rows allow (:func:`_head_groups`: one
-    head only where a head is whole 128-lane columns) and one sublane of
-    queries: that bounds ``block_size x head_dim`` in the POOL's storage
-    dtype (and, on the int8 tier, the scale planes of ``n_embd //
-    head_dim`` heads); what fits beyond it only widens the step.  For the verify tail
+    head only where a head is whole 128-lane columns), one sublane of
+    queries and a wave of one block: that bounds ``block_size x
+    head_dim`` in the POOL's storage dtype (and, on the int8 tier, the
+    scale planes of ``n_embd // head_dim`` heads); what fits beyond it
+    only widens the step and lengthens the wave, and a pool the step
+    cannot copy itself is walked by the grid (:func:`_copies_its_blocks`),
+    at the same bytes.  For the verify tail
     it bounds ``n_embd`` (the [TRUST_TILE, n_embd] head tile); for the
     adapter gather ``rows x n_embd`` (``rows`` = the most query rows one
     call carries, the prefill chunk).  ``verify`` and ``adapter`` need
@@ -483,14 +604,46 @@ def _dot(a: jax.Array, b: jax.Array, trans_b: bool = False) -> jax.Array:
     )
 
 
-def _times_head_scales(x: jax.Array, scale_ref, head0) -> jax.Array:
-    """``x`` [g, rows, bsz] times the int8 tier's per-(head, position)
-    scales of heads ``head0 ..``.  The scale block carries every head's
-    plane [1, H, bsz]; each head's sublane is read alone and the heads'
-    products restacked, because a dynamic window of several sublanes
-    lowers only where it starts on a multiple of 8."""
-    return jnp.stack([x[i] * scale_ref[0, pl.ds(head0 + i, 1), :]
-                      for i in range(x.shape[0])])
+def _times_head_scales(x: jax.Array, planes, at, head0) -> jax.Array:
+    """``x`` [g, rows, span] times the int8 tier's per-(head, position)
+    scales of heads ``head0 ..``: ``planes[at]`` is ``[H., span.]``, every
+    head's scales of the wave's positions (heads on the sublanes, positions
+    on the lanes as the scores have them; both may be padded).  Each head's
+    sublane is read alone (a dynamic window of several sublanes lowers only
+    where it starts on a multiple of 8) and the heads' products
+    restacked."""
+    span = x.shape[-1]
+    return jnp.stack([
+        x[i] * planes[(*at, pl.ds(head0 + i, 1), slice(None))][:, :span]
+        for i in range(x.shape[0])])
+
+
+def _wave_planes(scales: jax.Array, layer: jax.Array, table: jax.Array,
+                 wave: int, whole_tiles: bool) -> jax.Array:
+    """The int8 tier's scales ``[L, NB, BLOCK, H]`` at ``layer`` as the walk
+    reads them: each row's, gathered by its ``table`` i32[R, NBPS] and laid
+    wave by wave, heads on the sublanes and a wave's positions on the lanes:
+    ``[R, NW, H, wave * BLOCK]``, so that a wave's scales are ONE copy a
+    plane.  The planes are a sixty-fourth of the pool's elements and rest
+    block-index-minor (their two minor dimensions are far under a tile), so
+    the kernel cannot window them where they lie (PERF.md section 7 has what
+    else was tried); the gather is what the jnp path does for the same
+    scales.  The layer's plane is turned heads-before-positions FIRST and
+    gathered after: on the chip a third of the time of gathering the blocks
+    and turning each wave (PERF.md section 6).  ``whole_tiles`` pads heads
+    and positions to whole (8, 128) tiles, which a copy out of HBM takes
+    (:func:`_copies_its_blocks`)."""
+    r, nbps = table.shape
+    bsz, h = scales.shape[2:]
+    nw = -(-nbps // wave)
+    g = scales[layer].transpose(0, 2, 1)[table]     # [R, NBPS, H, BLOCK]
+    g = jnp.pad(g, ((0, 0), (0, nw * wave - nbps), (0, 0), (0, 0)))
+    g = g.reshape(r, nw, wave, h, bsz).transpose(0, 1, 3, 2, 4)
+    g = g.reshape(r, nw, h, wave * bsz)
+    if whole_tiles:
+        g = jnp.pad(g, ((0, 0), (0, 0), (0, -h % QROWS),
+                        (0, -(wave * bsz) % 128)))
+    return g
 
 
 # ---------------------------------------------------------------------------
@@ -499,15 +652,17 @@ def _times_head_scales(x: jax.Array, scale_ref, head0) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def _heads_from_lanes(block_ref, group: int) -> jax.Array:
-    """The rows of a step's K (or V) block ``[1, 1, bsz, group * Dh]`` (a
-    position's heads side by side in the lanes, as the pool keeps them)
-    -> f32 ``[group, bsz, Dh]``, the heads a batch dimension for the two
-    products.  Static lane windows, restacked: Mosaic refuses the reshape
-    that splits the lane dimension ("unsupported shape cast", 16 x 1280
-    -> 16 x 20 x 64) and this jax has no ``einshape`` (PERF.md section 6
-    has what the windows cost on the chip)."""
-    x = block_ref[0, 0].astype(jnp.float32)
+def _heads_from_lanes(x: jax.Array, group: int) -> jax.Array:
+    """A wave's K (or V) blocks ``[W, bsz, group * Dh]`` (a position's heads
+    side by side in the lanes, as the pool keeps them) -> f32 ``[group,
+    W·bsz, Dh]``, the heads a batch dimension for the two products.  The
+    blocks are laid end to end AFTER the upcast: a block is whole float32
+    sublanes where it may be half a packed tile of the pool's dtype (16
+    rows of int8).  Then static lane windows, restacked: Mosaic refuses the
+    reshape that splits the lane dimension ("unsupported shape cast", 16 x
+    1280 -> 16 x 20 x 64) and this jax has no ``einshape`` (PERF.md section
+    6 has what the windows cost on the chip): once a wave."""
+    x = x.astype(jnp.float32).reshape(-1, x.shape[-1])
     dh = x.shape[-1] // group
     if group == 1:
         return x[None]
@@ -515,83 +670,111 @@ def _heads_from_lanes(block_ref, group: int) -> jax.Array:
 
 
 def _paged_attn_kernel(table_ref, start_ref, jmax_ref, layer_ref, q_ref,
-                       k_ref, *rest, scale: float, bsz: int, qt: int,
-                       group: int, quantized: bool, rep: int = 1,
+                       k_in, *rest, scale: float, bsz: int, qt: int,
+                       group: int, wave: int, in_step: bool,
+                       quantized: bool, rep: int = 1,
                        v_lanes: Optional[int] = None):
-    """One (row, head group, query tile, logical block) grid step of the
-    online softmax: ``group`` heads of ONE physical block against ``qt``
-    query rows, the heads a batch dimension of both products (per head
-    the algebra is what a step of one head was; on the chip the batched
-    spelling beat a static unroll of two-dimensional dots by 1.4 to 1.7
-    times, PERF.md section 6).  Where ``rep`` query heads share a K/V
-    head, the tile's ``qt`` positions of each of them lie one after the
-    other in the step's ``rep * qt`` rows (:func:`_attend` lays them so).
+    """The walk of a row's blocks for ``group`` heads against ``qt`` query
+    rows, the online softmax a WAVE of ``wave`` physical blocks (``wave *
+    bsz`` positions) a pass.  The heads are a batch dimension of both
+    products (per head the algebra is what a step of one head was; on the
+    chip the batched spelling beat a static unroll of two-dimensional dots
+    by 1.4 to 1.7 times, PERF.md section 6).  Where ``rep`` query heads
+    share a K/V head, the tile's ``qt`` positions of each of them lie one
+    after the other in the step's ``rep * qt`` rows (:func:`_attend` lays
+    them so).
 
-    Scalar-prefetch refs: ``table_ref`` i32[R, NBPS] (physical ids —
-    also consumed by the index maps, which is what makes the gather part
-    of the DMA pipeline), ``start_ref`` i32[R] (first query's absolute
-    position) and ``jmax_ref`` i32[R, NT] (the last useful logical block
-    of each (row, query tile) — the ragged early-exit bound: tile
-    ``ti``'s causal window ends at its own last query, so an early tile
-    of a long chunk streams a fraction of the blocks the chunk touches).
-    ``rest`` is ``(v_ref, ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref)``
-    on the int8 tier and ``(v_ref, ...)`` with the last four otherwise:
-    the scale operands exist only when there are scales.
+    Scalar-prefetch refs: ``table_ref`` i32[R, NBPS] (physical ids: the
+    walk reads them to aim its copies, so the gather IS the copy),
+    ``start_ref`` i32[R] (first query's absolute position), ``jmax_ref``
+    i32[R, NT] (the last useful logical block of each (row, query tile),
+    the ragged bound: tile ``ti``'s causal window ends at its own last
+    query, so an early tile of a long chunk walks a fraction of the blocks
+    the chunk touches) and ``layer_ref`` i32[1].  After ``q_ref`` come the
+    pools (K, then V) and, on the int8 tier, the two scale planes
+    (:func:`_wave_planes`); then the output block and the softmax's scratch
+    ``(acc, m, l)``.
+
+    THE WALK IN THE STEP (``in_step``; a grid step is a (row, head group,
+    query tile)): the pools are the STACKED pools whole, in HBM where they
+    lie, and the scratch goes on with the wave's tiles ``[2, wave, bsz,
+    group * Dh]`` (one for K, one for V, on the int8 tier one ``[2, H.,
+    wave * bsz.]`` for each plane) and the DMA semaphores ``[2, tiles]``.
+    The walk is ``jmax // wave + 1`` waves, a traced count.  Wave ``w``
+    copies logical blocks ``w * wave ..`` of the row, block ``j`` from
+    ``pool[layer, table[r, min(j, jmax)], :, the group's lanes]``, into
+    tile ``w % 2``; wave ``w + 1`` is started before wave ``w`` is waited
+    for, so its copies fly while ``w`` is multiplied.  The blocks of the
+    last wave that lie past ``jmax`` are the ``jmax`` block again: every
+    position of theirs is past the tile's last query and the mask in
+    absolute positions hides it, as it hides what a live block holds past
+    the row's length; a row that holds nothing walks one wave.  The
+    accumulators are reset before the walk and the output written after it.
+
+    THE WALK IN THE GRID (a pool the step cannot copy,
+    :func:`_copies_its_blocks`; ``wave`` 1): the grid's fourth dimension is
+    the logical block ``j`` and its pipeline hands the step block ``min(j,
+    jmax)`` of each operand.  The accumulators are reset at ``j == 0``, a
+    step up to ``jmax`` is one pass of the same softmax, the output is
+    written at ``jmax``, and a step past it touches nothing (its index
+    repeats, so nothing is copied either).
 
     The LATENT shape (``v_lanes`` given; one shared row a position, so
-    ``group`` is 1 and the ``rep`` query heads are the rows): there is no
-    ``v_ref``, the values being the first ``v_lanes`` lanes of the SAME
-    block the scores were taken against, so ONE copy feeds both products;
-    and both products take their operands in the pool's dtype (the MXU's
-    own in bfloat16, accumulated in float32) instead of upcasting them:
-    the step is ``[rep * qt, lanes] x [bsz, lanes]^T`` then ``[rep * qt,
-    bsz] x [bsz, v_lanes]``, three times the operations of a per-head
-    K/V pair, and float32 operands would cost several passes each."""
+    ``group`` is 1 and the ``rep`` query heads are the rows): there is no V
+    pool, the values being the first ``v_lanes`` lanes of the SAME tile the
+    scores were taken against, so ONE copy feeds both products; and both
+    products take their operands in the pool's dtype (the MXU's own in
+    bfloat16, accumulated in float32) instead of upcasting them: a wave is
+    ``[rep * qt, lanes] x [wave * bsz, lanes]^T`` then ``[rep * qt, wave *
+    bsz] x [wave * bsz, v_lanes]``, three times the operations of a
+    per-head K/V pair, and float32 operands would cost several passes
+    each."""
     latent = v_lanes is not None
-    v_ref = None if latent else rest[0]
-    ks_ref, vs_ref = rest[1:3] if quantized else (None, None)
-    o_ref, acc_ref, m_ref, l_ref = rest[-4:]
+    n_pools = 1 if latent else 2
+    n_in = n_pools + (2 if quantized else 0)
+    ins = (k_in,) + rest[:n_in - 1]             # the pools, then the planes
+    o_ref, acc_ref, m_ref, l_ref = rest[n_in - 1:n_in + 3]
     r = pl.program_id(0)
     hg = pl.program_id(1)
     ti = pl.program_id(2)
-    j = pl.program_id(3)
+    jmax = jmax_ref[r, ti]
+    rows = rep * qt
+    span = wave * bsz
+    qrow = jax.lax.broadcasted_iota(jnp.int32, (rows, span), 0)
+    if rep > 1:
+        qrow = qrow % qt
+    qpos = start_ref[r] + ti * qt + qrow
+    # q [g, rows, Dh] (latent: [1, rows, lanes], in the pool's dtype)
+    q = q_ref[0] if latent else q_ref[0].astype(jnp.float32)
 
-    @pl.when(j == 0)
-    def _init():
+    def reset():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    jmax = jmax_ref[r, ti]
-
-    @pl.when(j <= jmax)
-    def _compute():
+    def attend(w, k, v, planes, at):
+        """Fold wave ``w`` into the accumulators: its K and V blocks
+        ``[wave, bsz, g * Dh]`` as the pool keeps them, and the int8 tier's
+        two ``planes``, the wave's at the leading indices ``at``."""
         # Causal + ragged mask in absolute positions: query start+ti·qt+t
         # sees cache slots [0, its own position]; everything past the
         # row's true length (garbage in the final block, trash-block
-        # padding) is masked.  One mask for the group.
-        rows = rep * qt
-        kpos = j * bsz + jax.lax.broadcasted_iota(jnp.int32, (rows, bsz), 1)
-        qrow = jax.lax.broadcasted_iota(jnp.int32, (rows, bsz), 0)
-        if rep > 1:
-            qrow = qrow % qt
-        qpos = start_ref[r] + ti * qt + qrow
+        # padding, the last wave's blocks past jmax) is masked.  One mask
+        # for the group.
+        kpos = w * span + jax.lax.broadcasted_iota(jnp.int32, (rows, span), 1)
         visible = (kpos <= qpos)[None]
-        if latent:
-            q = q_ref[0]                                 # [1, rows, lanes]
-            k = k_ref[0, 0][None]                        # [1, bsz, lanes]
-        else:
-            q = q_ref[0].astype(jnp.float32)             # [g, qt, Dh]
-            k = _heads_from_lanes(k_ref, group)          # [g, bsz, Dh]
-        s = _dot(q, k, trans_b=True) * scale             # [g, qt, bsz] f32
+        # q x k [g, span, Dh] (latent: [1, span, lanes])
+        k = (k.reshape(1, span, -1) if latent
+             else _heads_from_lanes(k, group))
+        s = _dot(q, k, trans_b=True) * scale             # [g, rows, span]
         if quantized:
             # Per-(head, position) K scale: constant along the contracted
             # Dh axis, so it multiplies the int8 score AFTER the dot —
             # the same algebra models/generate._block_with_cache applies
             # to the gathered view.
-            s = _times_head_scales(s, ks_ref, hg * group)
+            s = _times_head_scales(s, planes[0], at, hg * group)
         s = jnp.where(visible, s, NEG_INF)
-        m_prev = m_ref[:, :, :1]                         # [g, qt, 1]
+        m_prev = m_ref[:, :, :1]                         # [g, rows, 1]
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_cur)                           # masked -> 0
         corr = jnp.exp(m_prev - m_cur)
@@ -602,22 +785,70 @@ def _paged_attn_kernel(table_ref, start_ref, jmax_ref, layer_ref, q_ref,
         if quantized:
             # V scale folds into the probabilities before the PV
             # contraction — again the gathered-view algebra, in-register.
-            p = _times_head_scales(p, vs_ref, hg * group)
-        if latent:
-            v = k[:, :, :v_lanes]
-        else:
-            v = _heads_from_lanes(v_ref, group)          # [g, bsz, Dh]
+            p = _times_head_scales(p, planes[1], at, hg * group)
+        v = k[:, :, :v_lanes] if latent else _heads_from_lanes(v, group)
         acc_ref[:] = acc_ref[:] * corr + _dot(p.astype(v.dtype), v)
         m_ref[:] = jnp.broadcast_to(m_cur, m_ref.shape)
 
-    @pl.when(j == jmax)
-    def _finalize():
-        # Finalised at the tile's LAST USEFUL block, not the grid's last
-        # iteration — the remaining j > jmax steps touch neither the
-        # accumulators nor the output block, and their DMAs are clamped
-        # to repeats by the index maps (no copies issued).
+    def write_out():
         l = jnp.maximum(l_ref[:, :, :1], 1e-30)
         o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
+
+    if not in_step:
+        j = pl.program_id(3)
+        pl.when(j == 0)(reset)
+
+        @pl.when(j <= jmax)
+        def _pass():
+            attend(j, ins[0][0], None if latent else ins[1][0],
+                   ins[n_pools:], (0, 0))
+
+        pl.when(j == jmax)(write_out)
+        return
+
+    tiles, sem = rest[n_in + 3:-1], rest[-1]
+    layer = layer_ref[0]
+    width = tiles[0].shape[-1]
+    # The group's window of the pool's lanes: all of them where one group
+    # holds every head (no dynamic offset then), else whole 128-lane
+    # columns (:func:`_head_groups`).
+    lanes = (slice(None) if width == k_in.shape[-1]
+             else pl.ds(pl.multiple_of(hg * width, 128), width))
+
+    def copies(w, slot):
+        out = []
+        for i in range(wave):
+            block = table_ref[r, jnp.minimum(w * wave + i, jmax)]
+            out += [pltpu.make_async_copy(ins[n].at[layer, block, :, lanes],
+                                          tiles[n].at[slot, i],
+                                          sem.at[slot, n])
+                    for n in range(n_pools)]
+        return out + [pltpu.make_async_copy(ins[n].at[r, w],
+                                            tiles[n].at[slot],
+                                            sem.at[slot, n])
+                      for n in range(n_pools, n_in)]
+
+    reset()
+    waves = jmax // wave + 1
+    for dma in copies(0, 0):
+        dma.start()
+
+    def one_wave(w, carry):
+        slot = w % 2
+
+        @pl.when(w + 1 < waves)
+        def _next():
+            for dma in copies(w + 1, 1 - slot):
+                dma.start()
+
+        for dma in copies(w, slot):
+            dma.wait()
+        attend(w, tiles[0][slot], None if latent else tiles[1][slot],
+               tiles[n_pools:], (slot,))
+        return carry
+
+    jax.lax.fori_loop(0, waves, one_wave, 0)
+    write_out()
 
 
 def _attn_pallas_call(program: str, q: jax.Array, pool_k: jax.Array,
@@ -629,15 +860,21 @@ def _attn_pallas_call(program: str, q: jax.Array, pool_k: jax.Array,
                       v_lanes: Optional[int] = None,
                       scale: Optional[float] = None) -> jax.Array:
     """q [R, H, NT·rep·QT, Dh] x the STACKED pool [L, NB, BLOCK, H·Dh] at
-    ``layer`` i32[1] -> out like q, on the grid :func:`grid_steps` gives
-    ``program``; ``H`` the pool's heads, each read by ``rep`` query heads
-    whose rows lie tile by tile in q's third dimension.  ``jmax`` i32[R, NT] is the per-(row, query-tile) last
-    useful logical block.  The pool is handed over whole, in the buffer
-    and the layout it rests in: the layer is one more scalar-prefetch
-    operand of the K/V index map, so no layer is sliced out of the pool
-    and none relaid out for the call.  The int8 tier's scales come as
-    the LAYER's planes, heads before positions: [NB, H, BLOCK] (see
-    :func:`_attend`).  In the latent shape (``v_lanes``; ``pool_v`` None,
+    ``layer`` i32[1] -> out like q; ``H`` the pool's heads, each read by
+    ``rep`` query heads whose rows lie tile by tile in q's third dimension.
+    ``jmax`` i32[R, NT] is the per-(row, query-tile) last useful logical
+    block.  Head group, query tile and wave are :func:`_step_shape`'s (the
+    wave held to the table's blocks), and the grid :func:`grid_steps`'s
+    first three: a step a (row, head group, query tile), which walks the
+    row's blocks itself.  The pool is handed over whole and left where it
+    lies (``memory_space=pl.ANY``): the kernel copies the blocks it walks
+    out of it, a wave at a time, by the table, the layer and its group's
+    lanes, so no layer is sliced out of the pool and none relaid out for
+    the call.  A pool the step cannot copy (:func:`_copies_its_blocks`) is
+    walked by the grid's fourth dimension instead, a block a step, the
+    layer and the table in the K/V index map.  The int8 tier's scales come
+    as :func:`_wave_planes` lays them, [R, NW, H., wave·BLOCK.], and ride
+    the same waves.  In the latent shape (``v_lanes``; ``pool_v`` None,
     ``H`` 1) the pool's rows are ``[lanes]`` wide, the output ``[v_lanes]``
     wide, and the call carries its own name on the device trace."""
     r, h, t_pad, dh = q.shape
@@ -645,10 +882,13 @@ def _attn_pallas_call(program: str, q: jax.Array, pool_k: jax.Array,
     nbps = table.shape[1]
     bsz = pool_k.shape[2]
     latent = v_lanes is not None
-    grid = grid_steps(program, r, h * rep, nbps, t_pad, dh, bsz,
-                      pool_k.dtype, kv_heads=h, v_lanes=v_lanes)
-    group, qt = h // grid[1], t_pad // grid[2]
-    if jmax.shape != (r, grid[2]) or qt * grid[2] != t_pad:
+    group, qt, wave = _step_shape(
+        program, heads=h, head_dim=dh, block_size=bsz,
+        kv_dtype=pool_k.dtype, t=t_pad, rep=rep, v_lanes=v_lanes)
+    wave = min(wave, nbps)
+    in_step = _copies_its_blocks(bsz, h * dh)
+    grid = (r, h // group, t_pad // qt) + (() if in_step else (nbps,))
+    if jmax.shape != (r, t_pad // qt) or t_pad % qt:
         raise ValueError(
             f"{program}: q rows {t_pad} and jmax {jmax.shape} are not the "
             f"padding of grid {grid}")
@@ -657,54 +897,56 @@ def _attn_pallas_call(program: str, q: jax.Array, pool_k: jax.Array,
             f"{program}: pool {pool_k.shape} is not [L, NB, BLOCK, "
             f"{h} x {dh}]")
     quantized = k_scale is not None
+    if quantized and k_scale.shape[:2] != (r, -(-nbps // wave)):
+        raise ValueError(
+            f"{program}: scale planes {k_scale.shape} are not the rows' "
+            f"waves of {wave} blocks")
     kernel = functools.partial(
-        _paged_attn_kernel, bsz=bsz, qt=qt, group=group,
+        _paged_attn_kernel, bsz=bsz, qt=qt, group=group, wave=wave,
+        in_step=in_step,
         scale=1.0 / math.sqrt(dh) if scale is None else scale,
         quantized=quantized, rep=rep, v_lanes=v_lanes,
     )
     out_dh = v_lanes if latent else dh
 
-    # Ragged early exit at the DMA level: logical block j of (row r, tile
-    # ti) maps to physical block table[r, min(j, jmax[r, ti])] of the
-    # call's layer — beyond the tile's last useful block the index
-    # repeats and Pallas issues no further copy.  A head group is a
-    # window of the lanes.
+    def q_idx(ri, gi, ti, *_):
+        return (ri, gi, ti, 0)
+
+    # Where the grid walks: logical block j of (row r, tile ti) maps to
+    # physical block table[r, min(j, jmax[r, ti])] of the call's layer —
+    # beyond the tile's last useful block the index repeats and Pallas
+    # issues no further copy.  A head group is a window of the lanes.
     def kv_idx(ri, gi, ti, ji, tbl, st, jm, ly):
         return (ly[0], tbl[ri, jnp.minimum(ji, jm[ri, ti])], 0, gi)
 
-    def scale_idx(ri, gi, ti, ji, tbl, st, jm, ly):
-        return (tbl[ri, jnp.minimum(ji, jm[ri, ti])], 0, 0)
+    def plane_idx(ri, gi, ti, ji, tbl, st, jm, ly):
+        return (ri, jnp.minimum(ji, jm[ri, ti]), 0, 0)
 
-    def q_idx(ri, gi, ti, ji, tbl, st, jm, ly):
-        return (ri, gi, ti, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, group, rep * qt, dh), q_idx),
-        pl.BlockSpec((1, 1, bsz, group * dh), kv_idx),
-    ]
-    operands = [q, pool_k]
-    if not latent:
-        in_specs.append(pl.BlockSpec((1, 1, bsz, group * dh), kv_idx))
-        operands.append(pool_v)
-    if quantized:
-        # Mosaic tiles the last two dims, so a (group, bsz) window over
-        # a block's [H, BLOCK] scales lowers only where the group is
-        # whole sublanes: the block carries every head's scales for the
-        # physical block and the kernel picks each head's sublane.
-        in_specs += [
-            pl.BlockSpec((1, h, bsz), scale_idx),
-            pl.BlockSpec((1, h, bsz), scale_idx),
-        ]
-        operands += [k_scale, v_scale]
+    pools = [pool_k] + ([] if latent else [pool_v])
+    planes = [k_scale, v_scale] if quantized else []
+    if in_step:
+        where_it_lies = pl.BlockSpec(memory_space=pl.ANY)
+        in_specs = [where_it_lies] * len(pools + planes)
+        tiles = [pltpu.VMEM((2, wave, bsz, group * dh), pool_k.dtype)
+                 for _ in pools]
+        tiles += [pltpu.VMEM((2,) + a.shape[2:], a.dtype) for a in planes]
+        walk_scratch = tiles + [pltpu.SemaphoreType.DMA((2, len(tiles)))]
+    else:
+        in_specs = [pl.BlockSpec((1, 1, bsz, group * dh), kv_idx)
+                    for _ in pools]
+        in_specs += [pl.BlockSpec((1, 1) + a.shape[2:], plane_idx)
+                     for a in planes]
+        walk_scratch = []
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=grid,
-        in_specs=in_specs,
+        in_specs=[pl.BlockSpec((1, group, rep * qt, dh), q_idx)] + in_specs,
         out_specs=pl.BlockSpec((1, group, rep * qt, out_dh), q_idx),
         scratch_shapes=[
             pltpu.VMEM((group, rep * qt, out_dh), jnp.float32),
             pltpu.VMEM((group, rep * qt, 128), jnp.float32),
             pltpu.VMEM((group, rep * qt, 128), jnp.float32),
+            *walk_scratch,
         ],
     )
     return pl.pallas_call(
@@ -713,7 +955,7 @@ def _attn_pallas_call(program: str, q: jax.Array, pool_k: jax.Array,
         out_shape=jax.ShapeDtypeStruct(q.shape[:3] + (out_dh,), q.dtype),
         interpret=interpret,
         name="latent_decode" if latent else None,
-    )(table, start, jmax, layer, *operands)
+    )(table, start, jmax, layer, q, *pools, *planes)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "rep"))
@@ -789,31 +1031,19 @@ def _attend(program: str, q: jax.Array, pool_k: jax.Array,
         start = jnp.broadcast_to(start, (r,))
     start = start.astype(jnp.int32)
     layer = jnp.reshape(layer, (1,)).astype(jnp.int32)
-    _, qt = _step_shape(program, heads=kv_heads, head_dim=dh,
-                        block_size=bsz, kv_dtype=pool_k.dtype, t=t, rep=rep,
-                        v_lanes=v_lanes)
+    _, qt, wave = _step_shape(program, heads=kv_heads, head_dim=dh,
+                              block_size=bsz, kv_dtype=pool_k.dtype, t=t,
+                              rep=rep, v_lanes=v_lanes)
     nt = -(-t // qt)
-    # Tile ti's last useful logical block: that of its last REAL query,
-    # at start + min((ti+1)·qt, t) − 1 (pad rows compute a finite, masked
-    # attention nobody reads), clipped into the table: a padded prefill
-    # chunk can extend past the slot's allocation — those query rows are
-    # discarded by the caller, and the mask keeps them finite.
-    last = jnp.minimum((jnp.arange(nt, dtype=jnp.int32) + 1) * qt, t) - 1
-    jmax = jnp.clip((start[:, None] + last[None, :]) // bsz,
-                    0, nbps - 1).astype(jnp.int32)
+    jmax = _tile_bounds(start, t, qt, bsz, nbps).astype(jnp.int32)
     if nt * qt != t:
         # Mosaic sublane: the query tile's row dim pads to 8.
         q = jnp.pad(q, ((0, 0), (0, 0), (0, nt * qt - t), (0, 0)))
     if k_scale is not None:
-        # The scale planes [L, NB, BLOCK, H] are a sixty-fourth of the
-        # pool's elements and rest block-index-minor (their two minor
-        # dimensions are far under a tile), so the kernel cannot window
-        # them where they lie: it takes the layer's planes, heads on the
-        # sublanes and positions on the lanes as the scores have them,
-        # sliced out and relaid out here (2 MB a plane a layer at the
-        # serving cell; PERF.md section 7 has what else was tried).
-        k_scale = k_scale[layer[0]].transpose(0, 2, 1)
-        v_scale = v_scale[layer[0]].transpose(0, 2, 1)
+        k_scale, v_scale = (
+            _wave_planes(planes, layer[0], table, min(wave, nbps),
+                         _copies_its_blocks(bsz, pool_k.shape[3]))
+            for planes in (k_scale, v_scale))
     if rep == 1 and v_lanes is None:
         out = _ATTN_CALLS[program](q, pool_k, pool_v, k_scale, v_scale,
                                    table, start, jmax, layer,
@@ -879,9 +1109,9 @@ def paged_attention(q: jax.Array, pool_k: jax.Array,
     Semantics contract (pinned by tests/test_paged_attention.py against
     :func:`paged_attention_reference` and the jnp serve path): causal
     mask ``kpos <= start+t`` in absolute positions, int8 scales applied
-    post-dot (K) / pre-contraction (V), positions past a row's length
-    never read — neither compute nor DMA — and no layer but ``layer``
-    read at all."""
+    post-dot (K) / pre-contraction (V), nothing read past the wave that
+    holds a row's last position (and within it no block but the row's
+    own), and no layer but ``layer`` read at all."""
     return _attend("decode", q, pool_k, pool_v, table, start, layer,
                    k_scale, v_scale, interpret, v_lanes, scale)
 
@@ -948,8 +1178,8 @@ def paged_prefill_attention(q: jax.Array, pool_k: jax.Array,
     its final query can see), so KV streaming is proportional to the
     causal area — the flash-attention causal skip over a paged block
     table.  Same semantics contract as :func:`paged_attention`
-    (absolute-position mask, int8 scales post-dot / pre-contraction,
-    clamped DMAs past each bound); the jnp pin is the same
+    (absolute-position mask, int8 scales post-dot / pre-contraction, a
+    tile's walk ended at its bound); the jnp pin is the same
     :func:`paged_attention_reference`."""
     return _attend("prefill", q, pool_k, pool_v, table, start, layer,
                    k_scale, v_scale, interpret)
@@ -1323,4 +1553,5 @@ __all__ = [
     "resolve_attn_impl",
     "resolve_attn_impls",
     "supports_paged_attention",
+    "walked_blocks",
 ]
