@@ -669,9 +669,11 @@ def bench_spec() -> "dict":
         shed = _drive_serve_open_loop(engine, build_workload())
         row = _serve_sweep_row(engine, watcher, rate, shed)
         if spec_k:
-            sched = engine.scheduler
-            wall = max(sched.spec_draft_s + sched.spec_verify_s, 1e-9)
             summary = engine.metrics_summary()
+            phases = summary["tick_phases"]
+            draft_s = phases["serve.spec_draft"]["seconds"]
+            verify_s = phases["serve.spec_verify"]["seconds"]
+            wall = max(draft_s + verify_s, 1e-9)
             row["spec"] = {
                 "spec_k": spec_k,
                 "proposed": summary["spec_proposed"],
@@ -683,8 +685,8 @@ def bench_spec() -> "dict":
                 # Fractions of the spec-phase wall (host-observed; the
                 # draft chain syncs at its token pull, the verify at
                 # the packed pull) — where a tick's time actually goes.
-                "draft_frac": round(sched.spec_draft_s / wall, 4),
-                "verify_frac": round(sched.spec_verify_s / wall, 4),
+                "draft_frac": round(draft_s / wall, 4),
+                "verify_frac": round(verify_s / wall, 4),
             }
             log(f"spec k={spec_k}: {row['tokens_per_s']:8.1f} tok/s, "
                 f"accepted_rate {row['spec']['accepted_rate']:.3f} "

@@ -202,8 +202,14 @@ def test_the_engine_serves_the_reference_s_first_choices(params):
     assert len(summary["moe"]["held_expert_pairs"]) == 4
     assert summary["moe"]["since_last_summary"]["tokens_fed"] \
         == fed * CFG.n_layer
-    assert engine.metrics_summary()["moe"]["since_last_summary"][
-        "tokens_fed"] == 0
+    # a state row is zeroed once an admission, one program call each; the
+    # phases' window and the experts' are the same two summaries
+    phases = summary["tick_phases"]
+    assert phases["serve.tick.admit.zero_state"]["count"] == 6
+    again = engine.metrics_summary()
+    assert again["moe"]["since_last_summary"]["tokens_fed"] == 0
+    assert again["tick_phases"]["since_last_summary"][
+        "serve.tick.admit.zero_state"]["count"] == 0
     assert summary["state_pool_bytes"] == kv_slots.state_bytes_per_slot(
         CFG) * 3 == engine.scheduler.state.pool_bytes
     assert summary["kv_pool_bytes"] == engine.scheduler.kv.pool_bytes

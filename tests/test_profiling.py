@@ -170,14 +170,27 @@ def test_a_span_decorates_a_function_with_a_fresh_span_per_call():
     assert all(start > 1e9 and seconds >= 0.0 for _, start, seconds in mine)
 
 
-def test_recorded_spans_are_bounded_and_hold_set_up_only():
+@pytest.mark.parametrize("nested", [False, True])
+def test_recorded_spans_are_bounded_and_hold_set_up_only(nested):
+    """``nested``: pairs that close TOGETHER each satisfy, exactly, the
+    inequality the benchmark's ``span_readers.outermost`` nests by: a
+    recorded span's start and length come from one clock (with the wall
+    start read apart, 51 of 1,500 such inner spans read as outside)."""
     bound = profiling._RECORDED.maxlen
     assert bound <= 64
-    for i in range(bound + 10):
+    for i in range(1500 if nested else bound + 10):
         with span("setup.test_bound", i=i):
-            pass
+            if nested:
+                with span("setup.test_bound.inner"):
+                    pass
         with span("train.test_bound"):
             pass
+        if nested:
+            inner, outer = recorded_spans()[-2:]
+            assert (inner[0], outer[0]) == ("setup.test_bound.inner",
+                                            "setup.test_bound")
+            assert outer[1] <= inner[1], i
+            assert inner[1] + inner[2] <= outer[1] + outer[2], i
     kept = recorded_spans()
     assert len(kept) == bound
     assert all(name.startswith("setup.") for name, _, _ in kept)

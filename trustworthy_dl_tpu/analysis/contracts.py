@@ -77,7 +77,8 @@ HOT_LOOP_MODULES = (
 #: intentional pull per tick is inline-suppressed at the site.
 HOST_SYNC_SCOPES = {
     "trustworthy_dl_tpu/serve/scheduler.py": (
-        "decode_tick", "_spec_tick", "_advance_prefill", "admit",
+        "decode_tick", "_spec_tick", "_advance_prefill", "_dispatch_chunk",
+        "admit",
     ),
     "trustworthy_dl_tpu/engine/trainer.py": ("train_epoch",),
     # The kernel dispatch wrappers trace inside jitted serve programs:

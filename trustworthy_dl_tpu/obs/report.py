@@ -147,6 +147,10 @@ class StepTimeReporter:
         self._spans: Dict[str, Deque[Tuple[float, Dict[str, Any]]]] = \
             collections.defaultdict(
                 lambda: collections.deque(maxlen=max_steps))
+        #: name -> [count, seconds, longest, longest since
+        #: ``take_longest``] over EVERY ``record_span``, beside the ring.
+        self._totals: Dict[str, list] = collections.defaultdict(
+            lambda: [0, 0.0, 0.0, 0.0])
         self._mark: Optional[float] = None
         #: Optional obs.spans.SpanTracker: when attached (ObsSession
         #: enable_spans), finish_step synthesizes a ``train.step`` span
@@ -202,10 +206,31 @@ class StepTimeReporter:
         """An interval that ``utils.profiling.span`` measured (the
         ``perf_counter`` domain of the laps).  Kept by name, apart from
         the per-step ring: ``lap`` and ``finish_step`` never see it."""
-        self._spans[name].append((end - start, attrs))
+        seconds = end - start
+        self._spans[name].append((seconds, attrs))
+        total = self._totals[name]
+        total[0] += 1
+        total[1] += seconds
+        if seconds > total[3]:
+            total[3] = seconds
+            total[2] = max(total[2], seconds)
         if self.spans is not None:
             self.spans.add(name, start, end, kind=name.split(".", 1)[0],
                            **attrs)
+
+    def span_totals(self) -> Dict[str, Tuple[int, float, float]]:
+        """name -> (count, seconds, longest single interval) of every span
+        recorded so far: cumulative, where the ring keeps the newest."""
+        return {name: (t[0], t[1], t[2])
+                for name, t in self._totals.items()}
+
+    def take_longest(self) -> Dict[str, float]:
+        """name -> the longest single interval since the last call (0.0
+        where the name recorded nothing meanwhile)."""
+        out = {}
+        for name, total in self._totals.items():
+            out[name], total[3] = total[3], 0.0
+        return out
 
     def finish_step(self, step: Optional[int] = None) -> None:
         record = self._current

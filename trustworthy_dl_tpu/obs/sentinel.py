@@ -45,8 +45,8 @@ SENTINEL_METRICS: Dict[str, str] = {
     # like a throughput regression — tokens/s would eventually show it,
     # but accepted_rate names the cause.
     "accepted_rate": "higher",
-    # Decode-phase share of the serve wall (engine.decode_tick_s /
-    # elapsed).  A silent fall-back from the paged-attention kernel to
+    # Decode-phase share of the serve wall (the ``serve.decode_tick``
+    # spans' seconds / elapsed).  A silent fall-back from the paged-attention kernel to
     # the jnp gather path (gate flipped, geometry stopped tiling,
     # backend change) inflates exactly this number — it pages like a
     # perf regression even while tokens/s noise hides it, and the

@@ -44,6 +44,7 @@ from trustworthy_dl_tpu.models import gpt2
 from trustworthy_dl_tpu.obs import attribution
 from trustworthy_dl_tpu.obs.events import EventType
 from trustworthy_dl_tpu.obs.registry import get_registry
+from trustworthy_dl_tpu.obs.report import StepTimeReporter
 from trustworthy_dl_tpu.quant import int8 as q8
 from trustworthy_dl_tpu.serve.kv_slots import (
     kv_bytes_per_token,
@@ -55,11 +56,20 @@ from trustworthy_dl_tpu.serve.scheduler import (
     PagedBatchingScheduler,
     SlotTask,
     refuse_unsupported,
+    request_args,
     request_key_stream,
 )
 from trustworthy_dl_tpu.utils.metrics import MetricsCollector
+from trustworthy_dl_tpu.utils.profiling import span
 
 logger = logging.getLogger(__name__)
+
+#: The scope (a summary's key, a gauge's label) of what was counted between
+#: the last two ``metrics_summary()`` calls.
+SINCE_LAST = "since_last_summary"
+#: What a phase's entry of ``metrics_summary()["tick_phases"]`` holds, in
+#: ``StepTimeReporter.span_totals()``'s order.
+PHASE_FIELDS = ("count", "seconds", "longest_s")
 
 
 class _NullMetric:
@@ -535,7 +545,22 @@ class ServingEngine:
             "Tokens fed through an expert layer, a layer each",
             labels=("scope",) + self._rlabel_names,
         )
-        self._expert_seen: Optional[Dict[str, Any]] = None
+        # The tick's phases (``serve.*`` spans), kept the same way: totals
+        # and what was counted between the last two summaries, written by
+        # metrics_summary() alone.
+        phase_labels = ("phase", "scope") + self._rlabel_names
+        self._phase_gauges = (
+            _metric(registry.gauge, "tddl_serve_phase_count",
+                    "Spans a serving phase has closed", labels=phase_labels),
+            _metric(registry.gauge, "tddl_serve_phase_seconds",
+                    "Host wall under a serving phase's spans",
+                    labels=phase_labels),
+            _metric(registry.gauge, "tddl_serve_phase_longest_seconds",
+                    "Longest single span of a serving phase",
+                    labels=phase_labels),
+        )
+        #: What the summary before this one saw, by what was counted.
+        self._summary_seen: Dict[str, Dict[str, Sequence[float]]] = {}
         _metric(
             registry.gauge, "tddl_serve_slots_total",
             "KV slots in the pool, by storage dtype",
@@ -647,14 +672,17 @@ class ServingEngine:
         self._iteration = 0
         self._tokens_emitted = 0
         self._t_start: Optional[float] = None
-        # Host wall spent inside scheduler.decode_tick() (chunked
-        # prefill + the fused decode step + its packed pull): the
-        # decode-phase tick fraction of metrics_summary and the perf
-        # sentinel fingerprint — where a silent attention-path fallback
-        # shows up as time.
-        self.decode_tick_s = 0.0
+        # The ONE timer of this engine and its scheduler: every phase of
+        # a tick and of ``submit`` is ``span(name, self.timer)`` (the
+        # vocabulary is in utils/profiling.py), so the profiler's host
+        # plane, the totals behind metrics_summary()'s fractions and an
+        # attached SpanTracker read one pair of clock reads.
+        # Its ring keeps the newest span a name: the totals carry the rest.
+        self.timer = StepTimeReporter(max_steps=1)
+        self.scheduler.timer = self.timer
         # -- active observability plane (all optional, all host-only) --
-        # ``spans``: obs.spans.SpanTracker — request/phase timeline.
+        # ``spans``: obs.spans.SpanTracker — request/phase timeline; held
+        # by the timer, which forwards every phase span to it.
         # ``ledger``: obs.attribution.AttributionLedger — one durable
         # record per retired request.  ``slo``/``anomaly``: the
         # streaming watchers; when the SLO watcher is burning budget
@@ -684,7 +712,6 @@ class ServingEngine:
         self._trace_tags = ({"replica": replica_id}
                             if replica_id is not None else {})
         self.retire_hook = retire_hook
-        self.scheduler.spans = spans
         # Performance tier (obs/compilewatch.py): the fused decode
         # dispatch runs under the watcher's "serve_decode" guard — the
         # compile-once pin enforced at runtime.
@@ -750,6 +777,13 @@ class ServingEngine:
         """Enqueue a request; returns its request_id, or None when shed by
         backpressure (queue full).  Raises for requests that can never be
         served (longer than the cache)."""
+        with span("serve.submit", self.timer, request_id=self._next_id,
+                  prompt_len=len(request.prompt)) as noted:
+            request_id = self._submit(request)
+            noted["request_id"] = request_id      # None: shed
+        return request_id
+
+    def _submit(self, request: ServeRequest) -> Optional[int]:
         prompt = np.asarray(list(request.prompt), np.int32)
         if prompt.size == 0:
             raise ValueError("empty prompt")
@@ -781,15 +815,20 @@ class ServingEngine:
             return None
         request_id = self._next_id
         self._next_id += 1
-        rng = request.rng
-        if rng is None:
-            rng = jax.random.fold_in(self._rng, request_id)
+        # The key's derivation and its split are small device programs and
+        # a pull of their own, between ticks.
+        with span("serve.submit.key_stream", self.timer,
+                  max_new_tokens=int(request.max_new_tokens)):
+            rng = request.rng
+            if rng is None:
+                rng = jax.random.fold_in(self._rng, request_id)
+            keys = request_key_stream(rng, int(request.max_new_tokens))
         task = SlotTask(
             request_id=request_id,
             prompt=prompt,
             max_new_tokens=int(request.max_new_tokens),
             temperature=float(request.temperature),
-            keys=request_key_stream(rng, int(request.max_new_tokens)),
+            keys=keys,
             eos_id=request.eos_id,
             publish_prefix=bool(request.publish_prefix),
             adapter=adapter,
@@ -813,6 +852,7 @@ class ServingEngine:
                                       parent_id=root,
                                       request_id=request_id)
             self._req_spans[request_id] = {"root": root, "queued": queued}
+            task.span_root = root
         return request_id
 
     # -- terminal bookkeeping ----------------------------------------------
@@ -930,20 +970,39 @@ class ServingEngine:
         if self._t_start is None:
             self._t_start = now
         self._iteration += 1
-        self._expire_queued(now)
-        self._shed_for_slo()
+        timer = self.timer
+        with span("serve.tick", timer, iteration=self._iteration,
+                  queued=len(self._queue),
+                  active=self.scheduler.active_count):
+            with span("serve.tick.expire", timer):
+                self._expire_queued(now)
+                self._shed_for_slo()
+            with span("serve.tick.admit", timer) as noted:
+                emitted, noted["admitted"] = self._admit_queued()
+            with span("serve.decode_tick", timer) as noted:
+                ticked = self.scheduler.decode_tick()
+                noted.update(tokens=len(ticked),
+                             active=self.scheduler.active_count)
+            with span("serve.tick.emit", timer) as noted:
+                emitted += self._emit(ticked)
+                noted["tokens"] = emitted
+            with span("serve.tick.account", timer):
+                self._account(emitted)
+        return emitted
 
-        # Admit as many queued requests as there are free slots.
-        # Admission only books host-side state (block claim +
-        # prefix-cache lookup); the chunked prefill runs inside
-        # subsequent decode_ticks — the first token lands when the final
-        # chunk completes.
-        emitted = 0
+    def _admit_queued(self) -> "tuple[int, int]":
+        """Admit as many queued requests as there are free slots.
+        Admission only books host-side state (block claim + prefix-cache
+        lookup); the chunked prefill runs inside subsequent decode_ticks —
+        the first token lands when the final chunk completes.  Returns the
+        tokens admission itself brought and the requests it admitted."""
+        emitted = admitted = 0
         while self._queue and self.scheduler.has_free_slot:
             task, request = self._queue.popleft()
             if not self.scheduler.admit(task):
                 self._queue.appendleft((task, request))
                 break
+            admitted += 1
             rid = task.request_id
             self._inflight[rid] = (task, request)
             if self.trace is not None:
@@ -966,14 +1025,12 @@ class ServingEngine:
                 emitted += 1
                 if task.done:
                     self._finish(task, request, "completed")
-        t_tick = time.perf_counter()
-        ticked = self.scheduler.decode_tick()
-        self.decode_tick_s += time.perf_counter() - t_tick
-        if self.spans is not None and ticked:
-            self.spans.add("serve.decode_tick", t_tick,
-                           time.perf_counter(), kind="serve",
-                           tokens=len(ticked),
-                           active=self.scheduler.active_count)
+        return emitted, admitted
+
+    def _emit(self, ticked: List[SlotTask]) -> int:
+        """Stream the tick's tokens, retire what finished or ran out of
+        time; returns the tokens streamed."""
+        emitted = 0
         for task in ticked:
             rid = task.request_id
             if rid not in self._inflight:
@@ -1015,6 +1072,10 @@ class ServingEngine:
                     and time.perf_counter() - self._submit_t[rid]
                     > deadline):
                 self._finish(task, request, "deadline_exceeded")
+        return emitted
+
+    def _account(self, emitted: int) -> None:
+        """The tick's gauges and its row for the metrics collector."""
         self._tokens_emitted += emitted
         if emitted:
             self._tok_counter.inc(emitted, **self._rlabels)
@@ -1054,7 +1115,6 @@ class ServingEngine:
             "slots_in_service": self.scheduler.allocator.capacity,
         })
         self.metrics.tick()
-        return emitted
 
     def run_until_idle(self, max_iterations: int = 100_000
                        ) -> Dict[int, ServeResult]:
@@ -1282,6 +1342,12 @@ class ServingEngine:
 
     def _finish(self, task: SlotTask, request: ServeRequest,
                 status: str) -> None:
+        with span("serve.tick.retire", self.timer, status=status,
+                  **request_args(task)):
+            self._retire(task, request, status)
+
+    def _retire(self, task: SlotTask, request: ServeRequest,
+                status: str) -> None:
         rid = task.request_id
         if self.chaos is not None:
             # Chaos hook point: a SERVE_POISON event for this request id
@@ -1297,15 +1363,12 @@ class ServingEngine:
                      if self.ledger is not None
                      or self.retire_hook is not None else None)
         flagged, z = False, 0.0
-        t_mon = time.perf_counter()
         if self.monitor is not None and task.entropies:
-            flagged, z = self.monitor.observe(task.entropies, task.margins)
-            if self.spans is not None and rid in self._req_spans:
-                self.spans.add("serve.monitor", t_mon, time.perf_counter(),
-                               kind="serve",
-                               parent_id=self._req_spans[rid]["root"],
-                               request_id=rid, flagged=flagged,
-                               monitor_z=float(z))
+            with span("serve.monitor", self.timer,
+                      **request_args(task)) as noted:
+                flagged, z = self.monitor.observe(task.entropies,
+                                                  task.margins)
+                noted.update(flagged=flagged, monitor_z=float(z))
         self.scheduler.retire(task, quarantine=flagged)
         times = self._timing.pop(rid, [])
         t0 = self._submit_t.pop(rid, None)
@@ -1371,6 +1434,17 @@ class ServingEngine:
         self._inflight.pop(rid, None)
 
     # -- reporting ---------------------------------------------------------
+
+    @property
+    def spans(self) -> Any:
+        """The attached obs.spans.SpanTracker (None without one): the
+        timer holds it and forwards every phase span to it; the spans of
+        a request that cross ticks open and close on it directly."""
+        return self.timer.spans
+
+    @spans.setter
+    def spans(self, tracker: Any) -> None:
+        self.timer.spans = tracker
 
     @property
     def busy(self) -> bool:
@@ -1474,6 +1548,14 @@ class ServingEngine:
             (time.perf_counter() - self._t_start)
             if self._t_start is not None else 0.0
         )
+        totals = self.timer.span_totals()
+
+        def share(name: str) -> float:
+            """A phase's seconds over the wall since the first tick."""
+            if name not in totals or elapsed <= 0:
+                return 0.0
+            return totals[name][1] / elapsed
+
         out: Dict[str, Any] = {
             "requests_completed": self._status_counts.get("completed", 0),
             "requests_deadline_exceeded":
@@ -1491,8 +1573,7 @@ class ServingEngine:
             # Decode-phase share of the serve wall: the number the perf
             # sentinel bands (a silent attention-path fallback inflates
             # it) and the gauge's companion.
-            "decode_tick_fraction":
-                (self.decode_tick_s / elapsed) if elapsed > 0 else 0.0,
+            "decode_tick_fraction": share("serve.decode_tick"),
             "attn_kernel_path": self.attn_kernel_path,
             "attn_kernel_paths": self.attn_kernel_paths,
         }
@@ -1503,10 +1584,9 @@ class ServingEngine:
         # prefill chunks / inside the batched spec verify (both
         # direction LOWER in the sentinel fingerprint — a kernel arm
         # that does not shrink them is a regression signal).
-        out["prefill_chunk_fraction"] = (
-            sched.prefill_chunk_s / elapsed if elapsed > 0 else 0.0)
-        out["spec_verify_fraction"] = (
-            sched.spec_verify_s / elapsed if elapsed > 0 else 0.0)
+        out["prefill_chunk_fraction"] = share("serve.prefill_chunk.dispatch")
+        out["spec_verify_fraction"] = share("serve.spec_verify")
+        out["tick_phases"] = self._phase_summary(totals)
         out["prefix_lookups"] = sched.prefix_lookups
         out["prefix_hits"] = sched.prefix_hits
         out["prefix_tokens_reused"] = sched.prefix_tokens_reused
@@ -1547,31 +1627,60 @@ class ServingEngine:
                 out[f"{name}_p99_ms"] = float(p99 * 1e3)
         return out
 
+    def _since_last_summary(self, what: str,
+                            now: Dict[str, Sequence[float]]
+                            ) -> Dict[str, List[float]]:
+        """Running totals ``now`` (name -> numbers) less what the summary
+        before this one saw of ``what`` (nothing, for the first): the ONE
+        place "since the last summary" is reckoned, for the expert
+        counters and the phases alike.  A caller that asks at a
+        window's two ends gets the window."""
+        seen = self._summary_seen.get(what, {})
+        self._summary_seen[what] = now
+        return {name: [a - b for a, b in
+                       zip(values, seen.get(name) or [0] * len(values))]
+                for name, values in now.items()}
+
     def _expert_summary(self, now: Dict[str, Any]) -> Dict[str, Any]:
         """The expert counters as a summary gives them: the running totals
         and, under ``since_last_summary``, what was counted since the
         summary before this one (the whole run for the first); both go to
         the registry too.  The device's token counter is an int32 that
         wraps, so its difference is taken modulo 2**32."""
-        seen = self._expert_seen or {
-            "held_expert_pairs": [0] * len(now["held_expert_pairs"]),
-            "tokens_fed": 0}
-        since = {
-            "held_expert_pairs": [a - b for a, b in zip(
-                now["held_expert_pairs"], seen["held_expert_pairs"])],
-            "tokens_fed": (now["tokens_fed"] - seen["tokens_fed"])
-            % (1 << 32)}
-        self._expert_seen = now
+        delta = self._since_last_summary("experts", {
+            "held_expert_pairs": now["held_expert_pairs"],
+            "tokens_fed": [now["tokens_fed"]]})
+        since = {"held_expert_pairs": delta["held_expert_pairs"],
+                 "tokens_fed": delta["tokens_fed"][0] % (1 << 32)}
         first = self.cfg.first_expert
-        for scope, counts in (("total", now),
-                              ("since_last_summary", since)):
+        for scope, counts in (("total", now), (SINCE_LAST, since)):
             for i, n in enumerate(counts["held_expert_pairs"]):
                 self._expert_pairs_gauge.set(
                     float(n), expert=str(first + i), scope=scope,
                     **self._rlabels)
             self._expert_tokens_gauge.set(
                 float(counts["tokens_fed"]), scope=scope, **self._rlabels)
-        return {"first_expert": first, **now, "since_last_summary": since}
+        return {"first_expert": first, **now, SINCE_LAST: since}
+
+    def _phase_summary(self, totals: Dict[str, tuple]) -> Dict[str, Any]:
+        """The timer's ``span_totals()`` by name, as count, seconds and
+        the longest single interval, and the same under
+        ``since_last_summary``; both go to the registry too."""
+        recent = self.timer.take_longest()
+        delta = self._since_last_summary(
+            "phases", {name: t[:2] for name, t in totals.items()})
+        since = {name: (*delta[name], recent[name]) for name in totals}
+        for scope, by_name in (("total", totals), (SINCE_LAST, since)):
+            for name, values in by_name.items():
+                for gauge, value in zip(self._phase_gauges, values):
+                    gauge.set(float(value), phase=name, scope=scope,
+                              **self._rlabels)
+
+        def blocks(by_name: Dict[str, tuple]) -> Dict[str, Any]:
+            return {name: dict(zip(PHASE_FIELDS, values))
+                    for name, values in by_name.items()}
+
+        return {**blocks(totals), SINCE_LAST: blocks(since)}
 
     def analyze_programs(self, ledger: Any,
                          memory: Optional[bool] = None) -> Any:

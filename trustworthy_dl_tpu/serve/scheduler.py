@@ -69,6 +69,7 @@ from trustworthy_dl_tpu.serve.kv_slots import (
     validate_paged_geometry,
     zero_state_rows,
 )
+from trustworthy_dl_tpu.utils.profiling import span
 
 logger = logging.getLogger(__name__)
 
@@ -484,6 +485,10 @@ class SlotTask:
     # until admission, and again after retirement releases the claim.
     adapter: Optional[str] = None
     adapter_page: int = ZERO_PAGE
+    # The id of the request's ``serve.request`` span where the engine has
+    # a SpanTracker attached (None otherwise): what a phase span that
+    # works for this ONE request names as its parent.
+    span_root: Optional[int] = None
 
     @property
     def greedy(self) -> bool:
@@ -497,6 +502,15 @@ class SlotTask:
         if (len(self.emitted) >= self.max_new_tokens
                 or (self.eos_id is not None and token == self.eos_id)):
             self.done = True
+
+
+def request_args(task: SlotTask) -> Dict[str, Any]:
+    """What a phase span that works for ONE request carries: the id every
+    span of the request shares and, where a tracker holds the request's
+    root span, that span as the one that caused it."""
+    if task.span_root is None:
+        return {"request_id": task.request_id}
+    return {"request_id": task.request_id, "parent_id": task.span_root}
 
 
 # ---------------------------------------------------------------------------
@@ -681,7 +695,10 @@ class PagedBatchingScheduler:
         # prefix reuse, publishers) — the ledger reads it at retirement,
         # AFTER retire() has already cleared the live table.
         self._attrib: Dict[int, Dict[str, Any]] = {}
-        self.spans: Any = None  # optional obs.spans.SpanTracker (engine)
+        # The engine's obs.report.StepTimeReporter: every phase span
+        # below is ``span(name, self.timer)``; None (a scheduler driven
+        # without an engine) leaves the profiler's annotation alone.
+        self.timer: Any = None
         # Optional obs.compilewatch.CompileWatcher (engine) — the fused
         # paged decode dispatch runs under its "serve_decode" guard.
         self.compilewatch: Any = None
@@ -722,15 +739,6 @@ class PagedBatchingScheduler:
         self.spec_near_tie_flips = 0
         self.spec_ticks = 0
         self.spec_fallback_ticks = 0
-        # Host-observed wall time inside the two spec phases (the draft
-        # chain syncs at the token pull, the verify at the packed pull)
-        # — the bench A/B's draft/verify tick fractions.
-        self.spec_draft_s = 0.0
-        self.spec_verify_s = 0.0
-        # Host-observed wall time advancing prefills (chunk dispatches
-        # plus the final chunk's packed pull) — the bench prefill-arm
-        # A/B's ``prefill_chunk_fraction`` numerator.
-        self.prefill_chunk_s = 0.0
 
     # -- admission ---------------------------------------------------------
 
@@ -795,18 +803,13 @@ class PagedBatchingScheduler:
         shared: List[int] = []
         if self.prefix is not None:
             self.prefix_lookups += 1
-            import time as _time
-
-            t0 = _time.perf_counter()
-            # Cap at (p-1)//block: at least one prompt token always
-            # prefills, so the first sampled token has fresh logits.
-            shared = self.prefix.lookup(task.prompt.tolist(),
-                                        (p - 1) // self.block_size)
-            if self.spans is not None:
-                self.spans.add("serve.prefix_lookup", t0,
-                               _time.perf_counter(), kind="serve",
-                               request_id=task.request_id,
-                               hit=bool(shared), shared_blocks=len(shared))
+            with span("serve.prefix_lookup", self.timer,
+                      request_id=task.request_id) as noted:
+                # Cap at (p-1)//block: at least one prompt token always
+                # prefills, so the first sampled token has fresh logits.
+                shared = self.prefix.lookup(task.prompt.tolist(),
+                                            (p - 1) // self.block_size)
+                noted.update(hit=bool(shared), shared_blocks=len(shared))
         n_total = -(-total // self.block_size)             # ceil
         n_new = n_total - len(shared)
         fresh = self.blocks.alloc(n_new)
@@ -858,8 +861,10 @@ class PagedBatchingScheduler:
         """A slot's recurrent state starts from zero: at admission, and
         when a quarantined slot goes back into service."""
         if self.state is not None:
-            self.state = _programs()["zero_state"](
-                self.state, jnp.asarray(slot, jnp.int32))
+            with span("serve.tick.admit.zero_state", self.timer,
+                      slot=int(slot)):
+                self.state = _programs()["zero_state"](
+                    self.state, jnp.asarray(slot, jnp.int32))
 
     # -- decode ------------------------------------------------------------
 
@@ -874,14 +879,46 @@ class PagedBatchingScheduler:
         chunk completed its prompt (first token recorded)."""
         st = self._prefill[slot]
         task = st.task
-        c = self.chunk
-        import time as _time
+        n_real = min(st.plen - st.pos, self.chunk)
+        final = st.pos + n_real >= st.plen
+        with span("serve.prefill_chunk", self.timer, slot=int(slot),
+                  pos=int(st.pos), tokens=int(n_real), final=bool(final),
+                  **request_args(task)):
+            with span("serve.prefill_chunk.dispatch", self.timer):
+                packed = self._dispatch_chunk(slot, st, n_real)
+            if not final:
+                st.pos += self.chunk
+                return None
+            with span("serve.prefill_chunk.pull", self.timer):
+                # tddl-lint: disable=host-sync — the one pull a prefill
+                token, ent, margin = np.asarray(packed)[:, 0]
+            task._record(int(token), float(ent), float(margin))
+            self.lengths[slot] = st.plen
+            del self._prefill[slot]
+            if self.prefix is not None and task.publish_prefix:
+                # The prompt's FULL blocks are now authoritative in the
+                # pool — publish them so later same-prefix requests skip
+                # their prefill.  (Generated tokens are never cached; a
+                # publish_prefix=False audit replay caches nothing at
+                # all.)  The newly cached ids are remembered: if THIS
+                # request is later flagged, its publications must leave
+                # the cache with it.
+                self._published[slot] = self.prefix.insert(
+                    task.prompt.tolist(),
+                    self.tables[slot][:st.plen // self.block_size],
+                    publisher=task.request_id,
+                )
+            return task
 
-        t_chunk = _time.perf_counter()
-        n_real = min(st.plen - st.pos, c)
+    def _dispatch_chunk(self, slot: int, st: _PrefillProgress,
+                        n_real: int) -> jax.Array:
+        """Upload one chunk's inputs and call its program; the pool (and
+        the state) the call returns become the scheduler's.  Returns the
+        packed (token, entropy, margin) still on the device."""
+        task = st.task
+        c = self.chunk
         chunk = np.zeros(c, np.int32)
         chunk[:n_real] = task.prompt[st.pos:st.pos + n_real]
-        final = st.pos + n_real >= st.plen
         kv = self.kv
         if (st.pos == 0 and st.plen <= c and task.adapter_page == ZERO_PAGE
                 and not self.recurrent):
@@ -930,37 +967,10 @@ class PagedBatchingScheduler:
                 adapter_impl=self.attn_impls["adapter"],
                 **extra,
             )
+            if self.recurrent:
+                (self.state,) = state
         self.kv = PagedKV(k=new_k, v=new_v, k_scale=new_ks, v_scale=new_vs)
-        if self.recurrent:
-            (self.state,) = state
-        self.prefill_chunk_s += _time.perf_counter() - t_chunk
-        if self.spans is not None:
-            self.spans.add("serve.prefill_chunk", t_chunk,
-                           _time.perf_counter(), kind="serve",
-                           request_id=task.request_id, pos=int(st.pos),
-                           tokens=int(n_real), final=bool(final))
-        if not final:
-            st.pos += c
-            return None
-        # tddl-lint: disable=host-sync — the intentional per-prefill pull
-        token, ent, margin = np.asarray(packed)[:, 0]
-        task._record(int(token), float(ent), float(margin))
-        self.lengths[slot] = st.plen
-        del self._prefill[slot]
-        if self.prefix is not None and task.publish_prefix:
-            # The prompt's FULL blocks are now authoritative in the pool
-            # — publish them so later same-prefix requests skip their
-            # prefill.  (Generated tokens are never cached; a
-            # publish_prefix=False audit replay caches nothing at all.)
-            # The newly cached ids are remembered: if THIS request is
-            # later flagged, its publications must leave the cache with
-            # it.
-            self._published[slot] = self.prefix.insert(
-                task.prompt.tolist(),
-                self.tables[slot][:st.plen // self.block_size],
-                publisher=task.request_id,
-            )
-        return task
+        return packed
 
     def decode_tick(self) -> List[SlotTask]:
         """One engine tick: advance every mid-prefill slot by ONE chunk
@@ -991,35 +1001,38 @@ class PagedBatchingScheduler:
             # program of a spec engine).
             self.spec_fallback_ticks += 1
         ms = self.allocator.max_slots
-        tokens = np.zeros(ms, np.int32)
-        keys = np.zeros((ms, 2), np.uint32)
-        temps = np.ones(ms, np.float32)
-        greedy = np.ones(ms, bool)
-        tables = np.full((ms, self.nbps), TRASH_BLOCK, np.int32)
-        for slot, task in active.items():
-            tokens[slot] = task.next_token
-            keys[slot] = task.keys[len(task.emitted)]
-            temps[slot] = max(task.temperature, 1e-6)
-            greedy[slot] = task.greedy
-            tables[slot] = self._table_row(slot)
-        kv = self.kv
-        extra: Dict[str, Any] = {}
-        if self.adapters is not None:
-            # The adapter pool rides every tick: pool sides as traced
-            # arrays, per-slot pages as ONE traced i32[MAX_SLOTS] row
-            # (inactive and adapterless slots at ZERO_PAGE — an exact
-            # zero delta).  Residency churn changes buffer VALUES only;
-            # the program under the compile-once guard never changes.
-            a, b, a_s, b_s = self.adapters.device_args()
-            row = adapter_page_row(
-                {s: t.adapter_page for s, t in active.items()}, ms)
-            extra = dict(adapter_a=a, adapter_b=b, adapter_as=a_s,
-                         adapter_bs=b_s, apages=jnp.asarray(row))
-        if self.recurrent:
-            live = np.zeros(ms, bool)
-            live[list(active)] = True
-            extra = dict(state=self.state, active=jnp.asarray(live))
-        with guarded(self.compilewatch, "serve_decode"):
+        with span("serve.decode_tick.build", self.timer):
+            tokens = np.zeros(ms, np.int32)
+            keys = np.zeros((ms, 2), np.uint32)
+            temps = np.ones(ms, np.float32)
+            greedy = np.ones(ms, bool)
+            tables = np.full((ms, self.nbps), TRASH_BLOCK, np.int32)
+            for slot, task in active.items():
+                tokens[slot] = task.next_token
+                keys[slot] = task.keys[len(task.emitted)]
+                temps[slot] = max(task.temperature, 1e-6)
+                greedy[slot] = task.greedy
+                tables[slot] = self._table_row(slot)
+            kv = self.kv
+            extra: Dict[str, Any] = {}
+            if self.adapters is not None:
+                # The adapter pool rides every tick: pool sides as traced
+                # arrays, per-slot pages as ONE traced i32[MAX_SLOTS] row
+                # (inactive and adapterless slots at ZERO_PAGE — an exact
+                # zero delta).  Residency churn changes buffer VALUES
+                # only; the program under the compile-once guard never
+                # changes.
+                a, b, a_s, b_s = self.adapters.device_args()
+                row = adapter_page_row(
+                    {s: t.adapter_page for s, t in active.items()}, ms)
+                extra = dict(adapter_a=a, adapter_b=b, adapter_as=a_s,
+                             adapter_bs=b_s, apages=jnp.asarray(row))
+            if self.recurrent:
+                live = np.zeros(ms, bool)
+                live[list(active)] = True
+                extra = dict(state=self.state, active=jnp.asarray(live))
+        with span("serve.decode_tick.dispatch", self.timer), \
+                guarded(self.compilewatch, "serve_decode"):
             packed, new_k, new_v, new_ks, new_vs, *state = \
                 _programs()["paged_decode"](
                     self.cfg, kv.k, kv.v, kv.k_scale, kv.v_scale,
@@ -1035,16 +1048,18 @@ class PagedBatchingScheduler:
         self.kv = PagedKV(k=new_k, v=new_v, k_scale=new_ks, v_scale=new_vs)
         if self.recurrent:
             (self.state,) = state
-        # tddl-lint: disable=host-sync — the tick's single intentional pull
-        host = np.asarray(packed)
-        next_tok, ent, margin = host[0], host[1], host[2]
-        for slot in active:
-            self.lengths[slot] += 1
-        for slot, task in active.items():
-            task.tick_tokens = None   # single-token tick: emitted[-1]
-            task._record(int(next_tok[slot]), float(ent[slot]),
-                         float(margin[slot]))
-            ticked.append(task)
+        with span("serve.decode_tick.pull", self.timer):
+            # tddl-lint: disable=host-sync — the tick's one intended pull
+            host = np.asarray(packed)
+        with span("serve.decode_tick.record", self.timer):
+            next_tok, ent, margin = host[0], host[1], host[2]
+            for slot in active:
+                self.lengths[slot] += 1
+            for slot, task in active.items():
+                task.tick_tokens = None   # single-token tick: emitted[-1]
+                task._record(int(next_tok[slot]), float(ent[slot]),
+                             float(margin[slot]))
+                ticked.append(task)
         return ticked
 
     def _spec_tick(self, active: Dict[int, SlotTask]) -> List[SlotTask]:
@@ -1058,8 +1073,6 @@ class PagedBatchingScheduler:
         margin tolerated as draft-token flips), then release the claims
         — rejection is a refcount decrement plus NOT advancing the
         host-side length past the accepted prefix."""
-        import time as _time
-
         k = self.spec_k
         ms = self.allocator.max_slots
         tokens0 = np.zeros(ms, np.int32)
@@ -1101,94 +1114,92 @@ class PagedBatchingScheduler:
         tables_dev = jnp.asarray(tables)
         temps_dev = jnp.asarray(temps)
         greedy_dev = jnp.asarray(greedy)
-        t0 = _time.perf_counter()
-        cur = jnp.asarray(tokens0)
-        draft_dev = []
-        for j in range(k):
-            with guarded(self.compilewatch, "serve_spec_draft"):
-                cur, pk, pv, pks, pvs = prog["spec_draft"](
-                    self.cfg, *pool, self.draft_view, cur, tables_dev,
-                    jnp.asarray(lengths0 + j), jnp.asarray(keys[:, j]),
+        with span("serve.spec_draft", self.timer, slots=len(active)):
+            cur = jnp.asarray(tokens0)
+            draft_dev = []
+            for j in range(k):
+                with span("serve.spec_draft.dispatch", self.timer), \
+                        guarded(self.compilewatch, "serve_spec_draft"):
+                    cur, pk, pv, pks, pvs = prog["spec_draft"](
+                        self.cfg, *pool, self.draft_view, cur, tables_dev,
+                        jnp.asarray(lengths0 + j), jnp.asarray(keys[:, j]),
+                        temps_dev, greedy_dev, attn_impl=self.attn_impl,
+                    )
+                pool = (pk, pv, pks, pvs)
+                draft_dev.append(cur)
+            # ONE host sync point for the whole draft chain: the k draft
+            # token rows land together and become the verify inputs.
+            with span("serve.spec_draft.pull", self.timer):
+                # tddl-lint: disable=host-sync — the chain's one sync
+                drafts = np.stack([np.asarray(d) for d in draft_dev], axis=1)
+        with span("serve.spec_verify", self.timer,
+                  slots=len(active)) as noted:
+            tokens_v = np.concatenate([tokens0[:, None], drafts], axis=1)
+            with span("serve.spec_verify.dispatch", self.timer), \
+                    guarded(self.compilewatch, "serve_spec_verify"):
+                packed, pk, pv, pks, pvs = prog["spec_verify"](
+                    self.cfg, *pool, self.view, jnp.asarray(tokens_v),
+                    tables_dev, jnp.asarray(lengths0), jnp.asarray(keys),
                     temps_dev, greedy_dev, attn_impl=self.attn_impl,
+                    verify_impl=self.attn_impls["verify"],
                 )
-            pool = (pk, pv, pks, pvs)
-            draft_dev.append(cur)
-        # ONE host sync point for the whole draft chain: the k draft
-        # token rows land together and become the verify inputs.
-        # tddl-lint: disable=host-sync — the draft chain's one deliberate sync
-        drafts = np.stack([np.asarray(d) for d in draft_dev], axis=1)
-        t1 = _time.perf_counter()
-        self.spec_draft_s += t1 - t0
-        tokens_v = np.concatenate([tokens0[:, None], drafts], axis=1)
-        with guarded(self.compilewatch, "serve_spec_verify"):
-            packed, pk, pv, pks, pvs = prog["spec_verify"](
-                self.cfg, *pool, self.view, jnp.asarray(tokens_v),
-                tables_dev, jnp.asarray(lengths0), jnp.asarray(keys),
-                temps_dev, greedy_dev, attn_impl=self.attn_impl,
-                verify_impl=self.attn_impls["verify"],
-            )
-        self.kv = PagedKV(k=pk, v=pv, k_scale=pks, v_scale=pvs)
-        # tddl-lint: disable=host-sync — verify lands all windows in one pull
-        host = np.asarray(packed)                     # [3, ms, k+1]
-        t2 = _time.perf_counter()
-        self.spec_verify_s += t2 - t1
-        self.spec_ticks += 1
-        ticked: List[SlotTask] = []
-        tick_proposed = tick_accepted = 0
-        for slot, task in active.items():
-            tgt = host[0, slot]
-            ent = host[1, slot]
-            margin = host[2, slot]
-            d = drafts[slot]
-            # Acceptance walk: position i emits the TARGET token v_{i+1}
-            # (bit-identical to spec-off by construction — same logits,
-            # same key); the walk continues past i only when the draft
-            # guessed the emitted token, so every later target token was
-            # conditioned on the true stream.  A greedy mismatch under a
-            # near-tie top-1 margin (< the int8 parity probe's
-            # tolerance) emits the DRAFT token instead and continues —
-            # the same numerics-equivalence class the kv parity probe
-            # accepts, counted in ``spec_near_tie_flips``.
-            window: List[Tuple[int, float, float]] = []
-            for i in range(k + 1):
-                tok = int(tgt[i])
-                cont = False
-                if i < k:
-                    if int(d[i]) == tok:
-                        cont = True
-                    elif task.greedy and \
-                            float(margin[i]) < q8.PARITY_MARGIN_TOL:
-                        tok = int(d[i])
-                        self.spec_near_tie_flips += 1
-                        cont = True
-                window.append((tok, float(ent[i]), float(margin[i])))
-                if not cont:
-                    break
-            task.tick_tokens = []
-            n_fed = 0
-            for tok, e_sig, m_sig in window:
-                task._record(tok, e_sig, m_sig)
-                task.tick_tokens.append(tok)
-                n_fed += 1
-                if task.done:
-                    break          # eos / budget: later wins discarded
-            # Commit exactly the accepted inputs' KV: positions
-            # [len, len + n_fed) hold target-exact K/V for the emitted
-            # stream; everything beyond is rejected-draft garbage,
-            # causally invisible and rewritten before it could be seen.
-            self.lengths[slot] += n_fed
-            tick_proposed += proposable[slot]
-            tick_accepted += max(n_fed - 1, 0)
-            self.blocks.release_speculative(
-                self._spec_claims.pop(slot, []))
-            ticked.append(task)
-        self.spec_proposed += tick_proposed
-        self.spec_accepted += tick_accepted
-        if self.spans is not None:
-            self.spans.add("serve.spec_verify", t1, _time.perf_counter(),
-                           kind="serve", slots=len(active),
-                           proposed=tick_proposed,
-                           accepted=tick_accepted)
+            self.kv = PagedKV(k=pk, v=pv, k_scale=pks, v_scale=pvs)
+            with span("serve.spec_verify.pull", self.timer):
+                # tddl-lint: disable=host-sync — all windows, one pull
+                host = np.asarray(packed)                 # [3, ms, k+1]
+            self.spec_ticks += 1
+            ticked: List[SlotTask] = []
+            tick_proposed = tick_accepted = 0
+            for slot, task in active.items():
+                tgt = host[0, slot]
+                ent = host[1, slot]
+                margin = host[2, slot]
+                d = drafts[slot]
+                # Acceptance walk: position i emits the TARGET token v_{i+1}
+                # (bit-identical to spec-off by construction — same logits,
+                # same key); the walk continues past i only when the draft
+                # guessed the emitted token, so every later target token was
+                # conditioned on the true stream.  A greedy mismatch under a
+                # near-tie top-1 margin (< the int8 parity probe's
+                # tolerance) emits the DRAFT token instead and continues —
+                # the same numerics-equivalence class the kv parity probe
+                # accepts, counted in ``spec_near_tie_flips``.
+                window: List[Tuple[int, float, float]] = []
+                for i in range(k + 1):
+                    tok = int(tgt[i])
+                    cont = False
+                    if i < k:
+                        if int(d[i]) == tok:
+                            cont = True
+                        elif task.greedy and \
+                                float(margin[i]) < q8.PARITY_MARGIN_TOL:
+                            tok = int(d[i])
+                            self.spec_near_tie_flips += 1
+                            cont = True
+                    window.append((tok, float(ent[i]), float(margin[i])))
+                    if not cont:
+                        break
+                task.tick_tokens = []
+                n_fed = 0
+                for tok, e_sig, m_sig in window:
+                    task._record(tok, e_sig, m_sig)
+                    task.tick_tokens.append(tok)
+                    n_fed += 1
+                    if task.done:
+                        break          # eos / budget: later wins discarded
+                # Commit exactly the accepted inputs' KV: positions
+                # [len, len + n_fed) hold target-exact K/V for the emitted
+                # stream; everything beyond is rejected-draft garbage,
+                # causally invisible and rewritten before it could be seen.
+                self.lengths[slot] += n_fed
+                tick_proposed += proposable[slot]
+                tick_accepted += max(n_fed - 1, 0)
+                self.blocks.release_speculative(
+                    self._spec_claims.pop(slot, []))
+                ticked.append(task)
+            self.spec_proposed += tick_proposed
+            self.spec_accepted += tick_accepted
+            noted.update(proposed=tick_proposed, accepted=tick_accepted)
         return ticked
 
     # -- retirement --------------------------------------------------------

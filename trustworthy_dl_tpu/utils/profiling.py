@@ -3,15 +3,52 @@
 One vocabulary of host spans, on the clock the device trace uses:
 
 * ``span(name, timer=None, **args)`` — the ONE way the program opens a
-  host span.  It always enters a ``jax.profiler.TraceAnnotation`` (which
-  lands on ``/host:CPU`` of the profiler's trace, beside the device's
-  ops; a no-op without a profiler session), and where a
-  ``obs.report.StepTimeReporter`` is given it records the same interval
-  into it from one pair of clock reads — so ``obs_report.json``, the
-  ``SpanTracker`` Chrome timeline (``cli obs --chrome``) and the xplane
-  agree by construction.  Spans are named ``<layer>.<what>[.<part>]``; a
-  child's name extends its parent's, so a reader that has only
-  ``(name, start, duration)`` can still read the nesting.
+  host span, training and serving alike.  It always enters a
+  ``jax.profiler.TraceAnnotation`` (which lands on ``/host:CPU`` of the
+  profiler's trace, beside the device's ops; a no-op without a profiler
+  session), and where a ``obs.report.StepTimeReporter`` is given it
+  records the same interval into it from one pair of clock reads — so
+  ``obs_report.json``, the reporter's totals by name (``span_totals()``),
+  the ``SpanTracker`` Chrome timeline (``cli obs --chrome``) and the
+  xplane agree by construction.  Spans are named
+  ``<layer>.<what>[.<part>]``; a child's name extends its parent's where
+  the name is new, so a reader that has only ``(name, start, duration)``
+  can still read the nesting.
+* The rule for the serving program: a PHASE of a tick or of ``submit`` is
+  a ``span`` into the engine's one timer (``ServingEngine.timer``, shared
+  with its scheduler); a span of a REQUEST that crosses ticks
+  (``serve.request``, ``serve.queued``, ``serve.prefill``,
+  ``serve.decode``) cannot be an annotation, which is a context on one
+  thread's stack, and opens and closes on the attached ``SpanTracker``
+  (``start`` / ``end``, ``perf_counter`` too).  A phase that works for
+  ONE request carries its ``request_id`` and, where a tracker is
+  attached, the request's root span as ``parent_id``.  The vocabulary::
+
+      serve.submit                      engine.submit, whole
+        serve.submit.key_stream         the key's derivation and split
+      serve.tick                        engine.step, whole
+        serve.tick.expire               queue deadlines, SLO shedding
+        serve.tick.admit                the admission loop
+          serve.prefix_lookup             one request's cache lookup
+          serve.tick.admit.zero_state     a state row zeroed (one call)
+        serve.decode_tick               scheduler.decode_tick, whole
+          serve.prefill_chunk             one chunk of one request
+            serve.prefill_chunk.dispatch    uploads and the program call
+            serve.prefill_chunk.pull        a final chunk's packed pull
+          serve.decode_tick.build         the decode call's host arrays
+          serve.decode_tick.dispatch      uploads and the program call
+          serve.decode_tick.pull          the tick's packed pull
+          serve.decode_tick.record        tokens into their requests
+          serve.spec_draft, serve.spec_verify   (``spec_k`` > 0), each
+                                          with ``.dispatch`` and ``.pull``
+        serve.tick.emit                 streaming, the deadline sweeps
+          serve.tick.retire               one request's retirement
+            serve.monitor                   the output monitor's verdict
+        serve.tick.account              gauges, the collector's row
+
+  A ``*.dispatch`` span (and ``serve.tick.admit.zero_state``) is ONE
+  program call; a ``*.pull`` span (and ``serve.submit.key_stream``)
+  blocks on the device at least once.
 * ``step_annotation(step)`` — the ``StepTraceAnnotation`` round one step's
   dispatch (``train_step``); the dispatch gets no second span.
 * ``recorded_spans()`` — the set-up spans (``setup.*``) of this process.
@@ -51,6 +88,11 @@ logger = logging.getLogger(__name__)
 
 #: Spans whose name starts with this are kept for ``recorded_spans()``.
 SETUP_PREFIX = "setup."
+#: Wall clock minus ``perf_counter``, read once.  A recorded span's wall
+#: start AND end are its own two ``perf_counter`` reads plus this, so a span
+#: that closes with its outer one still reads as inside it (a second clock
+#: for the start put it outside, 51 times in 1,500 on a busy host).
+_WALL_OFFSET = time.time() - time.perf_counter()
 #: (name, wall-clock start, seconds) of the newest set-up spans.  A trainer
 #: leaves about ten; the bound only guards a process that builds hundreds.
 _RECORDED: Deque[Tuple[str, float, float]] = collections.deque(maxlen=64)
@@ -124,8 +166,6 @@ class span(contextlib.ContextDecorator):
         return span(self.name, self.timer, **self.args)
 
     def __enter__(self) -> Dict[str, Any]:
-        if self._kept:
-            self._wall = time.time()
         self._ann = _SafeAnnotation(jax.profiler.TraceAnnotation, self.name,
                                     **self.args)
         self._t0 = time.perf_counter()
@@ -138,7 +178,12 @@ class span(contextlib.ContextDecorator):
         if self.timer is not None:
             self.timer.record_span(self.name, self._t0, t1, **self.args)
         if self._kept:
-            _RECORDED.append((self.name, self._wall, t1 - self._t0))
+            # The length is taken between the two wall values (an exact
+            # difference of two floats this close), so start + seconds IS
+            # the wall end, and both are monotone in the reads: nesting
+            # holds to the last bit, at a quarter of a microsecond's grain.
+            start = self._t0 + _WALL_OFFSET
+            _RECORDED.append((self.name, start, (t1 + _WALL_OFFSET) - start))
         return False
 
 
