@@ -8,12 +8,15 @@ scenario — >= 8 concurrent heterogeneous requests through fewer slots with
 mid-flight retirement, the decode step compiled exactly once, and streamed
 tokens bit-identical to batch generate for the same params/keys."""
 
+import logging
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
+from trustworthy_dl_tpu.detect import baseline as bl
 from trustworthy_dl_tpu.models import gpt2
 from trustworthy_dl_tpu.models.generate import generate
 from trustworthy_dl_tpu.serve import (
@@ -106,6 +109,104 @@ def test_output_monitor_flags_outlier_and_does_not_absorb():
     flagged, _ = mon.observe(rng.normal(3.0, 0.05, 8),
                              rng.normal(1.0, 0.05, 8))
     assert not flagged and mon.count == before + 1
+
+
+class _DeviceRingMonitor:
+    """The reference: the monitor as it scored before its baseline moved to
+    the host, on ``detect.baseline``'s device ring (eager, one row)."""
+
+    def __init__(self, window, warmup, z_threshold):
+        self.warmup, self.z_threshold = warmup, z_threshold
+        self.state = bl.init_baseline_state(1, window,
+                                            OutputMonitor.NUM_SIGNALS)
+
+    def observe(self, entropies, margins):
+        vec = jnp.asarray(
+            [[float(np.mean(entropies)), float(np.mean(margins))]],
+            jnp.float32)
+        mean, std, valid = bl.baseline_moments(self.state)
+        z = float(jnp.max(bl.zscores(vec, mean, std)))
+        flagged = int(valid[0]) >= self.warmup and z > self.z_threshold
+        if not flagged:
+            self.state = bl.push_stats(self.state, vec)
+        return flagged, z
+
+    @property
+    def count(self):
+        return int(self.state.count[0])
+
+
+def _verdict_sequence(window, warmup, steps=640, seed=41):
+    """(entropies, margins) of ``steps`` requests: clean draws, and among
+    them an outlier one verdict BEFORE the baseline is warm and a collapse
+    right at the edge, a collapse or a garbage signature every 29th
+    request, and a stretch longer than the window of one exact vector
+    (zero variance in both signals) followed by clean draws again."""
+    rng = np.random.default_rng(seed)
+    flat = range(window + 32, 2 * window + 44)
+    sequence = []
+    for i in range(steps):
+        if i == warmup - 1:
+            pair = ([3.4] * 8, [1.4] * 8)            # cold: must be absorbed
+        elif i == warmup or i % 29 == 28:
+            pair = (([0.01] * 8, [25.0] * 8) if i % 2 == 0
+                    else ([9.0] * 8, [0.001] * 8))
+        elif i in flat:
+            pair = ([3.0] * 8, [1.0] * 8)
+        elif i == flat.stop:
+            # the first vector off the flat one is the whole deviation of
+            # the next verdicts: far enough that float32 resolves it
+            pair = ([3.125] * 8, [1.125] * 8)
+        else:
+            pair = (rng.normal(3.0, 0.05, 8), rng.normal(1.0, 0.05, 8))
+        sequence.append(pair)
+    return sequence
+
+
+@pytest.mark.parametrize("window,warmup", [(64, 8), (64, 16), (16, 4),
+                                           (256, 16)])
+def test_host_monitor_gives_the_device_ring_s_verdicts(window, warmup):
+    """Same work, same answers: at every step of a sequence that wraps the
+    window the host baseline flags, counts and scores as the device ring
+    of ``detect.baseline`` did (float64 moments against float32: ``z``
+    within 1e-3 relative, and within 1e-4 where a vector lies so close to
+    the mean that the float32 mean's own rounding is the difference)."""
+    host = OutputMonitor(window=window, warmup=warmup, z_threshold=4.0)
+    ref = _DeviceRingMonitor(window, warmup, 4.0)
+    flags, zero_variance = [], 0
+    for step, (ent, mar) in enumerate(_verdict_sequence(window, warmup)):
+        flagged, z = host.observe(ent, mar)
+        ref_flagged, ref_z = ref.observe(ent, mar)
+        assert flagged == ref_flagged, step
+        assert host.count == ref.count, step
+        assert z == pytest.approx(ref_z, rel=1e-3, abs=1e-4), step
+        flags.append(flagged)
+        zero_variance += (step >= window + 32 and z == 0.0)
+    assert host.count > 2 * window                   # the ring wrapped
+    assert not any(flags[:warmup]) and flags[warmup]  # the warm-up edge
+    assert sum(flags) >= 600 // 29                   # the signatures
+    assert zero_variance >= 3                        # the flat stretch
+    assert host.count + sum(flags) == len(flags)     # absorbed iff clean
+
+
+def test_output_monitor_touches_no_device(caplog):
+    """A verdict is host arithmetic over host floats: nothing is uploaded,
+    pulled, dispatched or compiled under ``serve.monitor``.  The window is
+    one no other test uses, so a device ring would have to compile."""
+    mon = OutputMonitor(window=53, warmup=8, z_threshold=4.0)
+    rng = np.random.default_rng(7)
+    with caplog.at_level(logging.DEBUG, logger="jax"), \
+            jax.log_compiles(), jax.transfer_guard("disallow"):
+        for _ in range(50):
+            flagged, z = mon.observe(list(rng.normal(3.0, 0.05, 8)),
+                                     list(rng.normal(1.0, 0.05, 8)))
+            assert type(flagged) is bool and type(z) is float
+        flagged, z = mon.observe([0.01] * 8, [25.0] * 8)
+    assert flagged and z > 4.0 and mon.count == 50
+    assert [r.getMessage() for r in caplog.records
+            if r.name.startswith("jax")] == []
+    assert not any(isinstance(value, jax.Array)
+                   for value in vars(mon).values())
 
 
 # --------------------------------------------------------------------------

@@ -237,6 +237,24 @@ def test_a_dispatch_span_is_one_program_call_and_a_pull_one_wait(drive):
     assert phases["serve.submit.key_stream"]["count"] == len(SHAPES)
 
 
+def test_a_monitor_span_is_one_verdict_in_the_retirement_it_judges(drive):
+    """``serve.monitor`` stays round every verdict wherever the baseline
+    lives: its count is the requests scored (what a reader divides its
+    seconds by), and the verdict it notes is the one its retirement put
+    into the result."""
+    engine, rids, ticks = drive
+    phases = engine.metrics_summary()["tick_phases"]
+    results = [engine.results[rid] for rid in rids]
+    scored = phases["serve.monitor"]["count"]
+    assert scored == len(rids) == phases["serve.tick.retire"]["count"] \
+        == sum(t["retired"] for t in ticks)
+    assert scored == engine.monitor.count + sum(r.flagged for r in results)
+    noted = {s.request_id: s.attrs for s in engine.spans.closed_spans()
+             if s.name == "serve.monitor"}
+    assert {rid: (a["flagged"], a["monitor_z"]) for rid, a in noted.items()} \
+        == {r.request_id: (r.flagged, r.monitor_z) for r in results}
+
+
 def test_a_speculative_tick_s_dispatch_spans_are_its_program_calls(params):
     """``spec_k`` drafts and one verify a tick, a ``.dispatch`` span each,
     one ``.pull`` a half: the rule holds for every engine."""

@@ -38,7 +38,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from trustworthy_dl_tpu.detect import baseline as bl
 from trustworthy_dl_tpu.models import generate as gen
 from trustworthy_dl_tpu.models import gpt2
 from trustworthy_dl_tpu.obs import attribution
@@ -173,9 +172,19 @@ class OutputMonitor:
     margin].  Both are cheap in-step reductions of the decode logits, and
     together they see the two anomaly directions: a backdoored/looping
     generation collapses entropy and inflates margin; a corrupted replica
-    emitting garbage logits does the reverse.  The baseline is the same
-    ring-buffer machinery the training detector uses (detect/baseline),
-    one fleet-wide row, and absorbs ONLY requests it did not flag."""
+    emitting garbage logits does the reverse.  One fleet-wide row, which
+    absorbs ONLY requests it did not flag.
+
+    The baseline lives ON THE HOST: a numpy ring of the last ``window``
+    clean vectors and a write count.  A verdict's inputs are host floats
+    (the decode tick's packed pull brought them back) and its answer is
+    read by host code in the same retirement, so scoring on the device
+    would be dispatches and pulls with nothing on the chip to overlap
+    them.  The arithmetic is that of ``detect/baseline`` (masked mean and
+    population deviation over the valid rows, ``|z|`` with 0 where the
+    deviation is 0), whose ring stays the DEVICE's where the training
+    detector reads it inside the jitted step; ``tests/test_serve.py``
+    holds the two to each other."""
 
     NUM_SIGNALS = 2
 
@@ -183,27 +192,36 @@ class OutputMonitor:
                  z_threshold: float = 4.0):
         self.warmup = warmup
         self.z_threshold = z_threshold
-        self._state = bl.init_baseline_state(1, window, self.NUM_SIGNALS)
+        self._ring = np.zeros((window, self.NUM_SIGNALS), np.float32)
+        self._count = 0
 
     def observe(self, entropies: Sequence[float],
                 margins: Sequence[float]) -> tuple:
         """Score one finished request; absorb it iff clean.  Returns
         (flagged, max_z)."""
-        vec = jnp.asarray(
-            [[float(np.mean(entropies)), float(np.mean(margins))]],
-            jnp.float32,
-        )
-        mean, std, valid = bl.baseline_moments(self._state)
-        z = float(jnp.max(bl.zscores(vec, mean, std)))
-        warm = int(valid[0]) >= self.warmup
-        flagged = warm and z > self.z_threshold
+        vec = np.asarray([sum(entropies) / len(entropies),
+                          sum(margins) / len(margins)], np.float32)
+        window = self._ring.shape[0]
+        valid = min(self._count, window)
+        z = 0.0
+        if valid:
+            # float32 rows, float64 moments (baseline_moments' sums).
+            rows = self._ring[:valid]
+            mean = rows.sum(axis=0, dtype=np.float64) / valid
+            dev = rows - mean
+            std = np.sqrt((dev * dev).sum(axis=0) / valid)
+            safe = np.where(std > 0, std, 1.0)
+            z = float(np.max(np.where(std > 0,
+                                      np.abs(vec - mean) / safe, 0.0)))
+        flagged = valid >= self.warmup and z > self.z_threshold
         if not flagged:
-            self._state = bl.push_stats(self._state, vec)
+            self._ring[self._count % window] = vec
+            self._count += 1
         return flagged, z
 
     @property
     def count(self) -> int:
-        return int(self._state.count[0])
+        return self._count
 
 
 class ServingEngine:

@@ -43,7 +43,8 @@ One vocabulary of host spans, on the clock the device trace uses:
                                           with ``.dispatch`` and ``.pull``
         serve.tick.emit                 streaming, the deadline sweeps
           serve.tick.retire               one request's retirement
-            serve.monitor                   the output monitor's verdict
+            serve.monitor                   the output monitor's verdict:
+                                            host arithmetic, no device work
         serve.tick.account              gauges, the collector's row
 
   A ``*.dispatch`` span (and ``serve.tick.admit.zero_state``) is ONE
