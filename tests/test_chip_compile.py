@@ -152,10 +152,10 @@ def test_paged_attention_lowers_at_the_serving_cell(v5e, program, kv_dtype):
 
 
 def _serving_program(dev, program, kv_dtype):
-    """``_paged_chunk_impl`` or ``_paged_decode_impl`` at the serving
-    cell's geometry (GPT-2 large: 36 layers, 24 slots, 20 heads of 64,
-    blocks of 16, chunk 64), the pool donated as on the chip, compiled
-    for the described v5e."""
+    """``_paged_chunk_impl`` (one call's rows, a chunk of each of eight
+    slots) or ``_paged_decode_impl`` at the serving cell's geometry (GPT-2
+    large: 36 layers, 24 slots, 20 heads of 64, blocks of 16, chunk 64),
+    the pool donated as on the chip, compiled for the described v5e."""
     from trustworthy_dl_tpu.models import generate as gen
     from trustworthy_dl_tpu.models import gpt2
     from trustworthy_dl_tpu.serve import scheduler as sch
@@ -176,8 +176,11 @@ def _serving_program(dev, program, kv_dtype):
     i32, f32 = jnp.int32, jnp.float32
     if program == "chunk":
         fn = sch._paged_chunk_impl
-        rest = (S((CHUNK,), i32), S((1, nbps), i32), S((), i32), S((), i32),
-                S((2,), jnp.uint32), S((), f32), S((), jnp.bool_))
+        rows = sch.chunk_call_rows(CHUNK, slots)
+        rest = (S((rows, CHUNK), i32), S((rows, nbps), i32),
+                S((rows,), i32), S((rows,), i32),
+                S((rows, 2), jnp.uint32), S((rows,), f32),
+                S((rows,), jnp.bool_))
     else:
         fn = sch._paged_decode_impl
         rest = (S((slots,), i32), S((slots, nbps), i32), S((slots,), i32),
@@ -195,9 +198,9 @@ def _serving_program(dev, program, kv_dtype):
 # elements and the one array here the compiler still relays out: the chip
 # rests them block-index-minor with BLOCK on the sublanes, the row scatter
 # wants the heads there, so each plane is copied whole before and after the
-# layer loop (77 MB each: 93 MB of temporaries in the chunk program, 185
-# in the decode program) — PERF.md section 7 has what the alternatives
-# cost on the chip.
+# layer loop (77 MB each: 93 MB of temporaries in a chunk program, of one
+# row or of eight, 185 in the decode program) — PERF.md section 7 has what
+# the alternatives cost on the chip.
 _POOL_TIERS = [pytest.param(jnp.bfloat16, 64 << 20, id="bfloat16"),
                pytest.param(jnp.int8, 256 << 20, id="int8")]
 
@@ -303,10 +306,10 @@ def _decoder_program(dev, monkeypatch, program, family, config_name, slots,
     i32, f32 = jnp.int32, jnp.float32
     if program == "chunk":
         fn = sch._paged_chunk_impl
-        rest = (S((chunk,), i32), S((1, nbps), i32), S((), i32),
-                S((), i32), S((2,), jnp.uint32), S((), f32),
-                S((), jnp.bool_))
-        extra = dict(state=state, slot=pin(S((), i32)))
+        rest = (S((1, chunk), i32), S((1, nbps), i32), S((1,), i32),
+                S((1,), i32), S((1, 2), jnp.uint32), S((1,), f32),
+                S((1,), jnp.bool_))
+        extra = dict(state=state, slot=pin(S((1,), i32)))
     else:
         fn = sch._paged_decode_impl
         rest = (S((slots,), i32), S((slots, nbps), i32), S((slots,), i32),

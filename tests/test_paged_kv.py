@@ -298,7 +298,7 @@ def test_quarantine_purges_published_prefix_blocks(params):
     prompt = list(range(1, 13))            # 3 full blocks
     a = _task(0, prompt, 4)
     assert sched.admit(a)                  # 4 blocks total
-    # What _advance_prefill does at prefill completion: publish and
+    # What _record_prefill does at prefill completion: publish and
     # remember the publication.
     sched._published[a.slot] = sched.prefix.insert(
         prompt, sched.tables[a.slot][:3])
@@ -406,15 +406,20 @@ def test_a_serving_program_writes_its_rows_and_nothing_else(
     view = gen._decode_view(params, CFG)
     key = jax.random.PRNGKey(3)
     if program == "chunk":
-        # 16 positions from the block-aligned 8: blocks 6 and 7 whole.
-        table = jnp.asarray([[5, 6, 7, 8]], jnp.int32)
-        tokens = jnp.asarray(rng.integers(0, CFG.vocab_size, 16), jnp.int32)
+        # Two slots' chunks and a padding row in one call: 16 positions
+        # from the block-aligned 8 (blocks 6 and 7 whole), 16 from 0
+        # (blocks 9 and 10 whole), and a row whose table is all trash.
+        table = jnp.asarray([[5, 6, 7, 8], [9, 10, 11, 12], [0, 0, 0, 0]],
+                            jnp.int32)
+        tokens = jnp.asarray(rng.integers(0, CFG.vocab_size, (3, 16)),
+                             jnp.int32).at[2].set(0)
         out = sch._paged_chunk_impl(
-            CFG, *before, view, tokens, table, jnp.asarray(8, jnp.int32),
-            jnp.asarray(15, jnp.int32), key, jnp.asarray(1.0),
-            jnp.asarray(True), attn_impl=attn_impl)
+            CFG, *before, view, tokens, table,
+            jnp.asarray([8, 0, 0], jnp.int32),
+            jnp.asarray([15, 15, 0], jnp.int32), jnp.stack([key] * 3),
+            jnp.ones(3), jnp.ones(3, bool), attn_impl=attn_impl)
         after = out[:4]
-        written = [(6, o) for o in range(bsz)] + [(7, o) for o in range(bsz)]
+        written = [(b, o) for b in (6, 7, 9, 10) for o in range(bsz)]
     else:
         # Three live rows at ragged lengths and an idle row (all trash).
         table = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8], [9, 10, 11, 12],

@@ -46,7 +46,8 @@ PARENT = {
     "serve.prefix_lookup": "serve.tick.admit",
     "serve.prefill_chunk": "serve.decode_tick",
     "serve.prefill_chunk.dispatch": "serve.prefill_chunk",
-    "serve.prefill_chunk.pull": "serve.prefill_chunk",
+    # a chunk call is pulled after the decode call's dispatch
+    "serve.prefill_chunk.pull": "serve.decode_tick",
     **{name: "serve.decode_tick" for name in DECODE_SPANS},
     "serve.tick.retire": "serve.tick.emit",
     "serve.monitor": "serve.tick.retire",
@@ -136,23 +137,25 @@ def test_a_tick_opens_its_phases_once_and_a_chunk_span_a_prefilling_slot(
         for name in DECODE_SPANS:
             assert names.count(name) == tick["decoded"] <= 1, name
         chunks = [s for s in spans if s.name == "serve.prefill_chunk"]
-        assert len(chunks) == tick["chunks"]
-        # one chunk a mid-prefill slot: no request twice, every id one
-        # that ``submit`` returned
-        ids = [s.request_id for s in chunks]
-        assert len(set(ids)) == len(ids) and set(ids) <= set(rids)
+        # ONE call holds a chunk of every mid-prefill slot, padded up to
+        # the call's rows
+        assert len(chunks) == tick["chunks"] <= 1
+        for s in chunks:
+            rows = s.attrs["rows"]
+            assert 0 <= s.attrs["final"] <= rows
+            assert rows + s.attrs["padded"] == engine.scheduler.chunk_rows
         assert names.count("serve.prefill_chunk.dispatch") == len(chunks)
         assert names.count("serve.prefill_chunk.pull") == sum(
-            s.attrs["final"] for s in chunks)
+            s.attrs["final"] > 0 for s in chunks)
         assert names.count("serve.tick.retire") == tick["retired"]
-    # every request was fed its whole prompt, a chunk at a time
-    fed = {rid: 0 for rid in rids}
-    for spans in grouped:
-        for s in spans:
-            if s.name == "serve.prefill_chunk":
-                assert s.attrs["pos"] == fed[s.request_id]
-                fed[s.request_id] += s.attrs["tokens"]
-    assert fed == {rid: plen for rid, (plen, _) in zip(rids, SHAPES)}
+    # every request was fed its whole prompt, a chunk a tick, and each
+    # prompt ended in one call
+    calls = [s for spans in grouped for s in spans
+             if s.name == "serve.prefill_chunk"]
+    assert sum(s.attrs["rows"] for s in calls) == sum(
+        -(-plen // CHUNK) for plen, _ in SHAPES)
+    assert sum(s.attrs["final"] for s in calls) == len(rids)
+    assert max(s.attrs["rows"] for s in calls) == 3    # every slot at once
 
 
 def test_the_spans_a_tick_opens_are_bounded_by_what_it_holds(drive):
@@ -191,11 +194,16 @@ def test_a_request_s_phase_spans_carry_its_id_and_its_root(drive):
     roots = {s.request_id: s.span_id for s in spans
              if s.name == "serve.request"}
     assert sorted(roots) == sorted(rids)
-    for name in ("serve.prefill_chunk", "serve.tick.retire",
-                 "serve.monitor"):
+    for name in ("serve.tick.retire", "serve.monitor"):
         mine = [s for s in spans if s.name == name]
         assert mine and all(s.parent_id == roots[s.request_id]
                             for s in mine), name
+    # a chunk call works for one request only where it holds one row
+    chunks = [s for s in spans if s.name == "serve.prefill_chunk"]
+    assert all((s.request_id is not None) == (s.attrs["rows"] == 1)
+               for s in chunks)
+    ones = [s for s in chunks if s.request_id is not None]
+    assert ones and all(s.parent_id == roots[s.request_id] for s in ones)
     submits = [s for s in spans if s.name == "serve.submit"]
     assert [s.request_id for s in submits] == rids
     assert all(s.kind == "serve" for s in spans)
@@ -224,15 +232,18 @@ def test_a_dispatch_span_is_one_program_call_and_a_pull_one_wait(drive):
     count: the phases' counts ARE the program calls and the pulls."""
     engine, _, ticks = drive
     phases = engine.metrics_summary()["tick_phases"]
-    chunks = sum(-(-plen // CHUNK) for plen, _ in SHAPES)
     decode_ticks = sum(t["decoded"] for t in ticks)
+    finishing = [s for s in engine.spans.closed_spans()
+                 if s.name == "serve.prefill_chunk" and s.attrs["final"]]
     assert phases["serve.tick"]["count"] == len(ticks)
-    assert phases["serve.prefill_chunk.dispatch"]["count"] == chunks \
-        == sum(t["chunks"] for t in ticks)
+    assert phases["serve.prefill_chunk.dispatch"]["count"] \
+        == sum(t["chunks"] for t in ticks) \
+        == sum(t["chunks"] > 0 for t in ticks)
     assert phases["serve.decode_tick.dispatch"]["count"] == decode_ticks
-    # a prompt's last chunk pulls its first token, a decode call its rows,
-    # and a submit its keys
-    assert phases["serve.prefill_chunk.pull"]["count"] == len(SHAPES)
+    # a chunk call that finishes prompts pulls their first tokens once, a
+    # decode call its rows, and a submit its keys
+    assert phases["serve.prefill_chunk.pull"]["count"] == len(finishing) \
+        <= len(SHAPES)
     assert phases["serve.decode_tick.pull"]["count"] == decode_ticks
     assert phases["serve.submit.key_stream"]["count"] == len(SHAPES)
 
@@ -325,6 +336,42 @@ def test_since_the_last_summary_is_the_window_between_two_summaries(params):
                                   "tokens_fed": 3})
     assert asked[-2:] == ["experts", "experts"]
     assert moe[window] == {"held_expert_pairs": [4, 0], "tokens_fed": 5}
+
+
+def test_the_chunk_calls_rows_are_tallied_in_both_scopes(params):
+    """``serve.prefill_chunk`` carries its calls' real and padding rows in
+    ``tick_phases`` and in the registry, totals and the window between two
+    summaries, as the spans noted them."""
+    registry = MetricsRegistry()
+    engine = build(params, registry=registry, spans=SpanTracker())
+    submit_all(engine)
+    for _ in range(3):
+        engine.step()
+    first = engine.metrics_summary()["tick_phases"]
+    engine.run_until_idle()
+    phases = engine.metrics_summary()["tick_phases"]
+    calls = [s for s in engine.spans.closed_spans()
+             if s.name == "serve.prefill_chunk"]
+    rows = sum(s.attrs["rows"] for s in calls)
+    padded = sum(s.attrs["padded"] for s in calls)
+    assert rows == sum(-(-plen // CHUNK) for plen, _ in SHAPES)
+    assert phases["serve.prefill_chunk"]["rows"] == rows
+    assert phases["serve.prefill_chunk"]["padded"] == padded
+    assert engine.scheduler.chunk_rows == 3         # three slots
+    assert padded == sum(3 - s.attrs["rows"] for s in calls) > 0
+    window = phases[engine_module.SINCE_LAST]["serve.prefill_chunk"]
+    assert window["rows"] == rows - first["serve.prefill_chunk"]["rows"] > 0
+    assert window["count"] == len(calls) - first["serve.prefill_chunk"][
+        "count"]
+    tally = {(s["labels"]["kind"], s["labels"]["scope"]): s["value"]
+             for s in registry.snapshot()["metrics"][
+                 "tddl_serve_phase_tally"]["series"]
+             if s["labels"]["phase"] == "serve.prefill_chunk"}
+    assert tally == {("rows", "total"): rows, ("padded", "total"): padded,
+                     ("rows", engine_module.SINCE_LAST): window["rows"],
+                     ("padded", engine_module.SINCE_LAST): window["padded"]}
+    # a phase that tallies nothing keeps its three fields
+    assert set(phases["serve.tick"]) == set(engine_module.PHASE_FIELDS)
 
 
 def test_with_nothing_attached_nothing_grows_without_bound(params):

@@ -77,8 +77,9 @@ HOT_LOOP_MODULES = (
 #: intentional pull per tick is inline-suppressed at the site.
 HOST_SYNC_SCOPES = {
     "trustworthy_dl_tpu/serve/scheduler.py": (
-        "decode_tick", "_spec_tick", "_advance_prefill", "_dispatch_chunk",
-        "admit",
+        "decode_tick", "_spec_tick", "_dispatch_prefill", "_prefill_call",
+        "_dispatch_whole_prompt", "_chunk_args", "_dispatch_chunk",
+        "_record_prefill", "_dispatch_decode", "_record_decode", "admit",
     ),
     "trustworthy_dl_tpu/engine/trainer.py": ("train_epoch",),
     # The kernel dispatch wrappers trace inside jitted serve programs:

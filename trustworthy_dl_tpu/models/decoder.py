@@ -305,7 +305,8 @@ def _latent_attention(p: Params, xn: jax.Array, pool: jax.Array,
     written into layer ``layer`` of the pool ``[L_mla, NB, BLOCK,
     latent_lanes]`` first (write-then-attend).  One position a row (a
     decode step) runs the absorbed form, the cache holding no per-head K or
-    V to read; a chunk (``R = 1``) the expanded form."""
+    V to read; a chunk (``R = CHUNK_ROWS``, which ``apply_paged`` holds it
+    to) the expanded form."""
     from trustworthy_dl_tpu.models import generate as gen
     from trustworthy_dl_tpu.ops import latent_attention as lattn
     from trustworthy_dl_tpu.ops import paged_attention as pattn
@@ -316,8 +317,6 @@ def _latent_attention(p: Params, xn: jax.Array, pool: jax.Array,
     pool = gen._pool_write_rows(pool, latent_rows(p, xn, cfg)[:, None],
                                 layer, phys, offs)
     if t > 1:
-        if r != 1:
-            raise ValueError(f"a chunk is one slot's, got {r} rows of {t}")
         nope = cfg.qk_nope_head_dim
         q = _mm(xn[0], p["wq"]).reshape(t, cfg.q_heads, -1).transpose(
             1, 0, 2).astype(cfg.dtype)                   # [H, T, nope + rope]
@@ -411,6 +410,12 @@ def _expert_half(p: Params, xn: jax.Array, cfg: DecoderConfig,
 
 # -- the serving forward -------------------------------------------------------
 
+#: The rows a CHUNK call of :func:`apply_paged` takes: one slot's.  The
+#: chunked delta rule reads and writes the state row of ONE traced slot, the
+#: latent chunk kernel attends for one row, and the expert layer routes one
+#: row's positions; the scheduler sizes its chunk calls by this.
+CHUNK_ROWS = 1
+
 
 def apply_paged(view: Params, tokens: jax.Array, pool_k: jax.Array,
                 pool_v: Optional[jax.Array], state: Any, table: jax.Array,
@@ -429,9 +434,10 @@ def apply_paged(view: Params, tokens: jax.Array, pool_k: jax.Array,
 
     Two shapes, as the scheduler's two programs call it.  DECODE: ``T = 1``,
     row ``r`` of the call IS slot ``r`` (``slot`` None, ``start i32[R]``):
-    the recurrence, one step a slot.  A CHUNK of prefill: ``R = 1``,
-    ``slot`` the traced row of the state, ``start`` a scalar: the chunked
-    form, from the slot's state and convolution tail and back into them.
+    the recurrence, one step a slot.  A CHUNK of prefill: ``R =
+    CHUNK_ROWS``, ``slot`` the traced row of the state, ``start`` a scalar:
+    the chunked form, from the slot's state and convolution tail and back
+    into them (a latent layer's chunk runs the expanded form).
     ``valid bool[R, T]`` marks the real positions: an idle slot and a
     chunk's padding leave state, tail and counters as they were (their K/V
     or latent rows land in the trash block through ``table``, as GPT-2's
@@ -439,6 +445,8 @@ def apply_paged(view: Params, tokens: jax.Array, pool_k: jax.Array,
     periods.
     """
     r, t = tokens.shape
+    if t > 1 and r != CHUNK_ROWS:
+        raise ValueError(f"a chunk is one slot's, got {r} rows of {t}")
     x = view["embed"][tokens].astype(jnp.float32)
     kinds = cfg.period
     n_real = jnp.sum(valid, axis=1).astype(jnp.int32)            # [R]

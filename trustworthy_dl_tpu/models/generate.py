@@ -341,11 +341,15 @@ def _final_logits(params: Params, x: jax.Array, cfg: gpt2.GPT2Config,
     """Project ONE position's activations to logits [B, V] — the shared
     tail of the dense and paged cache paths.  ``last_pos=None`` keeps the
     static [-1] slice (batch generate); a traced value selects the real
-    last prompt position under bucket/chunk padding."""
+    last prompt position under bucket/chunk padding: i32[] for every row,
+    or i32[B] a row's own (the chunk program's rows are different
+    prompts)."""
     if last_pos is None:
         x_last = x[:, -1:, :]
-    else:
+    elif jnp.ndim(last_pos) == 0:
         x_last = jax.lax.dynamic_slice_in_dim(x, last_pos, 1, axis=1)
+    else:
+        x_last = jnp.take_along_axis(x, last_pos[:, None, None], axis=1)
     wte_head = params.get("wte_head")
     if wte_head is None:
         return gpt2.unembed(params, x_last, cfg)[:, 0, :]  # [B, V]
@@ -500,8 +504,9 @@ def _paged_block(block: Params, x: jax.Array, pool_k: jax.Array,
       row owns exclusively (kv_slots' COW discipline) — no row can
       observe another row's same-tick write on either path.
 
-    ``start`` follows the dense contract: scalar (chunked prefill, R=1)
-    or i32[R] (fused decode, T=1).
+    ``start`` follows the dense contract: scalar (every row at one
+    offset) or i32[R], a row's own (the fused decode, T=1, and the chunk
+    program, a mid-prefill slot a row).
 
     ``adapter_l`` is one layer's slice of the paged adapter pool plus
     the per-slot page table: ``(a_l [P+1, 2, D, r], b_l [P+1, 2, r, D],

@@ -151,6 +151,10 @@ class StepTimeReporter:
         #: ``take_longest``] over EVERY ``record_span``, beside the ring.
         self._totals: Dict[str, list] = collections.defaultdict(
             lambda: [0, 0.0, 0.0, 0.0])
+        #: name -> {key: running sum} of ``tally``: what the spans of a
+        #: name held, beside their totals.
+        self._tallies: Dict[str, Dict[str, int]] = collections.defaultdict(
+            dict)
         self._mark: Optional[float] = None
         #: Optional obs.spans.SpanTracker: when attached (ObsSession
         #: enable_spans), finish_step synthesizes a ``train.step`` span
@@ -223,6 +227,18 @@ class StepTimeReporter:
         recorded so far: cumulative, where the ring keeps the newest."""
         return {name: (t[0], t[1], t[2])
                 for name, t in self._totals.items()}
+
+    def tally(self, name: str, **amounts: int) -> None:
+        """Add ``amounts`` to the running sums kept under ``name``: what a
+        span of that name held (``serve.prefill_chunk``: the real rows and
+        the padding rows of its chunk call)."""
+        sums = self._tallies[name]
+        for key, amount in amounts.items():
+            sums[key] = sums.get(key, 0) + amount
+
+    def tallies(self) -> Dict[str, Dict[str, int]]:
+        """name -> {key: running sum} of every ``tally`` so far."""
+        return {name: dict(sums) for name, sums in self._tallies.items()}
 
     def take_longest(self) -> Dict[str, float]:
         """name -> the longest single interval since the last call (0.0

@@ -6,8 +6,11 @@ The engine is a synchronous iteration loop (``step()``): each iteration
 admits queued requests into free slots, runs the scheduler's single fused
 decode step, streams new tokens to per-request callbacks, and retires
 finished/expired sequences.  Everything host-side is O(MAX_SLOTS) python;
-the device work per iteration is exactly one decode program plus one
-prefill chunk per slot still prefilling.
+the device work per iteration is one decode program plus a call of the
+chunk program for every ``chunk_call_rows`` slots still prefilling, each
+call a chunk of each of them (one call where they are that few; a call a
+slot where the description's chunk is one slot's, and a whole-prompt call
+for each fresh prompt that fits one chunk).
 
 Trust-aware admission control (the inference mirror of the training trust
 state machine): every emitted token's logit entropy and top-1 margin are
@@ -577,6 +580,11 @@ class ServingEngine:
                     "Longest single span of a serving phase",
                     labels=phase_labels),
         )
+        self._tally_gauge = _metric(
+            registry.gauge, "tddl_serve_phase_tally",
+            "What a serving phase's spans held, by kind (serve.prefill_chunk:"
+            " rows, the real rows of its chunk calls; padded, their padding"
+            " rows)", labels=("phase", "kind", "scope") + self._rlabel_names)
         #: What the summary before this one saw, by what was counted.
         self._summary_seen: Dict[str, Dict[str, Sequence[float]]] = {}
         _metric(
@@ -1682,23 +1690,35 @@ class ServingEngine:
 
     def _phase_summary(self, totals: Dict[str, tuple]) -> Dict[str, Any]:
         """The timer's ``span_totals()`` by name, as count, seconds and
-        the longest single interval, and the same under
-        ``since_last_summary``; both go to the registry too."""
+        the longest single interval, with what the timer tallied for the
+        name beside them (``serve.prefill_chunk``: ``rows`` and
+        ``padded``), and the same under ``since_last_summary``; both go to
+        the registry too."""
         recent = self.timer.take_longest()
+        tallies = self.timer.tallies()
+        tallied = {name: tallies.get(name, {}) for name in totals}
         delta = self._since_last_summary(
-            "phases", {name: t[:2] for name, t in totals.items()})
-        since = {name: (*delta[name], recent[name]) for name in totals}
-        for scope, by_name in (("total", totals), (SINCE_LAST, since)):
+            "phases", {name: [*t[:2], *tallied[name].values()]
+                       for name, t in totals.items()})
+        now = {name: (*t, *tallied[name].values())
+               for name, t in totals.items()}
+        since = {name: (*delta[name][:2], recent[name], *delta[name][2:])
+                 for name in totals}
+        for scope, by_name in (("total", now), (SINCE_LAST, since)):
             for name, values in by_name.items():
                 for gauge, value in zip(self._phase_gauges, values):
                     gauge.set(float(value), phase=name, scope=scope,
                               **self._rlabels)
+                for key, value in zip(tallied[name], values[3:]):
+                    self._tally_gauge.set(float(value), phase=name, kind=key,
+                                          scope=scope, **self._rlabels)
 
         def blocks(by_name: Dict[str, tuple]) -> Dict[str, Any]:
-            return {name: dict(zip(PHASE_FIELDS, values))
+            return {name: {**dict(zip(PHASE_FIELDS, values)),
+                           **dict(zip(tallied[name], values[3:]))}
                     for name, values in by_name.items()}
 
-        return {**blocks(totals), SINCE_LAST: blocks(since)}
+        return {**blocks(now), SINCE_LAST: blocks(since)}
 
     def analyze_programs(self, ledger: Any,
                          memory: Optional[bool] = None) -> Any:

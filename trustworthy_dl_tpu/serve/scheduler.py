@@ -14,10 +14,14 @@ the one pool layout (``kv_slots.PagedKV``):
   blocks even after evicting cached prefixes, is backpressure: the task
   stays queued, untouched.
 * **chunked prefill** — the unshared suffix of a prompt is fed
-  ``prefill_chunk`` positions a tick (``paged_chunk``: one compiled
-  program for every chunk of every prompt; a fresh prompt that fits one
-  chunk takes ``paged_prefill``), beside the decode step, so a long
-  prompt never head-of-line-blocks the live streams.
+  ``prefill_chunk`` positions a tick, beside the decode step, so a long
+  prompt never head-of-line-blocks the live streams.  One call of
+  ``paged_chunk`` holds the next chunk of up to ``chunk_call_rows``
+  mid-prefill slots, padded to that many rows (ONE compiled program,
+  compiled at its first call; a call a slot where the description's
+  chunk is one slot's); a fresh prompt that fits one chunk
+  takes ``paged_prefill``.  The chunks are pulled after the decode step's
+  dispatch, so the device runs the two back to back.
 * **decode** — one program for the scheduler's lifetime
   (``paged_decode``): [MAX_SLOTS] tokens in, [MAX_SLOTS] next tokens out,
   attending through per-slot BLOCK TABLES.  Tables and lengths are traced
@@ -154,11 +158,12 @@ def _local_prefill(cfg: gpt2.GPT2Config, view: Any, tokens: jax.Array,
     return logits, local.k, local.v, None, None
 
 
-def _sample_pack(logits: jax.Array, key: jax.Array, temp: jax.Array,
+def _sample_pack(logits: jax.Array, keys: jax.Array, temps: jax.Array,
                  greedy: jax.Array, attn_impl: str = "jnp") -> jax.Array:
-    """Single-slot sampling tail: first token + trust signals as one
-    packed f32[3, 1] — a single host sync per prefill, not three."""
-    token = _sample_tokens(logits, key[None], temp[None], greedy[None])
+    """The paged programs' sampling tail, a row each: the sampled token and
+    the trust signals of ``logits`` [R, V] as one packed f32[3, R] — a
+    single host sync per call, not three."""
+    token = _sample_tokens(logits, keys, temps, greedy)
     ent, margin = _logit_signals(logits, attn_impl)
     return _pack_step_outputs(token, ent, margin)
 
@@ -198,60 +203,67 @@ def _paged_prefill_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
         new_vs = pool_vs.at[:, block_ids].set(to_blocks(v_s))
     else:
         new_ks, new_vs = pool_ks, pool_vs
-    return new_k, new_v, new_ks, new_vs, _sample_pack(logits, key, temp,
-                                                      greedy, attn_impl)
+    return new_k, new_v, new_ks, new_vs, _sample_pack(
+        logits, key[None], temp[None], greedy[None], attn_impl)
 
 
 def _paged_chunk_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
                       pool_v: jax.Array, pool_ks: Any, pool_vs: Any,
                       view: Any, tokens: jax.Array, table: jax.Array,
                       start: jax.Array, last_idx: jax.Array,
-                      key: jax.Array, temp: jax.Array, greedy: jax.Array,
+                      keys: jax.Array, temps: jax.Array, greedy: jax.Array,
                       attn_impl: str = "jnp", adapter_impl: str = "jnp",
                       adapter_a: Any = None, adapter_b: Any = None,
                       adapter_as: Any = None, adapter_bs: Any = None,
                       apages: Any = None, state: Any = None,
                       slot: Any = None):
-    """One CHUNK of a paged prefill: C prompt positions starting at
-    ``start`` (block-aligned — a prefix-cache hit starts the suffix at a
-    block boundary), attending to everything already in the slot's
-    blocks (shared prefix included) through the gathered view and
-    scattering its own K/V into the pool.  ``last_idx`` locates the
-    prompt's last real position within this chunk; the sampled token is
-    meaningful only on the final chunk (the host ignores it otherwise).
-    One compiled program serves every chunk of every prompt.
+    """One CHUNK of paged prefill for each of R mid-prefill slots, in one
+    call: row ``r`` feeds ``tokens[r]`` (C prompt positions) from
+    ``start[r]`` (block-aligned — a prefix-cache hit starts the suffix at a
+    block boundary) through its block table ``table[r]``, attending to
+    everything already in the slot's blocks (shared prefix included) and
+    scattering its own K/V into the pool; ``last_idx[r]`` locates the
+    prompt's last real position within the row's chunk.  ``keys`` u32[R,
+    2], ``temps`` and ``greedy`` sample a token a row, meaningful only on a
+    prompt's final chunk (the host ignores it otherwise).  A padding row
+    (an all-trash table, start 0, ``last_idx`` 0, tokens 0) writes only the
+    trash block and is never read.  One compiled program (R =
+    ``chunk_call_rows``) serves every chunk of every prompt.  Returns the
+    pool and the packed f32[3, R] (token, entropy, margin).
 
     The trailing adapter args are the paged adapter pool's device sides
-    plus the single-row page table ``apages`` i32[1] (serve/adapters.py)
-    — None on adapterless engines, where they contribute zero pytree
-    leaves and the trace is the pre-adapter one (bit-identity).
-    ``adapter_impl`` (static, like ``attn_impl``) routes the per-layer
-    page gather through the in-grid ``ops.adapter_delta`` kernel.
+    plus the per-row page table ``apages`` i32[R] (serve/adapters.py;
+    ZERO_PAGE rows add an exact-zero delta) — None on adapterless
+    engines, where they contribute zero pytree leaves and the trace is the
+    pre-adapter one (bit-identity).  ``adapter_impl`` (static, like
+    ``attn_impl``) routes the per-layer page gather through the in-grid
+    ``ops.adapter_delta`` kernel.
 
     A ``models.decoder.DecoderConfig`` (the description's type decides)
-    runs ``decoder.apply_paged`` instead: ``state`` is the recurrent state
-    beside the pool (``kv_slots.RecurrentState``, donated like the pool)
-    and ``slot`` i32[] the row of it this chunk reads and writes; the
-    chunk's real positions are those up to ``last_idx``.  The updated
-    state is returned last.  Where its layers keep LATENT rows, ``pool_k``
-    is the one array of them and ``pool_v`` None, in and out (no leaf: none
-    is carried or donated)."""
+    runs ``decoder.apply_paged`` instead, whose chunk is one slot's (R =
+    ``decoder.CHUNK_ROWS``): ``state`` is the recurrent state beside the
+    pool (``kv_slots.RecurrentState``, donated like the pool) and ``slot``
+    i32[R] the row of it this chunk reads and writes; the chunk's real
+    positions are those up to ``last_idx``.  The updated state is returned
+    last.  Where its layers keep LATENT rows, ``pool_k`` is the one array
+    of them and ``pool_v`` None, in and out (no leaf: none is carried or
+    donated)."""
     if isinstance(cfg, decoder.DecoderConfig):
-        valid = (jnp.arange(tokens.shape[0]) <= last_idx)[None, :]
+        valid = jnp.arange(tokens.shape[1])[None, :] <= last_idx[:, None]
         logits, new_k, new_v, state = decoder.apply_paged(
-            view, tokens[None, :], pool_k, pool_v, state, table, start,
-            cfg, valid, slot=slot, last_pos=last_idx, attn_impl=attn_impl)
+            view, tokens, pool_k, pool_v, state, table, start[0], cfg,
+            valid, slot=slot[0], last_pos=last_idx[0], attn_impl=attn_impl)
         return new_k, new_v, None, None, _sample_pack(
-            logits, key, temp, greedy, attn_impl), state
+            logits, keys, temps, greedy, attn_impl), state
     adapter = (None if adapter_a is None
                else (adapter_a, adapter_b, adapter_as, adapter_bs, apages))
     logits, new_k, new_v, new_ks, new_vs = gen._apply_with_cache_paged(
-        view, tokens[None, :], pool_k, pool_v, pool_ks, pool_vs,
-        table, start, cfg, last_pos=last_idx, attn_impl=attn_impl,
-        adapter=adapter, adapter_impl=adapter_impl,
+        view, tokens, pool_k, pool_v, pool_ks, pool_vs, table, start, cfg,
+        last_pos=last_idx, attn_impl=attn_impl, adapter=adapter,
+        adapter_impl=adapter_impl,
     )
-    return new_k, new_v, new_ks, new_vs, _sample_pack(logits, key, temp,
-                                                      greedy, attn_impl)
+    return new_k, new_v, new_ks, new_vs, _sample_pack(
+        logits, keys, temps, greedy, attn_impl)
 
 
 def _paged_decode_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
@@ -299,9 +311,7 @@ def _paged_decode_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
             tables, lengths, cfg, attn_impl=attn_impl, adapter=adapter,
             adapter_impl=adapter_impl,
         )
-    next_tok = _sample_tokens(logits, keys, temps, greedy)
-    ent, margin = _logit_signals(logits, attn_impl)
-    packed = _pack_step_outputs(next_tok, ent, margin)
+    packed = _sample_pack(logits, keys, temps, greedy, attn_impl)
     if state is not None:
         return packed, new_k, new_v, new_ks, new_vs, state
     return packed, new_k, new_v, new_ks, new_vs
@@ -452,6 +462,24 @@ def request_key_stream(rng: jax.Array, max_new_tokens: int) -> np.ndarray:
     return np.stack(keys)
 
 
+#: The most prompt positions one call of the chunk program holds.  At 512
+#: rows a dense product is past the chip's ridge (v5e: 197 TFLOP/s over
+#: 819 GB/s, some 240 rows against a bf16 weight), so a larger call buys no
+#: device time, while each further compiled size costs a trace and a
+#: lowering before serving (1.1 to 1.4 s each for GPT-2 large on a v5e
+#: host).
+CHUNK_CALL_POSITIONS = 512
+
+
+def chunk_call_rows(chunk: int, max_slots: int) -> int:
+    """The rows of the one compiled chunk program, where a chunk is
+    ``chunk`` positions: as many as ``CHUNK_CALL_POSITIONS`` holds, at
+    least one and at most ``max_slots`` (8 at GPT-2 large's 24 slots and
+    chunk 64).  A call of fewer mid-prefill slots is padded up to it; more
+    take a call per that many."""
+    return max(1, min(max_slots, CHUNK_CALL_POSITIONS // chunk))
+
+
 @dataclasses.dataclass
 class SlotTask:
     """Host-side record of one in-flight sequence (scheduler's view)."""
@@ -551,6 +579,12 @@ def refuse_unsupported(cfg: Any, *, prefix_cache: bool, spec_k: int,
             "layout in the sharding registry yet")
 
 
+#: One prefill call as the tick keeps it between its dispatch and its pull:
+#: the packed (token, entropy, margin) of its rows, still on the device, and
+#: the (row, slot) of each prompt the call finished.
+_PrefillCall = Tuple[jax.Array, List[Tuple[int, int]]]
+
+
 @dataclasses.dataclass
 class _PrefillProgress:
     """Host record of a slot mid-prefill (chunked): ``pos`` is the next
@@ -634,6 +668,11 @@ class PagedBatchingScheduler:
         else:
             self.chunk = resolve_prefill_chunk(max_seq, block_size,
                                                prefill_chunk)
+        #: The rows of one chunk call, the one size the chunk program is
+        #: compiled for: the mid-prefill slots a call holds, padding
+        #: included.  One where the description's chunk is one slot's.
+        self.chunk_rows = (decoder.CHUNK_ROWS if self.recurrent
+                           else chunk_call_rows(self.chunk, max_slots))
         self.kv = init_paged_pool(cfg, self.num_blocks, block_size,
                                   kv_dtype=q8.resolve_kv_dtype(kv_dtype,
                                                                cfg))
@@ -874,27 +913,168 @@ class PagedBatchingScheduler:
         row[:len(t)] = t
         return row
 
-    def _advance_prefill(self, slot: int) -> Optional[SlotTask]:
-        """Run ONE chunk for a prefilling slot; returns the task when the
-        chunk completed its prompt (first token recorded)."""
-        st = self._prefill[slot]
-        task = st.task
-        n_real = min(st.plen - st.pos, self.chunk)
-        final = st.pos + n_real >= st.plen
-        with span("serve.prefill_chunk", self.timer, slot=int(slot),
-                  pos=int(st.pos), tokens=int(n_real), final=bool(final),
-                  **request_args(task)):
-            with span("serve.prefill_chunk.dispatch", self.timer):
-                packed = self._dispatch_chunk(slot, st, n_real)
-            if not final:
+    def _takes_whole_prompt(self, st: _PrefillProgress) -> bool:
+        """Whole prompt in one chunk, nothing shared: the full-precision
+        local prefill (``generate()``'s numerics, bit-for-bit — the int8
+        tier quantizes once at the block write).  An adapter-carrying
+        request takes the chunk program instead (its prompt must run
+        through the adapter-delta'd layers), and so does every prompt of a
+        description with recurrent state (it has no whole-prompt
+        program)."""
+        return (st.pos == 0 and st.plen <= self.chunk
+                and st.task.adapter_page == ZERO_PAGE and not self.recurrent)
+
+    def _dispatch_prefill(self) -> List[_PrefillCall]:
+        """Dispatch this tick's prefill, ONE chunk for every mid-prefill
+        slot: a whole-prompt call for each prompt that takes one, then the
+        chunk program over all the other slots, ``chunk_rows`` of them a
+        call (a call a slot where the description's chunk is one slot's).
+        Nothing is pulled."""
+        whole, rest = [], []
+        for slot in sorted(self._prefill):
+            (whole if self._takes_whole_prompt(self._prefill[slot])
+             else rest).append(slot)
+        most = self.chunk_rows
+        return ([self._prefill_call([slot], whole=True) for slot in whole]
+                + [self._prefill_call(rest[i:i + most])
+                   for i in range(0, len(rest), most)])
+
+    def _prefill_call(self, slots: List[int],
+                      whole: bool = False) -> _PrefillCall:
+        """One program call for ``slots``' next chunks; every slot whose
+        chunk does not end its prompt moves on by a chunk."""
+        progress = [self._prefill[slot] for slot in slots]
+        fed = [min(st.plen - st.pos, self.chunk) for st in progress]
+        final = [(row, slot) for row, (slot, st, n)
+                 in enumerate(zip(slots, progress, fed))
+                 if st.pos + n >= st.plen]
+        rows = len(slots)
+        padded = 0 if whole else self.chunk_rows - rows
+        one = request_args(progress[0].task) if rows == 1 else {}
+        with span("serve.prefill_chunk", self.timer, rows=rows,
+                  padded=padded, final=len(final), **one):
+            if whole:
+                with span("serve.prefill_chunk.dispatch", self.timer):
+                    packed = self._dispatch_whole_prompt(slots[0],
+                                                         progress[0])
+            else:
+                packed = self._dispatch_chunk(slots, progress, fed,
+                                              rows + padded)
+        if self.timer is not None:
+            self.timer.tally("serve.prefill_chunk", rows=rows, padded=padded)
+        done = {slot for _, slot in final}
+        for slot, st in zip(slots, progress):
+            if slot not in done:
                 st.pos += self.chunk
-                return None
+        return packed, final
+
+    def _dispatch_whole_prompt(self, slot: int,
+                               st: _PrefillProgress) -> jax.Array:
+        """The whole-prompt program for one fresh prompt that fits a chunk;
+        its pool becomes the scheduler's.  Returns the packed (token,
+        entropy, margin) still on the device."""
+        task = st.task
+        c = self.chunk
+        chunk = np.zeros(c, np.int32)
+        chunk[:st.plen] = task.prompt
+        ids = np.full(c // self.block_size, TRASH_BLOCK, np.int32)
+        n_ids = min(len(self.tables[slot]), len(ids))
+        ids[:n_ids] = self.tables[slot][:n_ids]
+        kv = self.kv
+        new_k, new_v, new_ks, new_vs, packed = _programs()["paged_prefill"](
+            self.cfg, kv.k, kv.v, kv.k_scale, kv.v_scale, self.view,
+            jnp.asarray(chunk), jnp.asarray(st.plen, jnp.int32),
+            jnp.asarray(ids), jnp.asarray(task.keys[0], jnp.uint32),
+            jnp.asarray(max(task.temperature, 1e-6), jnp.float32),
+            jnp.asarray(task.greedy), attn_impl=self.attn_impl,
+        )
+        self.kv = PagedKV(k=new_k, v=new_v, k_scale=new_ks, v_scale=new_vs)
+        return packed
+
+    def _chunk_args(self, slots: List[int], progress: List[_PrefillProgress],
+                    fed: List[int], rows: int
+                    ) -> Tuple[tuple, Dict[str, Any]]:
+        """The chunk program's arguments for ``slots`` (their chunks of
+        ``fed`` positions) padded to ``rows``: a padding row has an
+        all-trash table, start 0, ``last_idx`` 0 and tokens 0.  With no
+        slot at all, every row is padding (what the cost ledger is
+        handed)."""
+        c = self.chunk
+        tokens = np.zeros((rows, c), np.int32)
+        tables = np.full((rows, self.nbps), TRASH_BLOCK, np.int32)
+        start = np.zeros(rows, np.int32)
+        last = np.zeros(rows, np.int32)
+        keys = np.zeros((rows, 2), np.uint32)
+        temps = np.ones(rows, np.float32)
+        greedy = np.ones(rows, bool)
+        pages = np.full(rows, ZERO_PAGE, np.int32)
+        state_rows = np.zeros(rows, np.int32)
+        for row, (slot, st, n) in enumerate(zip(slots, progress, fed)):
+            task = st.task
+            tokens[row, :n] = task.prompt[st.pos:st.pos + n]
+            tables[row] = self._table_row(slot)
+            start[row] = st.pos
+            last[row] = n - 1
+            keys[row] = task.keys[0]
+            temps[row] = max(task.temperature, 1e-6)
+            greedy[row] = task.greedy
+            pages[row] = task.adapter_page
+            state_rows[row] = slot
+        kv = self.kv
+        args = (self.cfg, kv.k, kv.v, kv.k_scale, kv.v_scale, self.view,
+                jnp.asarray(tokens), jnp.asarray(tables), jnp.asarray(start),
+                jnp.asarray(last), jnp.asarray(keys), jnp.asarray(temps),
+                jnp.asarray(greedy))
+        kwargs: Dict[str, Any] = dict(
+            attn_impl=self.attn_impls["prefill"],
+            adapter_impl=self.attn_impls["adapter"])
+        if self.adapters is not None:
+            a, b, a_s, b_s = self.adapters.device_args()
+            kwargs.update(adapter_a=a, adapter_b=b, adapter_as=a_s,
+                          adapter_bs=b_s, apages=jnp.asarray(pages))
+        if self.recurrent:
+            kwargs.update(state=self.state, slot=jnp.asarray(state_rows))
+        return args, kwargs
+
+    def _dispatch_chunk(self, slots: List[int],
+                        progress: List[_PrefillProgress],
+                        fed: List[int], rows: int) -> jax.Array:
+        """Upload the rows, padded to ``rows`` (``chunk_rows``), and call
+        the chunk program once; the pool (and the state) the call
+        returns become the scheduler's.  Returns the packed (token,
+        entropy, margin) of every row, still on the device."""
+        args, kwargs = self._chunk_args(slots, progress, fed, rows)
+        with span("serve.prefill_chunk.dispatch", self.timer), \
+                guarded(self.compilewatch, "serve_chunk"):
+            new_k, new_v, new_ks, new_vs, packed, *state = _programs()[
+                "paged_chunk"](*args, **kwargs)
+        if self.recurrent:
+            (self.state,) = state
+        self.kv = PagedKV(k=new_k, v=new_v, k_scale=new_ks, v_scale=new_vs)
+        return packed
+
+    def _record_prefill(self, calls: List[_PrefillCall]
+                        ) -> List[SlotTask]:
+        """Pull each prefill call that finished a prompt, once, and record
+        the first tokens in slot order: the prompt's length becomes the
+        slot's and its full blocks are published to the prefix cache.
+        Returns the tasks that received their first token."""
+        firsts: Dict[int, np.ndarray] = {}
+        for packed, final in calls:
+            if not final:
+                continue
             with span("serve.prefill_chunk.pull", self.timer):
-                # tddl-lint: disable=host-sync — the one pull a prefill
-                token, ent, margin = np.asarray(packed)[:, 0]
+                # tddl-lint: disable=host-sync — a prefill call's one pull
+                host = np.asarray(packed)
+            for row, slot in final:
+                firsts[slot] = host[:, row]
+        ticked: List[SlotTask] = []
+        for slot in sorted(firsts):
+            token, ent, margin = firsts[slot]
+            st = self._prefill.pop(slot)
+            task = st.task
             task._record(int(token), float(ent), float(margin))
             self.lengths[slot] = st.plen
-            del self._prefill[slot]
             if self.prefix is not None and task.publish_prefix:
                 # The prompt's FULL blocks are now authoritative in the
                 # pool — publish them so later same-prefix requests skip
@@ -908,90 +1088,29 @@ class PagedBatchingScheduler:
                     self.tables[slot][:st.plen // self.block_size],
                     publisher=task.request_id,
                 )
-            return task
-
-    def _dispatch_chunk(self, slot: int, st: _PrefillProgress,
-                        n_real: int) -> jax.Array:
-        """Upload one chunk's inputs and call its program; the pool (and
-        the state) the call returns become the scheduler's.  Returns the
-        packed (token, entropy, margin) still on the device."""
-        task = st.task
-        c = self.chunk
-        chunk = np.zeros(c, np.int32)
-        chunk[:n_real] = task.prompt[st.pos:st.pos + n_real]
-        kv = self.kv
-        if (st.pos == 0 and st.plen <= c and task.adapter_page == ZERO_PAGE
-                and not self.recurrent):
-            # Whole prompt in one chunk, nothing shared: full-precision
-            # local prefill (``generate()``'s numerics, bit-for-bit —
-            # the int8 tier quantizes once at the block write).  An
-            # adapter-carrying request takes the chunk path below
-            # instead: its prompt must run through the adapter-delta'd
-            # layers.
-            ids = np.full(c // self.block_size, TRASH_BLOCK, np.int32)
-            n_ids = min(len(self.tables[slot]), len(ids))
-            ids[:n_ids] = self.tables[slot][:n_ids]
-            new_k, new_v, new_ks, new_vs, packed = _programs()[
-                "paged_prefill"](
-                self.cfg, kv.k, kv.v, kv.k_scale, kv.v_scale, self.view,
-                jnp.asarray(chunk), jnp.asarray(st.plen, jnp.int32),
-                jnp.asarray(ids),
-                jnp.asarray(task.keys[0], jnp.uint32),
-                jnp.asarray(max(task.temperature, 1e-6), jnp.float32),
-                jnp.asarray(task.greedy),
-                attn_impl=self.attn_impl,
-            )
-        else:
-            last_idx = int(np.clip(st.plen - 1 - st.pos, 0, c - 1))
-            extra: Dict[str, Any] = {}
-            if self.adapters is not None:
-                a, b, a_s, b_s = self.adapters.device_args()
-                extra = dict(
-                    adapter_a=a, adapter_b=b, adapter_as=a_s,
-                    adapter_bs=b_s,
-                    apages=jnp.asarray([task.adapter_page], jnp.int32),
-                )
-            if self.recurrent:
-                extra = dict(state=self.state,
-                             slot=jnp.asarray(slot, jnp.int32))
-            new_k, new_v, new_ks, new_vs, packed, *state = _programs()[
-                "paged_chunk"](
-                self.cfg, kv.k, kv.v, kv.k_scale, kv.v_scale, self.view,
-                jnp.asarray(chunk), jnp.asarray(self._table_row(slot)[None]),
-                jnp.asarray(st.pos, jnp.int32),
-                jnp.asarray(last_idx, jnp.int32),
-                jnp.asarray(task.keys[0], jnp.uint32),
-                jnp.asarray(max(task.temperature, 1e-6), jnp.float32),
-                jnp.asarray(task.greedy),
-                attn_impl=self.attn_impls["prefill"],
-                adapter_impl=self.attn_impls["adapter"],
-                **extra,
-            )
-            if self.recurrent:
-                (self.state,) = state
-        self.kv = PagedKV(k=new_k, v=new_v, k_scale=new_ks, v_scale=new_vs)
-        return packed
+            ticked.append(task)
+        return ticked
 
     def decode_tick(self) -> List[SlotTask]:
-        """One engine tick: advance every mid-prefill slot by ONE chunk
-        (prompts finishing their last chunk emit their first token), then
-        run the fused decode step for every decode-phase slot.  Returns
-        the tasks that received a token this tick."""
-        ticked: List[SlotTask] = []
-        finished_prefill = set()
-        for slot in sorted(self._prefill):
-            done = self._advance_prefill(slot)
-            if done is not None:
-                finished_prefill.add(slot)
-                ticked.append(done)
+        """One engine tick: dispatch ONE chunk of every mid-prefill slot
+        (``_dispatch_prefill``), then build and dispatch the fused decode
+        step for every decode-phase slot, and only then pull: the prompts
+        that finished this tick record their first tokens, then the
+        decode step's rows record theirs.  A slot finishing its prompt is
+        not in this tick's decode, so the decode call needs nothing from
+        the chunk's pull and the device runs the two back to back.  A
+        speculative tick pulls the chunks first (its draft chain pulls
+        before its verify).  Returns the tasks that received a token this
+        tick, the first tokens first."""
+        calls = self._dispatch_prefill()
         active = {s: t for s, t in self.tasks.items()
-                  if s not in self._prefill and not t.done
-                  and s not in finished_prefill}
+                  if s not in self._prefill and not t.done}
         if not active:
-            return ticked
+            return self._record_prefill(calls)
         if self.spec_k > 0 and any(
                 t.max_new_tokens - len(t.emitted) > 1
                 for t in active.values()):
+            ticked = self._record_prefill(calls)
             ticked.extend(self._spec_tick(active))
             return ticked
         if self.spec_k > 0:
@@ -1000,6 +1119,14 @@ class PagedBatchingScheduler:
             # (today's fused decode, the third compiled decode-phase
             # program of a spec engine).
             self.spec_fallback_ticks += 1
+        packed = self._dispatch_decode(active)
+        ticked = self._record_prefill(calls)
+        ticked.extend(self._record_decode(active, packed))
+        return ticked
+
+    def _dispatch_decode(self, active: Dict[int, SlotTask]) -> jax.Array:
+        """Build and dispatch the fused decode step for the ``active``
+        slots; returns its packed outputs, still on the device."""
         ms = self.allocator.max_slots
         with span("serve.decode_tick.build", self.timer):
             tokens = np.zeros(ms, np.int32)
@@ -1033,12 +1160,14 @@ class PagedBatchingScheduler:
                 extra = dict(state=self.state, active=jnp.asarray(live))
         with span("serve.decode_tick.dispatch", self.timer), \
                 guarded(self.compilewatch, "serve_decode"):
+            # The lengths go up as a copy: the prompts this tick finishes
+            # set theirs before this call's pull.
             packed, new_k, new_v, new_ks, new_vs, *state = \
                 _programs()["paged_decode"](
                     self.cfg, kv.k, kv.v, kv.k_scale, kv.v_scale,
                     self.view,
                     jnp.asarray(tokens), jnp.asarray(tables),
-                    jnp.asarray(self.lengths),
+                    jnp.asarray(self.lengths.copy()),
                     jnp.asarray(keys), jnp.asarray(temps),
                     jnp.asarray(greedy),
                     attn_impl=self.attn_impl,
@@ -1048,9 +1177,16 @@ class PagedBatchingScheduler:
         self.kv = PagedKV(k=new_k, v=new_v, k_scale=new_ks, v_scale=new_vs)
         if self.recurrent:
             (self.state,) = state
+        return packed
+
+    def _record_decode(self, active: Dict[int, SlotTask],
+                       packed: jax.Array) -> List[SlotTask]:
+        """Pull the decode step's rows once and record a token for every
+        ``active`` slot."""
         with span("serve.decode_tick.pull", self.timer):
             # tddl-lint: disable=host-sync — the tick's one intended pull
             host = np.asarray(packed)
+        ticked: List[SlotTask] = []
         with span("serve.decode_tick.record", self.timer):
             next_tok, ent, margin = host[0], host[1], host[2]
             for slot in active:
@@ -1430,11 +1566,8 @@ class PagedBatchingScheduler:
         pool = (kv.k, kv.v, kv.k_scale, kv.v_scale)
         # A description with recurrent state has no whole-prompt program,
         # and its two programs take the state beside the pool.
-        chunk_state: Dict[str, Any] = {}
         decode_state: Dict[str, Any] = {}
         if self.recurrent:
-            chunk_state = dict(state=self.state,
-                               slot=jnp.asarray(0, jnp.int32))
             decode_state = dict(state=self.state,
                                 active=jnp.ones(ms, bool))
         if not self.recurrent:
@@ -1446,15 +1579,9 @@ class PagedBatchingScheduler:
                 jnp.asarray(1.0, jnp.float32), jnp.asarray(True),
                 memory=memory, attn_impl=self.attn_impl,
             )
-        ledger.analyze(
-            "serve.paged_chunk", prog["paged_chunk"], self.cfg,
-            *pool, self.view, jnp.zeros(c, jnp.int32),
-            jnp.zeros((1, self.nbps), jnp.int32),
-            jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32),
-            jnp.zeros(2, jnp.uint32), jnp.asarray(1.0, jnp.float32),
-            jnp.asarray(True), memory=memory,
-            attn_impl=self.attn_impls["prefill"], **chunk_state,
-        )
+        args, kwargs = self._chunk_args([], [], [], self.chunk_rows)
+        ledger.analyze("serve.paged_chunk", prog["paged_chunk"], *args,
+                       memory=memory, **kwargs)
         ledger.analyze(
             "serve.paged_decode", prog["paged_decode"], self.cfg,
             *pool, self.view, jnp.zeros(ms, jnp.int32),

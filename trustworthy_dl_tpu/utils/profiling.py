@@ -32,11 +32,16 @@ One vocabulary of host spans, on the clock the device trace uses:
           serve.prefix_lookup             one request's cache lookup
           serve.tick.admit.zero_state     a state row zeroed (one call)
         serve.decode_tick               scheduler.decode_tick, whole
-          serve.prefill_chunk             one chunk of one request
+          serve.prefill_chunk             one prefill call: the next chunk
+                                          of up to ``chunk_rows``
+                                          mid-prefill slots (its ``rows``,
+                                          ``padded``, ``final``)
             serve.prefill_chunk.dispatch    uploads and the program call
-            serve.prefill_chunk.pull        a final chunk's packed pull
           serve.decode_tick.build         the decode call's host arrays
           serve.decode_tick.dispatch      uploads and the program call
+          serve.prefill_chunk.pull        a prefill call's packed pull,
+                                          where it finished a prompt:
+                                          after the decode dispatch
           serve.decode_tick.pull          the tick's packed pull
           serve.decode_tick.record        tokens into their requests
           serve.spec_draft, serve.spec_verify   (``spec_k`` > 0), each
