@@ -447,6 +447,92 @@ def test_latent_serving_program_lowers_in_place(v5e, monkeypatch, program):
         < 13 << 30                      # of the chip's 16 GB
 
 
+# -- the hybrid layer: rotary attention at 5 query heads a K/V head beside a
+# Mamba-2 state of [32, 128, 256] a slot (benchmark/configs/falcon-h1-...).
+FQ, FKV, FDH = 20, 4, 128
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_paged_attention_lowers_at_five_heads_a_group(v5e, program):
+    """The ONE paged kernel with the 5 query heads of each K/V head as rows
+    of its products (no cell ran a group of 5 before), both shapes, at the
+    hybrid cell's pool: 64 slots of 8,192 positions in blocks of 64."""
+    nbps = GMAX // GBLOCK
+    rows, t = (GSLOTS, 1) if program == "decode" else (1, GCHUNK)
+    grid = pa.grid_steps(program, rows, FQ, nbps, t, FDH, GBLOCK,
+                         jnp.bfloat16, kv_heads=FKV)
+    assert pa.supports_paged_attention(
+        head_dim=FDH, block_size=GBLOCK, kv_dtype=jnp.bfloat16,
+        interpret=False, program=program, n_embd=FKV * FDH)
+    rep = FQ // FKV
+    t_pad = max(t, pa.QROWS)
+    pool = S((1, GSLOTS * nbps + 1, GBLOCK, FKV * FDH), jnp.bfloat16)
+    _compile(_PAGED_CALL[program], v5e,
+             S((rows, FKV, rep * t_pad, FDH), jnp.bfloat16), pool, pool,
+             None, None, S((rows, nbps), jnp.int32), S((rows,), jnp.int32),
+             S((rows, grid[2]), jnp.int32), S((1,), jnp.int32),
+             interpret=False, rep=rep)
+
+
+def test_the_ssm_step_kernel_lowers_in_place(v5e):
+    """The Mamba-2 decode step over a stacked state of 4 layers x 64 slots:
+    it compiles under its own name, updates the state in the buffer it came
+    in (aliased, donated) and needs no temporary the size of a layer's
+    state (268 MB)."""
+    from trustworthy_dl_tpu.ops import ssm_step as ss
+
+    state = S((4, GSLOTS, 32, 128, 256), jnp.float32)
+    jitted = jax.jit(ss.ssm_step, static_argnames=("interpret",),
+                     donate_argnums=(0,))
+    compiled = _compile(
+        jitted, v5e, state, S((), jnp.int32),
+        S((GSLOTS, 32, 128), jnp.float32), S((GSLOTS, 32), jnp.float32),
+        S((32,), jnp.float32), S((GSLOTS, 2, 256), jnp.float32),
+        S((GSLOTS, 2, 256), jnp.float32), S((32,), jnp.float32),
+        interpret=False)
+    assert "ssm_step" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= state.size * 4
+    assert memory.temp_size_in_bytes < 16 << 20
+
+
+@pytest.mark.parametrize("program", ["chunk", "decode"])
+def test_hybrid_serving_program_lowers_in_place(v5e, monkeypatch, program):
+    """Both scheduler programs for the Falcon-H1 configuration at its
+    published widths, pool and Mamba-2 state donated as on the chip: they
+    compile with the paged kernel (and, in a decode step, ``ssm_step``);
+    pool AND state are updated in the buffers they came in; no copy of the
+    state, of a layer of it or of the pool exists (the state of all layers
+    is produced only by its parameter, the kernel or an in-place update);
+    arguments and temporaries stay under 15.5 GB of the chip's 16 GiB."""
+    import re
+
+    from benchmark.harness.families import falcon_h1
+
+    compiled, kv, state = _decoder_program(
+        v5e, monkeypatch, program, falcon_h1, "falcon-h1-34b-pp18-1of18",
+        GSLOTS, GBLOCK, GMAX, GCHUNK)
+    assert state.s is None and state.ssm.shape == (4, GSLOTS, 32, 128, 256)
+    text = compiled.as_text()
+    if program == "decode":
+        assert "ssm_step" in text and "_paged_attn_call" in text
+    else:
+        assert "_paged_prefill_call" in text and "ssm_step" not in text
+    for shape in (state.ssm.shape, state.ssm.shape[1:]):
+        made = set(re.findall(re.escape("f32[%s]" % ",".join(
+            map(str, shape))) + r"\S* ([a-z\-]+)\(", text))
+        assert made <= {"parameter", "get-tuple-element", "custom-call",
+                        "dynamic-update-slice", "fusion", "bitcast"}, made
+        assert "copy" not in made
+    memory = compiled.memory_analysis()
+    carried = kv.k.size * 2 * 2 + state.ssm.size * 4 \
+        + state.ssm_conv.size * 4
+    assert memory.alias_size_in_bytes >= carried
+    assert memory.temp_size_in_bytes < 256 << 20
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 15.5e9
+
+
 def _flash_forward(dev, dtype, bh=H, t=1024, d=DH, causal=True):
     q = S((bh, t, d), dtype)
     _compile(_flash_fwd, dev, q, q, q, causal=causal,

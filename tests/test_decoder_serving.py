@@ -79,8 +79,9 @@ def served_logits(params, cfg, prompts, replies, attn_impl="jnp",
     def settle(state):
         if state_dtype is None:
             return state
-        return state._replace(s=state.s.astype(state_dtype).astype(
-            jnp.float32))
+        rounded = lambda a: None if a is None else a.astype(
+            state_dtype).astype(jnp.float32)
+        return state._replace(s=rounded(state.s), ssm=rounded(state.ssm))
 
     while any(len(out[s]) < len(replies[s]) for s in range(slots)):
         decoding = [s for s in range(slots) if fed[s] >= len(prompts[s])

@@ -62,15 +62,19 @@ def rmsnorm(scale: jax.Array, x: jax.Array, eps: float = 1e-5) -> jax.Array:
         * scale
 
 
-def silu_gated_mlp(w_gate_up: jax.Array, w_down: jax.Array, x: jax.Array
-                   ) -> jax.Array:
-    """``W_down(SiLU(W_gate x) * W_up x)`` with the gate and the up
+def silu_gated_mlp(w_gate_up: jax.Array, w_down: jax.Array, x: jax.Array,
+                   gate_multiplier: float = 1.0) -> jax.Array:
+    """``W_down(SiLU(m W_gate x) * W_up x)`` with the gate and the up
     projection side by side in ONE matrix ``[dim, 2 * width]`` (gate first);
-    products in the weights' dtype with float32 accumulation, float32 out."""
+    products in the weights' dtype with float32 accumulation, float32 out.
+    ``m`` is a muP multiplier (1: none)."""
     width = w_down.shape[0]
     h = jnp.matmul(x.astype(w_gate_up.dtype), w_gate_up,
                    preferred_element_type=jnp.float32)
-    a = jax.nn.silu(h[..., :width]) * h[..., width:]
+    gate = h[..., :width]
+    if gate_multiplier != 1.0:
+        gate = gate * gate_multiplier
+    a = jax.nn.silu(gate) * h[..., width:]
     return jnp.matmul(a.astype(w_down.dtype), w_down,
                       preferred_element_type=jnp.float32)
 

@@ -545,12 +545,23 @@ class ServingEngine:
         ).set(float(self.latent_pool_bytes), **self._rlabels)
         state = self.scheduler.state
         self.state_pool_bytes = state.pool_bytes if state is not None else 0
+        #: The same bytes by the kind of layer that keeps them (KDA's,
+        #: the Mamba-2 mixers'); empty for GPT-2.
+        self.state_bytes_by_kind = (
+            {kind: n for kind, n in state.bytes_by_kind.items() if n}
+            if state is not None else {})
         _metric(
             registry.gauge, "tddl_serve_state_pool_bytes",
             "Recurrent-state rows' HBM footprint (0 where every layer "
             "keeps keys and values)",
             labels=self._rlabel_names,
         ).set(float(self.state_pool_bytes), **self._rlabels)
+        by_kind = _metric(
+            registry.gauge, "tddl_serve_state_kind_bytes",
+            "Recurrent-state rows' HBM footprint by the kind of layer",
+            labels=("kind",) + self._rlabel_names)
+        for kind, n in self.state_bytes_by_kind.items():
+            by_kind.set(float(n), kind=kind, **self._rlabels)
         # Expert-layer counters (models.decoder descriptions): pulled off
         # the device by metrics_summary() alone.  The registry holds the
         # running totals and what was counted BETWEEN the last two
@@ -1623,6 +1634,7 @@ class ServingEngine:
         out["kv_pool_bytes"] = sched.kv.pool_bytes
         out["latent_pool_bytes"] = self.latent_pool_bytes
         out["state_pool_bytes"] = self.state_pool_bytes
+        out["state_bytes_by_kind"] = dict(self.state_bytes_by_kind)
         experts = sched.expert_counters()
         if experts is not None:
             out["moe"] = self._expert_summary(experts)

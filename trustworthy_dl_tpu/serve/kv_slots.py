@@ -49,11 +49,12 @@ kernels' eligibility all read them.
 
 A SECOND KIND OF CACHE lives beside the blocks for descriptions whose
 layers keep a state rather than keys and values (``RecurrentState`` below:
-linear-attention state and a short convolution's tail): one row a decode
-slot a layer, indexed by the slot itself, because a state does not grow
-with the sequence.  The pool then has layers only for the softmax attention
-layers, ``[L_attn, NUM_BLOCKS + 1, BLOCK, KV_HEADS·Dh]`` (or the latent
-layers, as above).  Unlike a block, a
+KDA's linear-attention state or a Mamba-2 mixer's, and a short
+convolution's tail): one row a decode slot a layer, indexed by the slot
+itself, because a state does not grow with the sequence.  The pool then has
+layers only for the softmax attention layers, ``[L_attn, NUM_BLOCKS + 1,
+BLOCK, KV_HEADS·Dh]`` (or the latent layers, as above); a hybrid layer,
+attention beside a Mamba-2 mixer, has both.  Unlike a block, a
 state row IS scrubbed: every position reads it, so it is zeroed when its
 slot is admitted and when a quarantined slot is released.
 
@@ -67,6 +68,7 @@ handed to the device, and inactive rows are pointed at block 0.
 from __future__ import annotations
 
 import heapq
+import math
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Set, Tuple
 
 import jax
@@ -316,41 +318,76 @@ def init_paged_pool(cfg: Any, num_blocks: int, block_size: int,
 
 
 class RecurrentState(NamedTuple):
-    """What the layers that keep a STATE (``models/kda.py``) cache, one row
-    a decode slot a layer, beside the block pool: the state does not grow
-    with the sequence, so a slot owns its row for as long as it owns the
-    slot and no table maps it.  The serving programs take it donated and
-    write it in place like the pool; a row is zeroed when its slot is
-    admitted and when a quarantined slot is released
-    (:func:`zero_state_rows`).
+    """What the layers that keep a STATE cache, one row a decode slot a
+    layer, beside the block pool: the state does not grow with the
+    sequence, so a slot owns its row for as long as it owns the slot and no
+    table maps it.  Two kinds, each with its own arrays (None where the
+    description has no layer of the kind, so no buffer is carried or
+    donated): KDA's (``models/kda.py``) and the Mamba-2 mixer's of a hybrid
+    layer (``models/mamba2.py``), which keeps K and V in the pool besides.
+    The serving programs take it donated and write it in place like the
+    pool; a slot's rows are zeroed when its slot is admitted and when a
+    quarantined slot is released (:func:`zero_state_rows`).
 
     Beside it ride the expert layers' counters, accumulated on the device
-    by the same programs and pulled only when a summary is asked for."""
+    by the same programs and pulled only when a summary is asked for (None
+    where no layer has experts)."""
 
-    s: jax.Array              # f32 [L_state, SLOTS, H, dk, dv]
-    conv: jax.Array           # f32 [L_state, SLOTS, K - 1, 3·H·dk]
-    expert_pairs: jax.Array   # i32 [L_expert, held]: (token, expert) pairs
+    s: Optional[jax.Array]            # KDA: f32 [L_kda, SLOTS, H, dk, dv]
+    conv: Optional[jax.Array]         # KDA: f32 [L_kda, SLOTS, K-1, 3·H·dk]
+    #: i32 [L_expert, held]: (token, expert) pairs.
+    expert_pairs: Optional[jax.Array]
     #: i32 []: tokens fed through an EXPERT layer, a layer each (a leading
     #: dense layer feeds none).  It grows by ``expert layers x tokens`` a
     #: call and WRAPS (after some 500k chunk calls of 1,024 tokens over 4
     #: layers): it is only ever read as the difference of two summaries,
     #: which the engine takes modulo 2**32.
-    expert_tokens: jax.Array
+    expert_tokens: Optional[jax.Array]
+    #: Mamba-2: f32 [L_hybrid, SLOTS, H, P, N], a head's ``h [P, N]``.
+    ssm: Optional[jax.Array] = None
+    #: Mamba-2: f32 [L_hybrid, SLOTS, K-1, H·P + 2·G·N], the convolution's
+    #: last input rows (``x``, ``B``, ``C`` before it).
+    ssm_conv: Optional[jax.Array] = None
+
+    @property
+    def bytes_by_kind(self) -> Dict[str, int]:
+        """HBM the state rows of each kind hold, state and convolution tail
+        (the counters are a few hundred bytes)."""
+        size = lambda *arrays: sum(a.nbytes for a in arrays if a is not None)
+        return {decoder.KDA: size(self.s, self.conv),
+                decoder.HYBRID: size(self.ssm, self.ssm_conv)}
 
     @property
     def pool_bytes(self) -> int:
-        """HBM the state rows hold (the counters are a few hundred bytes)."""
-        return self.s.nbytes + self.conv.nbytes
+        """HBM the state rows hold, every kind."""
+        return sum(self.bytes_by_kind.values())
+
+
+def _state_shapes(cfg: Any, slots: int) -> Dict[str, Tuple[int, ...]]:
+    """The state arrays of a description, by field, for ``slots`` slots:
+    those of the kinds it has layers of."""
+    tail = cfg.conv_size - 1
+    shapes = {}
+    if cfg.n_kda_layers:
+        h, d = cfg.kda_heads, cfg.kda_head_dim
+        shapes["s"] = (cfg.n_kda_layers, slots, h, d, d)
+        shapes["conv"] = (cfg.n_kda_layers, slots, tail, cfg.conv_channels)
+    if cfg.n_hybrid_layers:
+        layers = cfg.n_hybrid_layers
+        shapes["ssm"] = (layers, slots, cfg.mamba_heads, cfg.mamba_head_dim,
+                         cfg.mamba_state)
+        shapes["ssm_conv"] = (layers, slots, tail, cfg.mamba_conv_channels)
+    return shapes
 
 
 def state_bytes_per_slot(cfg: Any) -> int:
     """Bytes ONE slot's recurrent state costs WITHOUT allocating (0 for a
-    description with no such layer): the HBM budget's other term."""
+    description with no such layer), every kind: the HBM budget's other
+    term."""
     if not isinstance(cfg, decoder.DecoderConfig):
         return 0
-    per_layer = cfg.kda_heads * cfg.kda_head_dim ** 2 \
-        + (cfg.conv_size - 1) * cfg.conv_channels
-    return 4 * cfg.n_kda_layers * per_layer
+    return sum(4 * math.prod(shape)
+               for shape in _state_shapes(cfg, 1).values())
 
 
 def init_state_pool(cfg: Any, max_slots: int) -> Optional[RecurrentState]:
@@ -358,22 +395,25 @@ def init_state_pool(cfg: Any, max_slots: int) -> Optional[RecurrentState]:
     whose every layer keeps keys and values."""
     if not isinstance(cfg, decoder.DecoderConfig):
         return None
-    h, d = cfg.kda_heads, cfg.kda_head_dim
-    layers = cfg.n_kda_layers
+    rows = {name: jnp.zeros(shape, jnp.float32)
+            for name, shape in _state_shapes(cfg, max_slots).items()}
+    experts = cfg.n_expert_layers
     return RecurrentState(
-        s=jnp.zeros((layers, max_slots, h, d, d), jnp.float32),
-        conv=jnp.zeros((layers, max_slots, cfg.conv_size - 1,
-                        cfg.conv_channels), jnp.float32),
-        expert_pairs=jnp.zeros((cfg.n_expert_layers, cfg.n_experts_held),
-                               jnp.int32),
-        expert_tokens=jnp.zeros((), jnp.int32))
+        s=rows.get("s"), conv=rows.get("conv"),
+        expert_pairs=jnp.zeros((experts, cfg.n_experts_held), jnp.int32)
+        if experts else None,
+        expert_tokens=jnp.zeros((), jnp.int32) if experts else None,
+        ssm=rows.get("ssm"), ssm_conv=rows.get("ssm_conv"))
 
 
 def zero_state_rows(state: RecurrentState, slot: jax.Array
                     ) -> RecurrentState:
-    """``state`` with slot ``slot``'s rows of every layer zeroed."""
-    return state._replace(s=state.s.at[:, slot].set(0.0),
-                          conv=state.conv.at[:, slot].set(0.0))
+    """``state`` with slot ``slot``'s rows of every layer and kind
+    zeroed."""
+    zero = lambda a: None if a is None else a.at[:, slot].set(0.0)
+    return state._replace(s=zero(state.s), conv=zero(state.conv),
+                          ssm=zero(state.ssm),
+                          ssm_conv=zero(state.ssm_conv))
 
 
 class BlockAllocator:

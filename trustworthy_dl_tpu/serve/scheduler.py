@@ -550,20 +550,20 @@ def refuse_unsupported(cfg: Any, *, prefix_cache: bool, spec_k: int,
                        adapter_rank: int, kv_dtype: str, weight_dtype: str,
                        tp_size: int = 1) -> None:
     """Loud construction-time refusal of what a description with recurrent
-    state (``models.decoder.DecoderConfig``) cannot be served with yet, one
-    ``ValueError`` a mechanism; each stays queued in ROADMAP.md.  A
-    ``GPT2Config`` passes."""
+    state rows (``models.decoder.DecoderConfig``: KDA's or a Mamba-2
+    mixer's) cannot be served with yet, one ``ValueError`` a mechanism; each
+    stays queued in ROADMAP.md.  A ``GPT2Config`` passes."""
     if not isinstance(cfg, decoder.DecoderConfig):
         return
     if prefix_cache:
         raise ValueError(
-            "prefix_cache: a recurrent state cannot be truncated to a "
+            "prefix_cache: a recurrent state row cannot be truncated to a "
             "shared prefix (no state snapshots at block edges yet); build "
             "the engine with prefix_cache=False")
     if spec_k > 0:
         raise ValueError(
             f"spec_k={spec_k}: speculative decoding needs a rollback of "
-            "rejected drafts, and a recurrent state has none yet")
+            "rejected drafts, and a recurrent state row has none yet")
     if adapter_rank > 0:
         raise ValueError(
             f"adapter_rank={adapter_rank}: the paged adapter tier has no "
@@ -1517,7 +1517,7 @@ class PagedBatchingScheduler:
         pairs each HELD expert took, summed over the layers, and the
         tokens fed through an expert layer, a layer each.  None for a
         description without routed experts."""
-        if self.state is None:
+        if self.state is None or self.state.expert_pairs is None:
             return None
         # tddl-lint: disable=host-sync — pulled only for a summary
         pairs = np.asarray(self.state.expert_pairs).sum(axis=0)
