@@ -153,9 +153,11 @@ def test_paged_attention_lowers_at_the_serving_cell(v5e, program, kv_dtype):
 
 def _serving_program(dev, program, kv_dtype):
     """``_paged_chunk_impl`` (one call's rows, a chunk of each of eight
-    slots) or ``_paged_decode_impl`` at the serving cell's geometry (GPT-2
-    large: 36 layers, 24 slots, 20 heads of 64, blocks of 16, chunk 64),
-    the pool donated as on the chip, compiled for the described v5e."""
+    slots), ``_paged_decode_impl`` or ``_paged_prefill_impl`` (one whole
+    prompt of a chunk) at the serving cell's geometry (GPT-2 large: 36
+    layers, 24 slots, 20 heads of 64, blocks of 16, chunk 64), the pool and
+    the token carry donated as on the chip, compiled for the described
+    v5e."""
     from trustworthy_dl_tpu.models import generate as gen
     from trustworthy_dl_tpu.models import gpt2
     from trustworthy_dl_tpu.serve import scheduler as sch
@@ -174,6 +176,7 @@ def _serving_program(dev, program, kv_dtype):
     kv = pin(jax.eval_shape(
         lambda: init_paged_pool(cfg, slots * nbps, BLOCK, kv_dtype)))
     i32, f32 = jnp.int32, jnp.float32
+    carry = pin(S((slots,), i32))
     if program == "chunk":
         fn = sch._paged_chunk_impl
         rows = sch.chunk_call_rows(CHUNK, slots)
@@ -181,16 +184,38 @@ def _serving_program(dev, program, kv_dtype):
                 S((rows,), i32), S((rows,), i32),
                 S((rows, 2), jnp.uint32), S((rows,), f32),
                 S((rows,), jnp.bool_))
-    else:
+        extra = dict(carry=carry, carry_rows=pin(S((rows,), i32)))
+    elif program == "decode":
         fn = sch._paged_decode_impl
         rest = (S((slots,), i32), S((slots, nbps), i32), S((slots,), i32),
                 S((slots, 2), jnp.uint32), S((slots,), f32),
                 S((slots,), jnp.bool_))
-    jitted = jax.jit(fn, static_argnums=(0,),
-                     static_argnames=("attn_impl", "adapter_impl"),
-                     donate_argnums=(1, 2, 3, 4))
+        extra = dict(active=pin(S((slots,), jnp.bool_)), carry=carry)
+    else:
+        fn = sch._paged_prefill_impl
+        rest = (S((CHUNK,), i32), S((), i32), S((CHUNK // BLOCK,), i32),
+                S((2,), jnp.uint32), S((), f32), S((), jnp.bool_))
+        extra = dict(carry=carry, carry_row=pin(S((), i32)))
+    static = (("attn_impl",) if program == "prefill"
+              else ("attn_impl", "adapter_impl"))
+    jitted = jax.jit(fn, static_argnums=(0,), static_argnames=static,
+                     donate_argnums=(1, 2, 3, 4), donate_argnames=("carry",))
     return kv, _compile(jitted, dev, cfg, kv.k, kv.v, kv.k_scale,
-                        kv.v_scale, view, *rest, attn_impl="pallas")
+                        kv.v_scale, view, *rest, attn_impl="pallas", **extra)
+
+
+def _carry_in_place(compiled, slots):
+    """The program's last output is the token carry, i32[slots], and it is
+    written in the buffer of the parameter of that shape it aliases."""
+    import re
+
+    text = compiled.as_text()
+    head = text[:text.index("\n")]
+    result = re.search(r"->\((.*)\)\}", head).group(1)
+    outputs = re.findall(r"[a-z]\w*\[[\d,]*\]", result)
+    assert outputs[-1] == "s32[%d]" % slots, outputs
+    aliases = dict(re.findall(r"\{(\d+)\}: \((\d+),", head))
+    assert str(len(outputs) - 1) in aliases, head[:400]
 
 
 # (pool dtype, the most the program's temporaries may take).  The int8
@@ -217,11 +242,12 @@ def test_serving_program_keeps_the_pool_in_place(v5e, program, kv_dtype,
     buffers they came in; and the optimized HLO holds no copy, fresh
     buffer or slice the size of the pool or of one layer of it — the row
     write, the kernels' operand and the resting layout agree, so nothing
-    relays the pool out."""
+    relays the pool out.  The token carry comes out last, in place."""
     import math
     import re
 
     kv, compiled = _serving_program(v5e, program, kv_dtype)
+    _carry_in_place(compiled, 24)
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < temp_limit
     pool_bytes = math.prod(kv.k.shape) * jnp.dtype(kv_dtype).itemsize
@@ -240,6 +266,17 @@ def test_serving_program_keeps_the_pool_in_place(v5e, program, kv_dtype,
                 or "slice" in opcode or "slice" in name):
             moved.append(line.strip()[:160])
     assert not moved, moved
+
+
+@pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8],
+                         ids=lambda d: jnp.dtype(d).name)
+def test_the_whole_prompt_program_lowers_with_the_carry_in_place(v5e,
+                                                                 kv_dtype):
+    """The third program a GPT-2 engine runs, a prompt that fits one chunk
+    at the serving cell's geometry: it compiles for the chip and writes
+    its first token into the token carry in place."""
+    _, compiled = _serving_program(v5e, "prefill", kv_dtype)
+    _carry_in_place(compiled, 24)
 
 
 # -- the second served architecture: grouped heads, state beside the pool ----
@@ -273,9 +310,9 @@ def test_paged_attention_lowers_at_grouped_heads(v5e, program):
 def _decoder_program(dev, monkeypatch, program, family, config_name, slots,
                      block, max_seq, chunk):
     """One of the two scheduler programs for the ``DecoderConfig`` of a
-    configuration of the benchmark at its published widths, pool and
-    recurrent state donated as on the chip, compiled for the described
-    chip: ``(compiled, pool, state)``."""
+    configuration of the benchmark at its published widths, pool,
+    recurrent state and token carry donated as on the chip, compiled for
+    the described chip: ``(compiled, pool, state)``."""
     import json
     import sys
 
@@ -309,20 +346,25 @@ def _decoder_program(dev, monkeypatch, program, family, config_name, slots,
         rest = (S((1, chunk), i32), S((1, nbps), i32), S((1,), i32),
                 S((1,), i32), S((1, 2), jnp.uint32), S((1,), f32),
                 S((1,), jnp.bool_))
-        extra = dict(state=state, slot=pin(S((1,), i32)))
+        extra = dict(state=state, slot=pin(S((1,), i32)),
+                     carry=pin(S((slots,), i32)),
+                     carry_rows=pin(S((1,), i32)))
     else:
         fn = sch._paged_decode_impl
         rest = (S((slots,), i32), S((slots, nbps), i32), S((slots,), i32),
                 S((slots, 2), jnp.uint32), S((slots,), f32),
                 S((slots,), jnp.bool_))
-        extra = dict(state=state, active=pin(S((slots,), jnp.bool_)))
+        extra = dict(state=state, active=pin(S((slots,), jnp.bool_)),
+                     carry=pin(S((slots,), i32)))
     jitted = jax.jit(fn, static_argnums=(0,),
                      static_argnames=("attn_impl", "adapter_impl"),
-                     donate_argnums=(1, 2, 3, 4), donate_argnames=("state",))
+                     donate_argnums=(1, 2, 3, 4),
+                     donate_argnames=("state", "carry"))
     # The dispatch predicates ask the backend; the trace is for the chip.
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     compiled = _compile(jitted, dev, cfg, kv.k, kv.v, None, None, view,
                         *rest, attn_impl="pallas", **extra)
+    _carry_in_place(compiled, slots)
     return compiled, kv, state
 
 

@@ -59,12 +59,26 @@ from trustworthy_dl_tpu.serve.scheduler import (
     SlotTask,
     refuse_unsupported,
     request_args,
+    request_key,
     request_key_stream,
 )
 from trustworthy_dl_tpu.utils.metrics import MetricsCollector
 from trustworthy_dl_tpu.utils.profiling import span
 
 logger = logging.getLogger(__name__)
+
+#: The scheduler's decode-call counters, by name, as ``metrics_summary()``
+#: gives them and the registry counts them (``tddl_serve_<name>_total``).
+DECODE_COUNTERS = {
+    "decode_calls": "Fused decode calls dispatched",
+    "decode_ahead_calls": "Decode calls dispatched while the previous "
+                          "one's pull was still outstanding",
+    "decode_settles": "Decode calls pulled outside a tick, before a slot "
+                      "was read or freed",
+    "decode_overrun_rows": "Decode rows dispatched past the end of their "
+                           "stream (EOS, deadline, cancel, migration) and "
+                           "thrown away",
+}
 
 #: The scope (a summary's key, a gauge's label) of what was counted between
 #: the last two ``metrics_summary()`` calls.
@@ -696,9 +710,19 @@ class ServingEngine:
             labels=self._rlabel_names,
         )
         self._spec_seen = (0, 0)   # (proposed, accepted) already counted
+        # The decode calls' surface (scheduler.decode_tick): the calls,
+        # those dispatched a tick ahead of their record, the drains forced
+        # outside a tick and the rows thrown away past a stream's end.
+        self._decode_counters = {
+            name: _metric(registry.counter, f"tddl_serve_{name}_total",
+                          help, labels=self._rlabel_names)
+            for name, help in DECODE_COUNTERS.items()}
+        self._decode_seen = dict.fromkeys(DECODE_COUNTERS, 0)
         self.peak_tokens_in_flight = 0
         self.peak_active = 0
-        self._rng = rng if rng is not None else jax.random.PRNGKey(0)
+        # Kept on the host: every request's key is made there.
+        self._rng = np.asarray(
+            rng if rng is not None else jax.random.PRNGKey(0), np.uint32)
         self._queue: Deque[tuple] = deque()   # (task, request)
         self._inflight: Dict[int, tuple] = {}  # request_id -> (task, req, t)
         self._timing: Dict[int, List[float]] = {}  # request_id -> token times
@@ -858,7 +882,7 @@ class ServingEngine:
                   max_new_tokens=int(request.max_new_tokens)):
             rng = request.rng
             if rng is None:
-                rng = jax.random.fold_in(self._rng, request_id)
+                rng = request_key(self._rng, request_id)
             keys = request_key_stream(rng, int(request.max_new_tokens))
         task = SlotTask(
             request_id=request_id,
@@ -1142,6 +1166,11 @@ class ServingEngine:
                 self._spec_accepted_counter.inc(accepted - seen_a,
                                                 **self._rlabels)
             self._spec_seen = (proposed, accepted)
+        for name, counter in self._decode_counters.items():
+            now = getattr(self.scheduler, name)
+            if now > self._decode_seen[name]:
+                counter.inc(now - self._decode_seen[name], **self._rlabels)
+                self._decode_seen[name] = now
         self.metrics.collect_batch_metrics({
             "step": self._iteration,
             "active_slots": self.scheduler.active_count,
@@ -1263,6 +1292,7 @@ class ServingEngine:
         if pair is None:
             return False
         task, _request = pair
+        self.scheduler.settle(task.slot)
         placement = (self.scheduler.attribution_info(task)
                      if self.ledger is not None
                      or self.retire_hook is not None else None)
@@ -1623,6 +1653,8 @@ class ServingEngine:
         # that does not shrink them is a regression signal).
         out["prefill_chunk_fraction"] = share("serve.prefill_chunk.dispatch")
         out["spec_verify_fraction"] = share("serve.spec_verify")
+        for name in DECODE_COUNTERS:
+            out[name] = getattr(sched, name)
         out["tick_phases"] = self._phase_summary(totals)
         out["prefix_lookups"] = sched.prefix_lookups
         out["prefix_hits"] = sched.prefix_hits

@@ -26,9 +26,13 @@ the one pool layout (``kv_slots.PagedKV``):
   (``paged_decode``): [MAX_SLOTS] tokens in, [MAX_SLOTS] next tokens out,
   attending through per-slot BLOCK TABLES.  Tables and lengths are traced
   values, so admission, retirement, block churn and prefix sharing never
-  change its shapes: it compiles exactly once.  With ``spec_k > 0`` a
-  tick drafts and verifies a window instead (``spec_draft``,
-  ``spec_verify``).
+  change its shapes: it compiles exactly once.  A tick dispatches the
+  NEXT tick's decode call before it pulls the one the tick before
+  dispatched: the input tokens stay on the device (the ``carry``, which
+  every program that samples a slot's token writes), so the device has
+  work queued while the host records, streams, retires and admits.  With
+  ``spec_k > 0`` a tick drafts and verifies a window instead
+  (``spec_draft``, ``spec_verify``), dispatched and pulled in the tick.
 
 Inactive rows still compute inside the decode step (static shapes); their
 outputs are ignored and their garbage cache writes land in the trash block
@@ -45,6 +49,7 @@ or sampled request reproduces the batch sampler token-for-token.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -159,13 +164,15 @@ def _local_prefill(cfg: gpt2.GPT2Config, view: Any, tokens: jax.Array,
 
 
 def _sample_pack(logits: jax.Array, keys: jax.Array, temps: jax.Array,
-                 greedy: jax.Array, attn_impl: str = "jnp") -> jax.Array:
+                 greedy: jax.Array, attn_impl: str = "jnp"
+                 ) -> Tuple[jax.Array, jax.Array]:
     """The paged programs' sampling tail, a row each: the sampled token and
     the trust signals of ``logits`` [R, V] as one packed f32[3, R] — a
-    single host sync per call, not three."""
+    single host sync per call, not three — and the tokens themselves as
+    i32[R], what the carry keeps on the device for the next decode call."""
     token = _sample_tokens(logits, keys, temps, greedy)
     ent, margin = _logit_signals(logits, attn_impl)
-    return _pack_step_outputs(token, ent, margin)
+    return _pack_step_outputs(token, ent, margin), token.astype(jnp.int32)
 
 
 def _paged_prefill_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
@@ -173,7 +180,8 @@ def _paged_prefill_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
                         view: Any, tokens: jax.Array, real_len: jax.Array,
                         block_ids: jax.Array, key: jax.Array,
                         temp: jax.Array, greedy: jax.Array,
-                        attn_impl: str = "jnp"):
+                        attn_impl: str = "jnp", carry: Any = None,
+                        carry_row: Any = None):
     """Fresh whole-prompt prefill into PAGED blocks: the
     ``_local_prefill`` prologue — so prompt self-attention and the first
     sampled token match ``generate()`` bit-for-bit (under the int8 tier
@@ -182,7 +190,9 @@ def _paged_prefill_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
     ``block_ids`` (i32[C/BLOCK]; entries past the slot's allocation
     point at the trash block).  Dispatched when the whole prompt fits
     one chunk and no prefix blocks were reused; longer or prefix-sharing
-    prompts go through ``_paged_chunk_impl``."""
+    prompts go through ``_paged_chunk_impl``.  Given the ``carry``
+    i32[MAX_SLOTS] (donated), the sampled token goes into it at
+    ``carry_row``, the slot's, and the carry is returned last."""
     c = tokens.shape[0]
     bsz = pool_k.shape[2]
     logits, k_rows, v_rows, k_s, v_s = _local_prefill(
@@ -203,8 +213,12 @@ def _paged_prefill_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
         new_vs = pool_vs.at[:, block_ids].set(to_blocks(v_s))
     else:
         new_ks, new_vs = pool_ks, pool_vs
-    return new_k, new_v, new_ks, new_vs, _sample_pack(
-        logits, key[None], temp[None], greedy[None], attn_impl)
+    packed, token = _sample_pack(logits, key[None], temp[None],
+                                 greedy[None], attn_impl)
+    if carry is None:
+        return new_k, new_v, new_ks, new_vs, packed
+    return (new_k, new_v, new_ks, new_vs, packed,
+            carry.at[carry_row].set(token[0]))
 
 
 def _paged_chunk_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
@@ -216,7 +230,8 @@ def _paged_chunk_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
                       adapter_a: Any = None, adapter_b: Any = None,
                       adapter_as: Any = None, adapter_bs: Any = None,
                       apages: Any = None, state: Any = None,
-                      slot: Any = None):
+                      slot: Any = None, carry: Any = None,
+                      carry_rows: Any = None):
     """One CHUNK of paged prefill for each of R mid-prefill slots, in one
     call: row ``r`` feeds ``tokens[r]`` (C prompt positions) from
     ``start[r]`` (block-aligned — a prefix-cache hit starts the suffix at a
@@ -247,23 +262,32 @@ def _paged_chunk_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
     positions are those up to ``last_idx``.  The updated state is returned
     last.  Where its layers keep LATENT rows, ``pool_k`` is the one array
     of them and ``pool_v`` None, in and out (no leaf: none is carried or
-    donated)."""
+    donated).
+
+    Given the ``carry`` i32[MAX_SLOTS] (donated), row ``r``'s token goes
+    into it at ``carry_rows[r]``: the row's slot where the chunk ends its
+    prompt, past the carry's end (dropped) for every other row.  The carry
+    is returned last."""
     if isinstance(cfg, decoder.DecoderConfig):
         valid = jnp.arange(tokens.shape[1])[None, :] <= last_idx[:, None]
         logits, new_k, new_v, state = decoder.apply_paged(
             view, tokens, pool_k, pool_v, state, table, start[0], cfg,
             valid, slot=slot[0], last_pos=last_idx[0], attn_impl=attn_impl)
-        return new_k, new_v, None, None, _sample_pack(
-            logits, keys, temps, greedy, attn_impl), state
-    adapter = (None if adapter_a is None
-               else (adapter_a, adapter_b, adapter_as, adapter_bs, apages))
-    logits, new_k, new_v, new_ks, new_vs = gen._apply_with_cache_paged(
-        view, tokens, pool_k, pool_v, pool_ks, pool_vs, table, start, cfg,
-        last_pos=last_idx, attn_impl=attn_impl, adapter=adapter,
-        adapter_impl=adapter_impl,
-    )
-    return new_k, new_v, new_ks, new_vs, _sample_pack(
-        logits, keys, temps, greedy, attn_impl)
+        out = (new_k, new_v, None, None)
+    else:
+        adapter = (None if adapter_a is None else
+                   (adapter_a, adapter_b, adapter_as, adapter_bs, apages))
+        logits, *pool = gen._apply_with_cache_paged(
+            view, tokens, pool_k, pool_v, pool_ks, pool_vs, table, start,
+            cfg, last_pos=last_idx, attn_impl=attn_impl, adapter=adapter,
+            adapter_impl=adapter_impl,
+        )
+        out = tuple(pool)
+    packed, token = _sample_pack(logits, keys, temps, greedy, attn_impl)
+    out += (packed,) + ((state,) if state is not None else ())
+    if carry is None:
+        return out
+    return out + (carry.at[carry_rows].set(token, mode="drop"),)
 
 
 def _paged_decode_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
@@ -275,7 +299,7 @@ def _paged_decode_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
                        adapter_a: Any = None, adapter_b: Any = None,
                        adapter_as: Any = None, adapter_bs: Any = None,
                        apages: Any = None, state: Any = None,
-                       active: Any = None):
+                       active: Any = None, carry: Any = None):
     """THE fused paged decode step: one token for every slot, live or
     not.  ``tables`` i32[MAX_SLOTS, NBPS] are the per-slot block maps
     (inactive rows all-trash — their garbage writes land in block 0) and
@@ -297,7 +321,14 @@ def _paged_decode_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
     the pool, returned last), and ``active`` bool[MAX_SLOTS] says which
     rows decode this tick: a slot that is free or mid-prefill keeps its
     state, where a K/V row would go to the trash block.  ``pool_v`` is None
-    where the pool keeps latent rows, as in ``_paged_chunk_impl``."""
+    where the pool keeps latent rows, as in ``_paged_chunk_impl``.
+
+    Given the ``carry`` i32[MAX_SLOTS] (donated), a row's input is its
+    ``tokens`` entry where that is not negative and the carry's otherwise
+    (the token the row's previous call sampled, never on the host), and
+    the ``active`` rows' sampled tokens go into the carry, returned last."""
+    if carry is not None:
+        tokens = jnp.where(tokens >= 0, tokens, carry)
     if isinstance(cfg, decoder.DecoderConfig):
         logits, new_k, new_v, state = decoder.apply_paged(
             view, tokens[:, None], pool_k, pool_v, state, tables, lengths,
@@ -311,10 +342,12 @@ def _paged_decode_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
             tables, lengths, cfg, attn_impl=attn_impl, adapter=adapter,
             adapter_impl=adapter_impl,
         )
-    packed = _sample_pack(logits, keys, temps, greedy, attn_impl)
-    if state is not None:
-        return packed, new_k, new_v, new_ks, new_vs, state
-    return packed, new_k, new_v, new_ks, new_vs
+    packed, token = _sample_pack(logits, keys, temps, greedy, attn_impl)
+    out = (packed, new_k, new_v, new_ks, new_vs)
+    out += (state,) if state is not None else ()
+    if carry is None:
+        return out
+    return out + (jnp.where(active, token, carry),)
 
 
 def _spec_draft_impl(cfg: gpt2.GPT2Config, pool_k: jax.Array,
@@ -406,13 +439,16 @@ def _programs() -> Dict[str, Any]:
         # identical geometry trace separate programs instead of silently
         # aliasing each other through this process-global table (bench
         # A/B arms and the kernel tests depend on that).
+        # The token carry rides as a keyword and is donated by name, as
+        # the recurrent state is (None, and no buffer, for a description
+        # without one).
+        donate_carry = ("carry",) if donate else ()
         _PROGRAMS["paged_prefill"] = jax.jit(
             _paged_prefill_impl, static_argnums=(0,),
-            static_argnames=("attn_impl",), donate_argnums=donate
+            static_argnames=("attn_impl",), donate_argnums=donate,
+            donate_argnames=donate_carry
         )
-        # The recurrent state rides as a keyword (None, and no buffer, for
-        # a description without one) and is donated by name.
-        donate_state = ("state",) if donate else ()
+        donate_state = ("state", "carry") if donate else ()
         _PROGRAMS["paged_chunk"] = jax.jit(
             _paged_chunk_impl, static_argnums=(0,),
             static_argnames=("attn_impl", "adapter_impl"),
@@ -443,11 +479,34 @@ def _programs() -> Dict[str, Any]:
     return _PROGRAMS
 
 
-def request_key_stream(rng: jax.Array, max_new_tokens: int) -> np.ndarray:
+@functools.lru_cache(maxsize=None)
+def _host_device() -> Any:
+    """The host's CPU device where the installation has one beside the
+    accelerator (None otherwise: the default device).  The key programs
+    run there: on the accelerator they would queue behind the decode call
+    in flight, and their pull would wait for it.  Threefry is integer
+    arithmetic, so the keys are the same bits on either device."""
+    try:
+        return jax.devices("cpu")[0]
+    except RuntimeError:
+        return None
+
+
+def request_key(rng: Any, request_id: int) -> np.ndarray:
+    """uint32[2]: a request's own key, ``fold_in(rng, request_id)``, made on
+    the host (``_host_device``)."""
+    with jax.default_device(_host_device()):
+        return np.asarray(jax.random.fold_in(np.asarray(rng, np.uint32),
+                                             request_id), np.uint32)
+
+
+def request_key_stream(rng: Any, max_new_tokens: int) -> np.ndarray:
     """uint32[max_new, 2] per-token sampling keys, laid out exactly like
     generate's stream: token 0 uses the request key itself, token i>0 uses
-    ``split(fold_in(key, 1), max_new-1)[i-1]``."""
-    keys = [np.asarray(rng, np.uint32)]
+    ``split(fold_in(key, 1), max_new-1)[i-1]``; made on the host
+    (``_host_device``)."""
+    key = np.asarray(rng, np.uint32)
+    keys = [key]
     if max_new_tokens > 1:
         # ``split(key, n)`` is a prefix of ``split(key, m)`` for n <= m
         # (the partitionable threefry this jax defaults to), so the split
@@ -457,8 +516,9 @@ def request_key_stream(rng: jax.Array, max_new_tokens: int) -> np.ndarray:
         padded = 1 << (count - 1).bit_length()
         if not jax.config.jax_threefry_partitionable:
             padded = count
-        rest = jax.random.split(jax.random.fold_in(rng, 1), padded)
-        keys.extend(np.asarray(rest, np.uint32)[:count])
+        with jax.default_device(_host_device()):
+            rest = jax.random.split(jax.random.fold_in(key, 1), padded)
+            keys.extend(np.asarray(rest, np.uint32)[:count])
     return np.stack(keys)
 
 
@@ -583,6 +643,17 @@ def refuse_unsupported(cfg: Any, *, prefix_cache: bool, spec_k: int,
 #: the packed (token, entropy, margin) of its rows, still on the device, and
 #: the (row, slot) of each prompt the call finished.
 _PrefillCall = Tuple[jax.Array, List[Tuple[int, int]]]
+
+
+@dataclasses.dataclass
+class _DecodeCall:
+    """One decode call between its dispatch and its record: the packed
+    rows on the device (and on the host once pulled) and the task each
+    row decodes for, by slot, in the order a tick records them."""
+
+    packed: jax.Array
+    rows: Dict[int, SlotTask]
+    host: Optional[np.ndarray] = None
 
 
 @dataclasses.dataclass
@@ -725,7 +796,26 @@ class PagedBatchingScheduler:
         self.blocks = BlockAllocator(self.num_blocks)
         self.prefix = (PrefixCache(block_size, self.blocks)
                        if prefix_cache else None)
+        # Per slot, the positions its calls have been DISPATCHED to write
+        # (the next decode row writes at ``lengths[slot]``), and the tokens
+        # dispatched for its request and not yet recorded: a final chunk's
+        # first token, a decode row in flight.
         self.lengths = np.zeros(max_slots, np.int32)
+        self._ahead = np.zeros(max_slots, np.int32)
+        #: The token each slot's next decode call reads, on the device:
+        #: written by the programs that sample it (``_paged_*_impl``).
+        self.carry = jnp.zeros(max_slots, jnp.int32)
+        #: The decode call dispatched a tick ahead of its record.
+        self._decode_call: Optional[_DecodeCall] = None
+        self.decode_calls = 0
+        #: Decode calls dispatched while the previous one's pull was
+        #: still outstanding.
+        self.decode_ahead_calls = 0
+        #: Decode calls pulled outside a tick (``settle``).
+        self.decode_settles = 0
+        #: Decode rows dispatched for a stream that ended (EOS, deadline,
+        #: cancel, migration) before the row was recorded: thrown away.
+        self.decode_overrun_rows = 0
         self.tables: List[List[int]] = [[] for _ in range(max_slots)]
         self.tasks: Dict[int, SlotTask] = {}       # slot -> task
         self._prefill: Dict[int, _PrefillProgress] = {}
@@ -796,8 +886,9 @@ class PagedBatchingScheduler:
     @property
     def tokens_in_flight(self) -> int:
         """Cached tokens currently backing live sequences (decode-phase
-        lengths plus prefill progress, shared prefix included)."""
-        total = sum(int(self.lengths[s]) for s in self.tasks
+        lengths plus prefill progress, shared prefix included): recorded,
+        not dispatched."""
+        total = sum(int(self.lengths[s] - self._ahead[s]) for s in self.tasks
                     if s not in self._prefill)
         total += sum(min(st.pos, st.plen) for st in self._prefill.values())
         return int(total)
@@ -878,6 +969,7 @@ class PagedBatchingScheduler:
             self.prefix_tokens_reused += len(shared) * self.block_size
         self.tables[slot] = shared + fresh
         self.lengths[slot] = 0
+        self._ahead[slot] = 0
         self._zero_state(slot)
         task.slot = slot
         self.tasks[slot] = task
@@ -942,7 +1034,8 @@ class PagedBatchingScheduler:
     def _prefill_call(self, slots: List[int],
                       whole: bool = False) -> _PrefillCall:
         """One program call for ``slots``' next chunks; every slot whose
-        chunk does not end its prompt moves on by a chunk."""
+        chunk does not end its prompt moves on by a chunk, and every slot
+        whose chunk ends it takes the prompt's length and a token ahead."""
         progress = [self._prefill[slot] for slot in slots]
         fed = [min(st.plen - st.pos, self.chunk) for st in progress]
         final = [(row, slot) for row, (slot, st, n)
@@ -964,7 +1057,10 @@ class PagedBatchingScheduler:
             self.timer.tally("serve.prefill_chunk", rows=rows, padded=padded)
         done = {slot for _, slot in final}
         for slot, st in zip(slots, progress):
-            if slot not in done:
+            if slot in done:
+                self.lengths[slot] = st.plen
+                self._ahead[slot] += 1
+            else:
                 st.pos += self.chunk
         return packed, final
 
@@ -981,12 +1077,14 @@ class PagedBatchingScheduler:
         n_ids = min(len(self.tables[slot]), len(ids))
         ids[:n_ids] = self.tables[slot][:n_ids]
         kv = self.kv
-        new_k, new_v, new_ks, new_vs, packed = _programs()["paged_prefill"](
+        new_k, new_v, new_ks, new_vs, packed, self.carry = _programs()[
+            "paged_prefill"](
             self.cfg, kv.k, kv.v, kv.k_scale, kv.v_scale, self.view,
             jnp.asarray(chunk), jnp.asarray(st.plen, jnp.int32),
             jnp.asarray(ids), jnp.asarray(task.keys[0], jnp.uint32),
             jnp.asarray(max(task.temperature, 1e-6), jnp.float32),
             jnp.asarray(task.greedy), attn_impl=self.attn_impl,
+            carry=self.carry, carry_row=jnp.asarray(slot, jnp.int32),
         )
         self.kv = PagedKV(k=new_k, v=new_v, k_scale=new_ks, v_scale=new_vs)
         return packed
@@ -996,9 +1094,10 @@ class PagedBatchingScheduler:
                     ) -> Tuple[tuple, Dict[str, Any]]:
         """The chunk program's arguments for ``slots`` (their chunks of
         ``fed`` positions) padded to ``rows``: a padding row has an
-        all-trash table, start 0, ``last_idx`` 0 and tokens 0.  With no
-        slot at all, every row is padding (what the cost ledger is
-        handed)."""
+        all-trash table, start 0, ``last_idx`` 0 and tokens 0.  A row
+        whose chunk ends its prompt writes its token into the carry at its
+        slot; no other row writes it.  With no slot at all, every row is
+        padding (what the cost ledger is handed)."""
         c = self.chunk
         tokens = np.zeros((rows, c), np.int32)
         tables = np.full((rows, self.nbps), TRASH_BLOCK, np.int32)
@@ -1009,6 +1108,7 @@ class PagedBatchingScheduler:
         greedy = np.ones(rows, bool)
         pages = np.full(rows, ZERO_PAGE, np.int32)
         state_rows = np.zeros(rows, np.int32)
+        carry_rows = np.full(rows, self.allocator.max_slots, np.int32)
         for row, (slot, st, n) in enumerate(zip(slots, progress, fed)):
             task = st.task
             tokens[row, :n] = task.prompt[st.pos:st.pos + n]
@@ -1020,6 +1120,8 @@ class PagedBatchingScheduler:
             greedy[row] = task.greedy
             pages[row] = task.adapter_page
             state_rows[row] = slot
+            if st.pos + n >= st.plen:
+                carry_rows[row] = slot
         kv = self.kv
         args = (self.cfg, kv.k, kv.v, kv.k_scale, kv.v_scale, self.view,
                 jnp.asarray(tokens), jnp.asarray(tables), jnp.asarray(start),
@@ -1027,7 +1129,8 @@ class PagedBatchingScheduler:
                 jnp.asarray(greedy))
         kwargs: Dict[str, Any] = dict(
             attn_impl=self.attn_impls["prefill"],
-            adapter_impl=self.attn_impls["adapter"])
+            adapter_impl=self.attn_impls["adapter"], carry=self.carry,
+            carry_rows=jnp.asarray(carry_rows))
         if self.adapters is not None:
             a, b, a_s, b_s = self.adapters.device_args()
             kwargs.update(adapter_a=a, adapter_b=b, adapter_as=a_s,
@@ -1041,13 +1144,14 @@ class PagedBatchingScheduler:
                         fed: List[int], rows: int) -> jax.Array:
         """Upload the rows, padded to ``rows`` (``chunk_rows``), and call
         the chunk program once; the pool (and the state) the call
-        returns become the scheduler's.  Returns the packed (token,
-        entropy, margin) of every row, still on the device."""
+        returns become the scheduler's, and so does the carry.  Returns
+        the packed (token, entropy, margin) of every row, still on the
+        device."""
         args, kwargs = self._chunk_args(slots, progress, fed, rows)
         with span("serve.prefill_chunk.dispatch", self.timer), \
                 guarded(self.compilewatch, "serve_chunk"):
-            new_k, new_v, new_ks, new_vs, packed, *state = _programs()[
-                "paged_chunk"](*args, **kwargs)
+            new_k, new_v, new_ks, new_vs, packed, *state, self.carry = \
+                _programs()["paged_chunk"](*args, **kwargs)
         if self.recurrent:
             (self.state,) = state
         self.kv = PagedKV(k=new_k, v=new_v, k_scale=new_ks, v_scale=new_vs)
@@ -1056,9 +1160,9 @@ class PagedBatchingScheduler:
     def _record_prefill(self, calls: List[_PrefillCall]
                         ) -> List[SlotTask]:
         """Pull each prefill call that finished a prompt, once, and record
-        the first tokens in slot order: the prompt's length becomes the
-        slot's and its full blocks are published to the prefix cache.
-        Returns the tasks that received their first token."""
+        the first tokens in slot order: the prompt's full blocks are
+        published to the prefix cache.  Returns the tasks that received
+        their first token."""
         firsts: Dict[int, np.ndarray] = {}
         for packed, final in calls:
             if not final:
@@ -1074,7 +1178,7 @@ class PagedBatchingScheduler:
             st = self._prefill.pop(slot)
             task = st.task
             task._record(int(token), float(ent), float(margin))
-            self.lengths[slot] = st.plen
+            self._ahead[slot] -= 1
             if self.prefix is not None and task.publish_prefix:
                 # The prompt's FULL blocks are now authoritative in the
                 # pool — publish them so later same-prefix requests skip
@@ -1093,53 +1197,105 @@ class PagedBatchingScheduler:
 
     def decode_tick(self) -> List[SlotTask]:
         """One engine tick: dispatch ONE chunk of every mid-prefill slot
-        (``_dispatch_prefill``), then build and dispatch the fused decode
-        step for every decode-phase slot, and only then pull: the prompts
-        that finished this tick record their first tokens, then the
-        decode step's rows record theirs.  A slot finishing its prompt is
-        not in this tick's decode, so the decode call needs nothing from
-        the chunk's pull and the device runs the two back to back.  A
-        speculative tick pulls the chunks first (its draft chain pulls
-        before its verify).  Returns the tasks that received a token this
+        (``_dispatch_prefill``), then the decode call of the NEXT tick,
+        and only then pull: the prompts that finished this tick record
+        their first tokens, then the decode call the previous tick
+        dispatched records its rows.  The next tick's call holds every
+        slot that will decode then, the slots whose prompt ends in this
+        tick's chunks included: its input tokens are the carry the calls
+        before it write on the device, and its positions, keys and budgets
+        count the tokens in flight (``_ahead``).  So the device has that
+        call queued while the host records, streams, retires, admits and
+        builds the next tick, and the tokens a tick brings are the ones a
+        tick that dispatched and pulled its own decode call would bring.
+
+        A slot that decodes now but is in no call in flight (a migrated
+        request) takes a call of its own first, from its host token.  A
+        speculative engine's tick dispatches and pulls its own calls
+        (``_sync_tick``).  Returns the tasks that received a token this
         tick, the first tokens first."""
         calls = self._dispatch_prefill()
+        if self.spec_k > 0:
+            return self._sync_tick(calls)
+        prev, self._decode_call = self._decode_call, None
+        covered = prev.rows if prev is not None else {}
+        late = {s: t for s, t in self._decoding().items()
+                if covered.get(s) is not t}
+        catch_up = self._dispatch_decode(late, from_host=True) if late \
+            else None
+        finals = {slot for _, final in calls for _, slot in final}
+        upcoming = self._decoding(finals)
+        if upcoming:
+            if prev is not None and prev.host is None:
+                self.decode_ahead_calls += 1
+            self._decode_call = self._dispatch_decode(upcoming)
+        ticked = self._record_prefill(calls)
+        for call in (prev, catch_up):
+            if call is not None:
+                ticked.extend(self._record_decode(call))
+        return ticked
+
+    def _decoding(self, finals: Any = ()) -> Dict[int, SlotTask]:
+        """The slots a decode call dispatched now holds, in the tasks'
+        order: past their prompt (or ending it in ``finals``, this tick's
+        chunks), not done, and with budget left beyond the tokens in
+        flight."""
+        return {s: t for s, t in self.tasks.items()
+                if (s not in self._prefill or s in finals) and not t.done
+                and len(t.emitted) + self._ahead[s] < t.max_new_tokens}
+
+    def _sync_tick(self, calls: List[_PrefillCall]) -> List[SlotTask]:
+        """A speculative engine's tick: the prompts that finished record
+        first, then every decode-phase slot drafts and verifies a window
+        (``_spec_tick``), or, where every live slot has one token left,
+        takes one fused decode call, dispatched and pulled in this tick."""
         active = {s: t for s, t in self.tasks.items()
                   if s not in self._prefill and not t.done}
         if not active:
             return self._record_prefill(calls)
-        if self.spec_k > 0 and any(
-                t.max_new_tokens - len(t.emitted) > 1
-                for t in active.values()):
+        if any(t.max_new_tokens - len(t.emitted) > 1
+               for t in active.values()):
             ticked = self._record_prefill(calls)
             ticked.extend(self._spec_tick(active))
             return ticked
-        if self.spec_k > 0:
-            # Every live slot has exactly one token left: drafting would
-            # be pure waste — dispatch the single-token FALLBACK program
-            # (today's fused decode, the third compiled decode-phase
-            # program of a spec engine).
-            self.spec_fallback_ticks += 1
-        packed = self._dispatch_decode(active)
+        # Every live slot has exactly one token left: drafting would be
+        # pure waste — dispatch the single-token FALLBACK program (the
+        # fused decode, the third compiled decode-phase program of a spec
+        # engine).
+        self.spec_fallback_ticks += 1
+        call = self._dispatch_decode(active, from_host=True)
         ticked = self._record_prefill(calls)
-        ticked.extend(self._record_decode(active, packed))
+        ticked.extend(self._record_decode(call))
         return ticked
 
-    def _dispatch_decode(self, active: Dict[int, SlotTask]) -> jax.Array:
-        """Build and dispatch the fused decode step for the ``active``
-        slots; returns its packed outputs, still on the device."""
+    def _dispatch_decode(self, rows: Dict[int, SlotTask],
+                         from_host: bool = False) -> _DecodeCall:
+        """Build and dispatch the fused decode call for the ``rows``
+        slots: each row's key is the one of its next token past those in
+        flight, and it writes at the slot's dispatched length, which goes
+        up by one, as its tokens in flight do.  A row reads its input
+        token from the carry, or, ``from_host``, from its task's
+        ``next_token``.  Returns the call, its rows still on the device."""
         ms = self.allocator.max_slots
         with span("serve.decode_tick.build", self.timer):
-            tokens = np.zeros(ms, np.int32)
+            tokens = np.full(ms, -1, np.int32)
             keys = np.zeros((ms, 2), np.uint32)
             temps = np.ones(ms, np.float32)
             greedy = np.ones(ms, bool)
             tables = np.full((ms, self.nbps), TRASH_BLOCK, np.int32)
-            for slot, task in active.items():
-                tokens[slot] = task.next_token
-                keys[slot] = task.keys[len(task.emitted)]
+            live = np.zeros(ms, bool)
+            for slot, task in rows.items():
+                if from_host:
+                    tokens[slot] = task.next_token
+                keys[slot] = task.keys[len(task.emitted) + self._ahead[slot]]
                 temps[slot] = max(task.temperature, 1e-6)
                 greedy[slot] = task.greedy
                 tables[slot] = self._table_row(slot)
+                live[slot] = True
+            lengths = self.lengths.copy()
+            for slot in rows:
+                self.lengths[slot] += 1
+                self._ahead[slot] += 1
             kv = self.kv
             extra: Dict[str, Any] = {}
             if self.adapters is not None:
@@ -1151,52 +1307,69 @@ class PagedBatchingScheduler:
                 # changes.
                 a, b, a_s, b_s = self.adapters.device_args()
                 row = adapter_page_row(
-                    {s: t.adapter_page for s, t in active.items()}, ms)
+                    {s: t.adapter_page for s, t in rows.items()}, ms)
                 extra = dict(adapter_a=a, adapter_b=b, adapter_as=a_s,
                              adapter_bs=b_s, apages=jnp.asarray(row))
             if self.recurrent:
-                live = np.zeros(ms, bool)
-                live[list(active)] = True
-                extra = dict(state=self.state, active=jnp.asarray(live))
+                extra = dict(state=self.state)
         with span("serve.decode_tick.dispatch", self.timer), \
                 guarded(self.compilewatch, "serve_decode"):
-            # The lengths go up as a copy: the prompts this tick finishes
-            # set theirs before this call's pull.
-            packed, new_k, new_v, new_ks, new_vs, *state = \
+            packed, new_k, new_v, new_ks, new_vs, *state, self.carry = \
                 _programs()["paged_decode"](
                     self.cfg, kv.k, kv.v, kv.k_scale, kv.v_scale,
                     self.view,
                     jnp.asarray(tokens), jnp.asarray(tables),
-                    jnp.asarray(self.lengths.copy()),
+                    jnp.asarray(lengths),
                     jnp.asarray(keys), jnp.asarray(temps),
                     jnp.asarray(greedy),
                     attn_impl=self.attn_impl,
                     adapter_impl=self.attn_impls["adapter"],
+                    active=jnp.asarray(live), carry=self.carry,
                     **extra,
                 )
         self.kv = PagedKV(k=new_k, v=new_v, k_scale=new_ks, v_scale=new_vs)
         if self.recurrent:
             (self.state,) = state
-        return packed
+        self.decode_calls += 1
+        return _DecodeCall(packed, rows)
 
-    def _record_decode(self, active: Dict[int, SlotTask],
-                       packed: jax.Array) -> List[SlotTask]:
-        """Pull the decode step's rows once and record a token for every
-        ``active`` slot."""
-        with span("serve.decode_tick.pull", self.timer):
-            # tddl-lint: disable=host-sync — the tick's one intended pull
-            host = np.asarray(packed)
+    def _record_decode(self, call: _DecodeCall) -> List[SlotTask]:
+        """Pull the decode call's rows once (unless ``settle`` has) and
+        record a token for every row whose request is still the slot's and
+        not done; a row past its request's end is thrown away (``retire``
+        counts it)."""
+        if call.host is None:
+            with span("serve.decode_tick.pull", self.timer):
+                # tddl-lint: disable=host-sync — the tick's one intended pull
+                call.host = np.asarray(call.packed)
         ticked: List[SlotTask] = []
         with span("serve.decode_tick.record", self.timer):
-            next_tok, ent, margin = host[0], host[1], host[2]
-            for slot in active:
-                self.lengths[slot] += 1
-            for slot, task in active.items():
+            next_tok, ent, margin = call.host
+            for slot, task in call.rows.items():
+                if self.tasks.get(slot) is not task or task.done:
+                    continue
+                self._ahead[slot] -= 1
                 task.tick_tokens = None   # single-token tick: emitted[-1]
                 task._record(int(next_tok[slot]), float(ent[slot]),
                              float(margin[slot]))
                 ticked.append(task)
         return ticked
+
+    def settle(self, slot: Optional[int] = None) -> None:
+        """Pull the decode call in flight now, where it holds a row of
+        ``slot`` (of any slot, without one): what anything outside a tick
+        does first before it reads or frees a slot.  The rows are recorded
+        where the next tick records them, so the tokens a request streams,
+        the tick that streams each and what a cancelled request's result
+        holds never depend on a settle."""
+        call = self._decode_call
+        if call is None or call.host is not None or (
+                slot is not None and slot not in call.rows):
+            return
+        with span("serve.decode_tick.settle", self.timer):
+            # tddl-lint: disable=host-sync — a drain outside a tick
+            call.host = np.asarray(call.packed)
+        self.decode_settles += 1
 
     def _spec_tick(self, active: Dict[int, SlotTask]) -> List[SlotTask]:
         """One speculative tick for every decode-phase slot: claim the
@@ -1209,6 +1382,7 @@ class PagedBatchingScheduler:
         margin tolerated as draft-token flips), then release the claims
         — rejection is a refcount decrement plus NOT advancing the
         host-side length past the accepted prefix."""
+        self.settle()
         k = self.spec_k
         ms = self.allocator.max_slots
         tokens0 = np.zeros(ms, np.int32)
@@ -1355,6 +1529,13 @@ class PagedBatchingScheduler:
         del self.tasks[slot]
         self._prefill.pop(slot, None)
         self._attrib.pop(slot, None)
+        # A decode row still in flight for this request (it ended by EOS,
+        # a deadline, a cancel or a migration) is thrown away at its
+        # record.  Its K/V lands in this slot's own blocks and its state
+        # in this slot's row, ahead on the device of whatever the next
+        # request of either writes.
+        self.decode_overrun_rows += int(self._ahead[slot])
+        self._ahead[slot] = 0
         if task.adapter is not None and self.adapters is not None:
             # Drop the request's residency claim on its adapter page.
             # The pool's OWN ref keeps the page resident (warm for the
@@ -1404,6 +1585,7 @@ class PagedBatchingScheduler:
     def release_quarantine(self, slot: int) -> None:
         """Operator action: return a quarantined slot AND the blocks
         impounded with it to service."""
+        self.settle(slot)
         self.allocator.release(slot)
         for b in self._q_blocks_by_slot.pop(slot, []):
             self.blocks.unquarantine(b)
@@ -1431,10 +1613,13 @@ class PagedBatchingScheduler:
             return None
         if slot in self._prefill or not task.emitted:
             return None
+        self.settle(slot)
         self.blocks.release_speculative(self._spec_claims.pop(slot, []))
         return {
             "task": task,
-            "length": int(self.lengths[slot]),
+            # recorded, not dispatched: the destination decodes the
+            # position a row in flight here writes, from the same token
+            "length": int(self.lengths[slot] - self._ahead[slot]),
             "block_ids": list(self.tables[slot]),
             "placement": self.attribution_info(task),
         }
@@ -1494,11 +1679,13 @@ class PagedBatchingScheduler:
         ids so ``verify_attribution`` reconciles the hand-off across
         both allocators' journals."""
         slot = claim["slot"]
+        self.settle(slot)
         task.slot = slot
         task.adapter_page = int(claim["adapter_page"])
         task.tick_tokens = None
         self.tables[slot] = list(claim["block_ids"])
         self.lengths[slot] = int(length)
+        self._ahead[slot] = 0
         self.tasks[slot] = task
         info: Dict[str, Any] = {
             "layout": "paged", "slot": slot,
@@ -1566,10 +1753,10 @@ class PagedBatchingScheduler:
         pool = (kv.k, kv.v, kv.k_scale, kv.v_scale)
         # A description with recurrent state has no whole-prompt program,
         # and its two programs take the state beside the pool.
-        decode_state: Dict[str, Any] = {}
+        decode_state: Dict[str, Any] = dict(active=jnp.ones(ms, bool),
+                                            carry=self.carry)
         if self.recurrent:
-            decode_state = dict(state=self.state,
-                                active=jnp.ones(ms, bool))
+            decode_state.update(state=self.state)
         if not self.recurrent:
             ledger.analyze(
                 "serve.paged_prefill", prog["paged_prefill"], self.cfg,
@@ -1577,7 +1764,8 @@ class PagedBatchingScheduler:
                 jnp.asarray(1, jnp.int32),
                 jnp.zeros(c // bsz, jnp.int32), jnp.zeros(2, jnp.uint32),
                 jnp.asarray(1.0, jnp.float32), jnp.asarray(True),
-                memory=memory, attn_impl=self.attn_impl,
+                memory=memory, attn_impl=self.attn_impl, carry=self.carry,
+                carry_row=jnp.asarray(0, jnp.int32),
             )
         args, kwargs = self._chunk_args([], [], [], self.chunk_rows)
         ledger.analyze("serve.paged_chunk", prog["paged_chunk"], *args,

@@ -37,12 +37,14 @@ One vocabulary of host spans, on the clock the device trace uses:
                                           mid-prefill slots (its ``rows``,
                                           ``padded``, ``final``)
             serve.prefill_chunk.dispatch    uploads and the program call
-          serve.decode_tick.build         the decode call's host arrays
+          serve.decode_tick.build         the NEXT tick's decode call's
+                                          host arrays
           serve.decode_tick.dispatch      uploads and the program call
           serve.prefill_chunk.pull        a prefill call's packed pull,
                                           where it finished a prompt:
                                           after the decode dispatch
-          serve.decode_tick.pull          the tick's packed pull
+          serve.decode_tick.pull          the packed pull of the decode
+                                          call the tick before dispatched
           serve.decode_tick.record        tokens into their requests
           serve.spec_draft, serve.spec_verify   (``spec_k`` > 0), each
                                           with ``.dispatch`` and ``.pull``
@@ -51,6 +53,10 @@ One vocabulary of host spans, on the clock the device trace uses:
             serve.monitor                   the output monitor's verdict:
                                             host arithmetic, no device work
         serve.tick.account              gauges, the collector's row
+      serve.decode_tick.settle          outside a tick: the decode call in
+                                        flight pulled before a cancel, a
+                                        quarantine's release or a
+                                        migration reads or frees its slot
 
   A ``*.dispatch`` span (and ``serve.tick.admit.zero_state``) is ONE
   program call; a ``*.pull`` span (and ``serve.submit.key_stream``)
