@@ -489,6 +489,31 @@ def test_latent_serving_program_lowers_in_place(v5e, monkeypatch, program):
         < 13 << 30                      # of the chip's 16 GB
 
 
+def test_the_chunked_delta_rule_solves_by_products(v5e):
+    """The chunked delta rule at the long-context cell's chunk: 32 heads of
+    1,024 positions, key and value width 128, sub-chunks of 64 in blocks of
+    16.  Its unit lower-triangular systems are inverted by batched matrix
+    products: no triangular solve and no ``InvertDiagBlocksLowerTriangular``
+    custom call (64 dependent row steps on the chip), and no loop but the
+    sub-chunk scan."""
+    import re
+
+    from trustworthy_dl_tpu.models import kda
+
+    x = jax.ShapeDtypeStruct((1, LHEADS, LCHUNK, 128), jnp.float32,
+                             sharding=v5e)
+    beta = jax.ShapeDtypeStruct((1, LHEADS, LCHUNK), jnp.float32,
+                                sharding=v5e)
+    s0 = jax.ShapeDtypeStruct((1, LHEADS, 128, 128), jnp.float32,
+                              sharding=v5e)
+    compiled = jax.jit(kda.kda_chunk, static_argnums=(6, 7)).lower(
+        x, x, x, x, beta, s0, 64, 16).compile()
+    text = compiled.as_text()
+    assert "InvertDiagBlocksLowerTriangular" not in text
+    assert "triangular-solve" not in text
+    assert len(re.findall(r" while\(", text)) == 1
+
+
 # -- the hybrid layer: rotary attention at 5 query heads a K/V head beside a
 # Mamba-2 state of [32, 128, 256] a slot (benchmark/configs/falcon-h1-...).
 FQ, FKV, FDH = 20, 4, 128

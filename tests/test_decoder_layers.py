@@ -68,7 +68,7 @@ def _gap(a, b):
 @pytest.mark.parametrize("decay", ["near one", "near zero", "mixed",
                                    "keys repeat"])
 @pytest.mark.parametrize("t,sub,block", [(48, 16, 4), (128, 64, 16),
-                                         (8, 64, 16)], ids=str)
+                                         (8, 64, 16), (48, 64, 16)], ids=str)
 def test_the_chunked_form_is_the_recurrence(decay, t, sub, block):
     args = _kda_inputs(3, 3, t, 16, 8, decay)
     want_o, want_s = _recurrence(*args)
@@ -76,6 +76,35 @@ def test_the_chunked_form_is_the_recurrence(decay, t, sub, block):
     assert np.isfinite(np.asarray(got_o)).all()
     assert _gap(got_o, want_o) < KDA_TOL
     assert _gap(got_s, want_s) < KDA_TOL
+
+
+def _kda_system(c, decay):
+    """The unit lower-triangular system of one sub-chunk of ``c`` positions
+    and its right-hand side, as :func:`kda.kda_chunk` builds them."""
+    q, k, v, g, beta, _ = _kda_inputs(7, 3, c, 16, 8, decay)
+    cum = jnp.cumsum(g, axis=-2)
+    a_kk = kda._pair_sums(k, k, cum, min(16, c), inclusive=False)
+    system = jnp.eye(c, dtype=jnp.float32) + beta[..., None] * a_kk
+    rhs = beta[..., None] * jnp.concatenate([k * jnp.exp(cum), v], axis=-1)
+    return system, rhs
+
+
+@pytest.mark.parametrize("decay", ["near one", "near zero", "mixed",
+                                   "keys repeat"])
+@pytest.mark.parametrize("c", [8, 16, 48, 64])
+def test_the_doubled_inverse_inverts_the_system(c, decay):
+    """The blocked-doubling inverse is the system's inverse to float32
+    rounding, at sizes that are and are not powers of two, and its product
+    with the right-hand side is what a triangular solve gives."""
+    system, rhs = _kda_system(c, decay)
+    inv = kda.unit_lower_inverse(system)
+    eye = jnp.eye(c, dtype=jnp.float32)
+    assert _gap(jnp.matmul(system, inv, precision=kda.HIGHEST), eye) < 1e-5
+    assert np.array_equal(np.triu(np.asarray(inv), 1), np.zeros_like(inv))
+    solved = jax.lax.linalg.triangular_solve(
+        system, rhs, left_side=True, lower=True, unit_diagonal=True)
+    got = jnp.matmul(inv, rhs, precision=kda.HIGHEST)
+    assert _gap(got, solved) < KDA_TOL
 
 
 def test_a_position_with_beta_and_g_zero_leaves_the_state():

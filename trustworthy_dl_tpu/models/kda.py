@@ -25,6 +25,10 @@ eigenvalue).  Two spellings of the same mathematics:
   sum over ``q_t, k_i`` for ``i <= t``) and
   ``S_C = Diag(exp(G_C)) S_0 + (k_i exp(G_C - G_i))^T U``.  The state is
   carried across sub-chunks by a scan, and across calls by the caller.
+  The system is solved through its exact inverse, formed by blocked
+  doubling (:func:`unit_lower_inverse`): ``log2 C`` levels of batched
+  matrix products and one more with the right-hand side, where a
+  triangular solve is ``C`` dependent steps.
 
 Every exponent taken is <= 0, so a decay near 0 underflows to an exact 0 and
 nothing overflows: pairs inside a block of ``block`` positions take
@@ -94,6 +98,37 @@ def _pair_sums(a: jax.Array, k: jax.Array, cum: jax.Array, block: int,
     return out.reshape(lead + (c, c))
 
 
+def unit_lower_inverse(system: jax.Array) -> jax.Array:
+    """The inverse of the unit lower-triangular ``system [..., C, C]`` by
+    blocked doubling, in matrix products alone.  The system is padded with
+    the identity to a power of two ``P``.  ``T`` starts as the inverse of
+    the diagonal (I), and each of the ``log2 P`` levels joins pairs of
+    diagonal blocks of ``s`` into blocks of ``2s``:
+    ``[[L11, 0], [N21, L22]]^-1 = [[T11, 0], [-T22 N21 T11, T22]]`` with
+    ``T11, T22`` the blocks' inverses so far.  That is
+    ``T <- T - T (N o M_s) T``, ``N`` the strictly lower part and ``M_s``
+    the lower-left ``s x s`` quarter of every diagonal block of ``2s``.
+    No power of ``N`` is formed, so nothing grows only to cancel later."""
+    c = system.shape[-1]
+    p = 1 << (c - 1).bit_length()
+    if p > c:
+        system = jnp.pad(system, [(0, 0)] * (system.ndim - 2)
+                         + [(0, p - c), (0, p - c)])
+    square = (1,) * (system.ndim - 2) + (p, p)
+    row = jax.lax.broadcasted_iota(jnp.int32, square, system.ndim - 2)
+    col = jax.lax.broadcasted_iota(jnp.int32, square, system.ndim - 1)
+    quarter = lambda s: jnp.where(
+        (row // (2 * s) == col // (2 * s)) & (row // s > col // s),
+        system, 0.0)
+    inv = (row == col).astype(system.dtype) - quarter(1)
+    s = 2
+    while s < p:
+        inv = inv - jnp.matmul(jnp.matmul(inv, quarter(s), precision=HIGHEST),
+                               inv, precision=HIGHEST)
+        s *= 2
+    return inv[..., :c, :c]
+
+
 def kda_chunk(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
               beta: jax.Array, s0: jax.Array, sub_chunk: int = 64,
               block: int = 16) -> Tuple[jax.Array, jax.Array]:
@@ -118,12 +153,14 @@ def kda_chunk(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     decay = jnp.exp(cum)
     a_kk = _pair_sums(k, k, cum, b, inclusive=False)
     a_qk = _pair_sums(q, k, cum, b, inclusive=True)
-    # (I + Diag(beta) A) [W | U0] = Diag(beta) [K+ | V]: one solve gives
-    # what the state is read through (W) and what it is not (U0).
+    # (I + Diag(beta) A) [W | U0] = Diag(beta) [K+ | V]: one product with
+    # the inverse gives what the state is read through (W) and what it is
+    # not (U0).
     system = jnp.eye(c, dtype=jnp.float32) + beta[..., None] * a_kk
     rhs = beta[..., None] * jnp.concatenate([k * decay, v], axis=-1)
-    solved = jax.lax.linalg.triangular_solve(
-        system, rhs, left_side=True, lower=True, unit_diagonal=True)
+    with jax.named_scope("kda.solve"):
+        solved = jnp.matmul(unit_lower_inverse(system), rhs,
+                            precision=HIGHEST)
     w, u0 = solved[..., :dk], solved[..., dk:]
     q_dec = q * decay
     k_end = k * jnp.exp(cum[..., -1:, :] - cum)
