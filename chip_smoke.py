@@ -170,8 +170,8 @@ def train_cli(size: Size, seed: int, workdir: str) -> str:
 
 def train_long(size: Size, seed: int, workdir: str, on_chip: bool) -> None:
     """A few steps through ``DistributedTrainer(model_overrides=...)`` (the
-    README's library entry, built as bench.py builds it) at the sequence
-    length where ``auto`` attention picks the flash kernel."""
+    README's library entry) at the sequence length where ``auto``
+    attention picks the flash kernel."""
     import jax
 
     from trustworthy_dl_tpu import (DistributedTrainer, TrainingConfig,
@@ -420,9 +420,9 @@ def serve(size: Size, seed: int, ckpt_dir: str, on_chip: bool,
 
 def block_until_ready_waits(on_chip: bool) -> bool:
     """Time a chain of matmuls whose cost is known: at the chip's peak it
-    cannot finish sooner than FLOPs / peak.  bench.py closes its windows
-    with host materialisation and slopes only because, on another
-    backend, ``block_until_ready`` once returned early."""
+    cannot finish sooner than FLOPs / peak.  The benchmark's training
+    driver closes its windows with ``jax.block_until_ready``; on another
+    backend it once returned early."""
     import jax
     import jax.numpy as jnp
     import numpy as np

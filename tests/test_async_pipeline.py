@@ -284,46 +284,3 @@ def test_lagged_guard_rolls_back_to_prewindow_checkpoint(
     # Discarded-timeline steps were never accounted by the host.
     assert all(rec["step"] <= 6 for rec in trainer.attack_history)
 
-
-# ---------------------------------------------------------------------------
-# Bench A/B smoke (slow: two measured epochs through the real host loop)
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.slow
-def test_bench_async_ab_records(monkeypatch, tmp_path):
-    """bench.py's TDDL_BENCH_ASYNC=1 leg: both arms run the real
-    ``train_epoch`` host loop and the record carries tokens/sec and the
-    obs phase shares (the async arm must report a ``host`` phase, the
-    sync arm a ``detection`` phase)."""
-    import sys
-    from pathlib import Path
-
-    sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-    import bench
-    from trustworthy_dl_tpu.models import gpt2
-
-    tiny = gpt2.GPT2Config(vocab_size=TINY["vocab_size"],
-                           n_positions=TINY["n_positions"],
-                           n_layer=2, n_embd=32, n_head=4,
-                           dtype=jnp.float32)
-    monkeypatch.setattr(gpt2.GPT2Config, "from_name",
-                        staticmethod(lambda name, **kw: tiny))
-    monkeypatch.setenv("TDDL_BENCH_MODEL", "gpt2")
-    monkeypatch.setenv("TDDL_BENCH_NODES", "4")
-    monkeypatch.setenv("TDDL_BENCH_BATCH", "2")
-    monkeypatch.setenv("TDDL_BENCH_SEQ", "16")
-    monkeypatch.setenv("TDDL_BENCH_ASYNC_STEPS", "4")
-    monkeypatch.setenv("TDDL_BENCH_REMAT", "0")
-
-    arms = bench.bench_async()
-    assert set(arms) == {"sync", "async", "speedup"}
-    assert arms["sync"]["async_host_depth"] == 0
-    assert arms["async"]["async_host_depth"] == \
-        TrainingConfig().async_host_depth
-    for arm in ("sync", "async"):
-        assert arms[arm]["tokens_per_s_per_chip"] > 0
-        assert arms[arm]["steps_per_s"] > 0
-    assert "host" in arms["async"]["phase_fractions"]
-    assert "detection" in arms["sync"]["phase_fractions"]
-    assert arms["speedup"] > 0

@@ -138,8 +138,8 @@ def test_only_the_helper_places_the_cache():
     setters = sorted(str(p.relative_to(REPO)) for p in sources
                      if setter.search(p.read_text()))
     assert setters == ["trustworthy_dl_tpu/utils/compile_cache.py"]
-    for entry in ("trustworthy_dl_tpu/cli.py", "bench.py", "chip_smoke.py",
-                  "tests/conftest.py"):
+    for entry in ("trustworthy_dl_tpu/cli.py", "benchmark/harness/common.py",
+                  "chip_smoke.py", "tests/conftest.py"):
         assert "configure_compile_cache()" in (REPO / entry).read_text(), \
             entry
 

@@ -592,7 +592,7 @@ def test_engine_trace_events_carry_replica_in_fleet_mode():
 
 
 def test_replay_workload_drives_any_serving_surface():
-    """The shared open-loop driver (bench + CLI use this one spelling):
+    """The shared open-loop driver (the CLI uses this one spelling):
     submits each arrival on time, steps while busy, returns accepted."""
     fleet, fakes = fake_fleet(num_replicas=2)
     items = generate_workload(WorkloadConfig(seed=1, num_requests=4,

@@ -501,8 +501,8 @@ def test_tenant_rides_engine_ledger_and_request_span():
 def test_closed_loop_driver_holds_inflight_and_drains():
     """The extracted PR 12 closed-loop bounded-queue driver
     (serve/workload.py): holds the in-flight target, accepts every
-    submission eventually, and drains — one spelling shared by bench,
-    drills and the autoscale arm."""
+    submission eventually, and drains — one spelling shared by the
+    drills."""
     fleet, fakes = ctl_fleet(num_replicas=2)
     items = generate_workload(
         WorkloadConfig(seed=1, num_requests=12, mean_rps=1000.0),

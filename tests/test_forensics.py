@@ -307,7 +307,7 @@ def test_assembler_pairs_with_flight_dump_index(tmp_path):
 
 
 def test_assembler_in_memory_mode_counts_without_writing(tmp_path):
-    asm = IncidentAssembler()                    # the bench arms' mode
+    asm = IncidentAssembler()                    # the in-memory mode
     assert asm.assemble("replica_quarantine", suspects=[1]) is None
     assert asm.assemble("replica_quarantine", suspects=[2]) is None
     assert asm.counts_by_reason() == {"replica_quarantine": 2}

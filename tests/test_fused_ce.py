@@ -238,7 +238,7 @@ def test_auto_ce_dispatch_predicate():
     from trustworthy_dl_tpu.models import gpt2
 
     V = 50257
-    # Bench default: 16 × 512 tokens/node -> 0.82 GiB bf16 logits:
+    # 16 × 512 tokens/node -> 0.82 GiB bf16 logits:
     # materialised.
     assert not gpt2.auto_picks_chunked_ce(16 * 512, V, itemsize=2)
     # b32/node -> 1.65 GiB: chunked (materialised exceeds HBM).
@@ -257,7 +257,7 @@ def test_auto_ce_dispatch_predicate():
 
 def test_auto_ce_default_is_materialised_at_tiny_shapes():
     """The 'auto' default is bit-compatible with the old lm_head_chunk=0
-    default at test/bench-small shapes: the loss routes through the
+    default at test-small shapes: the loss routes through the
     materialised head."""
     from trustworthy_dl_tpu.models import gpt2
 

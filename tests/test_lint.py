@@ -758,13 +758,13 @@ def test_cli_formats_filters_and_exit_codes(tmp_path, capsys):
 
 
 # ---------------------------------------------------------------------------
-# the real repo: tier-1 gate + bench hook + self-purity
+# the real repo: tier-1 gate + self-purity
 # ---------------------------------------------------------------------------
 
 
 def test_repo_is_clean_under_the_committed_baseline():
-    """THE gate: full default rule set over the real package, bench.py
-    and tests with the committed baseline — zero findings.  A new
+    """THE gate: full default rule set over the real package and tests
+    with the committed baseline — zero findings.  A new
     violation fails HERE, at review time, not in a chaos drill."""
     result = run_lint(root=str(REPO))
     assert result.clean, "\n".join(
@@ -800,15 +800,3 @@ def test_lint_cli_process_is_jax_free():
     assert proc.returncode == 0, proc.stderr
     assert "ok" in proc.stdout
 
-
-def test_bench_lint_hook_no_op_and_record(monkeypatch):
-    import bench
-
-    monkeypatch.delenv("TDDL_BENCH_LINT", raising=False)
-    assert bench.bench_lint() is None          # no-op-safe
-
-    monkeypatch.setenv("TDDL_BENCH_LINT", "1")
-    record = bench.bench_lint()
-    assert record["rc"] == 0, record
-    assert record["findings"] == []
-    assert record["files_scanned"] > 100

@@ -15,6 +15,7 @@ Slow tier: THE smoke — heterogeneous requests with a shared multi-block
 prefix through the paged ``ServingEngine``, streams bit-identical to
 batch ``generate()``, prefix hits > 0."""
 
+import dataclasses
 import json
 import pathlib
 
@@ -484,7 +485,7 @@ def _cli_legacy_stripe(_params):
 # its message names (argparse exits 2 and has none), and the ask.
 _STRIPE_ASKS = {
     "config-paged-false": (
-        ValueError, "stripe pool was removed",
+        TypeError, "paged",
         lambda params: ServeConfig(paged=False)),
     "engine-paged-kwarg": (
         TypeError, "paged",
@@ -502,13 +503,14 @@ _STRIPE_ASKS = {
 }
 
 
-@pytest.mark.parametrize("case", [*_STRIPE_ASKS, "paged-true-constructs"])
+@pytest.mark.parametrize("case",
+                         [*_STRIPE_ASKS, "benchmark-config-constructs"])
 def test_one_kv_pool_no_switch(params, case):
     """The serving stack has ONE KV pool since PR 33: every way the
     removed stripe pool could be asked for is refused loudly, where the
-    operator typed it, and the one surviving spelling — the checked
-    ``paged: true`` key the benchmark's configuration file still passes
-    — keeps constructing."""
+    operator typed it, ``paged`` is no key of ``ServeConfig`` at all,
+    and the benchmark's serving configuration constructs the paged
+    scheduler."""
     if case in _STRIPE_ASKS:
         error, match, ask = _STRIPE_ASKS[case]
         with pytest.raises(error, match=match) as refusal:
@@ -516,11 +518,13 @@ def test_one_kv_pool_no_switch(params, case):
         if error is SystemExit:
             assert refusal.value.code == 2
         return
-    assert ServeConfig(paged=True).paged
+    with pytest.raises(TypeError, match="paged"):
+        ServeConfig(paged=True)
     config = ServeConfig(**_serve_config_of_the_benchmark())
-    assert config.paged and config.max_slots == 24
+    assert config.max_slots == 24 and config.max_seq == 1024
+    # The file's every other key, at this file's tiny geometry.
     engine = ServingEngine.from_config(
-        params, CFG, ServeConfig(max_slots=2, max_seq=32, paged=True))
+        params, CFG, dataclasses.replace(config, max_slots=2, max_seq=32))
     assert isinstance(engine.scheduler, PagedBatchingScheduler)
     assert not hasattr(engine, "paged")
 

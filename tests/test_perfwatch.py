@@ -344,7 +344,7 @@ def test_serve_engine_program_cost_analysis():
 
 
 def _fp(tokens, **extra):
-    return fingerprint("bench", metric="m", tokens_per_s=tokens,
+    return fingerprint("test", metric="m", tokens_per_s=tokens,
                        run_metadata={"platform": "cpu",
                                      "device_kind": "cpu"}, **extra)
 
@@ -408,31 +408,36 @@ def test_sentinel_noise_band_verdicts(tmp_path):
 
 
 @perfwatch
-def test_sentinel_accepted_rate_pages_like_perf(tmp_path):
-    """PR 11 extension: ``accepted_rate`` (speculative draft quality)
-    is a sentinel metric with direction higher-is-better — a draft that
-    stops matching the target pages exactly like a tokens/s regression
-    — and the obs diff renders it."""
+@pytest.mark.parametrize("metric, good, bad, label", [
+    ("step_time_s", 1.0, 5.0, "step_time_mean_s"),
+    ("compile_total", 10, 50, "compile_total"),
+    ("hbm_watermark_bytes", 10 ** 9, 5 * 10 ** 9, "hbm_watermark_bytes"),
+])
+def test_sentinel_pages_a_regression_in_its_bad_direction(
+        tmp_path, metric, good, bad, label):
+    """Each lower-is-better fingerprint metric the noise-band test does
+    not page pages here: a blow-up past the band regresses in the
+    metric's BAD direction, a value inside it does not, and
+    ``trustworthy-dl-obs diff`` renders the fingerprint's value."""
     from trustworthy_dl_tpu.obs.sentinel import (
         SENTINEL_METRICS,
         load_perf_artifact,
         render_diff,
     )
 
-    assert SENTINEL_METRICS["accepted_rate"] == "higher"
+    assert SENTINEL_METRICS[metric] == "lower"
     ledger = PerfLedger(str(tmp_path / "ledger.jsonl"))
     for _ in range(3):
-        ledger.append(_fp(100.0, accepted_rate=0.9))
+        ledger.append(_fp(100.0, **{metric: good}))
     sentinel = PerfSentinel(ledger)
-    assert not sentinel.check(_fp(100.0, accepted_rate=0.88))["regressed"]
-    verdict = sentinel.check(_fp(100.0, accepted_rate=0.4))
-    check = next(c for c in verdict["checks"]
-                 if c["metric"] == "accepted_rate")
+    assert not sentinel.check(_fp(100.0, **{metric: good * 1.02}))[
+        "regressed"]
+    verdict = sentinel.check(_fp(100.0, **{metric: bad}))
+    check = next(c for c in verdict["checks"] if c["metric"] == metric)
     assert verdict["regressed"] and check["regressed"]
-    assert check["direction"] == "higher"
-    # `trustworthy-dl-obs diff` renders the fingerprint's rate.
+    assert check["direction"] == "lower" and check["delta_pct"] > 0
     view = load_perf_artifact(str(tmp_path / "ledger.jsonl"))
-    assert "accepted_rate" in render_diff(view, view)
+    assert label in render_diff(view, view)
 
 
 @perfwatch

@@ -497,3 +497,34 @@ def test_bounded_result_retention_with_exact_rollups(params):
     assert summary["requests_completed"] == 8      # rollup is exact
     assert summary["tokens_emitted"] == 16
     assert summary["itl_p50_ms"] >= 0.0
+
+
+def test_engine_feeds_its_slo_watcher_every_retirement(params):
+    """The serving defaults (TTFT and ITL rules) on a watched engine:
+    every completed request lands ONE TTFT observation and each gap
+    between its tokens one ITL observation in the watcher's sketches,
+    which ``metrics_summary`` then reads instead of its own; a healthy
+    engine leaves both rules out of breach."""
+    from trustworthy_dl_tpu.obs.slo import SLOWatcher, default_serve_rules
+
+    watcher = SLOWatcher(default_serve_rules())
+    engine = ServingEngine(params, CFG, max_slots=2, max_seq=32,
+                           queue_limit=8, slo=watcher)
+    for i in range(5):
+        engine.submit(ServeRequest(prompt=[i + 1, i + 2, i + 3],
+                                   max_new_tokens=2 + i % 3))
+    results = engine.run_until_idle()
+    assert all(r.status == "completed" for r in results.values())
+    ttft = watcher.percentiles("ttft_s")
+    itl = watcher.percentiles("itl_s")
+    assert ttft["count"] == len(results) == 5
+    assert itl["count"] == sum(len(r.tokens) - 1 for r in results.values())
+    assert ttft["p50"] > 0.0 and itl["p50"] > 0.0
+    status = watcher.status()
+    assert {r["name"] for r in status["rules"]} == {"ttft", "itl"}
+    assert all(r["burn_rate"] >= 0.0 and not r["active"]
+               for r in status["rules"])
+    assert status["breach_total"] == 0
+    summary = engine.metrics_summary()
+    assert summary["ttft_p50_ms"] == watcher.quantile("ttft_s", 0.5) * 1e3
+    assert summary["itl_p99_ms"] == watcher.quantile("itl_s", 0.99) * 1e3

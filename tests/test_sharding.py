@@ -163,6 +163,21 @@ def test_place_zero_sharded_bytes_per_device(eight_devices):
     assert shreg.tree_bytes_per_device(placed1) == 1024 + 20
 
 
+def test_trainer_fsdp_placement_shards_params_and_moments(eight_devices,
+                                                         tmp_path):
+    """The TRAINER's own FSDP placement, not a toy tree: with
+    ``shard_params`` and ``shard_opt_state`` on eight nodes, the
+    parameters and the optimizer state each sit on a device at close
+    to 1/8 of their bytes (the leaves no dim of which divides by 8
+    replicate, hence the allowance)."""
+    trainer = make_trainer(tmp_path, "fsdp-bytes", shard_params=True,
+                           shard_opt_state=True)
+    for tree in (trainer.state.params, trainer.state.opt_state):
+        total = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(tree))
+        ratio = shreg.tree_bytes_per_device(tree) / total
+        assert 1.0 / 8 - 0.01 <= ratio <= 1.0 / 8 + 0.15, ratio
+
+
 def test_row_placer_is_the_one_shared_rule(eight_devices):
     # Trainer placement and elastic migration share ONE per-node-row
     # rule: leading dim == n shards rows, everything else replicates.

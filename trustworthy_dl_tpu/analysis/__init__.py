@@ -27,8 +27,8 @@ at module level) may import jax — the ``import-purity`` rule lints the
 linter itself.
 
 Entry points: the ``trustworthy-dl-lint`` console script
-(:mod:`trustworthy_dl_tpu.analysis.cli`), the tier-1 test perimeter
-(``tests/test_lint.py``), and the ``TDDL_BENCH_LINT=1`` bench hook.
+(:mod:`trustworthy_dl_tpu.analysis.cli`) and the tier-1 test perimeter
+(``tests/test_lint.py``).
 """
 
 from trustworthy_dl_tpu.analysis.engine import (  # noqa: F401

@@ -40,8 +40,8 @@ def _build_parser() -> argparse.ArgumentParser:
     )
     parser.add_argument(
         "paths", nargs="*",
-        help="files/directories to lint (default: the package, "
-             "bench.py, and tests/)")
+        help="files/directories to lint (default: the package and "
+             "tests/)")
     parser.add_argument(
         "--root", default=None,
         help="repo root paths are reported relative to (default: "

@@ -104,7 +104,6 @@ ARTIFACT_MODULES = (
     "trustworthy_dl_tpu/detect/detector.py",
     "trustworthy_dl_tpu/serve/*.py",
     "trustworthy_dl_tpu/utils/*.py",
-    "bench.py",
 )
 
 #: Modules whose JSON artifacts must carry the run_metadata stamp
@@ -112,7 +111,6 @@ ARTIFACT_MODULES = (
 #: produced them).  Mirrors tests/test_obs.py's standing contract test.
 STAMPED_ARTIFACT_MODULES = (
     "trustworthy_dl_tpu/experiments/*.py",
-    "bench.py",
 )
 
 #: Recovery/supervision paths where a bare ``except:`` can swallow

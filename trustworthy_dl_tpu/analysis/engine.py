@@ -217,13 +217,12 @@ _SKIP_DIRS = {"__pycache__", ".git", ".pytest_cache", "checkpoints",
 
 
 def default_scan_paths(root: str, package_name: str) -> List[str]:
-    """The standing perimeter: the package tree, ``bench.py``, and the
-    test suite (rules scope themselves tighter via ``applies``)."""
+    """The standing perimeter: the package tree and the test suite
+    (rules scope themselves tighter via ``applies``)."""
     paths = [os.path.join(root, package_name)]
-    for extra in ("bench.py", "tests"):
-        p = os.path.join(root, extra)
-        if os.path.exists(p):
-            paths.append(p)
+    tests = os.path.join(root, "tests")
+    if os.path.exists(tests):
+        paths.append(tests)
     return paths
 
 
@@ -330,8 +329,7 @@ def run_lint(root: Optional[str] = None,
              use_baseline: bool = True,
              config: Optional[LintConfig] = None) -> LintResult:
     """One-call API: default rules over the default perimeter with the
-    committed baseline — what the CLI, the tier-1 test, and the bench
-    hook all share."""
+    committed baseline — what the CLI and the tier-1 test share."""
     from trustworthy_dl_tpu.analysis.baseline import load_baseline
     from trustworthy_dl_tpu.analysis.rules import all_rules
 

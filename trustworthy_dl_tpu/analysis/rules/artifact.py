@@ -46,7 +46,7 @@ class ArtifactReasonRule(Rule):
 
     def applies(self, rel: str, config: LintConfig) -> bool:
         return (rel.startswith(config.package_name + "/")
-                or rel == "bench.py" or rel.startswith("tests/"))
+                or rel.startswith("tests/"))
 
     def _reason_args(self, node: ast.Call):
         """Candidate literal reasons this call carries, with the node

@@ -101,7 +101,7 @@ class BareExceptRule(Rule):
 
 
 class ArtifactMetadataRule(Rule):
-    """Every experiments//bench module that ``json.dump``s an artifact
+    """Every experiments/ module that ``json.dump``s an artifact
     must reference the shared ``run_metadata`` helper (VERDICT weak #5:
     numbers without the platform that produced them)."""
 

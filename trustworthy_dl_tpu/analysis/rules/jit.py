@@ -67,8 +67,7 @@ class RecompileHazardRule(Rule):
                    "jnp.array literals inside hot loops")
 
     def applies(self, rel: str, config: LintConfig) -> bool:
-        return rel.startswith(config.package_name + "/") \
-            or rel == "bench.py"
+        return rel.startswith(config.package_name + "/")
 
     def check(self, module: ModuleInfo, project: Project,
               config: LintConfig) -> Iterable[Finding]:

@@ -48,8 +48,8 @@ class AdapterLocalityRule(Rule):
                    "only in serve/adapters.py")
 
     def applies(self, rel: str, config: LintConfig) -> bool:
-        return rel != config.adapter_home_module and (
-            rel.startswith(config.package_name + "/") or rel == "bench.py")
+        return (rel != config.adapter_home_module
+                and rel.startswith(config.package_name + "/"))
 
     def check(self, module: ModuleInfo, project: Project,
               config: LintConfig) -> Iterable[Finding]:
@@ -97,8 +97,7 @@ class ShardingRegistryRule(Rule):
             return False
         if match_any(rel, config.sharding_spec_whitelist):
             return False
-        return rel.startswith(config.package_name + "/") \
-            or rel == "bench.py"
+        return rel.startswith(config.package_name + "/")
 
     def check(self, module: ModuleInfo, project: Project,
               config: LintConfig) -> Iterable[Finding]:

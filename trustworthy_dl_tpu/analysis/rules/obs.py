@@ -19,10 +19,9 @@ _REGISTER_METHODS = frozenset({"counter", "gauge", "histogram"})
 
 
 def _package_scope(rel: str, config: LintConfig) -> bool:
-    """Package sources + bench.py; the test tree deliberately registers
-    invalid names/labels to exercise the registry's own validation."""
-    return (rel.startswith(config.package_name + "/")
-            or rel == "bench.py")
+    """Package sources; the test tree deliberately registers invalid
+    names/labels to exercise the registry's own validation."""
+    return rel.startswith(config.package_name + "/")
 
 
 class ObsEmitRule(Rule):

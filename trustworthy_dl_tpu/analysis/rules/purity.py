@@ -28,12 +28,10 @@ def _module_name(rel: str, package_name: str) -> Optional[str]:
     """Repo-relative path -> dotted module name (package files only)."""
     if not rel.endswith(".py"):
         return None
-    if rel == "bench.py":
-        return "bench"
     parts = rel[:-3].split("/")
     if parts[-1] == "__init__":
         parts = parts[:-1]
-    if parts and (parts[0] == package_name or rel == "bench.py"):
+    if parts and parts[0] == package_name:
         return ".".join(parts)
     return None
 

@@ -261,7 +261,7 @@ class AdaptivePoisonAttacker:
 
 
 class MarginSignatureMonitor:
-    """Deterministic output monitor for drills/bench/envelope cells:
+    """Deterministic output monitor for drills and envelope cells:
     flags iff the request's mean top-1 margin exceeds ``threshold``.
 
     The real :class:`~trustworthy_dl_tpu.serve.engine.OutputMonitor`

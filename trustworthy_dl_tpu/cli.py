@@ -496,8 +496,7 @@ def build_serve_parser() -> argparse.ArgumentParser:
                         help="fleet only: disable live KV block-table "
                              "migration everywhere (drains run out, "
                              "failures replay from the prompt — the "
-                             "pre-migration arcs; escape hatch and "
-                             "bench A/B toggle)")
+                             "pre-migration arcs; escape hatch)")
     parser.add_argument("--hedge-deadline-ms", type=float, default=None,
                         help="fleet only: launch a hedged duplicate on "
                              "a second replica when a request's "

@@ -404,11 +404,6 @@ class ServeConfig:
 
     Unknown dtype strings and bad paged geometry fail HERE, at
     construction — never at trace time inside a jitted serving program.
-
-    ``paged`` is a checked input, not a switch: the benchmark's
-    configuration file still passes ``"paged": true``, so the key is
-    accepted, ``False`` is refused (the per-request stripe pool was
-    removed in PR 33), and nothing reads the field after construction.
     """
 
     max_slots: int = 8
@@ -416,7 +411,6 @@ class ServeConfig:
     queue_limit: int = 64
     kv_dtype: str = "model"
     weight_dtype: str = "model"
-    paged: bool = True
     block_size: int = 16
     num_blocks: Optional[int] = None
     prefix_cache: bool = True
@@ -467,12 +461,6 @@ class ServeConfig:
             raise ValueError(
                 f"attn_impl must be one of ('auto', 'pallas', "
                 f"'interpret', 'jnp'), got {self.attn_impl!r}"
-            )
-        if not self.paged:
-            raise ValueError(
-                "ServeConfig.paged must be True: the stripe pool was "
-                "removed in PR 33; the paged block pool is the only KV "
-                "layout"
             )
         validate_spec(self.spec_k, self.weight_dtype)
         validate_adapters(self.adapter_rank, self.adapter_pool_pages,

@@ -243,9 +243,8 @@ def place_zero_sharded(tree: Any, mesh: Mesh,
 
 def tree_bytes_per_device(tree: Any) -> int:
     """Actual per-device bytes of a placed pytree: each leaf contributes
-    its shard size on the busiest device (replicated leaves count fully).
-    The bench's ``params_bytes_per_device`` — measured from shardings,
-    not estimated."""
+    its shard size on the busiest device (replicated leaves count fully),
+    measured from shardings, not estimated."""
     total = 0
     for leaf in jax.tree_util.tree_leaves(tree):
         sh = getattr(leaf, "sharding", None)

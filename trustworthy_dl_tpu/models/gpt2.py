@@ -147,11 +147,11 @@ def _auto_attention(q, k, v, causal=True):
 
 
 # lm_head_chunk="auto": chunk width used when the predicate picks the
-# fused path (the bench-swept sweet spot), and the per-node materialised-
-# logits budget above which it engages.  By arithmetic, 4 nodes × b16 ×
-# T512 × V50257 bf16 logits are ~0.82 GiB/node and b32/node ~1.65
-# GiB/node; 1 GiB/node splits the two.  Where the crossover really lies
-# on the chip is not measured (PERF.md).
+# fused path, and the per-node materialised-logits budget above which
+# it engages.  By arithmetic, 4 nodes × b16 × T512 × V50257 bf16
+# logits are ~0.82 GiB/node and b32/node ~1.65 GiB/node; 1 GiB/node
+# splits the two.  Where the crossover really lies on the chip is not
+# measured (PERF.md).
 AUTO_CE_CHUNK = 8192
 AUTO_CE_MAX_LOGITS_BYTES = 1 << 30
 

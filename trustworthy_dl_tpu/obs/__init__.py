@@ -83,7 +83,7 @@ from trustworthy_dl_tpu.obs.registry import (
     get_registry,
 )
 from trustworthy_dl_tpu.obs.report import PHASES, StepTimeReporter, \
-    mfu_from_throughput, peak_flops_per_chip
+    peak_flops_per_chip
 from trustworthy_dl_tpu.obs.session import ObsSession
 from trustworthy_dl_tpu.obs.slo import (
     P2Quantile,
@@ -131,7 +131,6 @@ __all__ = [
     "get_registry",
     "live_buffer_bytes",
     "load_incidents",
-    "mfu_from_throughput",
     "peak_flops_per_chip",
     "perf_fingerprint",
     "read_jsonl_rotated",

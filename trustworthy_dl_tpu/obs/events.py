@@ -260,7 +260,7 @@ EVENT_SCHEMAS: Dict[EventType, Dict[str, tuple]] = {
     EventType.TRACE_ROTATE: {"requires": (),
                              "fields": ("path", "segment")},
     # Forensics: an incident names the artifact it wrote (path is None
-    # in the in-memory bench mode) and the registered reason that
+    # in the in-memory mode) and the registered reason that
     # triggered assembly; a verdict names the (kind, outcome) pair the
     # VerdictStore recorded — same label the tddl_verdicts_total
     # counter pages on.

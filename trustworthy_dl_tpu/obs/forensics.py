@@ -135,8 +135,8 @@ def blast_radius(records: Iterable[Dict[str, Any]],
 class IncidentAssembler:
     """Joins the run's artifacts into one incident JSON per episode.
 
-    ``directory=None`` is the in-memory mode (bench arms): incidents
-    are assembled and counted but no file is written.  Trace events
+    ``directory=None`` is the in-memory mode (drills): incidents are
+    assembled and counted but no file is written.  Trace events
     resolve from, in order: an explicit ``events=`` list passed to
     :meth:`assemble`, a ``trace`` object exposing ``.events`` (the
     test RecordingTrace) or ``.jsonl_path`` (a TraceBus), or
@@ -170,8 +170,8 @@ class IncidentAssembler:
         self._run_meta = run_meta
         self._lock = threading.Lock()
         self._index = 0
-        #: (incident_id, reason) in assembly order — the bench's counts
-        #: source when no directory is attached.
+        #: (incident_id, reason) in assembly order — the counts source
+        #: when no directory is attached.
         self.incidents: List[Dict[str, str]] = []
         self._incident_counter = None
         if registry is not None:

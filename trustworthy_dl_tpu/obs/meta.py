@@ -4,8 +4,8 @@ VERDICT weak #5: experiment artifacts shipped without platform /
 jax-version metadata, so a published number could not be tied to the
 hardware that produced it (MLPerf-style run stamping — PAPERS.md).
 ``run_metadata()`` is the one shared helper; the fast-tier contract test
-(tests/test_obs.py) fails any ``experiments/`` or ``bench.py`` artifact
-writer that does not reference it.
+(tests/test_obs.py) fails any ``experiments/`` artifact writer that does
+not reference it.
 
 Device discovery is cached per process (``jax.devices()`` initialises
 the backend).  A backend that cannot start raises here as it would

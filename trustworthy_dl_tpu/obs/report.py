@@ -4,7 +4,7 @@ A utilization figure needs an artifact saying where the rest of the
 time goes.  This module is that artifact's producer: named-phase
 wall-clock accounting on the host step loop, and model-FLOPs utilization
 computed from the model config — attached by the trainer
-(``--obs-dir``), bench.py and the experiment runner.
+(``--obs-dir``) and the experiment runner.
 
 Phase semantics (the canonical names in :data:`PHASES`):
 
@@ -15,9 +15,8 @@ Phase semantics (the canonical names in :data:`PHASES`):
   incident records, synchronous loop), ``host`` (async-pipeline drain:
   time blocked on the lagged metrics landing + the host bookkeeping —
   the number the pipeline exists to collapse; compare it across
-  ``async_host_depth`` 0 vs K in ``bench.py``'s ``TDDL_BENCH_ASYNC=1``
-  A/B), ``checkpoint`` — are accounted by :class:`StepTimeReporter` per
-  step, from the loop's ``lap`` calls.
+  ``async_host_depth`` 0 vs K), ``checkpoint`` — are accounted by
+  :class:`StepTimeReporter` per step, from the loop's ``lap`` calls.
 * What a step's laps do not cover arrives as SPANS
   (``utils.profiling.span(name, timer)`` → :meth:`record_span`): the
   pieces of a lap (``train.data_wait``, ``train.batch_place``,
@@ -93,24 +92,13 @@ def peak_flops_per_chip(device_kind: str) -> "tuple[float, str]":
         "to PEAK_FLOPS_BF16 with its source")
 
 
-def mfu_from_throughput(n_params: int, tokens_per_s_per_chip: float,
-                        device_kind: Optional[str] = None) -> Dict[str, Any]:
-    """MFU block from an already-measured throughput (bench.py's path)."""
-    if device_kind is None:
-        from trustworthy_dl_tpu.obs.meta import run_metadata
+def _this_chip_peak() -> "tuple[str, float, str]":
+    """(device kind, peak FLOP/s, source) of the chip this process runs
+    on."""
+    from trustworthy_dl_tpu.obs.meta import run_metadata
 
-        device_kind = run_metadata()["device_kind"]
-    peak, source = peak_flops_per_chip(device_kind)
-    achieved = 6.0 * float(n_params) * float(tokens_per_s_per_chip)
-    return {
-        "n_params": int(n_params),
-        "tokens_per_s_per_chip": float(tokens_per_s_per_chip),
-        "model_flops_per_s_per_chip": achieved,
-        "peak_flops_per_chip": peak,
-        "peak_flops_source": source,
-        "device_kind": device_kind,
-        "mfu": achieved / peak if peak > 0 else None,
-    }
+    device_kind = run_metadata()["device_kind"]
+    return (device_kind, *peak_flops_per_chip(device_kind))
 
 
 class StepTimeReporter:
@@ -344,12 +332,20 @@ class StepTimeReporter:
         if self.has_model_info and steps:
             mean_step = out["step_time_s"]["mean"]
             if self.model_kind == "lm" and self.tokens_per_step:
-                tokens_per_s = self.tokens_per_step / mean_step
-                out["mfu"] = mfu_from_throughput(
-                    self.n_params, tokens_per_s / self.num_chips
-                )
-                out["mfu"]["tokens_per_step"] = self.tokens_per_step
-                out["mfu"]["num_chips"] = self.num_chips
+                device_kind, peak, source = _this_chip_peak()
+                per_chip = self.tokens_per_step / mean_step / self.num_chips
+                achieved = 6.0 * float(self.n_params) * per_chip
+                out["mfu"] = {
+                    "n_params": int(self.n_params),
+                    "tokens_per_s_per_chip": per_chip,
+                    "model_flops_per_s_per_chip": achieved,
+                    "peak_flops_per_chip": peak,
+                    "peak_flops_source": source,
+                    "device_kind": device_kind,
+                    "mfu": achieved / peak if peak > 0 else None,
+                    "tokens_per_step": self.tokens_per_step,
+                    "num_chips": self.num_chips,
+                }
             else:
                 # No comparable FLOPs-per-sample formula for convs; the
                 # report still carries the throughput inputs.
@@ -413,10 +409,7 @@ class StepTimeReporter:
         mean_step = (out.get("step_time_s") or {}).get("mean")
         if not flops or not mean_step:
             return None
-        from trustworthy_dl_tpu.obs.meta import run_metadata
-
-        device_kind = run_metadata()["device_kind"]
-        peak, source = peak_flops_per_chip(device_kind)
+        _, peak, source = _this_chip_peak()
         achieved = flops / mean_step / max(self.num_chips, 1)
         return {
             "flops_per_step": flops,

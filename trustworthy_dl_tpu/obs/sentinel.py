@@ -1,18 +1,17 @@
 """Perf regression sentinel: a rolling fingerprint ledger + noise-band
 comparison, so "did this run get slower than the last one?" has a
-machine answer instead of a human rereading BENCH_r*.json.
+machine answer.
 
-Every finishing run appends one compact **fingerprint** — tokens/s,
-step time, phase fractions, compile counts/seconds, HBM watermark —
-to ``PERF_LEDGER.jsonl`` (``ObsSession.finalize`` for instrumented
-runs, ``bench.py`` for bench rounds, each under its own ``key`` so a
-cpu debug round never bands against a TPU round).  The sentinel
-compares a fresh fingerprint against the ledger's recent entries for
-the same key: a metric outside ``max(nsigma·std, rel_floor·mean)`` of
-the baseline mean in its BAD direction is a regression — typed
-``perf_regression`` events, ``tddl_perf_regressions_total{metric=}``,
-and (for bench, behind ``TDDL_BENCH_SENTINEL=1``) a non-zero exit the
-CI can gate on.
+Every finishing instrumented run (``ObsSession.finalize``) appends one
+compact **fingerprint** — tokens/s, step time, phase fractions, compile
+counts/seconds, HBM watermark — to its perf ledger (``PERF_LEDGER.jsonl``
+beside the run's artifacts unless ``TDDL_PERF_LEDGER`` names a file),
+under a ``key`` that scopes comparability so a cpu debug round never
+bands against a TPU round.  The sentinel compares a fresh
+fingerprint against the ledger's recent entries for the same key: a
+metric outside ``max(nsigma·std, rel_floor·mean)`` of the baseline mean
+in its BAD direction is a regression — typed ``perf_regression``
+events and ``tddl_perf_regressions_total{metric=}``.
 
 Entirely host-side and jax-free: the ``trustworthy-dl-obs diff A B``
 subcommand renders two artifact sets (obs_report.json / ledger
@@ -39,43 +38,6 @@ SENTINEL_METRICS: Dict[str, str] = {
     "compile_total": "lower",
     "compile_seconds": "lower",
     "hbm_watermark_bytes": "lower",
-    # Speculative-decode draft quality: the fraction of drafted tokens
-    # the model-dtype verify accepted.  A draft-quality regression
-    # (quantization drift, a draft/verify numerics split) pages exactly
-    # like a throughput regression — tokens/s would eventually show it,
-    # but accepted_rate names the cause.
-    "accepted_rate": "higher",
-    # Decode-phase share of the serve wall (the ``serve.decode_tick``
-    # spans' seconds / elapsed).  A silent fall-back from the paged-attention kernel to
-    # the jnp gather path (gate flipped, geometry stopped tiling,
-    # backend change) inflates exactly this number — it pages like a
-    # perf regression even while tokens/s noise hides it, and the
-    # tddl_serve_attn_kernel{path=} gauge names the culprit.
-    "decode_tick_fraction": "lower",
-    # Prefill-chunk and speculative-verify shares of the serve wall —
-    # the same silent-downgrade story as decode_tick_fraction, one per
-    # new kernel program: the chunked-prefill flash program falling
-    # back to the gathered-view jnp path inflates the prefill share,
-    # the fused verify tail falling back to materialise-then-reduce
-    # inflates the verify share.  The per-program
-    # tddl_serve_attn_kernel{path=,program=} gauge names the culprit.
-    "prefill_chunk_fraction": "lower",
-    "spec_verify_fraction": "lower",
-    # Adapter-pool locality (pool hits / lookups) and the equal-HBM
-    # personalisation cost (adapter-arm tokens/s over base-arm tokens/s
-    # at the SAME budget, TDDL_BENCH_ADAPTERS rounds).  A colder pool
-    # (eviction thrash after a Zipf-shape shift) or a pricier gathered
-    # low-rank path both band — and name their cause — before the
-    # headline tokens/s notices.
-    "adapter_hit_rate": "higher",
-    "adapter_tokens_ratio": "higher",
-    # Live-migration success under capacity loss (migrations over
-    # migrations + replay failovers in the TDDL_BENCH_MIGRATE drain
-    # arm).  A structural regression — pool-geometry drift breaking
-    # ``can_migrate``, a claim path that starts refusing — silently
-    # degrades every capacity loss back to prompt replay; the fraction
-    # bands (and names the cause) before goodput noise shows it.
-    "migration_fraction": "higher",
 }
 
 
@@ -86,13 +48,6 @@ def fingerprint(source: str, *, metric: Optional[str] = None,
                 compile_total: Optional[int] = None,
                 compile_seconds: Optional[float] = None,
                 hbm_watermark_bytes: Optional[int] = None,
-                accepted_rate: Optional[float] = None,
-                decode_tick_fraction: Optional[float] = None,
-                prefill_chunk_fraction: Optional[float] = None,
-                spec_verify_fraction: Optional[float] = None,
-                adapter_hit_rate: Optional[float] = None,
-                adapter_tokens_ratio: Optional[float] = None,
-                migration_fraction: Optional[float] = None,
                 run_metadata: Optional[Dict[str, Any]] = None,
                 extra: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
     """One compact perf fingerprint.  ``key`` scopes comparability:
@@ -117,14 +72,7 @@ def fingerprint(source: str, *, metric: Optional[str] = None,
                         ("step_time_s", step_time_s),
                         ("compile_total", compile_total),
                         ("compile_seconds", compile_seconds),
-                        ("hbm_watermark_bytes", hbm_watermark_bytes),
-                        ("accepted_rate", accepted_rate),
-                        ("decode_tick_fraction", decode_tick_fraction),
-                        ("prefill_chunk_fraction", prefill_chunk_fraction),
-                        ("spec_verify_fraction", spec_verify_fraction),
-                        ("adapter_hit_rate", adapter_hit_rate),
-                        ("adapter_tokens_ratio", adapter_tokens_ratio),
-                        ("migration_fraction", migration_fraction)):
+                        ("hbm_watermark_bytes", hbm_watermark_bytes)):
         if value is not None:
             fp[name] = float(value)
     if phase_fractions:
@@ -335,8 +283,6 @@ def _flatten_perf(view: Dict[str, Any]) -> "List[Tuple[str, Any]]":
     add("hbm_watermark_bytes",
         hbm.get("watermark_bytes", fp.get("hbm_watermark_bytes")))
     add("tokens_per_s", fp.get("tokens_per_s"))
-    add("accepted_rate", fp.get("accepted_rate"))
-    add("decode_tick_fraction", fp.get("decode_tick_fraction"))
     return rows
 
 

@@ -96,8 +96,8 @@ class ObsSession:
         # watcher pair and the HBM monitor.  The cost ledger defaults ON
         # for artifact-producing sessions (obs_dir set) and OFF for
         # in-memory ones: its one lowering per analyzed program is cheap
-        # but not free, and a bench arm's ObsSession(None) must not pay
-        # it inside a measured loop.
+        # but not free, and an in-memory ObsSession(None) inside a
+        # measured loop must not pay it.
         self.compiles: Any = None         # obs.compilewatch.CompileRegistry
         self.compilewatch: Any = None     # obs.compilewatch.CompileWatcher
         self.hbm: Any = None              # obs.hbm.HbmMonitor

@@ -179,7 +179,7 @@ class SLORule:
 
 def default_serve_rules(ttft_target_s: float = 2.0,
                         itl_target_s: float = 0.25) -> Tuple[SLORule, ...]:
-    """The serving defaults the CLI/bench install: generous enough that
+    """The serving defaults the CLI installs: generous enough that
     a healthy engine never trips them, tight enough that a degrading
     engine (slow-but-completing requests) does.  TTFT/ITL are observed
     at retirement, so a FULLY wedged loop emits no observations — that
@@ -342,7 +342,7 @@ class SLOWatcher:
 
     def status(self) -> Dict[str, Any]:
         """JSON-serialisable rollup: per-rule burn + per-signal sketch
-        (what the CLI prints and the bench stamps)."""
+        (what the CLI prints)."""
         with self._lock:
             return {
                 "rules": [{

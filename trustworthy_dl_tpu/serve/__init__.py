@@ -32,7 +32,7 @@ answer, shaped for XLA's static-shape world:
   outcomes.
 * ``workload``  — seeded traffic generator (bursty arrivals,
   heavy-tailed prompt/output lengths, tenant priority skew) for the
-  scenario battery and the ``TDDL_BENCH_FLEET`` sweep.
+  CLI's ``serve --fleet-replicas`` path and the fleet drills.
 
 The int8 quantization tier (``quant/``, ``ServeConfig.kv_dtype`` /
 ``weight_dtype``) roughly halves KV bytes per slot (per-(head, position)

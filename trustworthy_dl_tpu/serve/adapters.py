@@ -127,7 +127,7 @@ def materialize_adapter(name: str, cfg: gpt2.GPT2Config, rank: int,
     """Deterministic per-tenant weights: (a [L, 2, D, r], b [L, 2, r, D])
     f32, drawn from a generator seeded by the adapter id alone.  In a
     real deployment these load from a registry; here the registry is a
-    seeded RNG so drills, benches and every fleet replica agree
+    seeded RNG so drills, tests and every fleet replica agree
     bit-for-bit on what tenant X's model delta IS."""
     rng = np.random.default_rng(_adapter_seed(name))
     d = cfg.n_embd

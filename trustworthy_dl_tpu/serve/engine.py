@@ -26,7 +26,7 @@ side.
 Serving metrics flow through ``utils.metrics.MetricsCollector``: per
 iteration (slot occupancy, queue depth, tokens emitted) and per request
 (TTFT, ITLs); ``metrics_summary()`` reports tokens/s and p50/p99
-inter-token latency — the numbers the bench serve leg records.
+inter-token latency.
 """
 
 from __future__ import annotations
@@ -359,8 +359,8 @@ class ServingEngine:
         # eager greedy-token probe against the full-precision path, with
         # automatic fallback to the model-dtype pool on failure (the
         # same always-safe-swap pattern as flash_attention's non-tiling
-        # fallback).  ``kv_parity_check=False`` skips the probe (bench
-        # arms that construct many engines).
+        # fallback).  ``kv_parity_check=False`` skips the probe (tests
+        # that construct many engines).
         q8.validate_dtypes(kv_dtype, weight_dtype)
         # Speculative decoding (README §Serving/"Speculative decoding"):
         # the same loud knob validation ServeConfig runs, so engines
@@ -377,7 +377,7 @@ class ServingEngine:
         # The decode view is built at most ONCE here and shared with the
         # parity probe, the scheduler (its ``view=`` kwarg) and the
         # weight-error histogram — quantize_decode_view walks every block
-        # matrix, and bench arms construct engines in a loop.
+        # matrix, and a fleet constructs engines in a loop.
         base_view = None
         view = None
         if weight_dtype == "int8" or (kv_dtype == "int8" and kv_parity_check):
@@ -695,10 +695,10 @@ class ServingEngine:
                     path=_path, program=_program, **self._rlabels,
                 )
         # Speculative-decode surface: drafted vs accepted tokens (their
-        # ratio is the accepted_rate the bench A/B and the perf sentinel
-        # track).  Registered on every engine — replica-labelled in
-        # fleet mode like the rest of the tddl_serve_* gauges — and
-        # incremented only when the spec tier runs.
+        # ratio is the summary's accepted_rate).  Registered on every
+        # engine — replica-labelled in fleet mode like the rest of the
+        # tddl_serve_* gauges — and incremented only when the spec tier
+        # runs.
         self._spec_proposed_counter = _metric(
             registry.counter, "tddl_serve_spec_proposed_total",
             "Draft tokens proposed by the speculative int8 self-draft",

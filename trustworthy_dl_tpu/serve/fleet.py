@@ -246,7 +246,7 @@ class FleetConfig:
     #: long decodes, and the autoscaler (when configured) scales each
     #: pool INDEPENDENTLY from its own pool-local signals.
     pool_roles: Optional[Tuple[str, ...]] = None
-    #: Operator escape hatch (and the bench A/B toggle): ``False``
+    #: Operator escape hatch: ``False``
     #: restores the pre-migration arcs everywhere — drains run out or
     #: replay, preemptions replay, disaggregated rebalance is inert —
     #: without touching any other knob.
@@ -720,7 +720,7 @@ class ServingFleet:
         from trustworthy_dl_tpu.obs.slo import StreamingPercentiles
 
         self._itl_est = StreamingPercentiles()
-        #: (tick, in-service replicas) on every change — the bench's
+        #: (tick, in-service replicas) on every change — the fleet's
         #: replica-count trace.  Bounded: a pathological flap cannot
         #: grow host memory without bound.
         self.replica_trace: List[Tuple[int, int]] = []

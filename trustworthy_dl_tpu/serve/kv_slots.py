@@ -122,8 +122,7 @@ def kv_bytes_per_token(cfg: Any, kv_dtype: Optional[Any] = None) -> int:
 def paged_pool_blocks(cfg: Any, hbm_bytes: int, block_size: int,
                       kv_dtype: Optional[Any] = None) -> int:
     """Largest USABLE block count whose paged pool (including the +1
-    trash block the layout always carries) fits in ``hbm_bytes`` — the
-    pool-sizing helper the bench's equal-HBM paged arm uses."""
+    trash block the layout always carries) fits in ``hbm_bytes``."""
     if block_size < 1:
         raise ValueError(f"block_size must be >= 1, got {block_size}")
     per_block = block_size * kv_bytes_per_token(cfg, kv_dtype)

@@ -437,8 +437,8 @@ def _programs() -> Dict[str, Any]:
         # construction-resolved per-program paths: the jit cache keys on
         # them, so a kernel-on engine and a jnp-fallback engine with
         # identical geometry trace separate programs instead of silently
-        # aliasing each other through this process-global table (bench
-        # A/B arms and the kernel tests depend on that).
+        # aliasing each other through this process-global table (the
+        # kernel tests depend on that).
         # The token carry rides as a keyword and is donated by name, as
         # the recurrent state is (None, and no buffer, for a description
         # without one).
@@ -1732,12 +1732,12 @@ class PagedBatchingScheduler:
     @property
     def accepted_rate(self) -> float:
         """Fraction of PROPOSABLE drafted tokens that became emitted
-        stream tokens — the draft-quality headline the bench A/B and
-        the perf sentinel fingerprint track.  The denominator is
-        budget-clamped per slot (min(k, remaining-1)), so the rate
-        measures int8-draft-vs-target agreement, not how short the
-        workload's requests were; eos truncation still counts against
-        it (an eos is a property of the stream both arms share)."""
+        stream tokens — the draft-quality headline of the engine's
+        summary.  The denominator is budget-clamped per slot
+        (min(k, remaining-1)), so the rate measures int8-draft-vs-target
+        agreement, not how short the workload's requests were; eos
+        truncation still counts against it (an eos is a property of the
+        stream both arms share)."""
         return (self.spec_accepted / self.spec_proposed
                 if self.spec_proposed else 0.0)
 

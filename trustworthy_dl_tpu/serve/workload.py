@@ -15,8 +15,8 @@ of users" actually looks like, reduced to its three awkward properties —
 
 Everything is driven by one ``numpy`` generator seeded from the config,
 so a workload is reproducible from its config alone (the same contract
-as ``chaos.FaultPlan``): drills and the ``TDDL_BENCH_FLEET`` sweep
-replay identical traffic on every arm.
+as ``chaos.FaultPlan``): the drills and the CLI replay identical traffic
+from one config.
 """
 
 from __future__ import annotations
@@ -77,7 +77,7 @@ class WorkloadConfig:
     #: pages << adapters forces real eviction traffic, while the hot
     #: head keeps the hit rate meaningful.  Assignment is part of the
     #: seeded workload contract (same config -> same tenant->adapter
-    #: map), so A/B bench arms replay identical adapter churn.
+    #: map), so two runs of one config replay identical adapter churn.
     num_adapters: int = 0
     adapter_zipf: float = 1.1       # Zipf exponent over adapter ranks
     #: Bimodal prompt mixture (0 = off, the lognormal above unchanged):
@@ -138,8 +138,8 @@ def zipf_adapter_assignments(tenant_names: Sequence[str],
     that exercises an adapter pool's LRU (pages << adapters) without
     killing its hit rate.  The draw stream is its OWN generator (seeded
     off ``seed``), so adding adapters to a workload config never
-    perturbs the arrival/length draws of the base traffic — the
-    adapter-off and adapter-on bench arms replay IDENTICAL request
+    perturbs the arrival/length draws of the base traffic — an
+    adapter-off and an adapter-on run replay IDENTICAL request
     schedules.  This is the one spelling of the assignment; the engine's
     ``adapter_map`` kwarg consumes it verbatim."""
     if num_adapters < 1:
@@ -155,8 +155,8 @@ def zipf_adapter_assignments(tenant_names: Sequence[str],
 def make_tenant_population(n: int, base: str = "tenant",
                            zipf: float = 1.2) -> Tuple[Tenant, ...]:
     """``n`` tenants with Zipf arrival weights (rank-1 heaviest) — the
-    many-tenant population the adapter-pool bench arms drive, where
-    DEFAULT_TENANTS' three classes are too few to churn a pool."""
+    many-tenant population an adapter pool needs, where DEFAULT_TENANTS'
+    three classes are too few to churn it."""
     if n < 1:
         raise ValueError("need n >= 1 tenants")
     return tuple(Tenant(f"{base}-{k}", weight=float((k + 1) ** -zipf))
@@ -223,7 +223,7 @@ def replay_workload(target: Any, items: Sequence[WorkloadItem],
     each item is submitted when the wall clock passes its arrival time,
     the target is stepped while busy, and idle gaps before the next
     arrival sleep instead of spinning empty ticks.  ONE spelling of the
-    driver loop for the bench sweep and the CLI.  Returns how many
+    driver loop for the CLI and the drills.  Returns how many
     submissions were accepted (backpressure sheds return None)."""
     t0 = time.perf_counter()
     pending = list(items)
@@ -253,15 +253,14 @@ def drive_closed_loop(target: Any, items: Sequence[WorkloadItem],
     ServingEngine), submitting from ``items`` in order as capacity
     frees and ticking the target every iteration.
 
-    This is the saturating driver the adversary bench introduced (PR
-    12) and the autoscale/overload drills need: an open-loop wall-clock
-    replay only loads a degraded/scaling fleet on a machine-specific
-    service-rate knife edge, while a closed loop keeps backpressure —
-    and therefore routing, throttling and scaling decisions — engaged
-    deterministically, tick for tick.  ONE spelling shared by
-    ``bench.py``, the drills and the CLI.  A submission the target
-    refuses (engine backpressure or a tenant-bucket throttle) is
-    retried on a later tick; a head item the target refuses for
+    This is the saturating driver the adversary and autoscale/overload
+    drills need: an open-loop wall-clock replay only loads a
+    degraded/scaling fleet on a machine-specific service-rate knife
+    edge, while a closed loop keeps backpressure — and therefore
+    routing, throttling and scaling decisions — engaged
+    deterministically, tick for tick.  A submission the target refuses
+    (engine backpressure or a tenant-bucket throttle) is retried on a
+    later tick; a head item the target refuses for
     ``max_refused_ticks`` CONSECUTIVE ticks is dropped (logged, not
     counted accepted) — a permanently-throttled item (cost above its
     tenant's bucket capacity, zero refill) must not head-of-line-block

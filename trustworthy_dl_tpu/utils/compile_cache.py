@@ -8,8 +8,8 @@ path is stable: the path is part of what a hit needs, so a temporary,
 per-run or per-process directory never hits.
 
 :func:`configure_compile_cache` is the one place that decides, and every
-entry point (the CLI, ``bench.py``, ``chip_smoke.py``, the tests) calls
-it before its first compile:
+entry point (the CLI, ``benchmark/run.py``, ``chip_smoke.py``, the tests)
+calls it before its first compile:
 
 * ``JAX_COMPILATION_CACHE_DIR`` set — JAX's own handling of the variable
   stands, and nothing here touches ``jax_compilation_cache_dir``;
